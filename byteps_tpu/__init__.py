@@ -16,12 +16,6 @@ byteps_tpu.torch.
 
 __version__ = "0.1.0"
 
-# Version-compat shims must land before any submodule touches jax
-# (common/jax_compat.py: jax.shard_map spelling/keyword drift).
-from byteps_tpu.common.jax_compat import install as _install_jax_compat
-
-_install_jax_compat()
-
 from byteps_tpu.core.api import (  # noqa: F401
     init,
     shutdown,

@@ -33,7 +33,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .mesh import CommContext, DCN_AXIS, ICI_AXIS
-from ..common import jax_compat as _jax_compat
+from ..common.logging import get_logger
 from ..common.telemetry import counters
 from ..fault import injector as _fault
 
@@ -51,12 +51,7 @@ def _cached(comm: CommContext, key, builder):
         # Miss counting is unconditional: the zero-new-compiles-after-
         # warmup contract (tests/test_aot_planner.py) reads this counter.
         counters.inc("engine.compile_cache_miss")
-        built = builder()
-        # legacy-runtime serial mode (jax_compat): executions of compiled
-        # programs hold the process lock; identity on modern runtimes.
-        # Scalar cache entries are arrays, not programs — left bare.
-        fn = comm.jit_cache[key] = (
-            _jax_compat.serialize(built) if callable(built) else built)
+        fn = comm.jit_cache[key] = builder()
     else:
         # Hit counting rides the dispatch hot path (several lookups per
         # push); one uncontended mutex inc is ~0.5 µs against ~1 ms of
@@ -84,7 +79,9 @@ def aot_compile(comm: CommContext, key, arg_structs) -> bool:
     against the warmed signature and falls back to the lazy wrapper on
     mismatch — correctness identical, only the warm's speedup scoped to
     the signature it compiled.  Returns False (leaving the lazy wrapper
-    untouched) when the runtime cannot lower ahead of time.
+    untouched, so the program still compiles lazily) when the compiler
+    refuses the program; the refusal is logged with its message and
+    counted in ``engine.aot_compile_failed``.
     """
     fn = comm.jit_cache.get(key)
     if fn is None:
@@ -92,9 +89,12 @@ def aot_compile(comm: CommContext, key, arg_structs) -> bool:
     if getattr(fn, "_bps_aot", False) or not hasattr(fn, "lower"):
         return True                    # already warmed (or a scalar)
     try:
-        compiled = _jax_compat.serialize(fn.lower(*arg_structs).compile())
-    except Exception:  # noqa: BLE001 — legacy runtimes / odd shardings
+        compiled = fn.lower(*arg_structs).compile()
+    except Exception as e:  # noqa: BLE001 — lazy jit stays as the fallback
         counters.inc("engine.aot_compile_failed")
+        get_logger().warning(
+            "AOT compile of %r failed; the program compiles lazily: "
+            "%s: %s", key, type(e).__name__, e)
         return False
     sig = tuple((tuple(s.shape), np.dtype(s.dtype)) for s in arg_structs)
     lazy = fn

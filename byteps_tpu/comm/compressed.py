@@ -93,13 +93,9 @@ def _fused_fn(comm: CommContext, worker_comp: Compressor,
 
         in_specs = tuple([P(axes), P()] + [P(axes)] * nw + [P()] * ns)
         out_specs = tuple([P()] + [P(axes)] * nw + [P()] * ns)
-        built = jax.jit(jax.shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=comm.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False))
-        # legacy-runtime serial mode (common/jax_compat.py): no-op wrap
-        # on modern runtimes
-        from ..common import jax_compat
-        return jax_compat.serialize(built)
 
     # Keyed by config, not object identity: same-config chunks (e.g. N
     # equal-shaped layers, or equal-length chunks of one tensor) share

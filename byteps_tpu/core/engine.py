@@ -39,7 +39,6 @@ from ..comm.compressed import (aot_warm_compressed_programs,
                                fused_compressed_push_pull)
 from ..comm.mesh import CommContext
 from ..compression import registry as compression_registry
-from ..common import jax_compat
 from ..common.config import Config
 from ..common.handles import Handle, HandleManager
 from ..common.logging import get_logger
@@ -351,8 +350,10 @@ class PushPullEngine:
                 from ..native import NativeChunkScheduler
                 return NativeChunkScheduler(
                     credit_bytes=cfg.scheduling_credit)
-            except Exception:  # noqa: BLE001 - toolchain may be absent
-                get_logger().info("falling back to Python chunk scheduler")
+            except Exception as e:  # noqa: BLE001 - toolchain may be absent
+                get_logger().warning(
+                    "native chunk scheduler unavailable, using the Python "
+                    "scheduler: %s: %s", type(e).__name__, e)
         return ChunkScheduler(credit_bytes=cfg.scheduling_credit)
 
     # ------------------------------------------------------------------ API
@@ -877,11 +878,8 @@ class PushPullEngine:
                         self.tracer.record_span(
                             "engine.aot_warm", t0, time.monotonic(),
                             tensor=name, programs=n_compiled)
-            except Exception:  # noqa: BLE001 — warm is an optimization
-                counters.inc("engine.aot_compile_failed")
-                get_logger().debug(
-                    "compressed AOT warm failed for %s; programs compile "
-                    "lazily", name, exc_info=True)
+            except Exception as e:  # noqa: BLE001 — lazy jit is the fallback
+                self._aot_warm_failed("compressed", name, e)
             return ctx
         if local is None:
             local = jax.process_count() == 1
@@ -899,10 +897,8 @@ class PushPullEngine:
                     self.tracer.record_span(
                         "engine.aot_warm", t0, time.monotonic(),
                         tensor=name, programs=n_compiled)
-        except Exception:  # noqa: BLE001 — warm is an optimization only
-            counters.inc("engine.aot_compile_failed")
-            get_logger().debug("AOT warm failed for %s; programs compile "
-                               "lazily", name, exc_info=True)
+        except Exception as e:  # noqa: BLE001 — lazy jit is the fallback
+            self._aot_warm_failed("chunk-program", name, e)
         return ctx
 
     def declare_update(self, name: str, shape, dtype=np.float32, *,
@@ -960,12 +956,19 @@ class PushPullEngine:
             if n:
                 get_logger().debug(
                     "AOT-compiled sharded-update program for %s", name)
-        except Exception:  # noqa: BLE001 — warm is an optimization only
-            counters.inc("engine.aot_compile_failed")
-            get_logger().debug(
-                "sharded-update AOT warm failed for %s; the program "
-                "compiles lazily", name, exc_info=True)
+        except Exception as e:  # noqa: BLE001 — lazy jit is the fallback
+            self._aot_warm_failed("sharded-update", name, e)
         return ctx
+
+    @staticmethod
+    def _aot_warm_failed(what: str, name: str, exc: Exception) -> None:
+        """A declare-time warm that raised: the programs still compile
+        lazily at first dispatch, but a refusal by the compiler must be
+        seen — counted, and logged with the compiler's message."""
+        counters.inc("engine.aot_compile_failed")
+        get_logger().warning(
+            "%s AOT warm failed for %s; programs compile lazily: %s: %s",
+            what, name, type(exc).__name__, exc)
 
     def push_pull_update_async(self, x, name: str, *,
                                stacked: bool = False, **kw) -> Handle:
@@ -1462,14 +1465,8 @@ class PushPullEngine:
                             "queue",
                             (head.t_dispatch - head.t_enqueue) * 1e3)
                     self.step_stats.note_retire(tasks[-1].name)
-                # Legacy-runtime serial mode (common/jax_compat.py): the
-                # callbacks below run eager assembly ops on this thread
-                # while the dispatcher executes programs on its own — the
-                # exact concurrency the old CPU runtime deadlocks on.
-                # Null context on modern runtimes.
                 t_fb0 = time.perf_counter() if self.cfg.telemetry_on else 0.0
-                with jax_compat.runtime_lock():
-                    self._finish_batch(tasks, out, err)
+                self._finish_batch(tasks, out, err)
                 if self.cfg.telemetry_on:
                     # assembly + callback wall: the retirement work after
                     # the device block — the tail segment of a push's

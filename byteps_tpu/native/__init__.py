@@ -23,6 +23,7 @@ _SRC = os.path.join(os.path.dirname(__file__), "core.cc")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_load_error = ""  # one-line cause of the latched failure, for callers' logs
 
 
 def _build_path() -> str:
@@ -42,7 +43,7 @@ def _compile(out: str) -> None:
 def load() -> Optional[ctypes.CDLL]:
     """Return the native core library, building it on first use; None when
     disabled or the build fails (callers fall back to Python)."""
-    global _lib, _load_failed
+    global _lib, _load_failed, _load_error
     if _lib is not None:
         return _lib
     if _load_failed:
@@ -69,14 +70,27 @@ def load() -> Optional[ctypes.CDLL]:
             if lib.bps_native_abi_version() != 4:
                 raise RuntimeError("native ABI mismatch")
             _lib = lib
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — Python twins take over
             _load_failed = True
+            _load_error = _describe_failure(e)
             from ..common.logging import get_logger
             get_logger().warning(
-                "native core unavailable (build or load failed); "
-                "using pure-Python scheduler/reducer", exc_info=True)
+                "native core unavailable, using the pure-Python "
+                "scheduler/reducer: %s", _load_error)
             return None
     return _lib
+
+
+def _describe_failure(exc: Exception) -> str:
+    """The build/load failure in one line: the compiler's own last
+    message for a failed g++ run, the missing tool, or the loader error."""
+    if isinstance(exc, subprocess.CalledProcessError):
+        tail = (exc.stderr or "").strip().splitlines()
+        return (f"g++ exited {exc.returncode} building core.cc: "
+                f"{tail[-1] if tail else 'no compiler output'}")
+    if isinstance(exc, FileNotFoundError):
+        return f"g++ not found ({exc})"
+    return f"{type(exc).__name__}: {exc}"
 
 
 def available() -> bool:
@@ -154,7 +168,9 @@ class NativeChunkScheduler:
                  = None):
         self._lib = lib or load()
         if self._lib is None:
-            raise RuntimeError("native core not available")
+            raise RuntimeError(
+                "native core not available"
+                + (f" ({_load_error})" if _load_error else ""))
         self._h = self._lib.bps_sched_create(credit_bytes)
         self._tasks = {}
         self._next_id = 0

@@ -22,9 +22,10 @@ AxisNames = Union[str, Sequence[str]]
 # (global.cc:137-139); here the knob gates the DCN hop specifically, because
 # the measured crossover is about wire time vs compression compute: on the
 # 8-device CPU mesh the onebit hop LOSES below ~2 MB/shard and wins above
-# (BENCH_r02: 4 MB/rank = 1 MB shard -> 32.5 vs 21.6 ms; 16 MB/rank = 4 MB
-# shard -> compressed faster; docs/performance.md has the table).  On real
-# DCN the crossover is lower (wire is slower), so the env override matters.
+# (a round-2 CPU-mesh run: 4 MB/rank = 1 MB shard -> 32.5 vs 21.6 ms;
+# 16 MB/rank = 4 MB shard -> compressed faster; docs/performance.md has the
+# table).  The crossover on real DCN is not measured; the env override
+# exists for when it is.
 DCN_COMPRESS_MIN_BYTES = 2 * 1024 * 1024
 
 

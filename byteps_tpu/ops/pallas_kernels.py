@@ -162,8 +162,7 @@ def onebit_unpack_sum(words, scales, interpret: bool = False):
 
 
 def on_tpu() -> bool:
-    """True when the default backend is a real TPU (kernels engaged)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when the default backend is a real TPU (kernels engaged).  A
+    backend that fails to come up raises here: answering False would
+    silently select interpret mode / the jnp codec on a broken chip."""
+    return jax.default_backend() == "tpu"

@@ -3,7 +3,7 @@
 The reference delegates data loading to the frameworks' loaders
 (torchvision/gluon in its examples); on TPU the equivalent gap is the
 host->device edge: a training loop that calls ``device_put`` inline
-serializes the PCIe/tunnel transfer with the step it feeds.  This module
+serializes the host-to-device transfer with the step it feeds.  This module
 overlaps them:
 
 - :func:`prefetch_to_device` wraps any host-batch iterator: a background

@@ -3,7 +3,7 @@ README.md:35-41 / BASELINE.md) on the byteps_tpu fused DP path.
 
 Run:  python example/jax/benchmark_bert.py [--steps N] [--batch B]
       [--seq L] [--compress-dcn]  (onebit on the inter-slice hop)
-CPU smoke uses bert_tiny automatically.
+      [--tiny]  (bert_tiny at seq 32, batch 2: the CPU smoke size)
 """
 
 import os
@@ -12,9 +12,9 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
-from example._common import honor_jax_platforms  # noqa: E402
+from byteps_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
-honor_jax_platforms()
+enable_compile_cache()
 
 import argparse
 import time
@@ -31,22 +31,27 @@ from byteps_tpu.parallel import make_dp_train_step, replicate, shard_batch
 
 
 def main():
-    on_tpu = jax.devices()[0].platform == "tpu"
     ap = argparse.ArgumentParser()
-    ap.add_argument("--steps", type=int, default=20 if on_tpu else 3)
-    ap.add_argument("--batch", type=int, default=32 if on_tpu else 2)
-    ap.add_argument("--seq", type=int, default=128 if on_tpu else 32)
+    ap.add_argument("--tiny", action="store_true",
+                    help="bert_tiny at smoke sizes instead of BERT-large")
+    ap.add_argument("--steps", type=int, default=None)
+    ap.add_argument("--batch", type=int, default=None, help="per device")
+    ap.add_argument("--seq", type=int, default=None)
     ap.add_argument("--compress-dcn", action="store_true")
     args = ap.parse_args()
+    d_steps, d_batch, d_seq = (3, 2, 32) if args.tiny else (20, 32, 128)
+    steps = args.steps or d_steps
+    per_dev = args.batch or d_batch
+    seq = args.seq or d_seq
 
     bps.init()
     comm = get_comm()
     n = comm.num_ranks
-    cfg = bert_large() if on_tpu else bert_tiny()
+    cfg = bert_tiny() if args.tiny else bert_large()
     model = BertForMLM(cfg)
     rng = jax.random.PRNGKey(0)
-    gb = args.batch * n
-    batch = synthetic_batch(rng, cfg, batch=gb, seq_len=args.seq)
+    gb = per_dev * n
+    batch = synthetic_batch(rng, cfg, batch=gb, seq_len=seq)
     params = model.init(rng, batch["input_ids"][:1],
                         batch["attention_mask"][:1])
     tx = optax.adamw(1e-4)
@@ -69,13 +74,15 @@ def main():
     params, opt_state, loss = step(params, opt_state, batch)  # compile
     jax.block_until_ready(loss)
     t0 = time.perf_counter()
-    for _ in range(args.steps):
+    for _ in range(steps):
         params, opt_state, loss = step(params, opt_state, batch)
     jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
-    eps = args.steps * gb / dt
+    eps = steps * gb / dt
+    dev = jax.devices()[0]
     print(f"loss {float(loss):.4f}  {eps:.1f} examples/s "
-          f"({eps / n:.1f}/chip, {n} chips)")
+          f"({eps / n:.1f}/device, {n} x {dev.platform} "
+          f"{dev.device_kind})")
     bps.shutdown()
 
 

@@ -18,9 +18,9 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
-from example._common import honor_jax_platforms  # noqa: E402
+from byteps_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
-honor_jax_platforms()
+enable_compile_cache()
 
 import argparse
 import time

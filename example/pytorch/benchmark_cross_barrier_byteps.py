@@ -12,10 +12,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
-from example._common import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
-
 import argparse
 import time
 

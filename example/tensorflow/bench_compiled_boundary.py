@@ -29,10 +29,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
-from example._common import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
-
 
 def _model(tf):
     # a real (if small) model: 4-block MLP-mixer-ish tower, ~1.1M params

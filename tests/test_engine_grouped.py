@@ -17,7 +17,6 @@ from byteps_tpu.common import Config
 from byteps_tpu.common.config import set_config
 from byteps_tpu.core.engine import _plan_batch, _pow2_split
 from byteps_tpu.common.types import ChunkTask
-from .conftest import legacy_skip
 
 
 # ---------------------------------------------------------------- planning
@@ -234,7 +233,6 @@ def test_drain_mixed_dtypes_and_ints_still_exact(no_session):
         assert np.asarray(h.wait()).dtype == xs[n].dtype
 
 
-@legacy_skip  # old XLA does not combine the k all-reduces into one
 def test_batched_program_is_one_module_with_combined_collective():
     # Wire-level proof of "one dispatch executes k chunks": the batched
     # program compiles to ONE XLA module, and XLA's all-reduce combiner

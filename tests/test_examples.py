@@ -7,8 +7,6 @@ import sys
 import pytest
 import runpy
 
-from .conftest import legacy_skip
-
 
 def _run(path, *argv):
     old = sys.argv
@@ -21,7 +19,8 @@ def _run(path, *argv):
 
 @pytest.mark.parametrize("path,argv", [
     ("example/jax/train_mnist_mlp.py", ("--steps", "2", "--batch", "2")),
-    ("example/jax/benchmark_bert.py", ("--steps", "1", "--batch", "1")),
+    ("example/jax/benchmark_bert.py",
+     ("--tiny", "--steps", "1", "--batch", "1")),
     ("example/jax/benchmark_resnet.py",
      ("--model", "tiny", "--batch", "1", "--size", "16", "--steps", "1")),
     ("example/jax/train_llama.py",
@@ -37,11 +36,9 @@ def _run(path, *argv):
      ("--mode", "zero", "--steps", "2", "--batch", "8", "--seq", "16")),
     ("example/jax/train_parallel_axes.py",
      ("--mode", "fsdp", "--steps", "2", "--batch", "8", "--seq", "16")),
-    pytest.param(
-        "example/jax/train_parallel_axes.py",
-        ("--mode", "3d", "--steps", "2", "--batch", "8", "--seq", "16",
-         "--microbatches", "2"),
-        marks=legacy_skip),  # 3d composite diverges on pre-VMA shard_map
+    ("example/jax/train_parallel_axes.py",
+     ("--mode", "3d", "--steps", "2", "--batch", "8", "--seq", "16",
+      "--microbatches", "2")),
     ("example/jax/train_long_context.py",
      ("--steps", "2", "--seq", "128", "--sp", "4", "--tiny",
       "--batch", "4")),

@@ -18,7 +18,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from byteps_tpu.parallel.expert import (
     DP_AXIS, EP_AXIS, init_moe_params, make_dp_ep_train_step, make_ep_mesh,
     moe_mlp, moe_mlp_reference, shard_moe_params)
-from .conftest import legacy_skip
 
 H, F, E = 16, 32, 8
 
@@ -78,7 +77,6 @@ def test_distributed_matches_reference_per_shard(n_ep, n_dp):
         np.testing.assert_allclose(aux[g], float(ref_aux), rtol=1e-5)
 
 
-@legacy_skip  # reference-gradient match diverges on pre-VMA shard_map
 def test_dp_ep_training_matches_reference_gradients():
     """One step of the (dp, ep) trainer == one step of the hand-built
     mean-of-shards objective on one device."""

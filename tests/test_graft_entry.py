@@ -7,10 +7,8 @@ import subprocess
 import sys
 
 import pytest
-from .conftest import legacy_skip
 
 
-@legacy_skip  # dry-run subprocess uses bare jax.shard_map
 def test_dryrun_multichip_8():
     sys.path.insert(0, "/root/repo")
     from __graft_entry__ import dryrun_multichip

@@ -19,7 +19,6 @@ from byteps_tpu.parallel.fsdp_tp import (
     make_fsdp_tp_mesh, make_fsdp_tp_train_step, shard_llama_batch,
     shard_llama_params)
 from byteps_tpu.parallel.long_context import synthetic_lm_batch
-from .conftest import legacy_skip
 
 
 def _cfg():
@@ -153,7 +152,6 @@ def test_rules_cover_the_sharded_layers():
     assert fsdp_tp_spec_for("wte/embedding") == P(TP_AXIS, FSDP_AXIS)
 
 
-@legacy_skip  # sharded-init tracking needs modern shard_map
 def test_sharded_init_never_materializes_unsharded():
     """init_llama_params_sharded births every weight on its (fsdp, tp)
     placement and matches the shard-after-init route bit for bit."""

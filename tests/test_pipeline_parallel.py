@@ -17,7 +17,6 @@ from byteps_tpu.parallel.long_context import synthetic_lm_batch
 from byteps_tpu.parallel.pipeline import (
     init_pipeline_params, make_dp_pp_train_step, make_pp_mesh,
     pipeline_params_to_gpt, shard_pipeline_params, shard_pp_batch)
-from .conftest import legacy_skip
 
 
 def _cfg(num_layers=4):
@@ -44,7 +43,6 @@ def test_restack_roundtrip():
 
 
 @pytest.mark.parametrize("n_pp,microbatches", [(4, 4), (2, 2), (4, 8)])
-@legacy_skip  # exact-match numerics diverge on pre-VMA shard_map
 def test_pp_training_matches_single_device(n_pp, microbatches):
     cfg = _cfg(num_layers=4)
     rng = jax.random.PRNGKey(1)

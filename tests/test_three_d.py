@@ -20,7 +20,6 @@ from byteps_tpu.parallel.pipeline import (init_pipeline_params,
 from byteps_tpu.parallel.three_d import (init_3d_opt_state, make_3d_mesh,
                                          make_dp_pp_tp_train_step,
                                          shard_3d_batch, shard_3d_params)
-from .conftest import legacy_skip
 
 
 def _cfg(num_layers=4):
@@ -31,7 +30,6 @@ def _cfg(num_layers=4):
 
 @pytest.mark.parametrize("n_pp,n_tp,microbatches", [(2, 2, 2), (2, 4, 4),
                                                     (4, 2, 2)])
-@legacy_skip  # exact-match numerics diverge on pre-VMA shard_map
 def test_3d_training_matches_single_device(n_pp, n_tp, microbatches):
     cfg = _cfg(num_layers=4)
     rng = jax.random.PRNGKey(1)
@@ -113,7 +111,6 @@ def test_pp_step_body_reuse_unchanged():
     assert np.isfinite(float(loss))
 
 
-@legacy_skip  # repro subprocess uses bare jax.shard_map
 def test_bf16_partial_manual_psum_canary():
     """Canary for the XLA CPU bug that forces f32 on the 3D path.
 

@@ -300,19 +300,12 @@ def validate_wire_formula():
 
 
 def _measured_throughput():
-    """Per-chip examples/s from the latest green TPU run — read from
-    BENCH_TPU_MEASURED.json so the projection tracks the hardware record
-    instead of going stale; conservative fallback if absent."""
-    path = os.path.join(REPO, "BENCH_TPU_MEASURED.json")
-    try:
-        with open(path) as f:
-            line = json.load(f)["line"]
-        v = float(line["value"])
-        batch = 32  # bench.py per_dev_batch on TPU
-        if v > 0:
-            return v, batch
-    except Exception:  # noqa: BLE001 - fall through to the recorded value
-        pass
+    """(per-chip examples/s, per-chip batch) the analytic projection is
+    anchored to.  526.41 ex/s at batch 32 is the one fused BERT-large
+    seq-128 step ever timed on a v5e chip (2026-07-30, round-4 code; the
+    record file is gone, ROADMAP.md quotes the line).  It is history,
+    not a measurement of today's code: until the benchmark (ROADMAP S1)
+    supplies a ledger figure the projection stays a projection."""
     return 526.41, 32
 
 
