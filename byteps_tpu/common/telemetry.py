@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import threading
 import time
 from typing import Callable, Deque, Dict, List, Optional, Tuple
@@ -48,12 +49,15 @@ from . import tracing as _tracing
 # (tools/bpslint metric-name rule) and every name is greppable.
 ATTRIB_GAUGE_NAMES = {
     "enqueue": "step.attrib_enqueue_ms",
+    "submit": "step.attrib_submit_ms",
+    "wait": "step.attrib_wait_ms",
     "queue": "step.attrib_queue_ms",
     "credit": "step.attrib_credit_ms",
     "wire": "step.attrib_wire_ms",
     "merge": "step.attrib_merge_ms",
     "sync": "step.attrib_sync_ms",
     "compile": "step.attrib_compile_ms",
+    "plan": "step.attrib_plan_ms",
     "dispatch": "step.attrib_dispatch_ms",
     "assemble": "step.attrib_assemble_ms",
     "other": "step.attrib_other_ms",
@@ -67,8 +71,7 @@ class AttributionSink:
     Components that happen OFF the engine's own threads — the sealed
     envelope wire hops (``wire``, incl. retransmit rounds), the server
     engine's merge work (``merge``), scheduler credit-gated waits
-    (``credit``), compile stalls detected on the dispatch path
-    (``compile``) — land here as they occur; the active
+    (``credit``) — land here as they occur; the active
     :class:`StepStatsTracker` snapshots the totals at each step boundary
     and publishes the per-step deltas as ``step.attrib_*`` gauges.  One
     lock + one dict add per event: cheap enough to stay unconditional
@@ -196,6 +199,15 @@ class StepStats:
     # sharded-update pulls at the owner-slice/codec-payload size) — the
     # figure the sharded-vs-unsharded bench ratio is computed from
     wire_bytes_per_step: int = 0
+    # ISSUE 23: the engine's own share of the step — wall inside
+    # ``byteps_tpu.jax.push_pull`` (first leaf enqueued -> last handle
+    # returned), summed over the step's calls; ``wall_ms`` runs push to
+    # push and so holds the user's gradient program and apply too
+    push_pull_ms: float = 0.0
+    # device programs launched / chunk tasks they consumed this step
+    # (the step's deltas of ``PushPullEngine.stats``)
+    dispatches: int = 0
+    chunks: int = 0
 
     def as_dict(self) -> Dict[str, float]:
         return dataclasses.asdict(self)
@@ -215,15 +227,21 @@ class StepStatsTracker:
     surface), the flight recorder (``step_stats`` events), and a
     bounded in-process history for bench summaries."""
 
-    def __init__(self, history: int = 64, recorder=None):
+    def __init__(self, history: int = 64, recorder=None,
+                 engine_stats: Optional[Dict[str, int]] = None):
         self._lock = threading.Lock()
         self._counts: Dict[str, int] = {}
         self._step = 0
-        self._t0 = time.perf_counter()
+        self._t0 = time.monotonic()
         self._bytes = 0
         self._pushes = 0
         self._stall_ms = 0.0
+        self._push_pull_ms = 0.0
         self._wire = 0
+        # the engine's live {"dispatches", "chunks"} totals, read at
+        # each step boundary (None: a tracker with no engine behind it)
+        self._engine_stats = engine_stats
+        self._units0 = (0, 0)
         self._retx0 = counters.get("integrity.retransmit")
         self._history: Deque[StepStats] = collections.deque(maxlen=history)
         # step-attribution state (ISSUE 12): baseline of the process-wide
@@ -240,7 +258,9 @@ class StepStatsTracker:
 
     # -- feeding -----------------------------------------------------------
 
-    def on_push(self, name: str, nbytes: int) -> None:
+    def on_push(self, name: str, nbytes: int) -> int:
+        """Returns the tensor's push count — the step this push belongs
+        to, which the engine's phase spans carry."""
         with self._lock:
             self._counts[name] = self._counts.get(name, 0) + 1
             step = self._counts[name]
@@ -253,16 +273,22 @@ class StepStatsTracker:
                     # is no ordering cycle to invert)
                     self._publish(self._finalize_locked())
                 self._step = step
-                self._t0 = time.perf_counter()
+                self._t0 = time.monotonic()
                 # flight-recorder stamp: every recorded event from here
                 # on carries this step even with tracing off
                 _tracing.note_step(step)
             self._bytes += int(nbytes)
             self._pushes += 1
+            return step
 
     def add_stall(self, ms: float) -> None:
         with self._lock:
             self._stall_ms += ms
+
+    def add_push_pull(self, ms: float) -> None:
+        """Caller feed: wall of one whole tree-level push_pull."""
+        with self._lock:
+            self._push_pull_ms += ms
 
     def add_wire(self, nbytes: int) -> None:
         """Syncer feed: wire bytes (push + pull legs) of each retired
@@ -276,6 +302,18 @@ class StepStatsTracker:
         with self._lock:
             self._comp[component] = self._comp.get(component, 0.0) + ms
 
+    def feed(self, component: str) -> Callable[[float], None]:
+        """Where a ``tracing.phase`` of this name sends its
+        milliseconds: ``add_component`` bound to the component, but
+        :meth:`add_stall` for ``sync`` (which is ``sync_stall_ms`` too)
+        and :meth:`add_push_pull` for ``push_pull`` (a field of its own,
+        not a component)."""
+        if component == "sync":
+            return self.add_stall
+        if component == "push_pull":
+            return self.add_push_pull
+        return functools.partial(self.add_component, component)
+
     def note_retire(self, name: str) -> None:
         """The syncer names each retired unit's tensor; the last one
         standing when the step finalizes is the lagging tensor."""
@@ -285,18 +323,23 @@ class StepStatsTracker:
     # -- finalization ------------------------------------------------------
 
     def _finalize_locked(self) -> StepStats:
-        wall_ms = max((time.perf_counter() - self._t0) * 1e3, 1e-6)
+        wall_ms = max((time.monotonic() - self._t0) * 1e3, 1e-6)
         retx = counters.get("integrity.retransmit")
         # Per-step attribution (ISSUE 12): deltas of the process-wide
-        # sink (wire / merge / credit / compile / dispatch) + locally
-        # fed components (enqueue / queue / assemble) + the syncer's
-        # block time (sync).  "other" is max(0, wall - sum): components
+        # sink (wire / merge / credit) + the engine's phases (enqueue /
+        # submit / wait / plan / dispatch / compile / assemble, fed by
+        # tracing.phase) + queue + the syncer's block time (sync).
+        # "other" is max(0, wall - sum) over everything but ``wait`` —
+        # a blocked caller is the other threads' work seen from outside,
+        # and counting it twice would zero the residual: components
         # are wall-time integrals of each activity, so on a serialized
         # profile they partition the step, while pipelined units or
         # parallel merge/wire threads can overlap and push the sum PAST
         # the wall (other clamps at 0) — documented in
         # docs/observability.md.
         now_tot = attribution.totals()
+        es = self._engine_stats
+        units = (es["dispatches"], es["chunks"]) if es else (0, 0)
         attrib: Dict[str, float] = {}
         for k in set(now_tot) | set(self._attrib0):
             d = now_tot.get(k, 0.0) - self._attrib0.get(k, 0.0)
@@ -305,7 +348,7 @@ class StepStatsTracker:
         for k, v in self._comp.items():
             attrib[k] = attrib.get(k, 0.0) + v
         attrib["sync"] = attrib.get("sync", 0.0) + self._stall_ms
-        known = sum(attrib.values())
+        known = sum(v for k, v in attrib.items() if k != "wait")
         attrib["other"] = max(0.0, wall_ms - known)
         attrib = {k: round(v, 3) for k, v in attrib.items()}
         stats = StepStats(
@@ -320,7 +363,12 @@ class StepStatsTracker:
             attrib=attrib,
             lagging_tensor=self._last_retired,
             wire_bytes_per_step=self._wire,
+            push_pull_ms=round(self._push_pull_ms, 3),
+            dispatches=units[0] - self._units0[0],
+            chunks=units[1] - self._units0[1],
         )
+        self._units0 = units
+        self._push_pull_ms = 0.0
         self._bytes = 0
         self._pushes = 0
         self._stall_ms = 0.0
@@ -340,6 +388,9 @@ class StepStatsTracker:
         gauges.set("step.wall_ms", stats.wall_ms)
         gauges.set("step.overlap_fraction", stats.overlap_fraction)
         gauges.set("step.wire_bytes_per_step", stats.wire_bytes_per_step)
+        gauges.set("step.push_pull_ms", stats.push_pull_ms)
+        gauges.set("step.dispatches", stats.dispatches)
+        gauges.set("step.chunks", stats.chunks)
         for comp, ms in stats.attrib.items():
             # KeyError here is deliberate: a new attribution component
             # must be added to ATTRIB_GAUGE_NAMES (and the doc table) —

@@ -40,6 +40,16 @@ ISSUE 12 additions — the causal layer on top of the per-process timeline:
   ``fault.membership.estimate_clock_offset`` over the ``ping`` verb), so
   ``tools/bps_trace.py`` can merge N per-rank files onto one aligned
   timeline.
+
+ISSUE 23 — :class:`phase`, the engine-mode step's phase primitive: every
+phase boundary of the step (adapter, enqueue, submit, wait, plan,
+dispatch, sync, assemble) is one ``with phase(...)`` that records the
+phase twice — as a ``jax.profiler.TraceAnnotation`` on the thread doing
+the work, so it lands in whatever profiler session the process is under
+(the benchmark's ``--trace 1``, ``BYTEPS_TRACE_JAX``, a profiler server)
+on the clock the device's ops are on, and as milliseconds fed to the
+step's record in ``StepStatsTracker``.  The chrome timeline above is a
+separate, per-push operator's view and is untouched by it.
 """
 
 from __future__ import annotations
@@ -52,7 +62,9 @@ import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceMe
 
 from .config import get_config
 from .logging import get_logger
@@ -158,6 +170,59 @@ def clock_offset() -> Dict[str, object]:
         return dict(_clock)
 
 
+# -- the step's phases: profiler span + per-step counter ---------------------
+
+
+class phase:
+    """One phase of the engine-mode step, recorded twice.
+
+    ``with phase("bps.engine.submit", feed) as ph:`` opens a
+    ``jax.profiler.TraceAnnotation`` of that name on the calling thread
+    when a profiler session is recording (``ph.ann``; None, and nothing
+    built, otherwise) and, on exit, hands the phase's milliseconds to
+    ``ph.feed`` (a bound ``StepStatsTracker`` method, or None with
+    telemetry off).  TraceMe stamps its own clock and takes no
+    timestamps from outside, so the two records are taken back to back
+    from one enter/exit pair: the annotation opens just before the
+    ``time.monotonic`` stamp ``t0`` and closes just after ``t1``, and
+    the two agree to about a microsecond.
+
+    Arguments (the step, the tensor) go on with :meth:`note`, any time
+    before exit — TraceMe fixes an event's NAME at construction, so what
+    is only known at the end (the step an ``update()`` landed in,
+    whether a dispatch compiled) can only be an argument.  Per-leaf and
+    per-unit sites guard it with ``if ph.ann is not None`` so that no
+    keyword dict is built outside a session: the three threads share
+    one interpreter lock with the step's critical path, and on the v5e
+    host every microsecond of Python in a phase showed 1:1 in the step
+    (PERF.md §6, PR 23) — which is also why this takes no ``**args``
+    and computes nothing it is not asked for."""
+
+    __slots__ = ("feed", "ann", "t0", "t1")
+
+    def __init__(self, name: str,
+                 feed: Optional[Callable[[float], None]] = None):
+        self.feed = feed
+        self.ann = _TraceMe(name) if _TraceMe.is_enabled() else None
+
+    def __enter__(self) -> "phase":
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = t1 = time.monotonic()
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
+        if self.feed is not None:
+            self.feed((t1 - self.t0) * 1e3)
+        return False
+
+    def note(self, **args) -> None:
+        """Attach arguments to the span (nothing outside a session)."""
+        if self.ann is not None:
+            self.ann.set_metadata(**args)
+
+
 # -- flow ids ----------------------------------------------------------------
 
 _flow_counter = itertools.count(1)
@@ -217,8 +282,9 @@ class Tracer:
         self._anchor_wall = time.time()
         self._anchor_mono = time.monotonic()
         # BYTEPS_TRACE_JAX: run jax.profiler over the same step window, so
-        # the device-side timeline (XLA ops, transfers) lands next to the
-        # host-side comm trace — the reference's timeline shows only the
+        # the device-side timeline (XLA ops, transfers) and the engine's
+        # bps.* phase spans (:class:`phase`) land next to the host-side
+        # comm trace — the reference's timeline shows only the
         # communication stages; on TPU the device view is the other half.
         self.jax_trace = cfg.trace_jax
         if self.jax_trace and not self.enabled:
@@ -318,7 +384,12 @@ class Tracer:
                 import jax
                 path = os.path.join(self.out_dir, "jax_profile")
                 os.makedirs(path, exist_ok=True)
-                jax.profiler.start_trace(path)
+                # device ops + TraceMe spans (the engine's bps.* phases)
+                # only: the Python tracer, on by default, put 450 k
+                # frame events into a two-step engine trace (PERF.md §6)
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(path, profiler_options=options)
                 self._jax_state = "running"
                 get_logger().info("jax profiler started -> %s", path)
             except Exception:  # noqa: BLE001 - must never kill a run
@@ -589,9 +660,6 @@ class Tracer:
             raise
         get_logger().info("wrote comm trace: %s (%d events)", path, n_out)
         return path
-
-    def now(self) -> float:
-        return time.monotonic()
 
 
 # -- the process-wide tracer -------------------------------------------------

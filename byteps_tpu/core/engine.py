@@ -46,7 +46,7 @@ from ..common.registry import TensorRegistry
 from ..common.scheduler import ChunkPlanner, ChunkScheduler
 from ..common import flight_recorder as _flight
 from ..common import tracing as _tracing
-from ..common.telemetry import (SpeedMonitor, StepStatsTracker, attribution,
+from ..common.telemetry import (SpeedMonitor, StepStatsTracker,
                                 counters, gauges, histograms)
 from ..common.types import ChunkTask, Status, StatusCode, TensorContext
 from ..fault import injector as _fault
@@ -287,7 +287,18 @@ class PushPullEngine:
         # Per-step stats (bytes pushed, sync stall, retransmits, overlap
         # fraction) — surfaced through /metrics (step.* gauges), the
         # flight recorder, and the bench tools (ISSUE 6).
-        self.step_stats = StepStatsTracker()
+        # dispatch amortization accounting: programs launched vs chunk
+        # tasks consumed (the bench's engine_grouped_* evidence; the
+        # tracker publishes each step's deltas)
+        self.stats = {"dispatches": 0, "chunks": 0}
+        self.step_stats = StepStatsTracker(engine_stats=self.stats)
+        # Where each phase of the step (common/tracing.py ``phase``)
+        # sends its milliseconds: the step's attribution component of
+        # the same name; nowhere with telemetry off.
+        self.phase_feeds = {
+            c: self.step_stats.feed(c) if cfg.telemetry_on else None
+            for c in ("push_pull", "enqueue", "submit", "wait", "plan",
+                      "dispatch", "compile", "sync", "assemble")}
         self._sync_q: "queue.Queue" = queue.Queue()
         # group_size < 0 = drain mode (VERDICT r4 task 3): every dispatch
         # iteration empties the whole eligible credit window and executes
@@ -297,9 +308,6 @@ class PushPullEngine:
         self._group_size = (1 if jax.process_count() > 1
                             else (-1 if cfg.group_size < 0
                                   else max(1, cfg.group_size)))
-        # dispatch amortization accounting: programs launched vs chunk
-        # tasks consumed (the bench's engine_grouped_* evidence)
-        self.stats = {"dispatches": 0, "chunks": 0}
         # Auto-tuned chunk/credit planner: measures completed push_pulls
         # and re-carves partition bounds per tensor-size bucket; inert
         # when pinned (env/explicit config) or multi-process (SPMD
@@ -383,6 +391,13 @@ class PushPullEngine:
         """
         if not self._running:
             raise RuntimeError("engine is shut down")
+        # Caller-side prep — validation, planning, staging — until the
+        # tasks enter the queue is the step's "enqueue" phase.  Opened
+        # and closed by hand: it ends mid-function, inside the claim's
+        # try (a raise abandons it unfed).
+        ph_enq = _tracing.phase("bps.engine.enqueue",
+                                self.phase_feeds["enqueue"])
+        ph_enq.__enter__()
         if _membership.is_parked():
             # minority side of a partition: no epoch can be agreed from
             # here, so fail the enqueue loudly instead of queueing work
@@ -511,7 +526,7 @@ class PushPullEngine:
                           and self.planner.locked(est_nbytes)
                           and not self.planner.compress_locked(est_nbytes))
             if track_plan or track_comp:
-                t_plan0 = time.perf_counter()
+                t_plan0 = time.monotonic()
                 miss0 = counters.get("engine.compile_cache_miss")
                 part_used = ctx.partition_bytes
                 codec_used = (ctx.compression_kwargs.get("compressor")
@@ -578,19 +593,12 @@ class PushPullEngine:
                 step, tctx = self.tracer.start_push(name)
             else:  # keep the hot enqueue path lock-free when tracing is off
                 step, tctx = 0, None
-            if tctx is not None or self.cfg.telemetry_on:
-                # caller-side prep starts here: staging/validation wall
-                # until the tasks actually enter the queue is the step's
-                # "enqueue" component (the queued span/queue component
-                # begin at the LATER t_enq stamp, so the two never
-                # double-count)
-                t_api0 = time.monotonic()
-            else:
-                t_api0 = 0.0
             if self.cfg.telemetry_on:
                 # per-step accounting: same per-tensor step definition as
-                # the tracer, independent of the trace window
-                self.step_stats.on_push(name, est_nbytes)
+                # the tracer, independent of the trace window (whose
+                # step, when one is armed, the tasks carry)
+                tstep = self.step_stats.on_push(name, est_nbytes)
+                step = step or tstep
             pending.trace = tctx
             local_mode = local
             if local:
@@ -641,41 +649,46 @@ class PushPullEngine:
                 bounds = col_layout
             else:
                 bounds = ctx.chunk_bounds
-            if t_api0:
-                # tasks enter the queue NOW: the queued span / queue
-                # component start here; the prep above is "enqueue"
-                t_enq = time.monotonic()
-                if self.cfg.telemetry_on:
-                    self.step_stats.add_component(
-                        "enqueue", (t_enq - t_api0) * 1e3)
-            else:
-                t_enq = 0.0
-            for part_idx, (off, ln) in enumerate(bounds):
-                # uncompressed parts mode (debug-sample, odd shapes) needs
-                # the materialized chunk; buffer mode, single-chunk
-                # tensors, and COMPRESSED chunks (whose fused program
-                # slices in-graph from the staged row via offset_elems)
-                # pass the full flat
-                if (nchunks > 1 and not use_buffer
-                        and ctx.compressor is None):
-                    chunk = flat[off:off + ln] if local else flat[:, off:off + ln]
-                else:
-                    chunk = flat
-                task = ChunkTask(
-                    name=name, key=ctx.key_list[part_idx], priority=prio,
-                    version=version, offset_elems=off, num_elems=ln,
-                    nbytes=ctx.chunk_bounds[part_idx][1] * itemsize,
-                    total_parts=nchunks,
-                    data=chunk,
-                    compression=(ctx.compressor[part_idx]
-                                 if ctx.compressor else None),
-                    scale=scale,
-                    pending=pending,
-                    step=step, t_enqueue=t_enq,
-                    trace_id=tctx.trace_id if tctx is not None else 0,
-                )
-                task.callback = self._make_chunk_callback(pending, part_idx)
-                self.scheduler.add_task(task)
+            # tasks enter the queue NOW: the queued span / queue
+            # component start at the stamp that ends "enqueue", so the
+            # two never double-count
+            if ph_enq.ann is not None:
+                ph_enq.note(step=step, tensor=name)
+            ph_enq.__exit__(None, None, None)
+            t_enq = ph_enq.t1
+            with _tracing.phase("bps.engine.submit",
+                                self.phase_feeds["submit"]) as ph_sub:
+                if ph_sub.ann is not None:
+                    ph_sub.note(step=step, tensor=name)
+                for part_idx, (off, ln) in enumerate(bounds):
+                    # uncompressed parts mode (debug-sample, odd shapes)
+                    # needs the materialized chunk; buffer mode,
+                    # single-chunk tensors, and COMPRESSED chunks (whose
+                    # fused program slices in-graph from the staged row
+                    # via offset_elems) pass the full flat
+                    if (nchunks > 1 and not use_buffer
+                            and ctx.compressor is None):
+                        chunk = (flat[off:off + ln] if local
+                                 else flat[:, off:off + ln])
+                    else:
+                        chunk = flat
+                    task = ChunkTask(
+                        name=name, key=ctx.key_list[part_idx],
+                        priority=prio, version=version, offset_elems=off,
+                        num_elems=ln,
+                        nbytes=ctx.chunk_bounds[part_idx][1] * itemsize,
+                        total_parts=nchunks,
+                        data=chunk,
+                        compression=(ctx.compressor[part_idx]
+                                     if ctx.compressor else None),
+                        scale=scale,
+                        pending=pending,
+                        step=step, t_enqueue=t_enq,
+                        trace_id=tctx.trace_id if tctx is not None else 0,
+                    )
+                    task.callback = self._make_chunk_callback(pending,
+                                                              part_idx)
+                    self.scheduler.add_task(task)
             # Auto-release on completion: the manager tracks only outstanding
             # work, so direct handle.wait() users don't leak table entries.
             # The same hook closes the planner's measurement window and frees
@@ -688,13 +701,13 @@ class PushPullEngine:
                     # charged to the codec it actually ran under
                     self.planner.observe_compression(
                         est_nbytes, codec_used,
-                        time.perf_counter() - t_plan0,
+                        time.monotonic() - t_plan0,
                         compiled=counters.get("engine.compile_cache_miss")
                         != miss0)
                 if track_plan and h.status.code == StatusCode.OK:
                     self.planner.observe(
                         est_nbytes, part_used,
-                        time.perf_counter() - t_plan0,
+                        time.monotonic() - t_plan0,
                         compiled=counters.get("engine.compile_cache_miss")
                         != miss0)
                     if self.planner.locked(est_nbytes) and self.tracer.active:
@@ -1186,99 +1199,114 @@ class PushPullEngine:
             task = self.scheduler.get_task(block=True)
             if task is None:
                 continue
-            if _fault.ENABLED:
-                # chaos site "dispatch": delay/straggler stalls issue order
-                _fault.fire("dispatch")
-            # Chunk-group batching (reference BYTEPS_NCCL_GROUP_SIZE,
-            # nccl_manager.cc:130-134): opportunistically pop whatever else
-            # is already eligible, then merge neighbors into the fewest
-            # device programs (_plan_batch).  Popping preserves priority
-            # order; merging only ever joins neighbors in that order.
-            # group_size=-1 drains the ENTIRE eligible credit window per
-            # iteration (one program per mergeable run); a positive value
-            # caps the pop count.  Multi-host runs keep group_size=1 (the
-            # reference pins followers to the root's order via DO_*
-            # socket signals, communicator.h:43).
-            drain = self._group_size < 0
-            # Drain bound = the queue depth at drain START (snapshot
-            # semantics): tasks enqueued while we pop wait for the next
-            # iteration, so a fast producer can neither defer the popped
-            # head's dispatch indefinitely nor grow the batch without
-            # limit (the credit window, when set, additionally gates each
-            # pop inside get_task).
-            limit = self.scheduler.pending if drain else self._group_size - 1
-            batch = [task]
-            while len(batch) - 1 < limit:
-                t2 = self.scheduler.get_task(block=False)
-                if t2 is None:
-                    break
-                batch.append(t2)
-            # Membership-epoch guard: chunks enqueued before a world
-            # change (elastic shrink/rejoin, fault/membership.py) must
-            # not be issued into a mesh that no longer exists — they are
-            # dropped here with an ABORTED status so waiters unblock and
-            # the caller re-pushes under the new epoch.
-            ep = _membership.current_epoch()
-            if any(t.pending is not None and t.pending.mepoch != ep
-                   for t in batch):
-                fresh = []
-                for t in batch:
-                    if t.pending is not None and t.pending.mepoch != ep:
-                        counters.inc("membership.stale_chunks_dropped")
-                        _flight.record("engine.stale_chunk", tensor=t.name,
-                                       key=t.key, enq_epoch=t.pending.mepoch,
-                                       epoch=ep)
-                        self._sync_q.put(([t], None, None,
-                                          _stale_epoch_error(t, ep), 0.0))
-                    else:
-                        fresh.append(t)
-                batch = fresh
-                if not batch:
-                    continue
-            if self.cfg.telemetry_on:
-                # point-in-time dispatch-path gauges (queue depth feeds
-                # the planner/overlap postmortems; sampling here costs
-                # one scheduler lock round-trip per dispatch iteration)
-                gauges.set("engine.sched_pending", self.scheduler.pending)
-                gauges.set("engine.bytes_in_flight",
-                           self.scheduler.bytes_in_flight)
-            for kind, unit in _plan_batch(batch, pow2_runs=drain):
+            # "plan": the pop returned -> the batch is carved into units
+            # (the blocking wait for a task above is idleness, not work)
+            feeds = self.phase_feeds
+            with _tracing.phase("bps.engine.plan", feeds["plan"]) as ph:
+                if ph.ann is not None:
+                    ph.note(step=task.step)
+                units = self._pop_and_plan(task)
+            for kind, unit in units:
                 if self.cfg.telemetry_on:
                     histograms.observe("engine.dispatch_unit_width",
                                        len(unit))
-                    # compile attribution (ISSUE 12): jit compiles are
-                    # synchronous inside the dispatch call (execution is
-                    # async), so a unit whose dispatch crossed a cache
-                    # miss spent its wall time compiling — charge it to
-                    # the step's attrib_compile_ms component
-                    t_d0 = time.perf_counter()
-                    miss0 = counters.get("engine.compile_cache_miss")
-                if kind == "run":
-                    self._dispatch_buffer_run(unit)
-                elif kind == "group":
-                    self._dispatch_parts_group(unit)
-                else:
-                    self._dispatch_single(unit[0])
-                if self.cfg.telemetry_on:
-                    # a unit whose dispatch crossed a cache miss spent
-                    # its wall compiling; otherwise it was ordinary
-                    # program-launch work — both are real critical-path
-                    # segments (dispatch is synchronous, execution async)
-                    dt_d = (time.perf_counter() - t_d0) * 1e3
-                    if (counters.get("engine.compile_cache_miss")
-                            != miss0):
-                        attribution.add("compile", dt_d)
+                # every compile-cache miss adds one program to the mesh's
+                # cache (collectives._cached): its size is the miss
+                # counter without the counter's lock, twice per unit
+                programs = len(self.comm.jit_cache)
+                with _tracing.phase("bps.engine.dispatch",
+                                    feeds["dispatch"]) as ph:
+                    if ph.ann is not None:
+                        ph.note(step=unit[0].step, tensor=unit[0].name,
+                                width=len(unit),
+                                bytes=sum(t.nbytes for t in unit))
+                    # the phase's opening stamp is the unit's dispatch
+                    # time (end of its chunks' queue wait)
+                    if kind == "run":
+                        self._dispatch_buffer_run(unit, ph.t0)
+                    elif kind == "group":
+                        self._dispatch_parts_group(unit, ph.t0)
                     else:
-                        attribution.add("dispatch", dt_d)
+                        self._dispatch_single(unit[0], ph.t0)
+                    if len(self.comm.jit_cache) != programs:
+                        # jit compiles are synchronous inside the
+                        # dispatch call (execution is async), so a unit
+                        # that crossed a cache miss spent its wall
+                        # compiling: it feeds "compile", not "dispatch",
+                        # and its span says so (a TraceMe cannot be
+                        # renamed once open) — both are real
+                        # critical-path segments
+                        ph.feed = feeds["compile"]
+                        ph.note(compiled=1)
 
-    def _dispatch_buffer_run(self, run: List[ChunkTask]):
+    def _pop_and_plan(self, task: ChunkTask):
+        """The dispatcher's work between a successful pop and its first
+        program launch: gather the batch, drop stale chunks, carve
+        dispatch units (``[]`` when every chunk was stale)."""
+        if _fault.ENABLED:
+            # chaos site "dispatch": delay/straggler stalls issue order
+            _fault.fire("dispatch")
+        # Chunk-group batching (reference BYTEPS_NCCL_GROUP_SIZE,
+        # nccl_manager.cc:130-134): opportunistically pop whatever else
+        # is already eligible, then merge neighbors into the fewest
+        # device programs (_plan_batch).  Popping preserves priority
+        # order; merging only ever joins neighbors in that order.
+        # group_size=-1 drains the ENTIRE eligible credit window per
+        # iteration (one program per mergeable run); a positive value
+        # caps the pop count.  Multi-host runs keep group_size=1 (the
+        # reference pins followers to the root's order via DO_*
+        # socket signals, communicator.h:43).
+        drain = self._group_size < 0
+        # Drain bound = the queue depth at drain START (snapshot
+        # semantics): tasks enqueued while we pop wait for the next
+        # iteration, so a fast producer can neither defer the popped
+        # head's dispatch indefinitely nor grow the batch without
+        # limit (the credit window, when set, additionally gates each
+        # pop inside get_task).
+        limit = self.scheduler.pending if drain else self._group_size - 1
+        batch = [task]
+        while len(batch) - 1 < limit:
+            t2 = self.scheduler.get_task(block=False)
+            if t2 is None:
+                break
+            batch.append(t2)
+        # Membership-epoch guard: chunks enqueued before a world
+        # change (elastic shrink/rejoin, fault/membership.py) must
+        # not be issued into a mesh that no longer exists — they are
+        # dropped here with an ABORTED status so waiters unblock and
+        # the caller re-pushes under the new epoch.
+        ep = _membership.current_epoch()
+        if any(t.pending is not None and t.pending.mepoch != ep
+               for t in batch):
+            fresh = []
+            for t in batch:
+                if t.pending is not None and t.pending.mepoch != ep:
+                    counters.inc("membership.stale_chunks_dropped")
+                    _flight.record("engine.stale_chunk", tensor=t.name,
+                                   key=t.key, enq_epoch=t.pending.mepoch,
+                                   epoch=ep)
+                    self._sync_q.put(([t], None, None,
+                                      _stale_epoch_error(t, ep), 0.0))
+                else:
+                    fresh.append(t)
+            batch = fresh
+            if not batch:
+                return []
+        if self.cfg.telemetry_on:
+            # point-in-time dispatch-path gauges (queue depth feeds
+            # the planner/overlap postmortems; sampling here costs
+            # one scheduler lock round-trip per dispatch iteration)
+            gauges.set("engine.sched_pending", self.scheduler.pending)
+            gauges.set("engine.bytes_in_flight",
+                       self.scheduler.bytes_in_flight)
+        return _plan_batch(batch, pow2_runs=drain)
+
+    def _dispatch_buffer_run(self, run: List[ChunkTask], now: float):
         """One device program for a contiguous run of column-slab chunks:
         slice -> reduce-scatter -> write shards into the tensor's
         block-sharded accumulator (donated, in place)."""
         t0 = run[0]
         pending = t0.pending
-        now = (time.monotonic()
-               if self.cfg.telemetry_on or self.tracer.active else 0.0)
         for t in run:
             t.t_dispatch = now
         self.stats["dispatches"] += 1
@@ -1290,20 +1318,18 @@ class PushPullEngine:
                 t0.num_elems, len(run), C, local=pending.local_mode)
             pending.buf = buf
             self._sync_q.put((run, token, None, None,
-                              time.perf_counter()))
+                              time.monotonic()))
         except Exception as e:  # noqa: BLE001
             get_logger().error("dispatch failed for %s: %s", t0.name, e)
             _flight.record("engine.dispatch_failed", tensor=t0.name,
                            error=str(e))
             self._sync_q.put((run, None, None, e, 0.0))
 
-    def _dispatch_parts_group(self, group: List[ChunkTask]):
+    def _dispatch_parts_group(self, group: List[ChunkTask], now: float):
         """One program for k equal-shape uncompressed chunks of distinct
         tensors (push_pull_arrays_batched): one dispatch replaces k, the
         per-chunk results come back separately so every downstream
         consumer (assembly, debug sampling, callbacks) is unchanged."""
-        now = (time.monotonic()
-               if self.cfg.telemetry_on or self.tracer.active else 0.0)
         t0 = group[0]
         for t in group:
             t.t_dispatch = now
@@ -1314,15 +1340,15 @@ class PushPullEngine:
                 self.comm, [t.data for t in group], scale=t0.scale,
                 local=t0.data.ndim == 1)
             self._sync_q.put((group, outs, None, None,
-                              time.perf_counter()))
+                              time.monotonic()))
         except Exception as e:  # noqa: BLE001
             get_logger().error("dispatch failed for %s: %s", t0.name, e)
             _flight.record("engine.dispatch_failed", tensor=t0.name,
                            error=str(e))
             self._sync_q.put((group, None, None, e, 0.0))
 
-    def _dispatch_single(self, task: ChunkTask):
-        task.t_dispatch = time.monotonic()
+    def _dispatch_single(self, task: ChunkTask, now: float):
+        task.t_dispatch = now
         self.stats["dispatches"] += 1
         self.stats["chunks"] += 1
         try:
@@ -1354,7 +1380,7 @@ class PushPullEngine:
                                       keep_acc=True,
                                       local=task.data.ndim == 1)
             self._sync_q.put(([task], out, rollback, None,
-                              time.perf_counter()))
+                              time.monotonic()))
         except Exception as e:  # noqa: BLE001
             get_logger().error("dispatch failed for %s: %s", task.name, e)
             _flight.record("engine.dispatch_failed", tensor=task.name,
@@ -1400,47 +1426,54 @@ class PushPullEngine:
                     with self._sync_block_lock:
                         self._sync_block = (time.monotonic(),
                                             [t.name for t in tasks])
+                head = tasks[0]
+                blocks = err is None
                 try:
-                    t_blk = time.perf_counter()
-                    if _fault.ENABLED:
-                        # chaos site "sync": delay completion -> callback.
-                        # Deliberately inside the timed window: the delay
-                        # is the test double for a wedged collective, so
-                        # it must surface exactly like one — as sync
-                        # stall (overlap collapse, the self-reported
-                        # slowness feed) — not vanish into untimed
-                        # bookkeeping around the block.
-                        _fault.fire("sync")
-                    if err is None:
-                        try:
-                            # For buffer runs ``out`` is the completion
-                            # token, not the buffer: the buffer itself may
-                            # already have been donated into a later
-                            # chunk's program.
-                            self._block(out)
-                        except Exception as e:  # noqa: BLE001
-                            err = e
-                            if rollback is not None:
-                                slot, wst, sst = rollback
-                                slot.wstates = wst
-                                slot.sstate = sst
-                        if self.cfg.telemetry_on:
-                            # time this thread spent BLOCKED on device
-                            # completion — the step's sync-stall share
-                            # (the un-overlapped remainder of
-                            # communication)
-                            dt_blk = time.perf_counter() - t_blk
-                            self.step_stats.add_stall(dt_blk * 1e3)
-                            # slowness feed: this process's own
-                            # data-path latency — the self-reported
-                            # half of gray-failure detection (the bus's
-                            # step-barrier lags are the cross-rank
-                            # half).  Imported lazily: utils pulls in
-                            # checkpoint → core.api, a cycle at engine
-                            # import time.
-                            from ..utils import slowness as _slowness
-                            _slowness.tracker().observe(
-                                self.cfg.host_id, dt_blk, site="sync")
+                    # "sync": time this thread spends BLOCKED on device
+                    # completion — the step's sync-stall share (the
+                    # un-overlapped remainder of communication); a unit
+                    # that failed at dispatch has nothing to block on
+                    # and feeds nothing
+                    with _tracing.phase(
+                            "bps.engine.sync",
+                            self.phase_feeds["sync"] if blocks else None
+                            ) as ph_blk:
+                        if ph_blk.ann is not None:
+                            ph_blk.note(step=head.step, tensor=head.name)
+                        if _fault.ENABLED:
+                            # chaos site "sync": delay completion ->
+                            # callback.  Deliberately inside the timed
+                            # window: the delay is the test double for a
+                            # wedged collective, so it must surface
+                            # exactly like one — as sync stall (overlap
+                            # collapse, the self-reported slowness feed)
+                            # — not vanish into untimed bookkeeping
+                            # around the block.
+                            _fault.fire("sync")
+                        if blocks:
+                            try:
+                                # For buffer runs ``out`` is the
+                                # completion token, not the buffer: the
+                                # buffer itself may already have been
+                                # donated into a later chunk's program.
+                                self._block(out)
+                            except Exception as e:  # noqa: BLE001
+                                err = e
+                                if rollback is not None:
+                                    slot, wst, sst = rollback
+                                    slot.wstates = wst
+                                    slot.sstate = sst
+                    if blocks and self.cfg.telemetry_on:
+                        # slowness feed: this process's own data-path
+                        # latency — the self-reported half of
+                        # gray-failure detection (the bus's step-barrier
+                        # lags are the cross-rank half).  Imported
+                        # lazily: utils pulls in checkpoint → core.api,
+                        # a cycle at engine import time.
+                        from ..utils import slowness as _slowness
+                        _slowness.tracker().observe(
+                            self.cfg.host_id, ph_blk.t1 - ph_blk.t0,
+                            site="sync")
                 finally:
                     if self._deadline_on:
                         with self._sync_block_lock:
@@ -1452,27 +1485,26 @@ class PushPullEngine:
                 if self.cfg.telemetry_on and t_disp:
                     histograms.observe(
                         "engine.unit_sync_ms",
-                        (time.perf_counter() - t_disp) * 1e3)
+                        (time.monotonic() - t_disp) * 1e3)
                 if self.cfg.telemetry_on:
                     # queue-wait attribution: how long this unit's head
                     # chunk sat in the priority queue before dispatch —
                     # plus the lagging-tensor bookkeeping (the LAST
                     # retired unit before a step finalizes names the
                     # chain the step actually waited on)
-                    head = tasks[0]
                     if head.t_dispatch and head.t_enqueue:
                         self.step_stats.add_component(
                             "queue",
                             (head.t_dispatch - head.t_enqueue) * 1e3)
                     self.step_stats.note_retire(tasks[-1].name)
-                t_fb0 = time.perf_counter() if self.cfg.telemetry_on else 0.0
-                self._finish_batch(tasks, out, err)
-                if self.cfg.telemetry_on:
-                    # assembly + callback wall: the retirement work after
-                    # the device block — the tail segment of a push's
-                    # critical path (step attribution, ISSUE 12)
-                    self.step_stats.add_component(
-                        "assemble", (time.perf_counter() - t_fb0) * 1e3)
+                # "assemble": assembly + callback wall, the retirement
+                # work after the device block — the tail segment of a
+                # push's critical path (step attribution, ISSUE 12)
+                with _tracing.phase("bps.engine.assemble",
+                                    self.phase_feeds["assemble"]) as ph:
+                    if ph.ann is not None:
+                        ph.note(step=head.step, tensor=head.name)
+                    self._finish_batch(tasks, out, err)
 
     def _deadline_loop(self):
         """Per-unit sync-deadline watchdog (BYTEPS_SYNC_DEADLINE_S): a

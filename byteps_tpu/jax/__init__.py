@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ..common import tracing as _tracing
 from ..core import api as _api
 from ..ops import push_pull_tree as _traced_push_pull_tree
 
@@ -62,12 +63,30 @@ def push_pull_async(tree, name_prefix: str = "byteps", op: str = "average"
             for n, leaf in zip(names, leaves)]
 
 
+def _enqueue_and_wait(enqueue) -> tuple:
+    """``enqueue()`` every leaf, then block on the handles: the caller
+    thread's two halves of a tree-level push_pull, as the phases
+    ``bps.push_pull`` (whole; ``StepStats.push_pull_ms``) and
+    ``bps.engine.wait`` (blocked; ``attrib["wait"]``).  Returns
+    ``(handles, results)``."""
+    eng = _api._require()
+    feeds = eng.phase_feeds
+    with _tracing.phase("bps.push_pull", feeds["push_pull"]) as ph:
+        handles = enqueue()
+        step = eng.step_stats.current_step
+        ph.note(step=step)
+        with _tracing.phase("bps.engine.wait", feeds["wait"]) as ph_wait:
+            ph_wait.note(step=step)
+            outs = [h.wait() for h in handles]
+    return handles, outs
+
+
 def push_pull(tree, name_prefix: str = "byteps", op: str = "average"):
     """Synchronously reduce a rank-stacked pytree; returns the reduced tree
     (leaves lose their leading rank axis)."""
     treedef = jax.tree_util.tree_structure(tree)
-    handles = push_pull_async(tree, name_prefix, op=op)
-    outs = [h.wait() for h in handles]
+    _, outs = _enqueue_and_wait(
+        lambda: push_pull_async(tree, name_prefix, op=op))
     return jax.tree_util.tree_unflatten(treedef, outs)
 
 
@@ -208,6 +227,14 @@ class DistributedOptimizer:
         updates are zeros (parameters unchanged), matching the reference's
         deferral of push_pull until the boundary pass.
         """
+        with _tracing.phase("bps.adapter.update") as ph:
+            out = self._update(grads, state, params)
+            # the engine step the update landed in is known only now
+            if _api._engine is not None:
+                ph.note(step=_api._engine.step_stats.current_step)
+        return out
+
+    def _update(self, grads, state, params):
         with self._lock:
             if self._bpps > 1:
                 self._accum = grads if self._accum is None else jax.tree.map(
@@ -240,10 +267,9 @@ class DistributedOptimizer:
             eng = _api._require()
             treedef = jax.tree_util.tree_structure(grads)
             leaves = jax.tree_util.tree_leaves(grads)
-            handles = [eng.push_pull_update_async(leaf, name, stacked=True)
-                       for (name, _, _), leaf in zip(self._leaf_meta,
-                                                     leaves)]
-            outs = [h.wait() for h in handles]
+            handles, outs = _enqueue_and_wait(lambda: [
+                eng.push_pull_update_async(leaf, name, stacked=True)
+                for (name, _, _), leaf in zip(self._leaf_meta, leaves)])
             for h in handles:
                 eng.handles.release(h.id)
             return jax.tree_util.tree_unflatten(treedef, outs), state

@@ -128,10 +128,12 @@ def test_flush_carries_merge_metadata(tmp_path):
 
 class _FakeProfiler:
     def __init__(self):
+        import jax
         self.calls = []
+        self.ProfileOptions = jax.profiler.ProfileOptions
 
-    def start_trace(self, path):
-        self.calls.append(("start", path))
+    def start_trace(self, path, profiler_options=None):
+        self.calls.append(("start", path, profiler_options))
 
     def stop_trace(self):
         self.calls.append(("stop",))
@@ -151,6 +153,8 @@ def test_jax_profiler_state_machine(tmp_path, monkeypatch):
     assert tr._jax_state == "running"
     tr.on_push("g")                      # step 3: still inside
     assert [c[0] for c in fake.calls] == ["start"]
+    # device ops and bps.* spans, not Python frames (ISSUE 23)
+    assert fake.calls[0][2].python_tracer_level == 0
     tr.on_push("g")                      # step 4: window closed
     assert tr._jax_state == "done"
     assert [c[0] for c in fake.calls] == ["start", "stop"]
@@ -163,7 +167,9 @@ def test_jax_profiler_start_failure_is_terminal(tmp_path, monkeypatch):
     import jax
 
     class _Broken:
-        def start_trace(self, path):
+        ProfileOptions = jax.profiler.ProfileOptions
+
+        def start_trace(self, path, profiler_options=None):
             raise RuntimeError("no profiler here")
 
     monkeypatch.setattr(jax, "profiler", _Broken())

@@ -89,8 +89,8 @@ def dominant_attrib(summary: dict) -> Optional[dict]:
     series = (summary or {}).get("series") or {}
     best = None
     for key, st in series.items():
-        if not key.startswith("attrib_"):
-            continue
+        if not key.startswith("attrib_") or key == "attrib_wait":
+            continue            # a blocked caller is an effect, not a cause
         mean = float(st.get("mean", 0.0))
         if mean > 0 and (best is None or mean > best["mean_ms"]):
             best = {"component": key[len("attrib_"):],

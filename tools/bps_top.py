@@ -70,12 +70,14 @@ def _attrib_cell(step: dict) -> str:
     (share of step wall time) — the one-glance answer to "what is this
     rank's step time going to".  '-' = no attribution yet (engine idle,
     telemetry off, or a pre-attribution snapshot); 'other' only shows
-    when nothing measured dominates."""
+    when nothing measured dominates, and 'wait' (the caller blocked on
+    the other threads' work) never does."""
     at = step.get("attrib") or {}
     wall = step.get("wall_ms") or 0.0
     if not at or not wall:
         return "-"
-    comps = {k: v for k, v in at.items() if k != "other" and v > 0}
+    comps = {k: v for k, v in at.items()
+             if k not in ("other", "wait") and v > 0}
     if not comps:
         comps = {k: v for k, v in at.items() if v > 0}
     if not comps:
