@@ -24,6 +24,7 @@ axis 0 over the whole mesh; the reduced result is replicated.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -437,12 +438,14 @@ def aot_warm_buffer_programs(comm: CommContext, *, col_layout, C: int,
                              n: int, out_shape, dtype_name: str,
                              local: bool, scaled: bool, denom: int,
                              shard_out: bool, scale_value=None,
-                             merge_widths=(), max_programs: int = 24
-                             ) -> int:
+                             merge_widths=(), max_programs: int = 24,
+                             assembled: bool = True) -> int:
     """Pre-compile the persistent program set for one buffer-mode tensor;
     returns the number of executables AOT-compiled.  ``merge_widths``:
     the run widths the dispatcher can form (engine-supplied: pow2 splits
-    in drain mode, 1..group_size otherwise)."""
+    in drain mode, 1..group_size otherwise).  ``assembled=False``: a
+    bucket tensor, which arrives packed and padded and leaves through
+    its unpack program -- only the chunk programs and their scalars."""
     from jax.sharding import NamedSharding
     np_dtype = np.dtype(dtype_name)
     acc = _acc_dtype(np_dtype)
@@ -494,7 +497,7 @@ def aot_warm_buffer_programs(comm: CommContext, *, col_layout, C: int,
     # Pad program (scatter layout needs n divisible by the mesh).  The
     # sharded staging pads on the host inside its one memcpy, so only
     # the replicated/stacked layouts dispatch a device pad.
-    if n != n_pad and local != "sharded":
+    if assembled and n != n_pad and local != "sharded":
         unpadded = (_struct((n,), np_dtype, rep) if local
                     else _struct((R, n), np_dtype,
                                  comm.stacked_sharding(extra_dims=1)))
@@ -502,14 +505,15 @@ def aot_warm_buffer_programs(comm: CommContext, *, col_layout, C: int,
         compiled += aot_compile(comm, ("pad_flat", n, n_pad, local),
                                 [unpadded])
     # Assembly program (donated accumulator in, declared dtype/shape out).
-    _assemble_program(comm, n, C, tuple(out_shape), dtype_name, scaled,
-                      denom, shard_out=shard_out)
-    asm_args = [buf_struct]
-    if scaled:
-        asm_args.append(_struct((), acc, rep))
-    compiled += aot_compile(
-        comm, ("assemble", n, C, tuple(out_shape), dtype_name, scaled,
-               denom, shard_out), asm_args)
+    if assembled:
+        _assemble_program(comm, n, C, tuple(out_shape), dtype_name, scaled,
+                          denom, shard_out=shard_out)
+        asm_args = [buf_struct]
+        if scaled:
+            asm_args.append(_struct((), acc, rep))
+        compiled += aot_compile(
+            comm, ("assemble", n, C, tuple(out_shape), dtype_name, scaled,
+                   denom, shard_out), asm_args)
     # Device scalars: one transfer per column offset / fused scale now,
     # zero per dispatch later.  The scale's cache key carries the jnp
     # class, exactly as assemble_scatter passes it at dispatch.
@@ -821,6 +825,16 @@ def assemble_shardable(comm: CommContext, out_shape) -> bool:
             and out_shape[0] % comm.num_ranks == 0)
 
 
+def _leaf_out_sharding(comm: CommContext, ndim: int, shard_out: bool):
+    """Block-sharded on axis 0 (deferred gather) or replicated: the two
+    layouts an assembled tensor comes back in."""
+    if not shard_out:
+        return comm.replicated_sharding()
+    from jax.sharding import NamedSharding
+    return NamedSharding(
+        comm.mesh, P((DCN_AXIS, ICI_AXIS), *([None] * (ndim - 1))))
+
+
 def _assemble_program(comm: CommContext, n: int, C: int, out_shape,
                       dtype_name: str, scaled: bool, denom: int,
                       shard_out: bool = False):
@@ -848,13 +862,7 @@ def _assemble_program(comm: CommContext, n: int, C: int, out_shape,
                        else out // denom)
             return out.astype(dtype_name).reshape(out_shape)
 
-        if shard_out:
-            from jax.sharding import NamedSharding
-            sharding = NamedSharding(
-                comm.mesh,
-                P((DCN_AXIS, ICI_AXIS), *([None] * (len(out_shape) - 1))))
-        else:
-            sharding = comm.replicated_sharding()
+        sharding = _leaf_out_sharding(comm, len(out_shape), shard_out)
         # Donation is opportunistic: the accumulator is dead after its one
         # assembly, and on backends that can alias it (TPU) XLA reuses its
         # pages for the output.  The CPU emitter can't alias through the
@@ -879,3 +887,137 @@ def assemble_scatter(comm: CommContext, buf, n: int, C: int, out_shape,
         acc = jnp.float64 if buf.dtype == jnp.float64 else jnp.float32
         return fn(buf, _cached_scalar(comm, float(scale), acc))
     return fn(buf)
+
+
+# ---------------------------------------------------------------------------
+# Bucket programs (ISSUE 24)
+#
+# The engine pushes a run of consecutive small leaves as ONE tensor: one
+# pack program replaces the run's per-leaf reshape / stage / pad launches,
+# one unpack program its per-leaf assemblies.  Between the two the packed
+# array is an ordinary flat engine tensor (chunk programs above).
+# ---------------------------------------------------------------------------
+
+
+def _bucket_pack_program(comm: CommContext, shapes, dtype_name: str,
+                         n_pad: int):
+    """(leaf_0 [R, *shapes[0]], leaf_1, ...) -> [R, n_pad]: every rank's
+    row is its leaves flattened end to end, zero-padded to the bucket
+    tensor's scatter layout, in the stacked sharding.  Rank-local (a
+    shard_map body over one row): no byte crosses a device.  No donation:
+    the leaves are the caller's gradient arrays."""
+    def build():
+        def body(*leaves):
+            rows = [leaf.reshape(1, -1) for leaf in leaves]
+            n = sum(r.shape[1] for r in rows)
+            if n != n_pad:
+                rows.append(jnp.zeros((1, n_pad - n), rows[0].dtype))
+            return jnp.concatenate(rows, axis=1)
+
+        spec = P(comm.dp_axes)
+        return jax.jit(
+            jax.shard_map(body, mesh=comm.mesh,
+                          in_specs=(spec,) * len(shapes), out_specs=spec,
+                          check_vma=False),
+            out_shardings=comm.stacked_sharding(extra_dims=1))
+    return _cached(comm, ("bucket_pack", shapes, dtype_name, n_pad), build)
+
+
+def pack_bucket(comm: CommContext, leaves, shapes, dtype_name: str,
+                n_pad: int):
+    """Pack a bucket's rank-stacked leaves (each already in the stacked
+    sharding) into its flat [R, n_pad] engine tensor: one program."""
+    return _bucket_pack_program(comm, shapes, dtype_name, n_pad)(*leaves)
+
+
+def _bucket_unpack_program(comm: CommContext, in_shape, shapes,
+                           dtype_name: str, scaled: bool, shard_out):
+    """Assembly of a bucket, all its leaves at once: the reduced bucket
+    ``x`` -- the block-sharded [n_ici, C] accumulator (buffer mode) or
+    the flat reduced row (parts mode), pad included -- in; the fused
+    scale applied, the declared dtype restored and the tuple of leaves
+    in their own shapes out, leaf i block-sharded on axis 0 where
+    ``shard_out[i]`` and replicated otherwise (the layout the leaf's own
+    assembly would give it).  ``x`` is engine-owned and dead afterwards:
+    donated where the backend can alias it (see _assemble_program)."""
+    def build():
+        def fn(x, *scale):
+            # ONE all-gather of the accumulator, then local slices: left
+            # to itself the partitioner gathers leaf by leaf (126
+            # collectives for a 16-leaf BERT layer on four chips)
+            rows = lax.with_sharding_constraint(
+                x if x.ndim == 2 else x.reshape(1, -1),
+                comm.replicated_sharding())
+            C = rows.shape[1]
+            outs, off = [], 0
+            for shape in shapes:
+                # element off + i of the bucket is rows[(off + i) // C,
+                # (off + i) % C]: slice each leaf out of the 2-D rows (a
+                # leaf rarely crosses one) -- flattening the whole
+                # accumulator first costs a relayout of all of it, a
+                # 1 ms `while` copy per 50 MB bucket on the v5e
+                pieces, n = [], math.prod(shape)
+                while n:
+                    d, c = divmod(off, C)
+                    take = min(n, C - c)
+                    pieces.append(rows[d:d + 1, c:c + take])
+                    off, n = off + take, n - take
+                leaf = (pieces[0] if len(pieces) == 1
+                        else jnp.concatenate(pieces, axis=1))
+                if scaled:
+                    leaf = leaf * scale[0]
+                outs.append(leaf.astype(dtype_name).reshape(shape))
+            return tuple(outs)
+
+        donate = (0,) if jax.default_backend() != "cpu" else ()
+        return jax.jit(
+            fn, donate_argnums=donate,
+            out_shardings=tuple(_leaf_out_sharding(comm, len(s), so)
+                                for s, so in zip(shapes, shard_out)))
+    return _cached(comm, ("bucket_unpack", in_shape, shapes, dtype_name,
+                          scaled, shard_out), build)
+
+
+def unpack_bucket(comm: CommContext, x, shapes, dtype_name: str, shard_out,
+                  scale=None):
+    """Split a reduced bucket into its leaves: one program (assembly and
+    unpack at once in buffer mode, where ``x`` is the accumulator)."""
+    fn = _bucket_unpack_program(comm, tuple(x.shape), shapes, dtype_name,
+                                scale is not None, shard_out)
+    if scale is not None:
+        acc = jnp.float64 if x.dtype == jnp.float64 else jnp.float32
+        return fn(x, _cached_scalar(comm, float(scale), acc))
+    return fn(x)
+
+
+def aot_warm_bucket_programs(comm: CommContext, *, shapes, dtype_name: str,
+                             n_pad: int, shard_out, buffered: bool,
+                             scale_value=None) -> int:
+    """Pre-compile a bucket's pack program and -- in buffer mode, where
+    the accumulator's layout is known -- its unpack program; returns the
+    number of executables AOT-compiled.  (The parts-mode unpack takes
+    whatever layout the collective returned and stays a lazy jit; the
+    chunk programs and device scalars between the two are
+    aot_warm_buffer_programs(assembled=False).)"""
+    from jax.sharding import NamedSharding
+    np_dtype = np.dtype(dtype_name)
+    acc = _acc_dtype(np_dtype)
+    R, n_ici = comm.num_ranks, comm.n_ici
+    _bucket_pack_program(comm, shapes, dtype_name, n_pad)
+    compiled = aot_compile(
+        comm, ("bucket_pack", shapes, dtype_name, n_pad),
+        [_struct((R,) + s, np_dtype,
+                 comm.stacked_sharding(extra_dims=len(s))) for s in shapes])
+    if buffered:
+        scaled = scale_value is not None
+        in_shape = (n_ici, n_pad // n_ici)
+        _bucket_unpack_program(comm, in_shape, shapes, dtype_name, scaled,
+                               shard_out)
+        args = [_struct(in_shape, acc,
+                        NamedSharding(comm.mesh, P(ICI_AXIS)))]
+        if scaled:
+            args.append(_struct((), acc, comm.replicated_sharding()))
+        compiled += aot_compile(
+            comm, ("bucket_unpack", in_shape, shapes, dtype_name, scaled,
+                   shard_out), args)
+    return compiled

@@ -80,6 +80,30 @@ class Handle:
         return self._status if self._status is not None else Status.in_progress()
 
 
+class TreeHandle:
+    """One tree-level push_pull (``PushPullEngine.push_pull_tree_async``):
+    the engine handles it enqueued -- one per bucket of leaves, one per
+    leaf that went alone -- and where each leaf's result lies.
+
+    ``index[i] = (k, j)``: leaf i is element ``j`` of ``handles[k]``'s
+    result (a bucket's tuple of leaves), or the whole result when ``j`` is
+    None."""
+
+    __slots__ = ("handles", "index")
+
+    def __init__(self, handles: List[Handle], index):
+        self.handles = handles
+        self.index = index
+
+    def wait(self, timeout: Optional[float] = None) -> list:
+        """Block on every handle, in enqueue order; the leaves' results
+        in flattening order.  A bucket that failed (or was dropped with
+        its membership epoch) raises for the tree, as any of its leaves
+        would have."""
+        outs = [h.wait(timeout) for h in self.handles]
+        return [outs[k] if j is None else outs[k][j] for k, j in self.index]
+
+
 class HandleManager:
     """Allocates handles and tracks outstanding ones (handle_manager.cc)."""
 

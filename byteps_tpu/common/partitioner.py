@@ -48,6 +48,42 @@ def chunk_bounds(num_elems: int, itemsize: int, partition_bytes: int
     return bounds
 
 
+def bucket_bounds(sigs, nbytes, cap_bytes: int) -> List[Tuple[int, int]]:
+    """Carve a tree's leaves, in flattening order, into buckets: runs
+    ``[(start, stop)]`` of CONSECUTIVE leaves of one dtype whose bytes sum
+    to at most ``cap_bytes``, each pushed as one engine tensor.
+
+    ``sigs[i]`` is leaf i's ``(shape, dtype_name)``, or ``None`` for a
+    leaf no bucket can carry; ``nbytes[i]`` its size.  Such a leaf, and
+    one at or over the cap, is in no run (it goes the per-tensor way).
+
+    Greedy, with one exception: a bucket at half the cap or more closes
+    early when the leaves that follow repeat its shapes one for one, so
+    that the repeated layers of a model make identical buckets and share
+    one pack and one unpack program (at most twice the buckets of the
+    plain greedy cut).  A pure function of its arguments: no timing
+    enters it, so every run and every process of a job carves the same
+    buckets."""
+    runs, i, n = [], 0, len(sigs)
+    while i < n:
+        if sigs[i] is None or nbytes[i] >= cap_bytes:
+            i += 1
+            continue
+        dtype = sigs[i][1]
+        j, size = i, 0
+        while (j < n and sigs[j] is not None and sigs[j][1] == dtype
+               and size + nbytes[j] <= cap_bytes):
+            size += nbytes[j]
+            j += 1
+            k = j - i
+            if (2 * size >= cap_bytes and j + k <= n
+                    and all(sigs[i + t] == sigs[j + t] for t in range(k))):
+                break
+        runs.append((i, j))
+        i = j
+    return runs
+
+
 def num_chunks(num_elems: int, itemsize: int, partition_bytes: int) -> int:
     return len(chunk_bounds(num_elems, itemsize, partition_bytes))
 

@@ -208,6 +208,11 @@ class StepStats:
     # (the step's deltas of ``PushPullEngine.stats``)
     dispatches: int = 0
     chunks: int = 0
+    # ISSUE 24: of this step's ``pushes``, the bucket tensors (a run of
+    # leaves pushed as one), and the leaves that rode them; the other
+    # ``pushes - buckets`` tensors were leaves that went alone
+    buckets: int = 0
+    bucketed_leaves: int = 0
 
     def as_dict(self) -> Dict[str, float]:
         return dataclasses.asdict(self)
@@ -235,6 +240,8 @@ class StepStatsTracker:
         self._t0 = time.monotonic()
         self._bytes = 0
         self._pushes = 0
+        self._buckets = 0
+        self._bucketed_leaves = 0
         self._stall_ms = 0.0
         self._push_pull_ms = 0.0
         self._wire = 0
@@ -258,9 +265,10 @@ class StepStatsTracker:
 
     # -- feeding -----------------------------------------------------------
 
-    def on_push(self, name: str, nbytes: int) -> int:
+    def on_push(self, name: str, nbytes: int, leaves: int = 0) -> int:
         """Returns the tensor's push count — the step this push belongs
-        to, which the engine's phase spans carry."""
+        to, which the engine's phase spans carry.  ``leaves``: how many
+        leaves a bucket tensor packs (0: a tensor pushed by itself)."""
         with self._lock:
             self._counts[name] = self._counts.get(name, 0) + 1
             step = self._counts[name]
@@ -279,6 +287,9 @@ class StepStatsTracker:
                 _tracing.note_step(step)
             self._bytes += int(nbytes)
             self._pushes += 1
+            if leaves:
+                self._buckets += 1
+                self._bucketed_leaves += leaves
             return step
 
     def add_stall(self, ms: float) -> None:
@@ -366,11 +377,15 @@ class StepStatsTracker:
             push_pull_ms=round(self._push_pull_ms, 3),
             dispatches=units[0] - self._units0[0],
             chunks=units[1] - self._units0[1],
+            buckets=self._buckets,
+            bucketed_leaves=self._bucketed_leaves,
         )
         self._units0 = units
         self._push_pull_ms = 0.0
         self._bytes = 0
         self._pushes = 0
+        self._buckets = 0
+        self._bucketed_leaves = 0
         self._stall_ms = 0.0
         self._wire = 0
         self._retx0 = retx
@@ -391,6 +406,8 @@ class StepStatsTracker:
         gauges.set("step.push_pull_ms", stats.push_pull_ms)
         gauges.set("step.dispatches", stats.dispatches)
         gauges.set("step.chunks", stats.chunks)
+        gauges.set("step.buckets", stats.buckets)
+        gauges.set("step.bucketed_leaves", stats.bucketed_leaves)
         for comp, ms in stats.attrib.items():
             # KeyError here is deliberate: a new attribution component
             # must be added to ATTRIB_GAUGE_NAMES (and the doc table) —

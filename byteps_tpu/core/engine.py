@@ -19,6 +19,7 @@ threads suffice:
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -28,20 +29,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..comm.collectives import (_as_stacked, aot_warm_buffer_programs,
+from ..comm.collectives import (_as_stacked, aot_warm_bucket_programs,
+                                aot_warm_buffer_programs,
                                 aot_warm_single_program, assemble_scatter,
-                                assemble_shardable, pad_stacked,
+                                assemble_shardable, pack_bucket, pad_stacked,
                                 push_pull_array, push_pull_array_scaled,
                                 push_pull_arrays_batched,
                                 push_pull_chunk_scatter, scatter_layout,
-                                stage_local_replicated, stage_local_sharded)
+                                stage_local_replicated, stage_local_sharded,
+                                unpack_bucket)
 from ..comm.compressed import (aot_warm_compressed_programs,
                                fused_compressed_push_pull)
 from ..comm.mesh import CommContext
 from ..compression import registry as compression_registry
 from ..common.config import Config
-from ..common.handles import Handle, HandleManager
+from ..common.handles import Handle, HandleManager, TreeHandle
 from ..common.logging import get_logger
+from ..common.partitioner import bucket_bounds, chunk_bounds
 from ..common.registry import TensorRegistry
 from ..common.scheduler import ChunkPlanner, ChunkScheduler
 from ..common import flight_recorder as _flight
@@ -55,6 +59,13 @@ from .sharded_update import ShardedUpdateSlot
 
 
 _SHUTDOWN = object()  # sync-queue sentinel
+
+# A bucket of leaves (push_pull_tree_async) holds at most this many
+# partitions' worth of bytes: 16 x the configured partition_bytes, 65.5 MB
+# at the default 4,096,000 B.  Measured on the v5e (PERF.md section 6,
+# PR 24).  A multiple of the CONFIGURED base, never of the planner's tuned
+# value, which moves with timing while it explores.
+BUCKET_CAP_PARTITIONS = 16
 
 
 class StaleEpochError(RuntimeError):
@@ -162,6 +173,47 @@ class _CompressionSlot:
         self.sstate = sstate        # replicated pytree
 
 
+class _Bucket:
+    """A run of consecutive leaves of one tree pushed as ONE engine tensor
+    (ISSUE 24): packed by one program, partitioned / scheduled / reduced
+    like any flat tensor of ``num_elems`` elements under ``name``, and
+    split back into the leaves by one program at assembly.  Made once
+    per tree signature (``PushPullEngine._plan_tree``); immutable but for
+    ``warmed``."""
+
+    __slots__ = ("name", "shapes", "dtype", "num_elems", "n_pad",
+                 "shard_out", "shardings", "warmed")
+
+    def __init__(self, name, shapes, dtype, n_ici, shard_out, shardings):
+        self.name = name
+        self.shapes = shapes        # the leaves' shapes, rank axis dropped
+        self.dtype = dtype          # np.dtype, one per bucket
+        self.num_elems = sum(int(np.prod(s)) for s in shapes)
+        # rounded up to the ICI axis: the scatter layout's C * n_ici,
+        # whatever the chunk bounds
+        self.n_pad = -(-self.num_elems // n_ici) * n_ici
+        self.shard_out = shard_out  # per leaf: block-sharded output?
+        self.shardings = shardings  # per leaf: the stacked input sharding
+        self.warmed = False
+
+    def pack(self, comm, leaves):
+        """The leaves -> the flat, padded [R, n_pad] array in the stacked
+        sharding.  A leaf that is not already rank-major on the mesh
+        (host data, a default-device array) is staged there first, as
+        the per-tensor path stages it."""
+        leaves = [leaf if (isinstance(leaf, jax.Array)
+                           and leaf.sharding == sh)
+                  else _as_stacked(comm, leaf)
+                  for leaf, sh in zip(leaves, self.shardings)]
+        return pack_bucket(comm, leaves, self.shapes, self.dtype.name,
+                           self.n_pad)
+
+    def unpack(self, comm, x, scale=None):
+        """The reduced bucket (accumulator or flat row) -> its leaves."""
+        return unpack_bucket(comm, x, self.shapes, self.dtype.name,
+                             self.shard_out, scale=scale)
+
+
 class _PendingTensor:
     """Accumulates finished chunks of one push_pull until all arrive.
 
@@ -180,7 +232,8 @@ class _PendingTensor:
 
     def __init__(self, handle: Handle, ctx: TensorContext, out_shape, op: str,
                  denom: int, use_buffer: bool = False, comm=None,
-                 scale=None, shard_out: bool = False, slot=None):
+                 scale=None, shard_out: bool = False, slot=None,
+                 bucket: Optional[_Bucket] = None):
         self.handle = handle
         self.ctx = ctx
         self.out_shape = out_shape
@@ -197,6 +250,9 @@ class _PendingTensor:
         # owner-resident optimizer instead of emitting the merged
         # gradient — the handle resolves to the optax UPDATES tensor
         self.slot = slot
+        # bucket tensor (ISSUE 24): assembly splits the reduced bucket
+        # into its leaves -- the handle resolves to their tuple
+        self.bucket = bucket
         self.local_mode = False  # staging mode (False | True | "sharded")
         # chunk bounds snapshot: the planner can repartition the ctx for a
         # LATER push while this one is in flight-free... bounds are only
@@ -237,6 +293,9 @@ class _PendingTensor:
                 return self.slot.apply_buffer(
                     self.buf, scale=self.scale, denom=self.denom,
                     shard_out=self.shard_out)
+            if self.bucket is not None:
+                # assembly and unpack in one program
+                return self.bucket.unpack(self.comm, self.buf, self.scale)
             return assemble_scatter(
                 self.comm, self.buf, self.ctx.num_elems, C, self.out_shape,
                 self.ctx.dtype_name, scale=self.scale, denom=self.denom,
@@ -245,6 +304,12 @@ class _PendingTensor:
             flat = self.parts[0]
         else:
             flat = jnp.concatenate([self.parts[i] for i in range(self.total)])
+        if self.bucket is not None:
+            # parts-mode bucket (under buffer_min_bytes, or a mesh the
+            # column layout cannot express): float and uncompressed, so
+            # the collective already applied the scale; a single chunk
+            # still carries the pack's pad, which the split ignores
+            return self.bucket.unpack(self.comm, flat)
         out = flat.reshape(self.out_shape)
         if self.denom != 1:
             # The reference divides by size in the done-callback
@@ -277,6 +342,9 @@ class PushPullEngine:
         # per-tensor owner-resident optimizer slots (ISSUE 20 sharded
         # weight update); populated by declare_update
         self.update_slots: Dict[str, ShardedUpdateSlot] = {}
+        # bucket plans by tree signature (push_pull_tree_async); dropped
+        # with the engine on an elastic transition
+        self._tree_plans: Dict[tuple, tuple] = {}
         self.scheduler = self._make_scheduler(cfg)
         self.speed = SpeedMonitor()
         # ONE tracer per process (common/tracing.py): the engine, the
@@ -374,6 +442,7 @@ class PushPullEngine:
                         local: bool = False,
                         replicate_out: bool = False,
                         update_slot=None,
+                        bucket: Optional[_Bucket] = None,
                         ) -> Handle:
         """Enqueue a rank-stacked tensor [R, ...] for reduction.
 
@@ -388,6 +457,10 @@ class PushPullEngine:
         host->device row copies — the host-staging fast path for the
         single-process adapter case (round-3 VERDICT task 4).  Callers
         guarantee no compression and no debug sampling on this path.
+
+        ``bucket`` (push_pull_tree_async only): ``stacked`` is the
+        bucket's list of leaves; they are packed into one flat tensor
+        here and the handle resolves to the tuple of reduced leaves.
         """
         if not self._running:
             raise RuntimeError("engine is shut down")
@@ -409,7 +482,12 @@ class PushPullEngine:
         if _fault.ENABLED:
             # one "step" per enqueued tensor: kill:step=N counts these
             _fault.on_step()
-        if local:
+        if bucket is not None:
+            # geometry is the plan's: the leaves' rank axis was checked
+            # when it was made
+            dtype, out_shape = bucket.dtype, (bucket.num_elems,)
+        elif local:
+            dtype = stacked.dtype
             if compression:
                 raise ValueError(
                     "compression= is not supported on the local "
@@ -422,6 +500,7 @@ class PushPullEngine:
             if out_shape is None:
                 out_shape = stacked.shape
         else:
+            dtype = stacked.dtype
             r = stacked.shape[0]
             if r != self.comm.num_ranks:
                 raise ValueError(
@@ -441,15 +520,17 @@ class PushPullEngine:
             # caller's stack with the accepted spellings named — not as
             # a KeyError deep in the server engine on first use.
             compression_registry.validate_kwargs(compression)
+            # a codec declared for a name takes its leaf out of a bucket
+            self._tree_plans.clear()
         # Planner-chosen chunk size: for uncompressed tensors over the
         # base bound the auto-tuner explores, then locks, a partition
         # bytes per size bucket; an initialized tensor re-carves its
         # bounds only between pushes (inflight == 0).
-        est_nbytes = self._est_nbytes(out_shape, stacked.dtype)
+        est_nbytes = self._est_nbytes(out_shape, dtype)
         plan_bytes = (self.cfg.partition_bytes if compression
                       else self.planner.plan_partition(est_nbytes))
         ctx = self.registry.init_tensor(
-            name, out_shape, stacked.dtype, compression_kwargs=compression,
+            name, out_shape, dtype, compression_kwargs=compression,
             partition_bytes=plan_bytes)
         # Claim the push (inflight++) ATOMICALLY with the repartition
         # decision: bounds may only move when no push holds a claim, and
@@ -511,7 +592,7 @@ class PushPullEngine:
             handle = self.handles.allocate(name)
             if denom is None:
                 denom = self.comm.num_ranks if op == "average" else 1
-            self._ensure_compression(ctx, stacked.dtype)
+            self._ensure_compression(ctx, dtype)
             # Per-push planner sample: wall seconds enqueue -> completion,
             # discarded when a program compile landed inside the window.
             # Two dimensions share the window: chunk size (uncompressed
@@ -547,46 +628,17 @@ class PushPullEngine:
             # assembly-time division (exact // semantics / post-merge denom).
             scale = None
             if (denom != 1 and ctx.compressor is None
-                    and jnp.issubdtype(np.dtype(stacked.dtype), jnp.inexact)):
+                    and jnp.issubdtype(np.dtype(dtype), jnp.inexact)):
                 scale = 1.0 / denom
                 denom = 1
             nchunks = len(ctx.chunk_bounds)
-            # Buffer mode (the hot path): uncompressed multi-chunk tensors —
-            # and large single-chunk ones (>= buffer_min_bytes, e.g. after
-            # the planner locked chunk=whole) — ride the fused slice ->
-            # reduce-scatter -> sharded-accumulator chunk programs; each
-            # dispatch consumes the previous accumulator by donation, and one
-            # assemble program scales/reshapes in a single order-identical
-            # pass.  Debug sampling needs per-chunk outputs, so it forces
-            # parts mode; so do chunk bounds the column layout can't express
-            # (non-power-of-2 meshes).
-            use_buffer = (ctx.compressor is None
-                          and not self.cfg.debug_sample_tensor
-                          and self._buffer_eligible(ctx))
-            if use_buffer and ctx.scatter_layout is None:
-                with ctx.lock:
-                    if ctx.scatter_layout is None:
-                        # "ineligible" is a computed-and-rejected marker so the
-                        # layout check runs once per tensor, not once per call
-                        ctx.scatter_layout = (scatter_layout(
-                            ctx.chunk_bounds, self.comm.n_ici) or "ineligible")
-            if use_buffer and ctx.scatter_layout == "ineligible":
-                use_buffer = False
-            # Deferred-gather assembly: the result stays block-sharded over
-            # the mesh when the output shape admits it — XLA materializes the
-            # all-gather only where a consumer needs replicated values, and
-            # mesh-aligned tensors assemble with zero cross-device movement.
-            # ``replicate_out``: callers that will immediately read the full
-            # result on host (the torch/TF adapters' np.asarray) opt OUT —
-            # eager assembly then runs the gather on the syncer thread,
-            # pipelined with other transport, instead of serializing it into
-            # the caller's wait.
-            shard_out = (use_buffer and self.cfg.deferred_gather
-                         and not replicate_out
-                         and assemble_shardable(self.comm, out_shape))
+            # buffer mode? block-sharded output?  (_route_shape)
+            use_buffer, shard_out = self._route(ctx, out_shape,
+                                                replicate_out)
             pending = _PendingTensor(handle, ctx, out_shape, op, denom,
                                      use_buffer, comm=self.comm, scale=scale,
-                                     shard_out=shard_out, slot=update_slot)
+                                     shard_out=shard_out, slot=update_slot,
+                                     bucket=bucket)
             if self.tracer.active:
                 # windowed AND/OR sampled capture decided here; tctx is
                 # None for pushes that record nothing
@@ -597,11 +649,20 @@ class PushPullEngine:
                 # per-step accounting: same per-tensor step definition as
                 # the tracer, independent of the trace window (whose
                 # step, when one is armed, the tasks carry)
-                tstep = self.step_stats.on_push(name, est_nbytes)
+                tstep = self.step_stats.on_push(
+                    name, est_nbytes,
+                    len(bucket.shapes) if bucket is not None else 0)
                 step = step or tstep
             pending.trace = tctx
             local_mode = local
-            if local:
+            if bucket is not None:
+                if not bucket.warmed:
+                    self._warm_bucket(bucket, ctx, use_buffer, scale, op)
+                # one program: flat, stacked-sharded and padded to the
+                # scatter layout, in place of the staging below for
+                # every leaf
+                flat = bucket.pack(self.comm, stacked)
+            elif local:
                 if use_buffer:
                     col_layout0, C0 = ctx.scatter_layout
                     n_pad0 = C0 * self.comm.n_ici
@@ -637,7 +698,7 @@ class PushPullEngine:
                 # per-chunk host slice copies are gone.
                 flat = _as_stacked(self.comm, flat)
             pending.local_mode = local_mode
-            itemsize = np.dtype(stacked.dtype).itemsize
+            itemsize = np.dtype(dtype).itemsize
             if use_buffer:
                 # Buffer-mode tasks are COLUMN slabs of the [n_ici, C] view
                 # (offset/num in columns).  nbytes below is taken from
@@ -654,6 +715,8 @@ class PushPullEngine:
             # two never double-count
             if ph_enq.ann is not None:
                 ph_enq.note(step=step, tensor=name)
+                if bucket is not None:
+                    ph_enq.note(leaves=len(bucket.shapes))
             ph_enq.__exit__(None, None, None)
             t_enq = ph_enq.t1
             with _tracing.phase("bps.engine.submit",
@@ -733,6 +796,155 @@ class PushPullEngine:
                 ctx.inflight -= 1
             raise
 
+    # ------------------------------------------------------- tree entry
+    def push_pull_tree_async(self, leaves, names,
+                             op: str = "average") -> TreeHandle:
+        """Enqueue the leaves of one rank-stacked tree (flattening order,
+        one name each): consecutive plain float leaves ride BUCKETS --
+        one engine tensor, one pack and one unpack program for the run
+        -- and every other leaf goes through :meth:`push_pull_async` by
+        itself.  ``TreeHandle.wait()`` gives the reduced leaves in order.
+
+        Chosen by what the input shows, per leaf (:meth:`_plan_tree`).
+        A bucket is a tensor: its name is stable (the first leaf's name
+        and the count of the rest), so declaration order -- priority --
+        partitioning, credit, step accounting, deadline and epoch guards
+        see it as they see any other."""
+        names = tuple(names)
+        sig = (names, op, tuple((leaf.shape, leaf.dtype) for leaf in leaves))
+        plan = self._tree_plans.get(sig)
+        if plan is None:
+            plan = self._tree_plans[sig] = self._plan_tree(leaves, names, op)
+        items, index = plan
+        handles = []
+        for start, stop, bucket in items:
+            if bucket is None:
+                handles.append(self.push_pull_async(
+                    leaves[start], names[start], op=op))
+            else:
+                handles.append(self.push_pull_async(
+                    leaves[start:stop], bucket.name, op=op, bucket=bucket))
+        return TreeHandle(handles, index)
+
+    def _plan_tree(self, leaves, names, op: str = "average") -> tuple:
+        """The bucket plan of one tree signature: ``(items, index)``,
+        ``items`` the pushes to make in order -- ``(start, stop,
+        bucket)``, ``bucket`` None for a leaf that goes alone -- and
+        ``index`` each leaf's place in their results (TreeHandle).
+
+        A leaf rides a bucket unless the per-tensor path has something
+        only it can give the leaf: exact ``//`` (a dtype that is not
+        inexact), a codec (declared for the name, or the compressor
+        ladder, which owns bare tensors by size), per-chunk debug
+        sampling; or unless it has nothing to pack (no elements, a rank
+        axis the per-tensor path will refuse by name) or is at or over
+        the cap.  A run of one is that leaf.  Nothing here reads a
+        clock: the plan is a pure function of the signature and the
+        configuration.
+
+        Making the plan declares its tensors.  Every push it will make
+        reserves its registry key here, in order, so that priority
+        (``-declared_key``) follows flattening order across buckets and
+        lone leaves.  A bucket compiles its programs at its first push
+        (_warm_bucket), and a leaf that goes alone for its size or dtype
+        is declared here with its geometry
+        (declare_tensor: every chunk program the dispatcher can form) --
+        a 125 MB embedding's 31 chunks otherwise bring up new run widths
+        and offsets for as long as timing finds new ones."""
+        comm, cfg = self.comm, self.cfg
+        R = comm.num_ranks
+        per_leaf = self.planner.compress_active or cfg.debug_sample_tensor
+        sigs, sizes, plain = [], [], []
+        for leaf, name in zip(leaves, names):
+            shape, dtype = tuple(leaf.shape), np.dtype(leaf.dtype)
+            nbytes = self._est_nbytes(shape[1:], dtype) if shape else 0
+            ctx = self.registry.get(name)
+            # nothing but size or dtype can keep a plain leaf out
+            plain.append(bool(nbytes) and shape[0] == R and not (
+                per_leaf or (ctx is not None and (ctx.compression_kwargs
+                                                  or ctx.compressor))))
+            sigs.append((shape[1:], dtype.name) if plain[-1]
+                        and jnp.issubdtype(dtype, jnp.inexact) else None)
+            sizes.append(nbytes)
+        runs = {start: stop for start, stop in bucket_bounds(
+            sigs, sizes, BUCKET_CAP_PARTITIONS * cfg.partition_bytes)
+            if stop - start > 1}
+        items, index, i = [], [], 0
+        while i < len(sigs):
+            stop = runs.get(i)
+            if stop is None:
+                index.append((len(items), None))
+                items.append((i, i + 1, None))
+                i += 1
+                continue
+            shapes = tuple(sig[0] for sig in sigs[i:stop])
+            bucket = _Bucket(
+                f"{names[i]}+{stop - i - 1}", shapes, np.dtype(sigs[i][1]),
+                comm.n_ici,
+                tuple(self._leaf_shard_out(s, sigs[i][1]) for s in shapes),
+                tuple(comm.stacked_sharding(extra_dims=len(s))
+                      for s in shapes))
+            index.extend((len(items), j) for j in range(stop - i))
+            items.append((i, stop, bucket))
+            i = stop
+        # keys -- priority -- in item order, which is flattening order:
+        # reserved for buckets and lone leaves alike before anything is
+        # declared with its geometry, or every leaf that goes alone
+        # would outrank the buckets around it
+        for start, _, bucket in items:
+            self.registry.declare(names[start] if bucket is None
+                                  else bucket.name)
+        for start, _, bucket in items:
+            if bucket is None and plain[start]:
+                self.declare_tensor(names[start], leaves[start].shape[1:],
+                                    leaves[start].dtype, op=op, local=False)
+        return items, index
+
+    def _leaf_shard_out(self, shape, dtype_name: str) -> bool:
+        """Would this leaf, pushed by itself, come back block-sharded
+        (deferred gather)?  A bucket gives each leaf the layout its own
+        assembly gives it, so the caller's jitted update sees the
+        argument layouts it was compiled for: the per-tensor routing
+        (:meth:`_route_shape`), asked at the configured partition size
+        -- like the cap, not at the planner's tuned one, which moves
+        with timing while it explores."""
+        itemsize = np.dtype(dtype_name).itemsize
+        n = int(np.prod(shape))
+        return self._route_shape(
+            shape, n * itemsize,
+            chunk_bounds(n, itemsize, self.cfg.partition_bytes))[1]
+
+    def _warm_bucket(self, bucket: _Bucket, ctx: TensorContext,
+                     use_buffer: bool, scale, op: str) -> None:
+        """A bucket's first push declares it: compile its pack and
+        unpack programs and, as declare_tensor does for a declared
+        tensor, every chunk program the dispatcher can form for it --
+        which run widths occur is a matter of timing, and a bucket's
+        dozen chunks would otherwise bring new ones up for many steps
+        (single process only: SPMD processes compile lazily, in
+        lockstep)."""
+        bucket.warmed = True
+        if jax.process_count() > 1:
+            return
+        t0 = time.monotonic()
+        try:
+            n_compiled = aot_warm_bucket_programs(
+                self.comm, shapes=bucket.shapes,
+                dtype_name=bucket.dtype.name, n_pad=bucket.n_pad,
+                shard_out=bucket.shard_out, buffered=use_buffer,
+                scale_value=scale)
+            if use_buffer:
+                n_compiled += self._aot_warm(
+                    ctx, bucket.dtype, op=op, local=False, assembled=False)
+            if n_compiled and self.tracer.active:
+                # as declare_tensor: the stall in the timeline where it
+                # was paid (it is inside this push's "enqueue")
+                self.tracer.record_span(
+                    "engine.aot_warm", t0, time.monotonic(),
+                    tensor=bucket.name, programs=n_compiled)
+        except Exception as e:  # noqa: BLE001 — lazy jit is the fallback
+            self._aot_warm_failed("bucket", bucket.name, e)
+
     @staticmethod
     def _est_nbytes(shape, dtype) -> int:
         """Logical payload bytes of one tensor (planner bucket key);
@@ -742,13 +954,60 @@ class PushPullEngine:
         return ((int(np.prod(shape)) if shape else 1)
                 * np.dtype(dtype).itemsize)
 
-    def _buffer_eligible(self, ctx: TensorContext) -> bool:
-        """Size/chunk half of the buffer-mode routing predicate —
-        shared by dispatch and AOT warm so the two cannot drift (the
-        compression/debug-sampling exclusions live at the call sites
-        that can see them)."""
-        return (len(ctx.chunk_bounds) > 1
-                or ctx.nbytes >= self.cfg.buffer_min_bytes)
+    def _route_shape(self, shape, nbytes: int, bounds, layout=None,
+                     replicate_out: bool = False):
+        """How one uncompressed tensor of ``shape`` / ``nbytes``, carved
+        into ``bounds``, travels: ``(use_buffer, shard_out, layout)``.
+        The one copy of the routing policy -- push_pull_async, the AOT
+        warm and the bucket plan (a leaf's output layout) all ask here.
+
+        Buffer mode (the hot path): multi-chunk tensors -- and large
+        single-chunk ones (>= buffer_min_bytes, e.g. after the planner
+        locked chunk=whole) -- ride the fused slice -> reduce-scatter ->
+        sharded-accumulator chunk programs; each dispatch consumes the
+        previous accumulator by donation, and one assemble program
+        scales/reshapes in a single order-identical pass.  Debug sampling
+        needs per-chunk outputs, so it forces parts mode; so do chunk
+        bounds the column layout can't express (non-power-of-2 meshes):
+        ``layout`` is the tensor's cached ``scatter_layout`` (None: not
+        computed yet; "ineligible": computed and rejected, so the check
+        runs once per tensor, not once per call).
+
+        Deferred-gather assembly (``shard_out``): the result stays
+        block-sharded over the mesh when the output shape admits it --
+        XLA materializes the all-gather only where a consumer needs
+        replicated values, and mesh-aligned tensors assemble with zero
+        cross-device movement.  ``replicate_out``: callers that will
+        immediately read the full result on host (the torch/TF adapters'
+        np.asarray) opt OUT -- eager assembly then runs the gather on the
+        syncer thread, pipelined with other transport, instead of
+        serializing it into the caller's wait."""
+        use_buffer = (not self.cfg.debug_sample_tensor
+                      and (len(bounds) > 1
+                           or nbytes >= self.cfg.buffer_min_bytes))
+        if use_buffer and layout is None:
+            layout = scatter_layout(bounds, self.comm.n_ici) or "ineligible"
+        use_buffer = use_buffer and layout != "ineligible"
+        shard_out = (use_buffer and self.cfg.deferred_gather
+                     and not replicate_out
+                     and assemble_shardable(self.comm, shape))
+        return use_buffer, shard_out, layout
+
+    def _route(self, ctx: TensorContext, out_shape,
+               replicate_out: bool = False):
+        """``(use_buffer, shard_out)`` of a registered tensor at its
+        current chunk bounds (:meth:`_route_shape`); compressed chunks
+        ride parts mode.  Caches the scatter layout on the context."""
+        if ctx.compressor is not None:
+            return False, False
+        use_buffer, shard_out, layout = self._route_shape(
+            out_shape, ctx.nbytes, ctx.chunk_bounds, ctx.scatter_layout,
+            replicate_out)
+        if layout is not None and ctx.scatter_layout is None:
+            with ctx.lock:
+                if ctx.scatter_layout is None:
+                    ctx.scatter_layout = layout
+        return use_buffer, shard_out
 
     def _sharded_staging_ok(self, col_layout, C: int) -> bool:
         """Sharded local staging is worth it only for SINGLE-run
@@ -853,6 +1112,7 @@ class PushPullEngine:
             # a bad codec/decorator/param fails at declare, in the
             # caller's stack (ISSUE 11 satellite)
             compression_registry.validate_kwargs(compression)
+            self._tree_plans.clear()    # as in push_pull_async
         est_nbytes = self._est_nbytes(shape, np_dtype)
         plan_bytes = (self.cfg.partition_bytes if compression
                       else self.planner.plan_partition(est_nbytes))
@@ -960,8 +1220,7 @@ class PushPullEngine:
             # mirror _aot_warm's denominator model for the local push
             # this slot's pushes will dispatch: float + denom=R rides
             # the fused-scale fast path (scaled=True)
-            buffered = (self._buffer_eligible(ctx)
-                        and ctx.scatter_layout not in (None, "ineligible"))
+            buffered = self._route(ctx, ctx.shape)[0]
             # buffer mode applies the fused 1/R scale inside the update
             # program; parts fallback receives the already-averaged
             # merged gradient (apply_full), so no scale arg there
@@ -1019,7 +1278,8 @@ class PushPullEngine:
                 for name, slot in self.update_slots.items()}
 
     def _aot_warm(self, ctx: TensorContext, np_dtype, *, op: str,
-                  local: bool, replicate_out: bool = False) -> int:
+                  local: bool, replicate_out: bool = False,
+                  assembled: bool = True) -> int:
         """Compile the program set for one uncompressed tensor's pushes.
 
         The denominator/scale model MUST mirror what push_pull will
@@ -1039,13 +1299,7 @@ class PushPullEngine:
         scale_value = (1.0 / base_denom) if scaled else None
         denom = 1 if scaled else base_denom
         nchunks = len(ctx.chunk_bounds)
-        use_buffer = self._buffer_eligible(ctx)
-        if use_buffer:
-            with ctx.lock:
-                if ctx.scatter_layout is None:
-                    ctx.scatter_layout = (scatter_layout(
-                        ctx.chunk_bounds, self.comm.n_ici) or "ineligible")
-            use_buffer = ctx.scatter_layout != "ineligible"
+        use_buffer, shard_out = self._route(ctx, ctx.shape, replicate_out)
         if use_buffer:
             col_layout, C = ctx.scatter_layout
             # Warm the staging variant push_pull will dispatch: a
@@ -1066,9 +1320,9 @@ class PushPullEngine:
                 self.comm, col_layout=col_layout, C=C, n=ctx.num_elems,
                 out_shape=ctx.shape, dtype_name=ctx.dtype_name,
                 local=local_eff, scaled=scaled, denom=denom,
-                shard_out=(self.cfg.deferred_gather and not replicate_out
-                           and assemble_shardable(self.comm, ctx.shape)),
-                scale_value=scale_value, merge_widths=ks)
+                shard_out=shard_out,
+                scale_value=scale_value, merge_widths=ks,
+                assembled=assembled)
         if nchunks == 1:
             return aot_warm_single_program(
                 self.comm, n=ctx.num_elems, dtype_name=ctx.dtype_name,
@@ -1220,6 +1474,9 @@ class PushPullEngine:
                         ph.note(step=unit[0].step, tensor=unit[0].name,
                                 width=len(unit),
                                 bytes=sum(t.nbytes for t in unit))
+                        bucket = unit[0].pending.bucket
+                        if bucket is not None:
+                            ph.note(leaves=len(bucket.shapes))
                     # the phase's opening stamp is the unit's dispatch
                     # time (end of its chunks' queue wait)
                     if kind == "run":
@@ -1238,6 +1495,11 @@ class PushPullEngine:
                         # critical-path segments
                         ph.feed = feeds["compile"]
                         ph.note(compiled=1)
+            # let go of the dispatched chunks before parking in the pop:
+            # they hold their tensor's staged array (a bucket's 50 MB
+            # packed tensor), which must not outlive its retirement
+            # until the next step's first task arrives
+            task = units = unit = None
 
     def _pop_and_plan(self, task: ChunkTask):
         """The dispatcher's work between a successful pop and its first
@@ -1405,13 +1667,18 @@ class PushPullEngine:
         # design sells.
         shutdown = False
         while not shutdown:
-            items = [self._sync_q.get()]
+            # Retired units are dropped as they go (popleft) and before
+            # the next blocking get (below): a unit's chunks hold their
+            # tensor's staged array, and a reference kept here would keep
+            # it on the device through the caller's optimizer update.
+            items = collections.deque([self._sync_q.get()])
             while True:  # opportunistic drain of everything already queued
                 try:
                     items.append(self._sync_q.get_nowait())
                 except queue.Empty:
                     break
-            for item in items:
+            while items:
+                item = items.popleft()
                 if item is _SHUTDOWN:
                     shutdown = True
                     continue
@@ -1505,6 +1772,7 @@ class PushPullEngine:
                     if ph.ann is not None:
                         ph.note(step=head.step, tensor=head.name)
                     self._finish_batch(tasks, out, err)
+                item = tasks = out = rollback = head = None
 
     def _deadline_loop(self):
         """Per-unit sync-deadline watchdog (BYTEPS_SYNC_DEADLINE_S): a

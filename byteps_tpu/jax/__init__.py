@@ -28,6 +28,7 @@ import numpy as np
 import optax
 
 from ..common import tracing as _tracing
+from ..common.handles import TreeHandle
 from ..core import api as _api
 from ..ops import push_pull_tree as _traced_push_pull_tree
 
@@ -64,29 +65,47 @@ def push_pull_async(tree, name_prefix: str = "byteps", op: str = "average"
 
 
 def _enqueue_and_wait(enqueue) -> tuple:
-    """``enqueue()`` every leaf, then block on the handles: the caller
-    thread's two halves of a tree-level push_pull, as the phases
+    """``enqueue(engine)`` the tree, then block on its handles: the
+    caller thread's two halves of a tree-level push_pull, as the phases
     ``bps.push_pull`` (whole; ``StepStats.push_pull_ms``) and
-    ``bps.engine.wait`` (blocked; ``attrib["wait"]``).  Returns
-    ``(handles, results)``."""
+    ``bps.engine.wait`` (blocked; ``attrib["wait"]``).  ``enqueue``
+    returns a ``TreeHandle``; returns ``(tree_handle, results)``."""
     eng = _api._require()
     feeds = eng.phase_feeds
     with _tracing.phase("bps.push_pull", feeds["push_pull"]) as ph:
-        handles = enqueue()
+        pushed = enqueue(eng)
         step = eng.step_stats.current_step
         ph.note(step=step)
         with _tracing.phase("bps.engine.wait", feeds["wait"]) as ph_wait:
             ph_wait.note(step=step)
-            outs = [h.wait() for h in handles]
-    return handles, outs
+            outs = pushed.wait()
+    return pushed, outs
 
 
 def push_pull(tree, name_prefix: str = "byteps", op: str = "average"):
     """Synchronously reduce a rank-stacked pytree; returns the reduced tree
-    (leaves lose their leading rank axis)."""
-    treedef = jax.tree_util.tree_structure(tree)
+    (leaves lose their leading rank axis).
+
+    The whole tree goes to the engine at once
+    (``PushPullEngine.push_pull_tree_async``), which pushes consecutive
+    plain float leaves in BUCKETS: a run of leaves of one dtype, up to 16
+    partitions' worth of bytes, is packed by one program, partitioned,
+    scheduled and reduced as one tensor, and split back into leaves by
+    one program -- a few dozen engine tensors for a model's few hundred
+    leaves.  Priority is per bucket, in declaration (= flattening)
+    order.  A leaf goes by itself, exactly as through
+    :func:`push_pull_async`, where only the per-tensor path can serve it:
+    an integer leaf (exact ``//``), a leaf at or over the cap, a name
+    with a codec declared or the compressor ladder on (codec state is
+    per tensor), ``BYTEPS_DEBUG_SAMPLE_TENSOR`` set.  Results, shapes
+    and output shardings are those of the per-leaf path (the cross-rank
+    summation order may differ within float32 rounding).
+    :func:`push_pull_async` and ``bps.push_pull[_async]`` are unchanged:
+    one tensor, one handle."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    names = _leaf_names(tree, name_prefix)
     _, outs = _enqueue_and_wait(
-        lambda: push_pull_async(tree, name_prefix, op=op))
+        lambda eng: eng.push_pull_tree_async(leaves, names, op=op))
     return jax.tree_util.tree_unflatten(treedef, outs)
 
 
@@ -150,9 +169,13 @@ class DistributedOptimizer:
     """Engine-mode optimizer wrapper (imperative, host-driven).
 
     Mirrors the reference torch ``DistributedOptimizer``
-    (torch/__init__.py:110-214): gradients are enqueued per-leaf into the
+    (torch/__init__.py:110-214): gradients are enqueued into the
     background engine (partitioned, priority-scheduled, credit-limited) and
-    the optax update runs on the averaged result.  Supports
+    the optax update runs on the averaged result.  The gradient tree goes
+    through :func:`push_pull`: consecutive plain float leaves travel in
+    buckets (one engine tensor, priority in declaration order per
+    bucket), the rest per leaf -- see there for what falls back and why.
+    Supports
     ``backward_passes_per_step`` gradient accumulation: micro-steps
     accumulate locally and only the boundary step communicates
     (reference torch/__init__.py:110-156).
@@ -164,7 +187,8 @@ class DistributedOptimizer:
     reduce-scatter owners, AOT-warmed at declare time — and ``update``
     pushes gradients through the same stacked chunk collectives but
     receives the owner-computed optax UPDATES back (pull leg N/R
-    instead of N).  The returned ``(updates, state)`` contract is
+    instead of N); every leaf is then its own tensor (a slot is
+    per-tensor state), never bucketed.  The returned ``(updates, state)`` contract is
     unchanged, and the trajectory is bit-for-bit the unsharded one
     (tests/test_sharded_update.py).
     """
@@ -264,13 +288,15 @@ class DistributedOptimizer:
                         "sharded_update re-declare after an elastic "
                         "transition needs params= (slot geometry)")
                 self._declare_sharded(params)
+            leaves, treedef = jax.tree_util.tree_flatten(grads)
+            # one handle per leaf: an engine-resident optimizer slot
+            # is per tensor, so no leaf rides a bucket here
+            pushed, outs = _enqueue_and_wait(lambda eng: TreeHandle(
+                [eng.push_pull_update_async(leaf, name, stacked=True)
+                 for (name, _, _), leaf in zip(self._leaf_meta, leaves)],
+                [(k, None) for k in range(len(leaves))]))
             eng = _api._require()
-            treedef = jax.tree_util.tree_structure(grads)
-            leaves = jax.tree_util.tree_leaves(grads)
-            handles, outs = _enqueue_and_wait(lambda: [
-                eng.push_pull_update_async(leaf, name, stacked=True)
-                for (name, _, _), leaf in zip(self._leaf_meta, leaves)])
-            for h in handles:
+            for h in pushed.handles:
                 eng.handles.release(h.id)
             return jax.tree_util.tree_unflatten(treedef, outs), state
         reduced = push_pull(grads, self._prefix, op=self._op)

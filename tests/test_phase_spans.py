@@ -9,6 +9,11 @@ real ``jax.profiler.start_trace`` on the CPU mesh, reads the
 against each other and against the counters; the untraced half holds
 that nothing is recorded, the counters still fill, and a fused step never
 touches them.
+
+Since ISSUE 24 the tree's float leaves ride a bucket, one engine tensor
+for all four: the traced half runs twice, once on a tree whose leaves
+share a bucket (enqueue / submit fire once a step and carry ``leaves``)
+and once on leaves at the bucket cap, which go per leaf as before.
 """
 
 import collections
@@ -33,12 +38,22 @@ from byteps_tpu.jax import DistributedOptimizer  # noqa: E402
 
 # the spans that feed an attribution component of the same name
 PHASES = ("enqueue", "submit", "wait", "plan", "dispatch", "sync", "assemble")
-PER_LEAF = ("bps.engine.enqueue", "bps.engine.submit")
+PER_TENSOR = ("bps.engine.enqueue", "bps.engine.submit")
 PER_UNIT = ("bps.engine.dispatch", "bps.engine.sync", "bps.engine.assemble")
 TRACED_STEPS = 3          # the first is cold: its units compile
+PART = 1 << 16            # pinned partition_bytes = bytes of every chunk
+LEAVES = 4
+# leaf elements -> what one step pushes.  The bucket cap is 16 partitions:
+# four 2-chunk leaves share one bucket (half the cap: were they larger,
+# two identical buckets of two would be cut), a 16-chunk leaf is at the
+# cap and goes alone.
+BUCKETED = dict(leaf_elems=1 << 15, tensors=1, chunks=8, buckets=1,
+                bucketed_leaves=LEAVES)
+PER_LEAF = dict(leaf_elems=1 << 18, tensors=LEAVES, chunks=64, buckets=0,
+                bucketed_leaves=0)
 
 
-def _tree(n_ranks, leaf_elems=1 << 16, leaves=4):
+def _tree(n_ranks, leaf_elems=1 << 16, leaves=LEAVES):
     params = {f"w{i}": jnp.zeros((leaf_elems,), jnp.float32)
               for i in range(leaves)}
     grads = jax.tree.map(
@@ -46,10 +61,11 @@ def _tree(n_ranks, leaf_elems=1 << 16, leaves=4):
     return params, grads
 
 
-def _engine_steps(n_steps, traced_dir=None, leaf_elems=1 << 16):
-    """Engine-mode steps on a fresh engine (4 leaves x 4 pinned chunks);
-    returns the steps' StepStats."""
-    set_config(Config(telemetry_on=True, partition_bytes=leaf_elems,
+def _engine_steps(n_steps, traced_dir=None, leaf_elems=1 << 16,
+                  part_bytes=PART):
+    """Engine-mode steps on a fresh engine (4 leaves, pinned chunks of
+    ``part_bytes``); returns the steps' StepStats."""
+    set_config(Config(telemetry_on=True, partition_bytes=part_bytes,
                       partition_pinned=True))
     bps.init()
     try:
@@ -81,14 +97,18 @@ def _engine_steps(n_steps, traced_dir=None, leaf_elems=1 << 16):
 Span = collections.namedtuple("Span", "name line start end args")
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """``(spans, steps)``: every ``bps.*`` event of a profiler session
-    over TRACED_STEPS engine-mode steps (line = index of its host thread
-    line), and those steps' StepStats by step number."""
+@pytest.fixture(scope="module", params=[BUCKETED, PER_LEAF],
+                ids=["bucketed", "per_leaf"])
+def traced(request, tmp_path_factory):
+    """``(spans, steps, shape)``: every ``bps.*`` event of a profiler
+    session over TRACED_STEPS engine-mode steps (line = index of its host
+    thread line), those steps' StepStats by step number, and what one
+    step pushes (BUCKETED or PER_LEAF)."""
     from jax.profiler import ProfileData
+    shape = request.param
     trace_dir = str(tmp_path_factory.mktemp("phase_trace"))
-    history = _engine_steps(TRACED_STEPS, traced_dir=trace_dir)
+    history = _engine_steps(TRACED_STEPS, traced_dir=trace_dir,
+                            leaf_elems=shape["leaf_elems"])
     path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     spans, n_line = [], 0
@@ -102,7 +122,7 @@ def traced(tmp_path_factory):
                         ev.start_ns + ev.duration_ns, dict(ev.stats)))
     steps = {s.step: s for s in history}
     assert sorted(steps) == list(range(1, TRACED_STEPS + 1)), history
-    return spans, steps
+    return spans, steps, shape
 
 
 def _named(spans, name):
@@ -117,7 +137,7 @@ def _inside(inner, outers):
 def test_three_threads_on_three_lines(traced):
     """(a) caller, dispatcher and syncer each annotate on their own
     thread: their spans lie on three different host lines."""
-    spans, _ = traced
+    spans, _, _ = traced
     lines = {n: {s.line for s in _named(spans, n)}
              for n in ("bps.push_pull", "bps.engine.dispatch",
                        "bps.engine.sync")}
@@ -133,13 +153,14 @@ def test_three_threads_on_three_lines(traced):
 def test_caller_spans_nest(traced):
     """(b) every enqueue / submit / wait lies inside a ``bps.push_pull``
     and every ``bps.push_pull`` inside a ``bps.adapter.update``."""
-    spans, _ = traced
+    spans, _, shape = traced
     updates = _named(spans, "bps.adapter.update")
     pushes = _named(spans, "bps.push_pull")
     assert len(updates) == len(pushes) == TRACED_STEPS
     assert all(_inside(p, updates) for p in pushes)
-    inner = [s for s in spans if s.name in PER_LEAF + ("bps.engine.wait",)]
-    assert len(inner) == TRACED_STEPS * (2 * 4 + 1)
+    inner = [s for s in spans if s.name in PER_TENSOR + ("bps.engine.wait",)]
+    # enqueue + submit once per engine tensor (a bucket is one), one wait
+    assert len(inner) == TRACED_STEPS * (2 * shape["tensors"] + 1)
     assert all(_inside(s, pushes) for s in inner)
 
 
@@ -147,17 +168,54 @@ def test_dispatch_events_equal_dispatch_count(traced):
     """(c) one dispatch span per launched program: their number is the
     steps' summed ``StepStats.dispatches`` (a unit that compiled is the
     same span with ``compiled=1``), and ``chunks`` counts every task."""
-    spans, steps = traced
+    spans, steps, shape = traced
     units = _named(spans, "bps.engine.dispatch")
     assert len(units) == sum(s.dispatches for s in steps.values()) > 0
-    assert all(s.chunks == 16 for s in steps.values()), steps
-    assert sum(u.args["width"] for u in units) == 16 * TRACED_STEPS
+    assert all(s.chunks == shape["chunks"] for s in steps.values()), steps
+    assert sum(u.args["width"] for u in units) == (
+        shape["chunks"] * TRACED_STEPS)
     for n in ("bps.engine.sync", "bps.engine.assemble"):
         assert len(_named(spans, n)) == len(units)
-    # the cold step compiled (which later widths do is up to timing)
-    assert any(u.args.get("compiled") for u in units
-               if u.args["step"] == 1)
-    assert "compile" in steps[1].attrib
+    # making the tree's plan declared its tensors, every program the
+    # dispatcher can form for them compiled on the caller's thread (a
+    # bucket's inside its first "enqueue"): no unit ever compiles
+    assert not any(u.args.get("compiled") for u in units)
+    assert all("compile" not in s.attrib for s in steps.values())
+    if shape["buckets"]:
+        assert steps[1].attrib["enqueue"] > 10 * steps[3].attrib["enqueue"]
+
+
+def test_a_unit_that_compiles_feeds_compile(tmp_path):
+    """A tensor pushed by itself, undeclared, compiles at its first
+    dispatch: that unit is ``bps.engine.dispatch`` with ``compiled=1``
+    and feeds ``compile``, not ``dispatch``."""
+    from jax.profiler import ProfileData
+    set_config(Config(telemetry_on=True, partition_bytes=PART,
+                      partition_pinned=True))
+    bps.init()
+    try:
+        eng = bps.core.api._require()
+        x = jnp.ones((bps.size(), 1 << 15), jnp.float32)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            jax.block_until_ready(bps.push_pull(x, "lone"))
+            time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        stats = eng.step_stats.flush()
+    finally:
+        bps.shutdown()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    units = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "bps.engine.dispatch"]
+    assert units and sum(u["width"] for u in units) == stats.chunks == 2
+    assert any(u.get("compiled") for u in units), units
+    assert stats.attrib["compile"] > 0
+    assert all("leaves" not in u for u in units)
 
 
 @pytest.mark.parametrize("component", PHASES + ("compile", "push_pull"))
@@ -166,7 +224,7 @@ def test_span_durations_are_the_counters(traced, component):
     one enter/exit pair (the TraceMe opens a moment before the
     ``time.monotonic`` stamp and closes a moment after it, so the span is
     never the shorter by more than the counter's rounding)."""
-    spans, steps = traced
+    spans, steps, _ = traced
     for n, stats in steps.items():
         if component == "push_pull":
             got = _named(spans, "bps.push_pull")
@@ -189,29 +247,56 @@ def test_span_durations_are_the_counters(traced, component):
 def test_spans_carry_step_and_tensor(traced):
     """Spans of one step share its number; per-leaf and per-unit spans
     name their tensor, dispatch its width and bytes."""
-    spans, steps = traced
+    spans, steps, _ = traced
     assert all(s.args.get("step") in steps for s in spans), [
         s for s in spans if s.args.get("step") not in steps]
     for s in spans:
-        if s.name in PER_LEAF + PER_UNIT:
+        if s.name in PER_TENSOR + PER_UNIT:
             assert str(s.args["tensor"]).startswith("grad['w"), s
     for u in _named(spans, "bps.engine.dispatch"):
-        assert u.args["bytes"] == u.args["width"] * (1 << 16), u
+        assert u.args["bytes"] == u.args["width"] * PART, u
 
 
-def test_without_a_session_nothing_is_recorded():
+def test_bucket_counters_and_leaves_argument(traced):
+    """ISSUE 24: the step counts its bucket tensors and the leaves that
+    rode them (the other ``pushes - buckets`` tensors went per leaf),
+    and a bucket's enqueue / dispatch spans carry ``leaves``; a leaf
+    pushed alone carries none."""
+    spans, steps, shape = traced
+    for stats in steps.values():
+        assert stats.pushes == shape["tensors"], stats
+        assert (stats.buckets, stats.bucketed_leaves) == (
+            shape["buckets"], shape["bucketed_leaves"]), stats
+    tagged = [s for s in spans
+              if s.name in ("bps.engine.enqueue", "bps.engine.dispatch")]
+    assert tagged
+    for s in tagged:
+        if shape["buckets"]:
+            assert s.args["leaves"] == LEAVES, s
+            assert s.args["tensor"] == "grad['w0']+3", s
+        else:
+            assert "leaves" not in s.args, s
+
+
+@pytest.mark.parametrize("shape", [BUCKETED, PER_LEAF],
+                         ids=["bucketed", "per_leaf"])
+def test_without_a_session_nothing_is_recorded(shape):
     """No profiler session: no annotation is ever built, the counters
-    fill all the same, and the caller thread's three phases account for
-    the whole push_pull (within 5 %)."""
+    fill all the same, no step compiles (the plan declared every
+    program), and the caller thread's three phases account for the whole
+    push_pull (within 5 %)."""
     assert not jax.profiler.TraceAnnotation.is_enabled()
     fed = []
     with tracing.phase("bps.test.none", fed.append) as ph:
         ph.note(step=1)
     assert ph.ann is None and fed == [(ph.t1 - ph.t0) * 1e3]
-    steps = _engine_steps(10, leaf_elems=1 << 18)[2:]
+    steps = _engine_steps(10, leaf_elems=shape["leaf_elems"])[2:]
     assert len(steps) == 8
     for s in steps:
-        assert s.dispatches > 0 and s.chunks == 16
+        assert "compile" not in s.attrib, s.attrib
+        assert s.dispatches > 0 and s.chunks == shape["chunks"]
+        assert (s.pushes, s.buckets, s.bucketed_leaves) == (
+            shape["tensors"], shape["buckets"], shape["bucketed_leaves"])
         assert all(s.attrib.get(c, 0.0) > 0 for c in PHASES), s.attrib
         assert s.push_pull_ms <= s.wall_ms
     shares = sorted((s.attrib["enqueue"] + s.attrib["submit"]
