@@ -9,6 +9,12 @@ from .llama import (  # noqa: F401
     llama_tiny,
 )
 from .mlp import MLP, mnist_mlp  # noqa: F401
+from .olmoe import (  # noqa: F401
+    Olmoe,
+    OlmoeConfig,
+    olmoe_loss,
+    olmoe_tiny,
+)
 from .resnet import (  # noqa: F401
     ResNet,
     VGG,

@@ -1,14 +1,22 @@
-"""Expert parallelism: switch-style MoE with all-to-all token dispatch.
+"""Expert MLPs: a switch MoE over an ``ep`` mesh axis, and a dropless
+top-k MoE over local experts.
 
-The reference is DP-only (SURVEY.md §2.6); expert parallelism is the
-axis that scales *width* sub-linearly in FLOPs — a Switch-Transformer
-MLP whose experts live one-shard-per-device on an ``ep`` mesh axis.
-TPU-native shape, matching this repo's explicit-collective idiom
-(sequence.py, pipeline.py): routing and capacity are computed per token
-shard, the dispatched [experts, capacity, hidden] block crosses the
-``ep`` axis as ONE ``lax.all_to_all`` each way (the same collective
-Ulysses uses for heads), and every shape is static — dropped-token
-semantics via a capacity factor, exactly the published Switch design.
+Two entries, two designs; they share nothing but this file.
+
+**Switch (top-1, capacity, ``ep``)** — :func:`switch_dispatch`,
+:func:`moe_mlp`, :func:`make_dp_ep_train_step`.  The reference is DP-only
+(SURVEY.md §2.6); expert parallelism is the axis that scales *width*
+sub-linearly in FLOPs — a Switch-Transformer MLP whose experts live
+one-shard-per-device on an ``ep`` mesh axis.  TPU-native shape, matching
+this repo's explicit-collective idiom (sequence.py, pipeline.py):
+routing and capacity are computed per token shard, the dispatched
+[experts, capacity, hidden] block crosses the ``ep`` axis as ONE
+``lax.all_to_all`` each way (the same collective Ulysses uses for
+heads), and every shape is static — dropped-token semantics via a
+capacity factor, the published Switch design: top-1, GELU experts with
+biases, a dense ``[N, E, C]`` one-hot contracted by ``einsum``.  This is
+what ``models/gpt.py`` ``MoEMLP`` (``GPTConfig.moe_experts``) and
+``parallel/moe_lm.py`` still build.
 
 Parity contract: :func:`moe_mlp` (distributed, inside shard_map) and
 :func:`moe_mlp_reference` (pure, single device, same token grouping)
@@ -16,10 +24,24 @@ compute the identical function — pinned to float tolerance by
 tests/test_expert_parallel.py.  Routing semantics are shard-local
 (capacity applies per token shard), so the math does not depend on the
 mesh size — only the placement does.
+
+**Dropless top-k (local experts)** — :func:`dropless_moe_mlp`, what
+``models/olmoe.py`` builds: softmax over all experts, the k largest
+kept with their weights as they are, no capacity and no dropped token,
+bias-free SiLU-gated experts.  The token–expert pairs are sorted by
+expert and the three expert matmuls run as grouped matmuls over the
+ragged groups (``_grouped_matmul``: JAX's Pallas megablox kernels; the
+interpreter off the TPU), so the work is k experts a token
+and no tensor grows with ``E x C``.  Every expert is local: there is NO
+``ep`` axis on this path yet (top-k dispatch by ``all_to_all`` is
+ROADMAP R1's next step), so under data parallelism each replica holds
+all experts and routing, both router losses and the counts are
+shard-local, as the switch path's are.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -215,3 +237,123 @@ def make_dp_ep_train_step(mesh: Mesh, num_experts: int,
 
     return jit_mapped_step(mesh, step, spec_of, P((DP_AXIS, EP_AXIS)),
                            donate=donate)
+
+
+# --------------------------------------------------- dropless top-k experts
+
+# (rows, contraction, columns) tile of the grouped matmul: the fastest of
+# six measured on a v5e at OLMoE's shape (65 536 pair rows, 64 groups of
+# ~1 024, 2048 x 1024 bf16 matrices; PERF.md section 6, PR 25); two larger
+# ones do not fit VMEM.  Each is clipped to the array.
+_GMM_TILE = (512, 1024, 1024)
+
+
+def _grouped_matmul(x, w, group_sizes, interpret: bool):
+    """Rows of ``x`` [M, a], sorted into ``len(group_sizes)`` consecutive
+    groups, times each group's own matrix of ``w`` [G, a, b] -> [M, b]:
+    JAX's Pallas grouped matmul (megablox ``gmm``; its VJP is ``gmm``
+    with the matrices transposed for the rows and ``tgmm`` for the
+    matrices).  A group may be empty."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m, a = x.shape
+    rows = math.gcd(m, _GMM_TILE[0])
+    if rows % 8:
+        raise ValueError(
+            f"the grouped matmul tiles its {m} rows (tokens x top_k) in "
+            f"blocks of a multiple of 8 rows that divides them; {m} has "
+            f"none")
+    tile = (rows, min(a, _GMM_TILE[1]), min(w.shape[-1], _GMM_TILE[2]))
+    return gmm(x, w, group_sizes, x.dtype, tile, interpret=interpret)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a PERMUTATION of the rows.  The transpose of a
+    gather is a scatter-add; of a permutation it is the gather by the
+    inverse permutation, which is what the backward pass runs."""
+    del inverse
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def dropless_moe_mlp(x, params, top_k: int,
+                     interpret: Optional[bool] = None):
+    """Dropless top-k MoE MLP over a token shard ``x`` [N, h].
+
+    params: ``{"router": [h, E] float32, "gate": [E, h, f], "up":
+    [E, h, f], "down": [E, f, h]}`` — the FULL expert stacks, all local
+    (no ``ep`` axis on this path; module docstring).  No biases.
+
+        p      = softmax(x_f32 @ router)            over all E
+        w, idx = top_k(p, k)                        w NOT renormalised
+        y      = sum_j w[:, j] * down_idx_j(silu(gate_idx_j x) * up_idx_j x)
+
+    Returns ``(y [N, h] in x.dtype, aux, z, counts [E] int32)``:
+    ``aux = E * sum_e f_e P_e`` with ``f_e`` = pairs routed to e / N and
+    ``P_e`` = mean router probability (the Switch load-balance loss
+    summed over the k choices), ``z = mean(logsumexp(logits)^2)``
+    (ST-MoE router z-loss), ``counts`` the pairs each expert received.
+    Router arithmetic is float32; the experts compute in ``x.dtype``.
+    Shapes are static: exactly ``N * k`` pair rows, so dropless needs no
+    padding and an expert may receive none.  ``interpret=None`` runs the
+    grouped-matmul kernels on a real TPU backend and through the Pallas
+    interpreter elsewhere (CPU tests), as ``ops.flash_attention`` does.
+    """
+    if interpret is None:
+        from ..ops.pallas_kernels import on_tpu
+        interpret = not on_tpu()
+    n, h = x.shape
+    e = params["router"].shape[-1]
+    with jax.named_scope("bps.moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)       # [N, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = lax.top_k(probs, top_k)                  # [N, k]
+        pair_expert = idx.reshape(n * top_k)
+        counts = jnp.bincount(pair_expert, length=e).astype(jnp.int32)
+        aux = e * jnp.sum(counts.astype(jnp.float32) / n
+                          * jnp.mean(probs, axis=0))
+        z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    with jax.named_scope("bps.moe.dispatch"):
+        # pairs sorted by expert (stable: a token's order within its
+        # group is its arrival order); each token's row gathered k times
+        order = jnp.argsort(pair_expert, stable=True)           # [N k]
+        inverse = jnp.argsort(order)
+        xs = _permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
+    with jax.named_scope("bps.moe.experts"):
+        dt = x.dtype
+        gate = _grouped_matmul(xs, params["gate"].astype(dt), counts,
+                               interpret)
+        up = _grouped_matmul(xs, params["up"].astype(dt), counts, interpret)
+        ys = _grouped_matmul(jax.nn.silu(gate) * up,
+                             params["down"].astype(dt), counts, interpret)
+    with jax.named_scope("bps.moe.combine"):
+        pairs = _permute_rows(ys, inverse, order).reshape(n, top_k, h)
+        y = jnp.sum(pairs.astype(jnp.float32) * weights[..., None], axis=1)
+    return y.astype(x.dtype), aux, z, counts
+
+
+def publish_moe_stats(counts) -> None:
+    """Set the load gauges ``bps.metrics_snapshot()`` reads from the
+    per-expert pair counts of one batch: ``counts`` [E] or [layers, E]
+    (``dropless_moe_mlp``'s fourth result; ``models/olmoe.py`` sows it
+    into ``moe_stats``).  Host side: it reads the values, so call it
+    outside any jitted step and off the step's critical path."""
+    from ..common.metrics import gauges
+    c = np.asarray(counts, np.float64).reshape(-1, np.shape(counts)[-1])
+    gauges.set("moe.load_max_over_mean",
+               float(np.max(c.max(axis=1) / c.mean(axis=1))))
+    gauges.set("moe.tokens_per_expert_min", float(c.min()))
+    gauges.set("moe.tokens_per_expert_max", float(c.max()))
