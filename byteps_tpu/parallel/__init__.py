@@ -15,6 +15,7 @@ step-for-step against single-device math by its test file.
 """
 
 from .data_parallel import (  # noqa: F401
+    collective_schedule,
     dp_specs,
     make_dp_train_step,
     make_dp_train_step_with_state,

@@ -16,7 +16,8 @@ Phases (each prints one JSON line):
                 push_pull and push_pull_async/synchronize == numpy mean;
                 the same with onebit compression vs tests/compression_refs
   fused_train   BERT-large, seq 128, 32 examples/chip, adamw, 5 steps of
-                make_dp_train_step; loss finite and falling
+                make_dp_train_step; loss finite and falling; on several
+                chips its all-reduce is asynchronous (collective_schedule)
   engine_train  same model/batch/params through engine-mode
                 DistributedOptimizer, 3 steps; losses agree with fused
   kernels       flash_attention fwd + grad vs exact attention; onebit
@@ -54,6 +55,9 @@ PHASES = ("device", "engine", "fused_train", "engine_train", "kernels", "dcn")
 PHASE_BUDGET_S = {"device": 120, "engine": 300, "fused_train": 420,
                   "engine_train": 480, "kernels": 240, "dcn": 420}
 MOSAIC_CALL = "tpu_custom_call"
+# asynchronous all-reduces of BERT-large's DP step on several chips: 20
+# when parallel.data_parallel's options engage, 2 when they do not
+MIN_ASYNC_REDUCES = 10
 
 
 def emit(doc: dict) -> None:
@@ -324,7 +328,8 @@ def _fused_steps(ctx, comm, steps: int, compress_dcn=None) -> dict:
     import numpy as np
     import optax
 
-    from byteps_tpu.parallel import (make_dp_train_step, replicate,
+    from byteps_tpu.parallel import (collective_schedule,
+                                     make_dp_train_step, replicate,
                                      shard_batch)
     _, _, loss_fn, batch_h, params_h = _bert(ctx)
     n = ctx["n"]
@@ -336,9 +341,22 @@ def _fused_steps(ctx, comm, steps: int, compress_dcn=None) -> dict:
     _spread(batch, n, "sharded batch")
     t0 = time.perf_counter()
     compiled = step.lower(params, opt_state, batch).compile()
-    obs = {"compile_s": round(time.perf_counter() - t0, 2)}
+    text = compiled.as_text()
+    obs = {"compile_s": round(time.perf_counter() - t0, 2),
+           "collective_schedule": collective_schedule(text)}
+    if compress_dcn is None and n > 1 and not ctx["rehearsal"]:
+        # tier-1 runs on the CPU, where no all-reduce is asynchronous:
+        # this is the one place the mechanism is guarded on real chips.
+        # BERT-large's two 119 MiB embeddings go asynchronous under the
+        # compiler's default packing too, so "> 0" would pass with the
+        # combiner threshold dead; with it, 18 FFN leaves join them (20)
+        check(obs["collective_schedule"]["async"] >= MIN_ASYNC_REDUCES,
+              "the DP step on several TPUs holds "
+              f"{obs['collective_schedule']} collectives, under "
+              f"{MIN_ASYNC_REDUCES} asynchronous (parallel.data_parallel."
+              "ASYNC_REDUCE_COMPILER_OPTIONS did not engage)")
     if compress_dcn is not None and not ctx["rehearsal"]:
-        check(MOSAIC_CALL in compiled.as_text(),
+        check(MOSAIC_CALL in text,
               "compressed-DCN step holds no Mosaic custom call: the "
               "onebit kernels were replaced by the jnp path")
     losses, times = [], []

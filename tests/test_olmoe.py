@@ -1,6 +1,9 @@
 """OLMoE on the CPU: the dropless top-k expert layer and the whole model
 against the plain reference (tests/olmoe_reference.py), the fused DP
-step on the 4-device mesh, and the load gauges.
+step on the 4-device mesh, and the load gauges.  (The layer's compile for
+a described v5e at the published widths is in tests/test_v5e_compile.py,
+with every other test that describes a TPU topology: one libtpu per
+process, so one file.)
 
 Tolerance: rtol 1e-5 (with an absolute floor of 1e-5 of each array's
 largest magnitude).  Both sides compute in float32 at full precision;
@@ -230,46 +233,3 @@ def test_publish_moe_stats_sets_the_gauges():
         (counts.max(1) / counts.mean(1)).max())
     publish_moe_stats(np.full((8,), 5))              # one layer, balanced
     assert bps.metrics_snapshot()["gauges"]["moe.load_max_over_mean"] == 1.0
-
-
-# ------------------------------------- the chip's compiler, without the chip
-
-@pytest.fixture(scope="module")
-def one_chip():
-    """A described (not attached) v5e chip: compiles run the real XLA:TPU
-    and Mosaic compilers, nothing executes.  Described inside the fixture
-    only — never while a module is imported (one libtpu per process)."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def test_layer_compiles_for_a_v5e_at_the_published_widths(one_chip):
-    """OLMoE-1B-7B's expert layer, forward and backward, at 4 x 4096
-    tokens: Mosaic takes the grouped matmuls at ``_GMM_TILE`` (two larger
-    tiles overflow VMEM) — nine kernels, none interpreted or replaced."""
-    n, h, f, e, k = 4 * 4096, 2048, 1024, 64, 8
-
-    def shaped(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = {"router": shaped((h, e), jnp.float32),
-              "gate": shaped((e, h, f), jnp.float32),
-              "up": shaped((e, h, f), jnp.float32),
-              "down": shaped((e, f, h), jnp.float32)}
-
-    def objective(params, x):
-        y, aux, z, _ = dropless_moe_mlp(x, params, k, interpret=False)
-        return jnp.sum(y.astype(jnp.float32)) + aux + z
-
-    text = jax.jit(jax.grad(objective, argnums=(0, 1))).lower(
-        params, shaped((n, h), jnp.bfloat16)).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 9
-    for scope in ("bps.moe.route", "bps.moe.dispatch", "bps.moe.experts",
-                  "bps.moe.combine"):
-        assert scope in text

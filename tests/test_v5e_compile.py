@@ -1,0 +1,110 @@
+"""The chip's compiler, without the chip: programs of the main path compiled
+for a DESCRIBED (not attached) v5e:2x2 by the real XLA:TPU and Mosaic
+compilers.  Nothing executes; what the compiler refuses, or stops doing,
+fails here at no chip time.
+
+Every test that describes a TPU topology lives in THIS file: one process
+loads libtpu at a time, so under several test workers a second file's
+fixture would skip all of its tests in silence.  The topology is described
+inside a fixture only, never while a module is imported.
+"""
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from byteps_tpu.comm.mesh import CommContext, _build_mesh
+from byteps_tpu.parallel import collective_schedule, make_dp_train_step
+from byteps_tpu.parallel.expert import dropless_moe_mlp
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# ------------------------------------------------ the dropless expert layer
+
+def test_layer_compiles_for_a_v5e_at_the_published_widths(one_chip):
+    """OLMoE-1B-7B's expert layer, forward and backward, at 4 x 4096
+    tokens: Mosaic takes the grouped matmuls at ``_GMM_TILE`` (two larger
+    tiles overflow VMEM) — nine kernels, none interpreted or replaced."""
+    n, h, f, e, k = 4 * 4096, 2048, 1024, 64, 8
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"router": shaped((h, e), jnp.float32),
+              "gate": shaped((e, h, f), jnp.float32),
+              "up": shaped((e, h, f), jnp.float32),
+              "down": shaped((e, f, h), jnp.float32)}
+
+    def objective(params, x):
+        y, aux, z, _ = dropless_moe_mlp(x, params, k, interpret=False)
+        return jnp.sum(y.astype(jnp.float32)) + aux + z
+
+    text = jax.jit(jax.grad(objective, argnums=(0, 1))).lower(
+        params, shaped((n, h), jnp.bfloat16)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    for scope in ("bps.moe.route", "bps.moe.dispatch", "bps.moe.experts",
+                  "bps.moe.combine"):
+        assert scope in text
+
+
+# ------------------------------- the fused step's asynchronous all-reduce
+
+def _compiled_dp_step(devices) -> str:
+    """``make_dp_train_step`` (its own jit, its own compiler options) for
+    an MLP of four 32 MiB leaves under AdamW, compiled for ``devices``.
+    (Over the combiner's 30 MiB, so no leaf can ride a tuple.)"""
+    n = len(devices)
+    comm = CommContext(mesh=_build_mesh(devices, 1), n_dcn=1, n_ici=n)
+    rep = comm.replicated_sharding()
+
+    def loss(p, b):
+        h = b["x"]
+        for name in sorted(p):
+            h = jnp.tanh(h @ p[name])
+        return jnp.mean(h ** 2)
+
+    def shaped(tree, sharding):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    tx = optax.adamw(1e-3)
+    params = {f"w{i}": jax.ShapeDtypeStruct(
+        (2048, 4096) if i % 2 == 0 else (4096, 2048), jnp.float32)
+        for i in range(4)}
+    batch = {"x": jax.ShapeDtypeStruct((8 * n, 2048), jnp.float32)}
+    step = make_dp_train_step(comm, loss, tx)
+    return step.lower(
+        shaped(params, rep), shaped(jax.eval_shape(tx.init, params), rep),
+        shaped(batch, NamedSharding(comm.mesh, P(comm.dp_axes)))
+    ).compile().as_text()
+
+
+def test_dp_step_on_four_chips_reduces_asynchronously(topo):
+    """The mechanism of PR 26, end to end through the builder: a described
+    chip's platform is "tpu", so the step carries
+    ``ASYNC_REDUCE_COMPILER_OPTIONS`` and XLA:TPU wraps each 32 MiB
+    leaf's all-reduce in an async-collective fusion.  Only the loss's
+    scalar stays synchronous."""
+    assert collective_schedule(_compiled_dp_step(topo.devices)) == {
+        "sync": 1, "async": 4}
+
+
+def test_dp_step_on_one_chip_holds_no_collective(topo):
+    assert collective_schedule(_compiled_dp_step(topo.devices[:1])) == {
+        "sync": 0, "async": 0}
