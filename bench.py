@@ -224,13 +224,13 @@ def _bench_push_pull(devices, on_tpu, emit=None):
             eng.shutdown(wait=False)
         return to_gbps(nbytes, times)
 
-    def engine_device_gbps(nbytes, reps=5, **cfg_kw):
+    def engine_device_gbps(nbytes, reps=5):
         """Engine path fed a device-resident stacked array: measures the
         engine itself (scheduler, partitioner, per-chunk dispatch,
         collective) without the host->device staging cost — the fair
         comparison against the fused path (round-1 weakness #4: the host
         round-trip must not be mistaken for engine overhead)."""
-        cfg = Config(telemetry_on=False, trace_on=False, **cfg_kw)
+        cfg = Config(telemetry_on=False, trace_on=False)
         eng = PushPullEngine(comm, cfg)
         try:
             # (n, nbytes/4): every rank contributes nbytes, matching
@@ -271,13 +271,13 @@ def _bench_push_pull(devices, on_tpu, emit=None):
 
     def dispatch_amortization(nchunks=64):
         """Deterministic dispatch-count datum (VERDICT r4 task 3): the
-        same multi-chunk push through both dispatcher modes with the
-        dispatcher paused until the queue holds every chunk, so the
-        merge width is the mode's property, not a race."""
+        same multi-chunk push unmerged and at the default group size,
+        with the dispatcher paused until the queue holds every chunk, so
+        the merge width is the setting's property, not a race."""
         counts = {}
         chunk_elems = 65536 // 4
         x = np.zeros(nchunks * chunk_elems, np.float32)
-        for label, gs in (("group4", 4), ("drain", -1)):
+        for label, gs in (("group1", 1), ("group4", 4)):
             cfg = Config(telemetry_on=False, trace_on=False,
                          group_size=gs, partition_bytes=65536)
             eng = PushPullEngine(comm, cfg)
@@ -322,18 +322,6 @@ def _bench_push_pull(devices, on_tpu, emit=None):
     add(f"engine_device_{big // mb}MB", lambda: engine_device_gbps(big))
     for nbytes in sizes:
         add(f"engine_{nbytes // mb}MB", lambda n=nbytes: engine_gbps(n))
-    # Drain-mode dispatch amortization (round-4 VERDICT task 3): the whole
-    # eligible window executes as the fewest XLA programs (one chunk-
-    # scatter program per contiguous run) — the ready answer if hardware
-    # says per-chunk dispatch dominates the engine's rent.  Runs before
-    # the window-economy gate on purpose: when the plain engine is slow
-    # is exactly when this figure matters.  The device-resident variant
-    # is the clean isolate (vs engine_device: same input, fewer
-    # dispatches; no host-staging noise in the comparison).
-    add(f"engine_grouped_{big // mb}MB",
-        lambda: engine_gbps(big, group_size=-1))
-    add(f"engine_device_grouped_{big // mb}MB",
-        lambda: engine_device_gbps(big, group_size=-1))
     # Headline ratios (ISSUE 5 acceptance: engine >= 0.7x fused, from
     # 0.30x): the engine-vs-fused gap IS the metric this bench exists to
     # track, so it rides the compact summary line, not just the full
@@ -1385,7 +1373,7 @@ def _compact_summary(doc):
                         best = (int(m.group(1)), k, v)
         return best
 
-    for prefix in ("fused", "engine_device", "engine_grouped", "engine"):
+    for prefix in ("fused", "engine_device", "engine"):
         b = _largest(prefix)
         if b:
             heads[b[1] + "_gbps"] = b[2]

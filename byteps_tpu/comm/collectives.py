@@ -442,8 +442,8 @@ def aot_warm_buffer_programs(comm: CommContext, *, col_layout, C: int,
                              assembled: bool = True) -> int:
     """Pre-compile the persistent program set for one buffer-mode tensor;
     returns the number of executables AOT-compiled.  ``merge_widths``:
-    the run widths the dispatcher can form (engine-supplied: pow2 splits
-    in drain mode, 1..group_size otherwise).  ``assembled=False``: a
+    the run widths the dispatcher can form (engine-supplied:
+    1..group_size).  ``assembled=False``: a
     bucket tensor, which arrives packed and padded and leaves through
     its unpack program -- only the chunk programs and their scalars."""
     from jax.sharding import NamedSharding

@@ -172,10 +172,9 @@ class Config:
     enable_priority: bool = True     # priority ordering of chunk dispatch
     group_size: int = 4              # BYTEPS_GROUP_SIZE: chunks per device
     #                                  program (reference BYTEPS_NCCL_GROUP_SIZE
-    #                                  batching, nccl_manager.cc:130-134).
-    #                                  -1 = drain mode: every dispatch empties
-    #                                  the whole eligible credit window into
-    #                                  the fewest programs (engine._plan_batch)
+    #                                  batching, nccl_manager.cc:130-134);
+    #                                  a count: 0 is read as 1, negative is
+    #                                  rejected
     autotune: bool = True            # BYTEPS_AUTOTUNE: online chunk-size /
     #                                  credit-window planner
     #                                  (common/scheduler.py ChunkPlanner).
@@ -267,7 +266,11 @@ class Config:
     #                                  wall-time race
 
     # --- native core ---
-    use_native: bool = True          # BYTEPS_NATIVE: C++ scheduler/reducer
+    use_native: bool = True          # BYTEPS_NATIVE: C++ host reducer /
+    #                                  partition arithmetic / Elias coder /
+    #                                  CRC32C (server/, compression/elias.py,
+    #                                  common/integrity.py); the engine's
+    #                                  chunk queue is Python either way
     use_pallas: bool = True          # BYTEPS_PALLAS: TPU kernels for hot ops
 
     # --- modes ---
@@ -771,6 +774,12 @@ class Config:
             self.credit_pinned = self.scheduling_credit != 0
         if self.buffer_min_bytes < 0:
             raise ValueError("buffer_min_bytes must be >= 0")
+        if self.group_size < 0:
+            raise ValueError(
+                f"group_size {self.group_size}: a negative value selected "
+                "drain mode, which was removed; group_size "
+                "(BYTEPS_GROUP_SIZE) is the count of chunks merged per "
+                "device program, >= 1 (0 is read as 1)")
         if self.sharded_param_codec not in ("", "auto"):
             # "name" or "name:k" — structural check here; the codec name
             # and parameter are validated against the registry at declare

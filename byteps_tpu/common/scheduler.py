@@ -107,7 +107,7 @@ class ChunkScheduler:
     def wake(self) -> None:
         """Latched wakeup: every blocked and future get_task returns
         without waiting (engine shutdown).  Queue contents survive for
-        :meth:`drain` — mirrors the native scheduler's bps_sched_wake."""
+        :meth:`drain`."""
         with self._cv:
             self._shutdown = True
             self._cv.notify_all()

@@ -372,8 +372,6 @@ def metrics_snapshot(light: bool = False) -> Dict[str, Any]:
     eng = _engine
     if eng is not None:
         snap["speed_mbps"] = round(eng.speed.speed()[1], 3)
-        # which of the two schedulers actually ran (the native build can
-        # fail and fall back): NativeChunkScheduler | ChunkScheduler
         snap["scheduler"] = type(eng.scheduler).__name__
         snap["sched_pending"] = eng.scheduler.pending
         snap["bytes_in_flight"] = eng.scheduler.bytes_in_flight

@@ -80,21 +80,7 @@ def _stale_epoch_error(task, epoch: int) -> StaleEpochError:
         f"epoch {epoch}; chunk dropped, re-push under the new epoch")
 
 
-def _pow2_split(seq):
-    """Split a task run into power-of-two-sized groups.  Drain mode merges
-    runs of unbounded width; each distinct width is a fresh XLA compile
-    (the group program's k is static), so bucketing widths to powers of
-    two bounds the compile cache at log2(n) entries per layout while
-    keeping the dispatch count within 2x of optimal."""
-    out, i, n = [], 0, len(seq)
-    while i < n:
-        k = 1 << ((n - i).bit_length() - 1)
-        out.append(seq[i:i + k])
-        i += k
-    return out
-
-
-def _plan_batch(batch, pow2_runs: bool = False):
+def _plan_batch(batch):
     """Group a popped priority-ordered task batch into dispatch units:
 
     - ``("run", tasks)``: contiguous equal-width column slabs of ONE
@@ -123,10 +109,7 @@ def _plan_batch(batch, pow2_runs: bool = False):
                    == run[-1].offset_elems + run[-1].num_elems):
                 run.append(batch[j])
                 j += 1
-            if pow2_runs and len(run) > 1:
-                units.extend(("run", sub) for sub in _pow2_split(run))
-            else:
-                units.append(("run", run))
+            units.append(("run", run))
             i = j
             continue
         if t.compression is None:
@@ -141,13 +124,10 @@ def _plan_batch(batch, pow2_runs: bool = False):
                    and batch[j].scale == t.scale):
                 group.append(batch[j])
                 j += 1
-            subs = (_pow2_split(group) if pow2_runs and len(group) > 1
-                    else [group])
             # a width-1 "group" would compile a fresh batched_ar program
             # for a computation the single-task all_reduce cache already
             # holds — route it through _dispatch_single instead
-            units.extend(("group" if len(sub) > 1 else "single", sub)
-                         for sub in subs)
+            units.append(("group" if len(group) > 1 else "single", group))
             i = j
             continue
         units.append(("single", [t]))
@@ -345,7 +325,7 @@ class PushPullEngine:
         # bucket plans by tree signature (push_pull_tree_async); dropped
         # with the engine on an elastic transition
         self._tree_plans: Dict[tuple, tuple] = {}
-        self.scheduler = self._make_scheduler(cfg)
+        self.scheduler = ChunkScheduler(credit_bytes=cfg.scheduling_credit)
         self.speed = SpeedMonitor()
         # ONE tracer per process (common/tracing.py): the engine, the
         # membership bus, the wire hops and the serving plane all emit
@@ -368,14 +348,13 @@ class PushPullEngine:
             for c in ("push_pull", "enqueue", "submit", "wait", "plan",
                       "dispatch", "compile", "sync", "assemble")}
         self._sync_q: "queue.Queue" = queue.Queue()
-        # group_size < 0 = drain mode (VERDICT r4 task 3): every dispatch
-        # iteration empties the whole eligible credit window and executes
-        # it as the fewest programs _plan_batch can form.  Multi-host
-        # stays at 1: merging is timing-dependent and SPMD processes must
-        # dispatch identical programs in identical order.
+        # Chunk tasks popped per dispatch iteration.  Multi-host stays at
+        # 1: merging is timing-dependent and SPMD processes must dispatch
+        # identical programs in identical order (the reference pins
+        # followers to the root's order via DO_* socket signals,
+        # communicator.h:43).
         self._group_size = (1 if jax.process_count() > 1
-                            else (-1 if cfg.group_size < 0
-                                  else max(1, cfg.group_size)))
+                            else max(1, cfg.group_size))
         # Auto-tuned chunk/credit planner: measures completed push_pulls
         # and re-carves partition bounds per tensor-size bucket; inert
         # when pinned (env/explicit config) or multi-process (SPMD
@@ -416,21 +395,6 @@ class PushPullEngine:
             self._deadline_thread.start()
         _flight.record("engine.init", ranks=comm.num_ranks,
                        epoch=_membership.current_epoch())
-
-    @staticmethod
-    def _make_scheduler(cfg: Config):
-        """Native C++ priority/credit queue when available (the reference's
-        scheduler is C++ too, scheduled_queue.cc); Python heap otherwise."""
-        if cfg.use_native:
-            try:
-                from ..native import NativeChunkScheduler
-                return NativeChunkScheduler(
-                    credit_bytes=cfg.scheduling_credit)
-            except Exception as e:  # noqa: BLE001 - toolchain may be absent
-                get_logger().warning(
-                    "native chunk scheduler unavailable, using the Python "
-                    "scheduler: %s: %s", type(e).__name__, e)
-        return ChunkScheduler(credit_bytes=cfg.scheduling_credit)
 
     # ------------------------------------------------------------------ API
     def push_pull_async(self, stacked, name: str,
@@ -1310,12 +1274,8 @@ class PushPullEngine:
             local_eff = local
             if local and self._sharded_staging_ok(col_layout, C):
                 local_eff = "sharded"
-            # run widths the dispatcher can form: pow2 splits in drain
-            # mode, anything up to the group cap otherwise
-            if self._group_size < 0:
-                ks = {1 << i for i in range(max(1, nchunks).bit_length())}
-            else:
-                ks = set(range(1, self._group_size + 1))
+            # run widths the dispatcher can form: up to the group cap
+            ks = set(range(1, self._group_size + 1))
             return aot_warm_buffer_programs(
                 self.comm, col_layout=col_layout, C=C, n=ctx.num_elems,
                 out_shape=ctx.shape, dtype_name=ctx.dtype_name,
@@ -1513,21 +1473,10 @@ class PushPullEngine:
         # is already eligible, then merge neighbors into the fewest
         # device programs (_plan_batch).  Popping preserves priority
         # order; merging only ever joins neighbors in that order.
-        # group_size=-1 drains the ENTIRE eligible credit window per
-        # iteration (one program per mergeable run); a positive value
-        # caps the pop count.  Multi-host runs keep group_size=1 (the
-        # reference pins followers to the root's order via DO_*
-        # socket signals, communicator.h:43).
-        drain = self._group_size < 0
-        # Drain bound = the queue depth at drain START (snapshot
-        # semantics): tasks enqueued while we pop wait for the next
-        # iteration, so a fast producer can neither defer the popped
-        # head's dispatch indefinitely nor grow the batch without
-        # limit (the credit window, when set, additionally gates each
-        # pop inside get_task).
-        limit = self.scheduler.pending if drain else self._group_size - 1
+        # group_size caps the pop count (the credit window, when set,
+        # additionally gates each pop inside get_task).
         batch = [task]
-        while len(batch) - 1 < limit:
+        while len(batch) < self._group_size:
             t2 = self.scheduler.get_task(block=False)
             if t2 is None:
                 break
@@ -1561,7 +1510,7 @@ class PushPullEngine:
             gauges.set("engine.sched_pending", self.scheduler.pending)
             gauges.set("engine.bytes_in_flight",
                        self.scheduler.bytes_in_flight)
-        return _plan_batch(batch, pow2_runs=drain)
+        return _plan_batch(batch)
 
     def _dispatch_buffer_run(self, run: List[ChunkTask], now: float):
         """One device program for a contiguous run of column-slab chunks:

@@ -48,8 +48,8 @@ def load() -> Optional[ctypes.CDLL]:
         return _lib
     if _load_failed:
         return None
-    # single gate shared with the engine: Config parses BYTEPS_NATIVE (and
-    # programmatic set_config(use_native=False) must win over the env)
+    # one gate: Config parses BYTEPS_NATIVE (and programmatic
+    # set_config(use_native=False) must win over the env)
     from ..common.config import get_config
     if not get_config().use_native:
         return None  # not latched: a later config may re-enable native
@@ -67,7 +67,7 @@ def load() -> Optional[ctypes.CDLL]:
                 _compile(path)
                 lib = ctypes.CDLL(path)
             _declare_signatures(lib)
-            if lib.bps_native_abi_version() != 4:
+            if lib.bps_native_abi_version() != 5:
                 raise RuntimeError("native ABI mismatch")
             _lib = lib
         except Exception as e:  # noqa: BLE001 — Python twins take over
@@ -76,7 +76,7 @@ def load() -> Optional[ctypes.CDLL]:
             from ..common.logging import get_logger
             get_logger().warning(
                 "native core unavailable, using the pure-Python "
-                "scheduler/reducer: %s", _load_error)
+                "reducer/coder/CRC: %s", _load_error)
             return None
     return _lib
 
@@ -100,7 +100,6 @@ def available() -> bool:
 def _declare_signatures(lib: ctypes.CDLL) -> None:
     i64, u64, f32, f64 = (ctypes.c_int64, ctypes.c_uint64, ctypes.c_float,
                           ctypes.c_double)
-    p = ctypes.c_void_p
     lib.bps_make_key.restype = u64
     lib.bps_make_key.argtypes = [u64, u64]
     lib.bps_key_declared.restype = u64
@@ -111,25 +110,6 @@ def _declare_signatures(lib: ctypes.CDLL) -> None:
     lib.bps_chunk_bounds.argtypes = [i64, i64, i64, i64,
                                      ctypes.POINTER(i64),
                                      ctypes.POINTER(i64), i64]
-    lib.bps_sched_create.restype = p
-    lib.bps_sched_create.argtypes = [i64]
-    lib.bps_sched_destroy.argtypes = [p]
-    lib.bps_sched_add.argtypes = [p, i64, i64, u64, i64]
-    lib.bps_sched_get.restype = i64
-    lib.bps_sched_get.argtypes = [p, ctypes.c_int, f64,
-                                  ctypes.POINTER(i64)]
-    lib.bps_sched_report_finish.argtypes = [p, i64]
-    lib.bps_sched_wake.argtypes = [p]
-    lib.bps_sched_interrupt.argtypes = [p]
-    lib.bps_sched_set_credit.argtypes = [p, i64]
-    lib.bps_sched_get_credit.restype = i64
-    lib.bps_sched_get_credit.argtypes = [p]
-    lib.bps_sched_pending.restype = i64
-    lib.bps_sched_pending.argtypes = [p]
-    lib.bps_sched_in_flight.restype = i64
-    lib.bps_sched_in_flight.argtypes = [p]
-    lib.bps_sched_drain.restype = i64
-    lib.bps_sched_drain.argtypes = [p, ctypes.POINTER(i64), i64]
     for name, ct in (("bps_reduce_sum_f32", f32), ("bps_reduce_sum_f64", f64)):
         fn = getattr(lib, name)
         fn.argtypes = [ctypes.POINTER(ct), ctypes.POINTER(ct), i64,
@@ -155,86 +135,6 @@ def _declare_signatures(lib: ctypes.CDLL) -> None:
     lib.bps_crc32c.restype = ctypes.c_uint32
     lib.bps_crc32c.argtypes = [ctypes.c_char_p, i64, ctypes.c_uint32]
     lib.bps_native_abi_version.restype = ctypes.c_int
-
-
-# --------------------------------------------------------------- scheduler
-
-class NativeChunkScheduler:
-    """Drop-in for common.scheduler.ChunkScheduler backed by the C++
-    priority/credit queue.  Python keeps the task objects; only the ordering
-    state (priority, key, nbytes, credit window) lives native."""
-
-    def __init__(self, credit_bytes: int = 0, lib: Optional[ctypes.CDLL]
-                 = None):
-        self._lib = lib or load()
-        if self._lib is None:
-            raise RuntimeError(
-                "native core not available"
-                + (f" ({_load_error})" if _load_error else ""))
-        self._h = self._lib.bps_sched_create(credit_bytes)
-        self._tasks = {}
-        self._next_id = 0
-        self._mu = threading.Lock()
-
-    def add_task(self, task) -> None:
-        with self._mu:
-            tid = self._next_id
-            self._next_id += 1
-            self._tasks[tid] = task
-        self._lib.bps_sched_add(self._h, tid, task.priority, task.key,
-                                task.nbytes)
-
-    def get_task(self, block: bool = False,
-                 timeout: Optional[float] = None):
-        nbytes = ctypes.c_int64(0)
-        tid = self._lib.bps_sched_get(
-            self._h, 1 if block else 0,
-            -1.0 if timeout is None else float(timeout),
-            ctypes.byref(nbytes))
-        if tid < 0:
-            return None
-        with self._mu:
-            return self._tasks.pop(tid)
-
-    def report_finish(self, nbytes: int) -> None:
-        self._lib.bps_sched_report_finish(self._h, nbytes)
-
-    @property
-    def pending(self) -> int:
-        return int(self._lib.bps_sched_pending(self._h))
-
-    @property
-    def bytes_in_flight(self) -> int:
-        return int(self._lib.bps_sched_in_flight(self._h))
-
-    def drain(self) -> list:
-        cap = max(1, self.pending)
-        ids = (ctypes.c_int64 * cap)()
-        n = self._lib.bps_sched_drain(self._h, ids, cap)
-        with self._mu:
-            return [self._tasks.pop(ids[i]) for i in range(n)
-                    if ids[i] in self._tasks]
-
-    def interrupt(self) -> None:
-        """One-shot wakeup of a blocked get_task (pause handshake)."""
-        self._lib.bps_sched_interrupt(self._h)
-
-    def set_credit_bytes(self, credit_bytes: int) -> None:
-        self._lib.bps_sched_set_credit(self._h, int(credit_bytes))
-
-    @property
-    def credit_bytes(self) -> int:
-        return int(self._lib.bps_sched_get_credit(self._h))
-
-    def wake(self) -> None:
-        """Release any blocked get_task (engine shutdown)."""
-        self._lib.bps_sched_wake(self._h)
-
-    def __del__(self):
-        lib, h = getattr(self, "_lib", None), getattr(self, "_h", None)
-        if lib is not None and h:
-            lib.bps_sched_destroy(h)
-            self._h = None
 
 
 # -------------------------------------------------------------- partitioner

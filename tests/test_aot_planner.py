@@ -57,18 +57,6 @@ def _task(key, nbytes=64, priority=0):
                      total_parts=1)
 
 
-def _schedulers():
-    out = [("python", lambda: ChunkScheduler(credit_bytes=0))]
-    try:
-        from byteps_tpu.native import NativeChunkScheduler, load
-        if load() is not None:
-            out.append(("native",
-                        lambda: NativeChunkScheduler(credit_bytes=0)))
-    except Exception:  # noqa: BLE001 — toolchain absent
-        pass
-    return out
-
-
 # ---------------------------------------------------------------- headline
 
 
@@ -221,9 +209,8 @@ def test_planner_stale_inflight_sample_ignored():
 # ------------------------------------------------------------- scheduler
 
 
-@pytest.mark.parametrize("name,mk", _schedulers())
-def test_scheduler_interrupt_wakes_blocked_get(name, mk):
-    s = mk()
+def test_scheduler_interrupt_wakes_blocked_get():
+    s = ChunkScheduler(credit_bytes=0)
     got = {}
 
     def worker():
@@ -239,18 +226,16 @@ def test_scheduler_interrupt_wakes_blocked_get(name, mk):
     assert got["task"] is None
 
 
-@pytest.mark.parametrize("name,mk", _schedulers())
-def test_scheduler_interrupt_is_one_shot(name, mk):
-    s = mk()
+def test_scheduler_interrupt_is_one_shot():
+    s = ChunkScheduler(credit_bytes=0)
     s.interrupt()                               # latched for the NEXT get
     assert s.get_task(block=True) is None       # consumed here
     s.add_task(_task(1))
     assert s.get_task(block=True) is not None   # back to normal popping
 
 
-@pytest.mark.parametrize("name,mk", _schedulers())
-def test_scheduler_set_credit_unblocks_waiter(name, mk):
-    s = mk()
+def test_scheduler_set_credit_unblocks_waiter():
+    s = ChunkScheduler(credit_bytes=0)
     s.set_credit_bytes(64)
     assert s.credit_bytes == 64
     s.add_task(_task(1, nbytes=64))
@@ -270,9 +255,8 @@ def test_scheduler_set_credit_unblocks_waiter(name, mk):
     assert not t.is_alive() and got["task"] is not None
 
 
-@pytest.mark.parametrize("name,mk", _schedulers())
-def test_scheduler_wake_is_latched(name, mk):
-    s = mk()
+def test_scheduler_wake_is_latched():
+    s = ChunkScheduler(credit_bytes=0)
     s.wake()
     assert s.get_task(block=True) is None       # returns without waiting
     assert s.get_task(block=True) is None       # and keeps returning

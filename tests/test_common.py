@@ -47,6 +47,20 @@ def test_config_validation():
         Config(failure_exit_code=256)
     with pytest.raises(ValueError):
         Config(restart_limit=-1)
+    assert Config(group_size=0).group_size == 0     # the engine reads 0 as 1
+
+
+@pytest.mark.parametrize("via", ["Config", "BYTEPS_GROUP_SIZE"])
+@pytest.mark.parametrize("value", [-1, -8])
+def test_negative_group_size_is_rejected(monkeypatch, via, value):
+    """A negative group_size selected drain mode (removed): it arrives from
+    outside the program, so it is refused by name, not clamped."""
+    with pytest.raises(ValueError, match="drain mode.*removed"):
+        if via == "Config":
+            Config(group_size=value)
+        else:
+            monkeypatch.setenv(via, str(value))
+            Config.from_env()
 
 
 def test_config_fault_tolerance_knobs_from_env(monkeypatch):
@@ -148,17 +162,19 @@ def _task(name, key, priority, nbytes=100):
                      total_parts=1)
 
 
-def test_priority_order():
+@pytest.mark.parametrize("arrivals,expect", [
+    ([(make_key(2, 0), -2), (make_key(0, 1), 0), (make_key(0, 0), 0),
+      (make_key(1, 0), -1)],
+     [make_key(0, 0), make_key(0, 1), make_key(1, 0), make_key(2, 0)]),
+    ([(30, -3), (10, -1), (21, -2), (20, -2)], [10, 20, 21, 30]),
+])
+def test_priority_order(arrivals, expect):
     # priority desc, then key asc — the reference comparator
     # (scheduled_queue.cc:82-102)
     s = ChunkScheduler()
-    s.add_task(_task("low", key=make_key(2, 0), priority=-2))
-    s.add_task(_task("hi", key=make_key(0, 1), priority=0))
-    s.add_task(_task("hi", key=make_key(0, 0), priority=0))
-    s.add_task(_task("mid", key=make_key(1, 0), priority=-1))
-    order = [s.get_task().key for _ in range(4)]
-    assert order == [make_key(0, 0), make_key(0, 1), make_key(1, 0),
-                     make_key(2, 0)]
+    for key, priority in arrivals:
+        s.add_task(_task("t", key=key, priority=priority))
+    assert [s.get_task().key for _ in arrivals] == expect
 
 
 def test_credit_window_blocks_and_returns():
@@ -166,18 +182,44 @@ def test_credit_window_blocks_and_returns():
     s.add_task(_task("a", 0, 0, nbytes=100))
     s.add_task(_task("b", 1, 0, nbytes=100))
     s.add_task(_task("c", 2, 0, nbytes=100))
-    assert s.get_task() is not None
-    assert s.get_task() is not None
+    assert s.get_task().name == "a"
+    assert s.get_task().name == "b"
     # third would exceed 250 in-flight bytes
     assert s.get_task() is None
+    assert s.bytes_in_flight == 200
     s.report_finish(100)
-    assert s.get_task() is not None
+    assert s.get_task().name == "c"
 
 
-def test_oversized_task_still_runs():
-    s = ChunkScheduler(credit_bytes=50)
-    s.add_task(_task("huge", 0, 0, nbytes=1000))
-    assert s.get_task() is not None  # window empty -> allowed through
+@pytest.mark.parametrize("credit,huge", [(50, 1000), (64, 10_000)])
+def test_oversized_task_still_runs(credit, huge):
+    s = ChunkScheduler(credit_bytes=credit)
+    s.add_task(_task("huge", 0, 0, nbytes=huge))
+    assert s.get_task().name == "huge"  # window empty -> allowed through
+    s.add_task(_task("next", 1, 0, nbytes=10))
+    assert s.get_task() is None         # oversized still in flight
+    s.report_finish(huge)
+    assert s.get_task().name == "next"
+
+
+def test_blocking_get_wakes_on_add():
+    s = ChunkScheduler()
+    got = []
+    t = threading.Thread(
+        target=lambda: got.append(s.get_task(block=True, timeout=5.0)))
+    t.start()
+    s.add_task(_task("late", 1, 0, nbytes=8))
+    t.join(timeout=10)
+    assert not t.is_alive() and got[0].name == "late"
+
+
+def test_drain_returns_remaining():
+    s = ChunkScheduler()
+    for i in range(5):
+        s.add_task(_task(f"t{i}", i, -i, nbytes=10))
+    assert s.get_task().name == "t0"
+    assert sorted(t.name for t in s.drain()) == ["t1", "t2", "t3", "t4"]
+    assert s.pending == 0
 
 
 # --- handles ---------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Dispatch-amortization tests (round-4 VERDICT task 3): drain mode
-(`group_size=-1`) executes the whole eligible credit window as the fewest
+"""Dispatch-amortization tests (round-4 VERDICT task 3): the dispatcher
+pops up to `group_size` eligible chunks and executes them as the fewest
 XLA programs — one chunk-scatter program per contiguous buffer run, one
 batched collective per run of equal-shape small tensors — with results
 bit-identical to ungrouped dispatch and provably fewer dispatches.
@@ -15,7 +15,7 @@ import pytest
 import byteps_tpu as bps
 from byteps_tpu.common import Config
 from byteps_tpu.common.config import set_config
-from byteps_tpu.core.engine import _plan_batch, _pow2_split
+from byteps_tpu.core.engine import _plan_batch
 from byteps_tpu.common.types import ChunkTask
 
 
@@ -41,13 +41,6 @@ def _task(name, key, off=0, ln=64, pending=None, data=None, scale=None):
     return t
 
 
-def test_pow2_split_widths():
-    assert [len(s) for s in _pow2_split(list(range(64)))] == [64]
-    assert [len(s) for s in _pow2_split(list(range(63)))] == [32, 16, 8, 4,
-                                                             2, 1]
-    assert _pow2_split([]) == []
-
-
 def test_plan_merges_contiguous_buffer_run():
     p = _FakePending(use_buffer=True)
     batch = [_task("w", k, off=k * 64, pending=p) for k in range(8)]
@@ -71,11 +64,10 @@ def test_plan_groups_equal_shape_parts_tasks():
     batch = [_task(f"g{i}", i, data=d, scale=0.125) for i in range(5)]
     units = _plan_batch(batch)
     assert [(k, len(u)) for k, u in units] == [("group", 5)]
-    # pow2 bucketing caps the compile-cache key space in drain mode; a
-    # width-1 remainder rides the single-task path (its program is
+    # a width-1 "group" rides the single-task path (its program is
     # already cached) instead of compiling a k=1 batched program
-    units = _plan_batch(batch, pow2_runs=True)
-    assert [(k, len(u)) for k, u in units] == [("group", 4), ("single", 1)]
+    units = _plan_batch(batch[:1])
+    assert [(k, len(u)) for k, u in units] == [("single", 1)]
 
 
 def test_plan_never_groups_incompatible_neighbors():
@@ -117,8 +109,8 @@ class _Gate:
 
 def _gated_engine(cfg):
     """bps session whose dispatcher is held until every push is enqueued:
-    makes the drain width deterministic (everything is in the queue when
-    the gate opens)."""
+    makes the merge widths deterministic (everything is in the queue
+    when the gate opens, so every pop finds group_size chunks)."""
     set_config(cfg)
     bps.init()
     from byteps_tpu.core import api
@@ -133,9 +125,9 @@ def no_session():
     bps.shutdown()
 
 
-def test_drain_buffer_tensor_one_dispatch_bitexact(no_session):
-    # 1 MiB f32 per rank / 4 KiB chunks = 256 column slabs; drain mode
-    # must execute them as ONE program (256 is a power of two) and match
+def test_grouped_buffer_tensor_fewer_dispatches_bitexact(no_session):
+    # 1 MiB f32 per rank / 4 KiB chunks = 256 column slabs; group_size=8
+    # must execute them as 32 programs of 8 contiguous slabs and match
     # the ungrouped result bit for bit.
     rng = np.random.RandomState(7)
     x = rng.randn(8, 1 << 18).astype(np.float32)
@@ -148,23 +140,23 @@ def test_drain_buffer_tensor_one_dispatch_bitexact(no_session):
     base_stats = dict(eng.stats)
     bps.shutdown()
 
-    eng, gate = _gated_engine(Config(partition_bytes=4096, group_size=-1,
+    eng, gate = _gated_engine(Config(partition_bytes=4096, group_size=8,
                                      telemetry_on=False))
     h = eng.push_pull_async(x, "bulk", op="average")
     gate.set()
     out = np.asarray(h.wait())
-    drain_stats = dict(eng.stats)
+    grouped_stats = dict(eng.stats)
 
     np.testing.assert_array_equal(out, ref)
-    assert base_stats["chunks"] == drain_stats["chunks"] == 256
+    assert base_stats["chunks"] == grouped_stats["chunks"] == 256
     assert base_stats["dispatches"] == 256         # group_size=1: one each
-    assert drain_stats["dispatches"] == 1          # one program for all 256
+    assert grouped_stats["dispatches"] == 32       # one program per 8 slabs
 
 
-def test_drain_groups_small_tensors_fewer_dispatches(no_session):
-    # 8 equal-shape gradients: drain mode batches them into one program
-    # (pow2: exactly one for 8); results identical to sequential sync
-    # pushes through an ungrouped engine.
+def test_grouped_small_tensors_fewer_dispatches(no_session):
+    # 8 equal-shape gradients: group_size=8 batches them into one
+    # program; results identical to sequential sync pushes through an
+    # ungrouped engine.
     rng = np.random.RandomState(8)
     xs = [rng.randn(8, 300).astype(np.float32) for _ in range(8)]
 
@@ -174,7 +166,7 @@ def test_drain_groups_small_tensors_fewer_dispatches(no_session):
            for i, x in enumerate(xs)]
     bps.shutdown()
 
-    eng, gate = _gated_engine(Config(group_size=-1, telemetry_on=False))
+    eng, gate = _gated_engine(Config(group_size=8, telemetry_on=False))
     handles = [eng.push_pull_async(x, f"g{i}", op="average")
                for i, x in enumerate(xs)]
     gate.set()
@@ -187,7 +179,7 @@ def test_drain_groups_small_tensors_fewer_dispatches(no_session):
     assert stats["dispatches"] == 1
 
 
-def test_drain_groups_bitexact_on_dcn_mesh(no_session, monkeypatch):
+def test_grouped_bitexact_on_dcn_mesh(no_session, monkeypatch):
     # code-review r5: on a (dcn=2, ici=4) mesh a single dispatch reduces
     # hierarchically (RS over ICI + psum over DCN); the batched group
     # program must use the SAME body, or grouping — a timing-dependent
@@ -203,7 +195,7 @@ def test_drain_groups_bitexact_on_dcn_mesh(no_session, monkeypatch):
            for i, x in enumerate(xs)]
     bps.shutdown()
 
-    eng, gate = _gated_engine(Config(group_size=-1, telemetry_on=False))
+    eng, gate = _gated_engine(Config(group_size=4, telemetry_on=False))
     assert eng.comm.n_dcn == 2
     handles = [eng.push_pull_async(x, f"g{i}", op="average")
                for i, x in enumerate(xs)]
@@ -214,7 +206,7 @@ def test_drain_groups_bitexact_on_dcn_mesh(no_session, monkeypatch):
         np.testing.assert_array_equal(o, r)
 
 
-def test_drain_mixed_dtypes_and_ints_still_exact(no_session):
+def test_grouped_mixed_dtypes_and_ints_still_exact(no_session):
     # int chunks keep the assembly // semantics through the batched path
     xs = {"f": np.random.RandomState(0).randn(8, 100).astype(np.float32),
           "i": np.arange(8 * 40, dtype=np.int32).reshape(8, 40),
@@ -225,7 +217,7 @@ def test_drain_mixed_dtypes_and_ints_still_exact(no_session):
            for n, x in xs.items()}
     bps.shutdown()
 
-    eng, gate = _gated_engine(Config(group_size=-1, telemetry_on=False))
+    eng, gate = _gated_engine(Config(group_size=4, telemetry_on=False))
     hs = {n: eng.push_pull_async(x, n, op="average") for n, x in xs.items()}
     gate.set()
     for n, h in hs.items():

@@ -5,8 +5,8 @@ Round-3 VERDICT Weak #5 / task 3: the wall-clock mechanism benches
 *mechanisms themselves* — priority reordering, chunk-granular preemption
 under a credit window — are deterministic at the scheduler level.  These
 tests pin exactly the dispatch-order claims docs/performance.md makes, with
-zero timing dependence, against BOTH scheduler implementations (Python heap
-and the native C++ twin, reference scheduled_queue.cc:82-161).
+zero timing dependence, against the engine's one queue
+(common/scheduler.py ChunkScheduler; reference scheduled_queue.cc:82-161).
 
 The scenario modeled is the one the latency benches measure:
 
@@ -20,20 +20,9 @@ The scenario modeled is the one the latency benches measure:
 
 from __future__ import annotations
 
-import pytest
-
-from byteps_tpu import native
 from byteps_tpu.common.registry import make_key
 from byteps_tpu.common.scheduler import ChunkScheduler
 from byteps_tpu.common.types import ChunkTask
-
-
-def _make_scheduler(impl: str, credit_bytes: int):
-    if impl == "native":
-        if not native.available():
-            pytest.skip("native toolchain unavailable")
-        return native.NativeChunkScheduler(credit_bytes=credit_bytes)
-    return ChunkScheduler(credit_bytes=credit_bytes)
 
 
 def _task(name, key, priority, nbytes=100):
@@ -55,16 +44,12 @@ def _drain_order(s):
     return order
 
 
-IMPLS = ("python", "native")
-
-
-@pytest.mark.parametrize("impl", IMPLS)
-def test_backward_enqueue_order_dispatches_declaration_order(impl):
+def test_backward_enqueue_order_dispatches_declaration_order():
     """K gradients enqueued in REVERSE declaration order (backward-pass
     production order) while the window is full dispatch in DECLARATION
     order once the window opens — the priority mechanism's core claim
     (priority = -declared_key, engine.py push_pull_async)."""
-    s = _make_scheduler(impl, credit_bytes=100)
+    s = ChunkScheduler(credit_bytes=100)
     blocker = _task("blocker", key=make_key(99, 0), priority=-99)
     s.add_task(blocker)
     assert s.get_task().name == "blocker"   # fills the window
@@ -75,12 +60,11 @@ def test_backward_enqueue_order_dispatches_declaration_order(impl):
     assert _drain_order(s) == [f"layer{i}" for i in range(6)]
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_fifo_priorities_dispatch_in_arrival_order(impl):
+def test_fifo_priorities_dispatch_in_arrival_order():
     """The FIFO baseline (priority pinned to arrival order, what a plain
     allreduce queue executes) dispatches in arrival order — the contrast
     that makes the previous test a mechanism proof, not a tautology."""
-    s = _make_scheduler(impl, credit_bytes=100)
+    s = ChunkScheduler(credit_bytes=100)
     blocker = _task("blocker", key=make_key(99, 0), priority=0)
     s.add_task(blocker)
     s.get_task()
@@ -91,13 +75,12 @@ def test_fifo_priorities_dispatch_in_arrival_order(impl):
     assert _drain_order(s) == [f"layer{i}" for i in reversed(range(6))]
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_urgent_preempts_partitioned_bulk_at_chunk_granularity(impl):
+def test_urgent_preempts_partitioned_bulk_at_chunk_granularity():
     """With a bulk tensor split into 16 chunks and a 1-chunk credit window,
     an urgent tensor arriving mid-transfer dispatches after exactly ONE
     more bulk chunk — partitioning bounds head-of-line blocking to a chunk
     (reference operations.cc:140-180 partitioning rationale)."""
-    s = _make_scheduler(impl, credit_bytes=100)
+    s = ChunkScheduler(credit_bytes=100)
     for i in range(16):
         s.add_task(_task(f"bulk{i}", key=make_key(1, i), priority=-10))
     first = s.get_task()
@@ -112,13 +95,12 @@ def test_urgent_preempts_partitioned_bulk_at_chunk_granularity(impl):
     assert _drain_order(s) == [f"bulk{i}" for i in range(1, 16)]
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_unpartitioned_bulk_blocks_urgent_for_whole_tensor(impl):
+def test_unpartitioned_bulk_blocks_urgent_for_whole_tensor():
     """The contrast case: the same bytes as ONE task (no partitioning)
     occupy the window whole, so the urgent tensor waits out the entire
     transfer — 16x the dispatched-bytes head-of-line cost of the
     partitioned case above."""
-    s = _make_scheduler(impl, credit_bytes=100)
+    s = ChunkScheduler(credit_bytes=100)
     s.add_task(_task("bulk", key=make_key(1, 0), priority=-10, nbytes=1600))
     first = s.get_task()                    # oversized-but-idle clamp
     assert first.name == "bulk"
@@ -130,12 +112,11 @@ def test_unpartitioned_bulk_blocks_urgent_for_whole_tensor(impl):
     assert s.get_task().name == "urgent"
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_credit_window_admits_multiple_small_chunks(impl):
+def test_credit_window_admits_multiple_small_chunks():
     """The window is a byte budget, not a task count: two 100 B chunks fit
     a 250 B window simultaneously, a third waits (reference
     scheduled_queue.cc:136-150)."""
-    s = _make_scheduler(impl, credit_bytes=250)
+    s = ChunkScheduler(credit_bytes=250)
     for i in range(3):
         s.add_task(_task(f"c{i}", key=make_key(1, i), priority=0))
     assert s.get_task().name == "c0"

@@ -1,4 +1,4 @@
-"""Native C++ core tests: the ctypes-loaded scheduler/partitioner/reducer
+"""Native C++ core tests: the ctypes-loaded partitioner/reducer
 must agree with the pure-Python implementations (the reference's analogous
 split is C++ core + numpy test replications, SURVEY.md §4)."""
 
@@ -7,17 +7,9 @@ import pytest
 
 from byteps_tpu import native
 from byteps_tpu.common.partitioner import chunk_bounds as py_bounds
-from byteps_tpu.common.scheduler import ChunkScheduler
-from byteps_tpu.common.types import ChunkTask
 
 pytestmark = pytest.mark.skipif(not native.available(),
                                 reason="native toolchain unavailable")
-
-
-def _task(name, key, priority, nbytes):
-    return ChunkTask(name=name, key=key, priority=priority, version=0,
-                     offset_elems=0, num_elems=nbytes // 4, nbytes=nbytes,
-                     total_parts=1)
 
 
 def test_key_encoding_matches_python():
@@ -38,63 +30,14 @@ def test_chunk_bounds_matches_python(num_elems, itemsize, pbytes):
         py_bounds(num_elems, itemsize, pbytes)
 
 
-def test_scheduler_priority_and_key_order():
-    for sched in (native.NativeChunkScheduler(0), ChunkScheduler(0)):
-        sched.add_task(_task("c", 30, -3, 100))
-        sched.add_task(_task("a", 10, -1, 100))
-        sched.add_task(_task("b2", 21, -2, 100))
-        sched.add_task(_task("b1", 20, -2, 100))
-        order = [sched.get_task().name for _ in range(4)]
-        assert order == ["a", "b1", "b2", "c"], type(sched).__name__
-
-
-def test_scheduler_credit_window():
-    sched = native.NativeChunkScheduler(credit_bytes=250)
-    sched.add_task(_task("x", 1, 0, 100))
-    sched.add_task(_task("y", 2, 0, 100))
-    sched.add_task(_task("z", 3, 0, 100))
-    assert sched.get_task().name == "x"
-    assert sched.get_task().name == "y"
-    # window full: 200 in flight + 100 > 250
-    assert sched.get_task() is None
-    assert sched.bytes_in_flight == 200
-    sched.report_finish(100)
-    assert sched.get_task().name == "z"
-
-
-def test_scheduler_oversized_task_allowed_when_idle():
-    sched = native.NativeChunkScheduler(credit_bytes=64)
-    sched.add_task(_task("huge", 1, 0, 10_000))
-    assert sched.get_task().name == "huge"  # window empty -> clamp through
-    sched.add_task(_task("next", 2, 0, 10))
-    assert sched.get_task() is None         # oversized still in flight
-    sched.report_finish(10_000)
-    assert sched.get_task().name == "next"
-
-
-def test_scheduler_blocking_get_wakes_on_add():
-    import threading
-    sched = native.NativeChunkScheduler(0)
-    got = []
-
-    def consumer():
-        got.append(sched.get_task(block=True, timeout=5.0))
-
-    t = threading.Thread(target=consumer)
-    t.start()
-    sched.add_task(_task("late", 1, 0, 8))
-    t.join(timeout=10)
-    assert not t.is_alive() and got[0].name == "late"
-
-
-def test_scheduler_drain_returns_remaining():
-    sched = native.NativeChunkScheduler(0)
-    for i in range(5):
-        sched.add_task(_task(f"t{i}", i, -i, 10))
-    assert sched.get_task().name == "t0"
-    names = [t.name for t in sched.drain()]
-    assert sorted(names) == ["t1", "t2", "t3", "t4"]
-    assert sched.pending == 0
+def test_library_has_no_scheduler_and_reports_its_abi():
+    """The chunk queue is Python (common/scheduler.py): the library
+    exports no bps_sched_* symbol, and says so through its ABI version."""
+    lib = native.load()
+    assert lib.bps_native_abi_version() == 5
+    for sym in ("bps_sched_create", "bps_sched_add", "bps_sched_get",
+                "bps_sched_drain"):
+        assert not hasattr(lib, sym), sym
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64, np.int32,
@@ -142,21 +85,3 @@ def test_bf16_reduce_round_to_nearest_even():
         src.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
         dst.size, 2)
     np.testing.assert_array_equal(dst.view(ml_dtypes.bfloat16), expect)
-
-
-def test_engine_uses_native_scheduler_by_default():
-    import jax
-    if jax.default_backend() != "cpu":
-        pytest.skip("mesh fixture is CPU-only")
-    import byteps_tpu as bps
-    from byteps_tpu.core import api as _api
-    bps.init()
-    try:
-        name = type(_api._require().scheduler).__name__
-        assert name == "NativeChunkScheduler"
-        x = np.random.randn(bps.size(), 1024).astype(np.float32)
-        out = bps.push_pull(x, "native_path")
-        np.testing.assert_allclose(np.asarray(out), x.mean(0), rtol=1e-5,
-                                   atol=1e-6)
-    finally:
-        bps.shutdown()

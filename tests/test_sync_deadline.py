@@ -171,7 +171,8 @@ def test_concurrent_stall_reports_fire_the_action_once(_clean_slate):
 
 
 @pytest.mark.chaos
-def test_stall_during_inflight_shrink_does_not_double_exit(_clean_slate):
+def test_stall_during_inflight_shrink_does_not_double_exit(_clean_slate,
+                                                           monkeypatch):
     """Regression guard: a watchdog stall landing DURING an in-flight
     elastic transition (epoch already advanced by the shrink) resolves
     through the membership's already-moving-world path — never a second
@@ -182,6 +183,25 @@ def test_stall_during_inflight_shrink_does_not_double_exit(_clean_slate):
     m = mm.ElasticMembership(0, [0], f"127.0.0.1:{port}",
                              rendezvous_timeout_s=2.0,
                              sync_timeout_s=5.0).start()
+    # "DURING" held by events, not by who wins a race: the applier stops
+    # inside the transition (epoch up, view not yet moved) until the
+    # stall report is following it.  Left to timing, an applier that
+    # finishes first turns the report into an ordinary reconcile to
+    # epoch 2 — correct behaviour, and a failed assertion under load.
+    in_transition, following = threading.Event(), threading.Event()
+    real_resume, real_wait_ready = mm._resume_for_world, m.wait_ready
+
+    def held_resume(view, devices):
+        in_transition.set()
+        assert following.wait(10.0), "the stall report never followed"
+        return real_resume(view, devices)
+
+    def wait_ready(*args, **kwargs):
+        following.set()
+        return real_wait_ready(*args, **kwargs)
+
+    monkeypatch.setattr(mm, "_resume_for_world", held_resume)
+    monkeypatch.setattr(m, "wait_ready", wait_ready)
     try:
         fd.install_failure_action(m.on_failure)
         # an in-flight transition: another thread is applying epoch 1
@@ -189,10 +209,12 @@ def test_stall_during_inflight_shrink_does_not_double_exit(_clean_slate):
             target=lambda: m._maybe_apply(mm.MembershipView(1, (0,))))
         mm.set_epoch(1)          # the shrink's guard is already up
         applier.start()
+        assert in_transition.wait(10.0)
         # the stall report arrives mid-transition: reconcile sees the
         # epoch already moving and FOLLOWS it (wait_ready), no exit
         fd.data_path_stalled(3.0, "watchdog during shrink")
         applier.join(timeout=30)
+        assert not applier.is_alive() and following.is_set()
         assert m.view().epoch == 1
         assert exits == [], exits
     finally:

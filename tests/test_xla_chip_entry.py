@@ -201,27 +201,52 @@ def test_native_build_failure_names_its_cause():
     assert "g++ not found" in native._describe_failure(missing)
 
 
-def test_scheduler_fallback_warns_and_snapshot_names_what_ran(
-        monkeypatch, caplog):
+def test_snapshot_names_the_engines_one_queue():
+    """Chunk tasks go through common/scheduler.py ChunkScheduler whatever
+    BYTEPS_NATIVE says (it gates the host reducer / CRC / Elias coder
+    only), and ``metrics_snapshot()["scheduler"]`` says so."""
+    import numpy as np
+
+    import byteps_tpu as bps
+    from byteps_tpu.common.config import Config
+
+    for use_native in (True, False):
+        bps.init(Config(use_native=use_native))
+        try:
+            assert bps.metrics_snapshot(light=True)["scheduler"] \
+                == "ChunkScheduler"
+            x = np.random.randn(bps.size(), 1024).astype(np.float32)
+            out = bps.push_pull(x, "one_queue")
+            np.testing.assert_allclose(np.asarray(out), x.mean(0),
+                                       rtol=1e-5, atol=1e-6)
+        finally:
+            bps.shutdown()
+
+
+def test_engine_mode_step_never_loads_the_native_library(monkeypatch):
+    """bps.init -> DistributedOptimizer.update under the default Config
+    builds and opens no .so: g++ is not on the training path."""
+    import numpy as np
+    import optax
+
     import byteps_tpu as bps
     from byteps_tpu import native
     from byteps_tpu.common.config import Config
+    from byteps_tpu.jax import DistributedOptimizer
 
-    monkeypatch.setattr(native, "load", lambda: None)
-    monkeypatch.setattr(native, "_load_error", "g++ not found (test)")
+    # as in a fresh process (another test may have loaded the library)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_failed", False)
+    bps.init(Config())
     try:
-        with _package_log(caplog):
-            bps.init(Config())
-        assert bps.metrics_snapshot(light=True)["scheduler"] \
-            == "ChunkScheduler"
+        params = {"w": np.zeros((64, 32), np.float32),
+                  "b": np.zeros((32,), np.float32)}
+        opt = DistributedOptimizer(optax.sgd(0.1))
+        state = opt.init(params)
+        grads = {k: np.ones((bps.size(),) + v.shape, np.float32)
+                 for k, v in params.items()}
+        upd, state = opt.update(grads, state, params)
+        np.testing.assert_allclose(np.asarray(upd["w"]), -0.1, rtol=1e-6)
     finally:
         bps.shutdown()
-    assert "g++ not found (test)" in caplog.text
-    monkeypatch.undo()
-    if native.available():
-        bps.init(Config())
-        try:
-            assert bps.metrics_snapshot(light=True)["scheduler"] \
-                == "NativeChunkScheduler"
-        finally:
-            bps.shutdown()
+    assert native._lib is None and not native._load_failed
