@@ -202,7 +202,8 @@ class StepStats:
     # ISSUE 23: the engine's own share of the step — wall inside
     # ``byteps_tpu.jax.push_pull`` (first leaf enqueued -> last handle
     # returned), summed over the step's calls; ``wall_ms`` runs push to
-    # push and so holds the user's gradient program and apply too
+    # push (from where the call that made the step's first push began:
+    # ``open_call``) and so holds the user's gradient program and apply too
     push_pull_ms: float = 0.0
     # device programs launched / chunk tasks they consumed this step
     # (the step's deltas of ``PushPullEngine.stats``)
@@ -238,6 +239,9 @@ class StepStatsTracker:
         self._counts: Dict[str, int] = {}
         self._step = 0
         self._t0 = time.monotonic()
+        # when the caller's open tree-level push_pull began (open_call);
+        # taken by the next push
+        self._call_t0: Optional[float] = None
         self._bytes = 0
         self._pushes = 0
         self._buckets = 0
@@ -272,16 +276,24 @@ class StepStatsTracker:
         with self._lock:
             self._counts[name] = self._counts.get(name, 0) + 1
             step = self._counts[name]
+            call_t0, self._call_t0 = self._call_t0, None
             if step > self._step:
+                # the step's wall starts where the call that makes its
+                # first push began, so the call's own span
+                # (``push_pull_ms``) lies inside the wall; a push outside
+                # any tree-level call starts it now
+                t0 = time.monotonic()
+                if call_t0 is not None and call_t0 >= self._t0:
+                    t0 = call_t0
                 if self._step > 0 and self._pushes:
                     # published under the lock: two concurrent pushers
                     # finalizing steps N and N+1 must land their gauge
                     # writes and flight events in step order (the gauge
                     # and recorder locks never take this one, so there
                     # is no ordering cycle to invert)
-                    self._publish(self._finalize_locked())
+                    self._publish(self._finalize_locked(end=t0))
                 self._step = step
-                self._t0 = time.monotonic()
+                self._t0 = t0
                 # flight-recorder stamp: every recorded event from here
                 # on carries this step even with tracing off
                 _tracing.note_step(step)
@@ -291,6 +303,13 @@ class StepStatsTracker:
                 self._buckets += 1
                 self._bucketed_leaves += leaves
             return step
+
+    def open_call(self) -> None:
+        """Caller feed: a tree-level push_pull begins now, before its
+        ``bps.push_pull`` span opens.  If its first push starts a step,
+        the step's wall starts here."""
+        with self._lock:
+            self._call_t0 = time.monotonic()
 
     def add_stall(self, ms: float) -> None:
         with self._lock:
@@ -333,8 +352,10 @@ class StepStatsTracker:
 
     # -- finalization ------------------------------------------------------
 
-    def _finalize_locked(self) -> StepStats:
-        wall_ms = max((time.monotonic() - self._t0) * 1e3, 1e-6)
+    def _finalize_locked(self, end: Optional[float] = None) -> StepStats:
+        if end is None:
+            end = time.monotonic()
+        wall_ms = max((end - self._t0) * 1e3, 1e-6)
         retx = counters.get("integrity.retransmit")
         # Per-step attribution (ISSUE 12): deltas of the process-wide
         # sink (wire / merge / credit) + the engine's phases (enqueue /

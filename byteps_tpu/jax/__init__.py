@@ -72,6 +72,7 @@ def _enqueue_and_wait(enqueue) -> tuple:
     returns a ``TreeHandle``; returns ``(tree_handle, results)``."""
     eng = _api._require()
     feeds = eng.phase_feeds
+    eng.step_stats.open_call()
     with _tracing.phase("bps.push_pull", feeds["push_pull"]) as ph:
         pushed = enqueue(eng)
         step = eng.step_stats.current_step

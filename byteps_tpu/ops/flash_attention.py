@@ -6,17 +6,44 @@ families run attention through XLA's exact softmax — fine at BERT's seq
 standard flash decomposition (online softmax over key blocks, recompute
 backward) as Pallas kernels so the hot op stays in VMEM:
 
-- forward: one pass over K/V blocks per Q block; running (m, l, acc) in
-  VMEM scratch; emits the output and the log-sum-exp residual.
-- backward: the Dao (2022) two-kernel scheme — dK/dV accumulate over Q
-  blocks, dQ accumulates over K blocks, both recomputing P from (Q, K,
-  lse) instead of storing the [T, T] probability matrix.
+- forward: per q sub-block, one pass over the key sub-blocks up to the
+  causal diagonal; running (m, l, acc) in VMEM scratch (l spread over
+  the 128 lanes and folded once at the end); emits the output and the
+  log-sum-exp residual.
+- backward: recompute P from (Q, K, lse) instead of storing the [T, T]
+  probability matrix.  Where the whole q side of a head fits in VMEM
+  (T <= 4096 at 128 bf16 lanes) ONE kernel does it in five matmuls a
+  sub-block: per k/v sub-block, over the q sub-blocks from the diagonal
+  on, dK/dV accumulate and dQ adds into a float32 VMEM scratch.  Beyond
+  that the Dao (2022) two-kernel scheme stays (seven matmuls: dK/dV over
+  q, dQ over k).
+
+What is visited.  The unit is one ``block_q x block_k`` score sub-block
+(512 x 512 by default).  A grid step holds a span of sub-blocks of one
+side and the whole other side (or, for a context too long for VMEM, a
+span of it: then the grid also walks those spans) and loops over
+sub-blocks IN the kernel, with trip counts computed from the runtime
+SMEM scalar ``q_off`` (parallel/ring_flash.py passes one per device):
+sub-blocks wholly above the diagonal or in the padded key tail are never
+computed; every visited one takes the mask (one compare and one select
+a score: a second, unmasked body for interior sub-blocks measured no
+faster and doubled what each kernel costs to lower).  ``block_schedule``
+is the same rule on Python ints, and the gauge
+``flash.visited_block_share`` its visited / total for the last traced
+call.  Why 512: a sub-block's matmuls run near
+the MXU's peak only when each is long enough to hide its fill and drain
+(256 x 256 costs ~1.6x a score of 512 x 512), and the forward pays one
+cross-lane max per row and sub-block — so at T = 1024 the schedule
+visits 3 of 4 sub-blocks (the triangle is 0.50 + the diagonal blocks'
+upper halves), at T = 4096 36 of 64.  Numbers: PERF.md section 6, PR 28.
 
 Layout notes (Mosaic): all kernel operands are [BH, T, D] with D padded
-to a lane multiple (128) and T padded to the block size; the per-row
+to a lane multiple (128) and T padded to whole sub-blocks; the per-row
 residuals (lse, delta) are carried as [BH, T, 128] lane-broadcast arrays
 so every block spec keeps a full (8, 128)-or-larger tile — this image's
-Mosaic rejects narrower output tiles (see ops/pallas_kernels.py).
+Mosaic rejects narrower output tiles (see ops/pallas_kernels.py).  The
+``pallas_call``s carry no ``name=``: the benchmark finds them as the
+``pallas_call``s under the models' ``attn`` scope.
 
 The reference has no attention kernels at all (it is a gradient-
 communication library, SURVEY.md §2); this is TPU-first capability the
@@ -39,10 +66,191 @@ from .pallas_kernels import on_tpu
 
 _NEG = -1e30
 _LANES = 128
+# Edge of one score sub-block: the unit the kernels' inner loops visit,
+# skip or mask (``block_q`` / ``block_k`` of the public entry).
+_SUB = 512
+# Rows one grid step holds of the side its OUTER loop walks (q for the
+# forward and dQ kernels, k/v for dK/dV): enough sub-blocks a step that
+# the ~0.35 us a grid step costs stays small beside its matmuls.
+_SPAN_ROWS = 2048
+# Bytes of ONE operand of the other side (k or v; q or dO), which the
+# INNER loop walks and which therefore stays resident in VMEM for the
+# whole step: T = 4096 at 128 bf16 lanes.  A longer context falls back
+# to a grid over spans of this size, each looped inside.
+_RESIDENT_BYTES = 1 << 20
+# Scoped VMEM a kernel may ask for (the default, 16 MiB, is under what the
+# one-kernel backward holds at T = 4096: ~20 MiB, double-buffered).
+_VMEM_LIMIT = 48 << 20
 
 
 def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def _span(t: int, sub: int, cap_rows: int) -> int:
+    """Rows of one grid step: the most whole sub-blocks that divide the
+    padded length ``t`` and fit ``cap_rows`` (at least one)."""
+    n = t // sub
+    g = max(g for g in range(1, n + 1)
+            if n % g == 0 and (g == 1 or g * sub <= cap_rows))
+    return g * sub
+
+
+def _spans(tq, tk, d, itemsize, bq, bk):
+    """(outer span, resident span) of the q side and of the k side."""
+    resident = max(_RESIDENT_BYTES // (d * itemsize), 1)
+    return ((_span(tq, bq, _SPAN_ROWS), _span(tq, bq, resident)),
+            (_span(tk, bk, _SPAN_ROWS), _span(tk, bk, resident)))
+
+
+# --------------------------------------------------------------------------
+# the schedule: which score sub-blocks are visited.  One rule, written
+# over (max, min, floor division of non-negative ints): the kernels
+# evaluate it on the runtime SMEM ``q_off``, ``block_schedule`` and the
+# tests on Python ints.  On a traced scalar these are the bare lax
+# primitives: every jnp wrapper (``//`` above all, a jitted
+# sign-correcting floor_divide) is a nested function the Mosaic lowering
+# re-emits in each of a step's 48 kernels, and that lowering is Python
+# the job waits for before its first step (``setup_s``).
+# --------------------------------------------------------------------------
+
+def _ints(a, b):
+    return isinstance(a, int) and isinstance(b, int)
+
+
+def _max(a, b):
+    return max(a, b) if _ints(a, b) else jax.lax.max(a, b)
+
+
+def _min(a, b):
+    return min(a, b) if _ints(a, b) else jax.lax.min(a, b)
+
+
+def _div(a, b):
+    return a // b if _ints(a, b) else jax.lax.div(a, b)
+
+
+def _live_keys(row0, bq, col0, n, bk, kv_len, causal):
+    """How many of the ``n`` key sub-blocks ``[col0 + j*bk, +bk)`` hold
+    a live score for q rows ``[row0, row0 + bq)``: the first ``hi``; the
+    rest lie wholly above the diagonal or in the padded key tail and are
+    not visited."""
+    hi = _min(n, _div(_max(kv_len - col0 + bk - 1, 0), bk))
+    if causal:
+        hi = _min(hi, _div(_max(row0 + bq - 1 - col0 + bk, 0), bk))
+    return hi
+
+
+def _live_queries(col0, bk, row0, n, bq, tail, causal):
+    """The mirror, for dK/dV: of the ``n`` q sub-blocks ``[row0 + i*bq,
+    +bq)`` the first ``lo`` hold no live score against key columns
+    ``[col0, col0 + bk)`` and are not visited (all ``n`` where the columns
+    lie wholly in the padded tail).  The same set of sub-blocks as
+    ``_live_keys`` leaves, cut by columns: tests/test_flash_attention.py
+    holds the two to each other."""
+    lo = _min(n, _div(_max(col0 - row0, 0), bq)) if causal else 0
+    if tail is not None:
+        # 1 where col0 >= tail, else 0
+        lo = _max(lo, n * _min(_div(_max(col0 - tail + bk, 0), bk), 1))
+    return lo
+
+
+def block_schedule(tq: int, tk: int, causal: bool, q_off: int = 0, *,
+                   block_q: int = _SUB, block_k: int = _SUB) -> dict:
+    """Score sub-blocks the kernels run at this shape: ``{"visited",
+    "total", "needed"}``.
+
+    ``total`` is the padded ``[Tq, Tk]`` square cut into ``block_q x
+    block_k`` sub-blocks, ``needed`` those with at least one unmasked
+    entry, ``visited`` those the forward and the backward kernels
+    compute: counted here by q rows (``_live_keys``, the forward and dQ
+    loops); dK/dV cuts the same set by key columns (``_live_queries``).  ``q_off`` is the global row of
+    the first q row against key column 0 (``Tk - Tq`` for the public
+    entry's decode alignment).  Pure arithmetic on the shapes — the
+    manner of ``parallel.collective_schedule``: it reads, and changes no
+    program."""
+    bq, bk, tq_p, tk_p = _blocks(tq, tk, block_q, block_k)
+    nq, nk = tq_p // bq, tk_p // bk
+    visited = needed = 0
+    for i in range(nq):
+        row0 = q_off + i * bq
+        visited += _live_keys(row0, bq, 0, nk, bk, tk, causal)
+        last_row = q_off + min((i + 1) * bq, tq) - 1
+        needed += sum(1 for j in range(nk) if j * bk < tk
+                      and (not causal or j * bk <= last_row))
+    return {"visited": visited, "total": nq * nk, "needed": needed}
+
+
+def _tail(kv_len, tk):
+    """``_mask``'s ``tail`` for ``kv_len`` real keys padded to ``tk``."""
+    return kv_len if kv_len < tk else None
+
+
+def _blocks(tq, tk, block_q, block_k):
+    """(bq, bk, padded Tq, padded Tk) of a call."""
+    bq = min(block_q, _ceil_to(tq, 8))
+    bk = min(block_k, _ceil_to(tk, 8))
+    return bq, bk, _ceil_to(tq, bq), _ceil_to(tk, bk)
+
+
+def _mask(s, row0, col0, tail, causal):
+    """Causal and key-tail masks of one score sub-block whose corner is
+    ``(row0, col0)``.  ``tail`` is the key length where the keys are
+    padded beyond it, None where they are not."""
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    valid = None
+    if causal:
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        valid = (row - col) >= (col0 - row0)
+    if tail is not None:
+        inside = col < (tail - col0)
+        valid = inside if valid is None else valid & inside
+    return s if valid is None else jnp.where(valid, s, _NEG)
+
+
+def _at(j, b, n):
+    """Row offset of sub-block ``j`` of ``n`` (static when there is one:
+    a lone sub-block may be any multiple of 8 rows)."""
+    return 0 if n == 1 else pl.multiple_of(j * b, b)
+
+
+def _loop(lo, hi, body):
+    """``for j in range(lo, hi): body(j)`` with runtime bounds.  The
+    bodies act on refs only: a lax.cond RETURNING values lowers to a
+    select on this Mosaic (both sides run), so the schedule branches on
+    effects — a loop's trip count, ``pl.when`` — never on values."""
+    jax.lax.fori_loop(lo, hi, lambda j, c: (body(j), c)[1], 0)
+
+
+def _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, body):
+    """``body(cols, col0)`` over the key sub-blocks of one resident span
+    that hold a live score for q rows ``[row0, +bq)``."""
+    n = sk // bk
+    hi = _live_keys(row0, bq, col_base, n, bk, kv_len, causal)
+
+    def at(j):
+        c = _at(j, bk, n)
+        body(pl.ds(c, bk), col_base + c)
+
+    _loop(0, hi, at)
+
+
+def _fold_lanes(x):
+    """(rows, n * 128) -> (rows, 128), summing to the rows' sums: the sum
+    of the lane tiles, or, where the width is no whole number of tiles (a
+    lone sub-block shorter than 128), the row sum in lane 0."""
+    rows, width = x.shape
+    if width % _LANES:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+        return jnp.where(lane == 0, x.sum(axis=1, keepdims=True), 0.0)
+    return sum(x[:, t:t + _LANES] for t in range(0, width, _LANES))
+
+
+def _scores(q, k, scale):
+    # dots stay in the input dtype (bf16 rides the MXU's native path;
+    # upcasting first would force slow f32 passes); accumulate f32.
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * scale
 
 
 # --------------------------------------------------------------------------
@@ -50,191 +258,219 @@ def _ceil_to(x: int, m: int) -> int:
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, kv_len, nk):
-    q_off = qoff_ref[0]
-    ik = pl.program_id(2)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
+                m_scr, l_scr, acc_scr, *, scale, causal, kv_len, tail, bq, bk):
+    sq, sk = q_ref.shape[1], k_ref.shape[1]
+    ik, nk = pl.program_id(2), pl.num_programs(2)
+    row_base = qoff_ref[0] + pl.program_id(1) * sq
+    col_base = ik * sk
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def q_block(i):
+        r = _at(i, bq, sq // bq)
+        rows = pl.ds(r, bq)
+        row0 = row_base + r
 
-    iq = pl.program_id(1)
-    # Causal: skip key blocks entirely in the future of this q block.
-    last_row = q_off + iq * bq + (bq - 1)
-    live = (ik * bk <= last_row) if causal else True
+        @pl.when(ik == 0)
+        def _init():
+            m_scr[rows, :] = jnp.full((bq, _LANES), _NEG, jnp.float32)
+            l_scr[rows, :] = jnp.zeros((bq, _LANES), jnp.float32)
+            acc_scr[rows, :] = jnp.zeros((bq, acc_scr.shape[1]),
+                                         jnp.float32)
 
-    @pl.when(live)
-    def _attend():
-        # dots stay in the input dtype (bf16 rides the MXU's native path;
-        # upcasting first would force slow f32 passes); accumulate f32.
-        q = q_ref[0]                                       # (bq, D)
-        k = k_ref[0]                                       # (bk, D)
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = _mask_block(s, causal, kv_len, q_off, iq, ik, bq, bk)
+        def attend(cols, col0):
+            v = v_ref[0, cols, :]
+            s = _mask(_scores(q_ref[0, rows, :], k_ref[0, cols, :], scale),
+                      row0, col0, tail, causal)
+            m_prev = m_scr[rows, :1]                       # (bq, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)                         # (bq, bk)
+            alpha = jnp.exp(m_prev - m_new)                # (bq, 1)
+            # the running sum stays spread over the 128 lanes (VPU adds of
+            # p's lane tiles) and is folded once, in _finish: a second
+            # cross-lane reduction a sub-block was a third of the forward
+            l_scr[rows, :] = l_scr[rows, :] * alpha + _fold_lanes(p)
+            acc_scr[rows, :] = acc_scr[rows, :] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (bq, _LANES))
 
-        m_prev = m_scr[:, :1]                              # (bq, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                             # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)                    # (bq, 1)
-        l_new = l_scr[:, :1] * alpha + p.sum(axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, attend)
 
-    @pl.when(ik == nk - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, :, :] = m_scr[...] + jnp.log(
-            jnp.maximum(l_scr[...], 1e-30))
+        @pl.when(ik == nk - 1)
+        def _finish():
+            # rows no key reached (a ring block wholly in the future)
+            # keep l = 0: out 0, lse ~ -1e30, weight 0 in the ring's merge
+            l = jnp.maximum(l_scr[rows, :].sum(axis=1, keepdims=True), 1e-30)
+            o_ref[0, rows, :] = (acc_scr[rows, :] / l).astype(o_ref.dtype)
+            lse_ref[0, rows, :] = m_scr[rows, :] + jnp.log(
+                jnp.broadcast_to(l, (bq, _LANES)))
+
+    _loop(0, sq // bq, q_block)
 
 
-def _mask_block(s, causal, kv_len, q_off, iq, ik, bq, bk):
-    """Apply the kv-tail and causal masks to one (bq, bk) score block.
-
-    Unconditional: a lax.cond around the mask (tried) lowers to a select
-    on this Mosaic — both branches execute, the duplicated code only
-    inflates compile size and rejects large-block configs."""
-    col = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    valid = col < kv_len
-    if causal:
-        row = q_off + iq * bq + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 0)
-        valid = valid & (row >= col)
-    return jnp.where(valid, s, _NEG)
+def _call(kern, grid, in_specs, out_specs, out_shape, scratch, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    # no ``name=``: the benchmark's readers find these kernels as the
+    # ``pallas_call``s under the models' ``attn`` scope (PERF.md section 7)
+    return pl.pallas_call(
+        kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)
 
 
 def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret):
-    """[BH, Tq, D] x [BH, Tk, D] (padded) -> (out, lse[BH, Tq, 128])."""
+    """[BH, Tq, D] x [BH, Tk, D] (padded to whole ``bq`` / ``bk``
+    sub-blocks) -> (out, lse[BH, Tq, 128])."""
     from jax.experimental.pallas import tpu as pltpu
     bh, tq, d = q.shape
     tk = k.shape[1]
-    nq, nk = tq // bq, tk // bk
+    (sq, _), (_, sk) = _spans(tq, tk, d, q.dtype.itemsize, bq, bk)
+    nq, nk = tq // sq, tk // sk
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             kv_len=kv_len, nk=nk)
-    return pl.pallas_call(
-        kern,
-        grid=(bh, nq, nk),
-        in_specs=[
+                             kv_len=kv_len, tail=_tail(kv_len, tk),
+                             bq=bq, bk=bk)
+    return _call(
+        kern, (bh, nq, nk),
+        [
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, sq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, sk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, sk, d), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
+        [
+            pl.BlockSpec((1, sq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, sq, _LANES), lambda b, i, j: (b, i, 0)),
         ],
-        out_shape=[
+        [
             jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+        [
+            pltpu.VMEM((sq, _LANES), jnp.float32),
+            pltpu.VMEM((sq, _LANES), jnp.float32),
+            pltpu.VMEM((sq, d), jnp.float32),
         ],
-        interpret=interpret,
-    )(qoff, q, k, v)
+        interpret)(qoff, q, k, v)
 
 
 # --------------------------------------------------------------------------
 # backward
 # --------------------------------------------------------------------------
 
-def _recompute_p(q_ref, k_ref, lse_ref, scale, causal, kv_len, q_off,
-                 iq, ik, bq, bk):
-    q = q_ref[0]
-    k = k_ref[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = _mask_block(s, causal, kv_len, q_off, iq, ik, bq, bk)
-    p = jnp.exp(s - lse_ref[0, :, :1])                     # (bq, bk)
-    return p, q, k
+def _p_ds(q, k, v, do, lse, delta, scale, row0, col0, tail, causal):
+    """Recompute the probabilities of the sub-block at (row0, col0) from
+    (q, k, lse), and dS = P * (dO V^T - delta) * scale."""
+    s = _mask(_scores(q, k, scale), row0, col0, tail, causal)
+    p = jnp.exp(s - lse)                                   # (bq, bk)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * scale
 
 
 def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, kv_len, nq):
-    q_off = qoff_ref[0]
-    iq = pl.program_id(2)
-    ik = pl.program_id(1)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
+                    delta_ref, *refs, scale, causal, kv_len, tail, bq, bk,
+                    fused):
+    """dK/dV of one k/v span against the resident q side, looped from the
+    diagonal on.  ``fused`` (the whole q side is resident): dQ too, in a
+    float32 scratch that lives across the head's k/v spans, so P and dS
+    are recomputed once a sub-block and not once in each of two kernels."""
+    if fused:
+        dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, dq_scr = refs
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = refs
+    sq, sk = q_ref.shape[1], k_ref.shape[1]
+    ik, iq, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    row_base = qoff_ref[0] + iq * sq
+    col_base = ik * sk
+    n = sq // bq
 
-    @pl.when(iq == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+    if fused:
+        @pl.when(ik == 0)
+        def _init_dq():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    last_row = q_off + iq * bq + (bq - 1)
-    live = (ik * bk <= last_row) if causal else True
+    def k_block(j):
+        c = _at(j, bk, sk // bk)
+        cols = pl.ds(c, bk)
+        col0 = col_base + c
 
-    @pl.when(live)
-    def _accum():
-        p, q, _ = _recompute_p(q_ref, k_ref, lse_ref, scale, causal,
-                               kv_len, q_off, iq, ik, bq, bk)
-        do = do_ref[0]                                     # (bq, D)
-        v = v_ref[0]                                       # (bk, D)
-        # dV += P^T dO
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dS = P * (dO V^T - delta); dK += dS^T Q
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, :, :1]) * scale)
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        @pl.when(iq == 0)
+        def _init():
+            dk_scr[cols, :] = jnp.zeros((bk, dk_scr.shape[1]), jnp.float32)
+            dv_scr[cols, :] = jnp.zeros((bk, dv_scr.shape[1]), jnp.float32)
 
-    @pl.when(iq == nq - 1)
-    def _finish():
-        dk_ref[0, :, :] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, :, :] = dv_scr[...].astype(dv_ref.dtype)
+        def accum(i):
+            r = _at(i, bq, n)
+            rows = pl.ds(r, bq)
+            q, do, k = q_ref[0, rows, :], do_ref[0, rows, :], k_ref[0, cols, :]
+            p, ds = _p_ds(
+                q, k, v_ref[0, cols, :], do,
+                lse_ref[0, rows, :1], delta_ref[0, rows, :1], scale,
+                row_base + r, col0, tail, causal)
+            ds = ds.astype(q.dtype)
+            # dV += P^T dO; dK += dS^T Q; dQ += dS K
+            dv_scr[cols, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[cols, :] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if fused:
+                dq_scr[rows, :] += jax.lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+        _loop(_live_queries(col0, bk, row_base, n, bq, tail, causal), n,
+              accum)
+
+        @pl.when(iq == nq - 1)
+        def _finish():
+            dk_ref[0, cols, :] = dk_scr[cols, :].astype(dk_ref.dtype)
+            dv_ref[0, cols, :] = dv_scr[cols, :].astype(dv_ref.dtype)
+
+    _loop(0, sk // bk, k_block)
+
+    if fused:
+        @pl.when(ik == pl.num_programs(1) - 1)
+        def _finish_dq():
+            dq_ref[0, :, :] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_scr,
-                   *, scale, causal, kv_len, nk):
-    q_off = qoff_ref[0]
-    ik = pl.program_id(2)
-    iq = pl.program_id(1)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
+                   *, scale, causal, kv_len, tail, bq, bk):
+    sq, sk = q_ref.shape[1], k_ref.shape[1]
+    ik, nk = pl.program_id(2), pl.num_programs(2)
+    row_base = qoff_ref[0] + pl.program_id(1) * sq
+    col_base = ik * sk
 
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+    def q_block(i):
+        r = _at(i, bq, sq // bq)
+        rows = pl.ds(r, bq)
+        row0 = row_base + r
 
-    last_row = q_off + iq * bq + (bq - 1)
-    live = (ik * bk <= last_row) if causal else True
+        @pl.when(ik == 0)
+        def _init():
+            dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), jnp.float32)
 
-    @pl.when(live)
-    def _accum():
-        p, _, k = _recompute_p(q_ref, k_ref, lse_ref, scale, causal,
-                               kv_len, q_off, iq, ik, bq, bk)
-        do = do_ref[0]
-        v = v_ref[0]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, :, :1]) * scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        def accum(cols, col0):
+            k = k_ref[0, cols, :]
+            _, ds = _p_ds(
+                q_ref[0, rows, :], k, v_ref[0, cols, :], do_ref[0, rows, :],
+                lse_ref[0, rows, :1], delta_ref[0, rows, :1], scale,
+                row0, col0, tail, causal)
+            dq_scr[rows, :] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(ik == nk - 1)
-    def _finish():
-        dq_ref[0, :, :] = dq_scr[...].astype(dq_ref.dtype)
+        _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, accum)
+
+        @pl.when(ik == nk - 1)
+        def _finish():
+            dq_ref[0, rows, :] = dq_scr[rows, :].astype(dq_ref.dtype)
+
+    _loop(0, sq // bq, q_block)
 
 
 def _bwd(res, g, scale, causal, q_off, kv_len, bq, bk, interpret):
@@ -257,57 +493,56 @@ def _bwd_impl(q, k, v, do, lse, delta, scale, causal, q_off, kv_len,
     from jax.experimental.pallas import tpu as pltpu
     bh, tq, d = q.shape
     tk = k.shape[1]
-    nq, nk = tq // bq, tk // bk
+    (sq, rq), (sk, rk) = _spans(tq, tk, d, q.dtype.itemsize, bq, bk)
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
+    static = dict(scale=scale, causal=causal, kv_len=kv_len,
+                  tail=_tail(kv_len, tk), bq=bq, bk=bk)
+    # one kernel, five matmuls a sub-block, where the whole q side (q, dO,
+    # lse, delta and a float32 dQ) fits in VMEM beside a k/v span; two
+    # kernels, seven, where the context is too long for that
+    fused = rq == tq
 
-    dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                                 kv_len=kv_len, nq=nq)
-    dk, dv = pl.pallas_call(
-        dkv_kern,
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),       # q
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),       # k
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),       # v
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),       # do
-            pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0)),  # lse
-            pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0)),  # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qoff, q, k, v, do, lse, delta)
+    # dK/dV (and dQ): a k/v span a step, the q side resident
+    def q_side(w):
+        return pl.BlockSpec((1, rq, w), lambda b, j, i: (b, i, 0))
 
-    dq_kern = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                kv_len=kv_len, nk=nk)
-    dq = pl.pallas_call(
-        dq_kern,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-    )(qoff, q, k, v, do, lse, delta)
+    def k_side():
+        return pl.BlockSpec((1, sk, d), lambda b, j, i: (b, j, 0))
+
+    def like(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+    def acc(rows):
+        return pltpu.VMEM((rows, d), jnp.float32)
+
+    grads = _call(
+        functools.partial(_bwd_dkv_kernel, fused=fused, **static),
+        (bh, tk // sk, tq // rq),
+        [pl.BlockSpec(memory_space=pltpu.SMEM), q_side(d), k_side(),
+         k_side(), q_side(d), q_side(_LANES), q_side(_LANES)],
+        [k_side(), k_side()] + [q_side(d)] * fused,
+        [like(k), like(v)] + [like(q)] * fused,
+        [acc(sk), acc(sk)] + [acc(rq)] * fused,
+        interpret)(qoff, q, k, v, do, lse, delta)
+    if fused:
+        dk, dv, dq = grads
+        return dq, dk, dv
+    dk, dv = grads
+
+    # dQ: a q span a step, k/v resident and looped up to the diagonal
+    def q_span(w):
+        return pl.BlockSpec((1, sq, w), lambda b, i, j: (b, i, 0))
+
+    def kv_resident():
+        return pl.BlockSpec((1, rk, d), lambda b, i, j: (b, j, 0))
+
+    dq = _call(
+        functools.partial(_bwd_dq_kernel, **static),
+        (bh, tq // sq, tk // rk),
+        [pl.BlockSpec(memory_space=pltpu.SMEM), q_span(d), kv_resident(),
+         kv_resident(), q_span(d), q_span(_LANES), q_span(_LANES)],
+        q_span(d), like(q), [acc(sq)], interpret)(
+            qoff, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -343,15 +578,19 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 1024,
+                    block_q: int = _SUB, block_k: int = _SUB,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Flash attention.  [B, Tq, H, D] x [B, Tk, H, D] -> [B, Tq, H, D].
 
     Same contract as parallel/sequence.py full_attention (including the
     decode-style alignment: with causal=True and Tq < Tk the q rows cover
     the LAST Tq key positions).  Differentiable via the flash backward
-    kernels.  ``interpret=None`` engages the Mosaic path on a real TPU
-    backend and the interpreter elsewhere (CPU tests).
+    kernels.  ``block_q`` / ``block_k`` are the edges of one score
+    sub-block, the unit the kernels visit, skip or mask; how many of them
+    one grid step holds follows from the shape (module docstring).
+    ``interpret=None`` engages the Mosaic path on a real TPU backend and
+    the interpreter elsewhere (CPU tests).  Tracing a call sets the gauge
+    ``flash.visited_block_share`` (``block_schedule``'s visited / total).
     """
     if interpret is None:
         interpret = not on_tpu()
@@ -369,9 +608,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     q_off = tk - tq  # decode alignment (0 when square)
 
-    bq = min(block_q, _ceil_to(tq, 8))
-    bk = min(block_k, _ceil_to(tk, 8))
-    tq_p, tk_p, d_p = _ceil_to(tq, bq), _ceil_to(tk, bk), _ceil_to(d, _LANES)
+    bq, bk, tq_p, tk_p = _blocks(tq, tk, block_q, block_k)
+    d_p = _ceil_to(d, _LANES)
+    sched = block_schedule(tq, tk, causal, q_off,
+                           block_q=block_q, block_k=block_k)
+    from ..common.metrics import gauges
+    gauges.set("flash.visited_block_share",
+               sched["visited"] / sched["total"])
 
     def to3(x, t_p):
         x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, x.shape[1], d)

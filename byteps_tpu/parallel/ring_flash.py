@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.flash_attention import (_LANES, _NEG, _bwd_impl, _ceil_to,
+from ..ops.flash_attention import (_LANES, _NEG, _SUB, _bwd_impl, _ceil_to,
                                    _delta, _fwd)
 from ..ops.pallas_kernels import on_tpu
 from .sequence import SP_AXIS
@@ -102,9 +102,10 @@ def _ring_fwd_loop(q3, k3, v3, axis_name, scale, causal, t_local, blocks,
             return _merge(o_acc, lse_acc, o_b, lse_b)
 
         if causal:
-            # Skip blocks entirely in the future: the kernel's pl.when
-            # already kills the MXU work, but the block DMAs and the
-            # full-size merge pass would still run.  Device-divergent
+            # Skip blocks entirely in the future: the kernel's in-kernel
+            # loops run zero trips for one (its trip counts come from the
+            # runtime q_off), but the block DMAs and the full-size merge
+            # pass would still run.  Device-divergent
             # predicate is safe — attend() contains no collectives (same
             # pattern as sequence.py ring_attention).
             o_acc, lse_acc = lax.cond(
@@ -194,12 +195,16 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          axis_name: str = SP_AXIS, *,
                          causal: bool = False,
                          sm_scale: Optional[float] = None,
-                         block_q: int = 512, block_k: int = 1024,
+                         block_q: int = _SUB, block_k: int = _SUB,
                          interpret: Optional[bool] = None) -> jax.Array:
     """Ring attention with flash-kernel block math.  Call inside
     shard_map; same contract as sequence.py ring_attention: q/k/v are the
     local [B, T/sp, H, D] shards (sequence axis in ring order), returns
-    the local output shard.
+    the local output shard.  ``block_q`` / ``block_k`` are the edges of
+    one score sub-block, the unit the flash kernels' in-kernel loops
+    visit, skip or mask (ops/flash_attention.py; until PR 28 they were
+    the kernels' grid block, 512 x 1024): how many sub-blocks one grid
+    step holds follows from the shape.
     """
     if interpret is None:
         interpret = not on_tpu()

@@ -6,13 +6,18 @@ use — in interpret mode (the identical kernel code runs compiled by
 Mosaic on a real TPU backend; bench.py re-validates there).
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from byteps_tpu.ops.flash_attention import flash_attention
+from byteps_tpu.ops.flash_attention import block_schedule, flash_attention
 from byteps_tpu.parallel import full_attention
+
+# ``byteps_tpu.ops.flash_attention`` the attribute is the function
+_fa = importlib.import_module("byteps_tpu.ops.flash_attention")
 
 
 def _rand(shape, dtype, seed):
@@ -123,6 +128,176 @@ def test_bf16_forward():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+# (Tq, Tk, block_q, block_k): every case spans >= 3 key sub-blocks, so the
+# kernels' inner loops run their unmasked range, their masked range and
+# their skipped tail
+_SCHEDULE_CASES = {
+    "causal_square": (96, 96, 32, 32),
+    "causal_square_wide_q": (128, 128, 64, 32),
+    "causal_square_wide_k": (128, 128, 32, 64),
+    "decode_q_off_64": (32, 96, 16, 32),
+    "decode_q_off_96": (40, 136, 16, 32),
+    "decode_q_off_128": (72, 200, 24, 40),
+    "ragged_kv_len": (100, 100, 32, 32),
+}
+
+
+@pytest.fixture(params=["resident", "spans"])
+def form(request, monkeypatch):
+    """Both forms of the kernels at test sizes: the whole other side
+    resident in VMEM (one grid step a head), and a grid over spans of two
+    sub-blocks of either side, each looped inside (what a context too
+    long for VMEM gets)."""
+    if request.param == "spans":
+        monkeypatch.setattr(_fa, "_SPAN_ROWS", 64)
+        monkeypatch.setattr(_fa, "_RESIDENT_BYTES", 64 * 128 * 4)
+    return request.param
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("case", sorted(_SCHEDULE_CASES))
+def test_sub_block_schedule_matches_exact(case, causal, form):
+    """Forward and all three gradients where the diagonal, the decode
+    offset and the padded key tail cut through sub-blocks."""
+    tq, tk, bq, bk = _SCHEDULE_CASES[case]
+    b, h, d = 1, 2, 32
+    q = _rand((b, tq, h, d), jnp.float32, 20)
+    k = _rand((b, tk, h, d), jnp.float32, 21)
+    v = _rand((b, tk, h, d), jnp.float32, 22)
+    w = _rand((b, tq, h, d), jnp.float32, 23)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=bq,
+                               block_k=bk, interpret=True)
+
+    def exact(q, k, v):
+        return full_attention(q, k, v, causal=causal)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(exact(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    g_got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    g_want = jax.grad(lambda *a: jnp.sum(exact(*a) * w), (0, 1, 2))(q, k, v)
+    for a, b_ in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("q_off", [-64, 0, 32, 64])
+def test_runtime_q_off(q_off, form):
+    """The ring's contract: ``q_off`` is a runtime scalar that differs per
+    device, so every loop bound comes from it inside the kernel; a block
+    wholly in the future returns out 0 and lse ~ -1e30 (weight 0 in
+    ring_flash's merge)."""
+    bh, t, d, blk = 2, 64, 128, 16
+    q = _rand((bh, t, d), jnp.float32, 30)
+    k = _rand((bh, t, d), jnp.float32, 31)
+    v = _rand((bh, t, d), jnp.float32, 32)
+    fwd = jax.jit(lambda off: _fa._fwd(q, k, v, 0.1, True, off, t, blk, blk,
+                                       True))
+    out, lse = fwd(jnp.int32(q_off))
+    if q_off < 0:
+        assert np.all(np.asarray(out) == 0.0)
+        assert np.all(np.asarray(lse) < -1e29)
+        return
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * 0.1
+    row = q_off + jnp.arange(t)[:, None]
+    s = jnp.where(row >= jnp.arange(t)[None], s, -jnp.inf)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(
+        jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)),
+        rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0]), np.asarray(
+        jax.scipy.special.logsumexp(s, -1)), rtol=2e-5, atol=2e-5)
+
+
+def _count_by_mask(tq, tk, causal, q_off, bq, bk):
+    """Sub-blocks of the padded square with at least one unmasked entry,
+    counted from the mask itself."""
+    tq_p, tk_p = -(-tq // bq) * bq, -(-tk // bk) * bk
+    row = np.arange(tq_p)[:, None]
+    col = np.arange(tk_p)[None, :]
+    live = (row < tq) & (col < tk)
+    if causal:
+        live &= col <= q_off + row
+    blocks = live.reshape(tq_p // bq, bq, tk_p // bk, bk).any(axis=(1, 3))
+    return int(blocks.sum()), blocks.size
+
+
+@pytest.mark.parametrize("tq,tk,q_off", [
+    (1024, 1024, 0),      # gpt2_medium.fused_1c
+    (4096, 4096, 0),      # olmoe_1b_7b.fused_1c
+    (1000, 1000, 0), (256, 1024, 768), (40, 136, 96)])
+def test_block_schedule_against_the_mask(tq, tk, q_off):
+    for causal in (True, False):
+        got = block_schedule(tq, tk, causal, q_off)
+        needed, total = _count_by_mask(
+            tq, tk, causal, q_off,
+            *_fa._blocks(tq, tk, _fa._SUB, _fa._SUB)[:2])
+        assert (got["needed"], got["total"]) == (needed, total)
+        assert got["needed"] <= got["visited"] <= got["total"]
+        if not causal:
+            assert got["visited"] == got["total"]
+    if tq == tk:
+        share = block_schedule(tq, tk, True)
+        assert share["visited"] / share["total"] <= 0.75
+    assert block_schedule(1024, 1024, True) == {
+        "visited": 3, "total": 4, "needed": 3}
+    assert block_schedule(4096, 4096, True) == {
+        "visited": 36, "total": 64, "needed": 36}
+    # the parent's grid at this shape: two (512, 1024) steps, both live
+    assert block_schedule(1024, 1024, True, block_q=512, block_k=1024) == {
+        "visited": 2, "total": 2, "needed": 2}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("tq,tk,q_off,bq,bk", [
+    (1024, 1024, 0, 512, 512),       # gpt2_medium.fused_1c
+    (4096, 4096, 0, 512, 512),       # olmoe_1b_7b.fused_1c
+    (1000, 1000, 0, 512, 512),       # ragged: a key tail inside the last
+    (200, 520, 320, 64, 128),        # decode-aligned, a whole tail block
+    (4096, 4096, 4096, 512, 512),    # a ring block wholly in the past
+    (4096, 4096, -4096, 512, 512),   # ... and wholly in the future
+    (256, 384, 64, 32, 64)])
+def test_dkv_mirror_visits_the_counted_set(tq, tk, q_off, bq, bk, causal):
+    """``block_schedule`` counts the sub-blocks by q rows (``_live_keys``:
+    the forward and dQ loops); dK/dV walks them by key columns
+    (``_live_queries``).  The two rules leave the same set, and it holds
+    every sub-block the mask leaves a score in."""
+    bq, bk, tq_p, tk_p = _fa._blocks(tq, tk, bq, bk)
+    nq, nk = tq_p // bq, tk_p // bk
+    by_rows = {(i, j) for i in range(nq) for j in range(
+        _fa._live_keys(q_off + i * bq, bq, 0, nk, bk, tk, causal))}
+    by_cols = {(i, j) for j in range(nk) for i in range(
+        _fa._live_queries(j * bk, bk, q_off, nq, bq, _fa._tail(tk, tk_p),
+                          causal), nq)}
+    assert by_rows == by_cols
+    got = block_schedule(tq, tk, causal, q_off, block_q=bq, block_k=bk)
+    assert got["visited"] == len(by_rows) and got["total"] == nq * nk
+    row = q_off + np.arange(tq_p)[:, None]
+    col = np.arange(tk_p)[None, :]
+    live = (col < tk) & ((col <= row) | (not causal))
+    live = live.reshape(nq, bq, nk, bk).any(axis=(1, 3))
+    assert all((i, j) in by_rows for i, j in zip(*np.nonzero(live)))
+
+
+def test_visited_block_share_gauge():
+    import byteps_tpu as bps
+    q = _rand((1, 96, 1, 32), jnp.float32, 40)
+
+    @jax.jit
+    def f(q):
+        return flash_attention(q, q, q, causal=True, block_q=32, block_k=32,
+                               interpret=True)
+
+    f(q)
+    assert bps.metrics_snapshot()["gauges"][
+        "flash.visited_block_share"] == pytest.approx(6 / 9)
+    flash_attention(q, q, q, causal=False, block_q=32, block_k=32,
+                    interpret=True)
+    assert bps.metrics_snapshot()["gauges"][
+        "flash.visited_block_share"] == 1.0
 
 
 def test_long_context_flash_mode():

@@ -214,6 +214,29 @@ def test_step_stats_tracker_boundaries_and_surfaces():
     assert tr.summary()["steps"] == 2
 
 
+def test_step_wall_starts_where_the_call_that_opens_the_step_began():
+    """A tree-level push_pull marks its start (``open_call``) before its
+    span opens; the step its first push starts takes that as the wall's
+    start, however late the push itself lands, so the span the call
+    feeds (``push_pull_ms``) lies inside ``wall_ms``.  A push with no
+    open call, or one whose mark an earlier push already took, starts
+    the wall at the push."""
+    tr = StepStatsTracker(recorder=flight.FlightRecorder(capacity=16))
+    for lead in (0.03, 0.0, 0.03):       # the miss was a lead that SHRANK
+        tr.open_call()
+        t0 = time.monotonic()
+        time.sleep(lead)                 # plan lookup, packing: the lead
+        tr.on_push("a", 8)
+        tr.on_push("b", 8)               # same step: the mark is spent
+        tr.add_push_pull((time.monotonic() - t0) * 1e3)
+    tr.on_push("a", 8)                   # no call open: starts at the push
+    steps = tr.history()
+    assert [s.step for s in steps] == [1, 2, 3]
+    for s in steps:
+        assert s.push_pull_ms <= s.wall_ms, s
+    assert steps[0].wall_ms >= 30.0 and steps[2].wall_ms >= 30.0
+
+
 # -- flight recorder --------------------------------------------------------
 
 

@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from byteps_tpu.comm.mesh import CommContext, _build_mesh
+from byteps_tpu.ops import flash_attention
 from byteps_tpu.parallel import collective_schedule, make_dp_train_step
 from byteps_tpu.parallel.expert import dropless_moe_mlp
 
@@ -61,6 +62,32 @@ def test_layer_compiles_for_a_v5e_at_the_published_widths(one_chip):
     for scope in ("bps.moe.route", "bps.moe.dispatch", "bps.moe.experts",
                   "bps.moe.combine"):
         assert scope in text
+
+
+# ---------------------------------------------------- the flash kernels
+
+@pytest.mark.parametrize("shape,dtype,causal,kernels", [
+    ((8, 1024, 16, 64), jnp.bfloat16, True, 2),    # gpt2_medium.fused_1c
+    ((4, 4096, 16, 128), jnp.bfloat16, True, 2),   # olmoe_1b_7b.fused_1c
+    ((1, 8192, 2, 128), jnp.bfloat16, True, 3),    # too long for VMEM
+    ((2, 1000, 2, 64), jnp.bfloat16, True, 2),     # a padded key tail
+    ((8, 128, 16, 64), jnp.float32, False, 2),     # one short sub-block
+], ids=["gpt2_1024", "olmoe_4096", "long_8192", "ragged_1000", "short_128"])
+def test_flash_kernels_compile_for_a_v5e(one_chip, shape, dtype, causal,
+                                         kernels):
+    """Forward and backward at both cells' shapes: Mosaic takes the
+    in-kernel loops with runtime trip counts and the VMEM the resident
+    side needs; one backward kernel where the q side of a head fits in
+    VMEM, two where the context is too long for that."""
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def objective(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal,
+                                       interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(objective, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
 
 
 # ------------------------------- the fused step's asynchronous all-reduce
