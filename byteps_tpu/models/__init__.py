@@ -8,6 +8,12 @@ from .llama import (  # noqa: F401
     llama3_8b,
     llama_tiny,
 )
+from .mellum import (  # noqa: F401
+    Mellum,
+    MellumConfig,
+    mellum_loss,
+    mellum_tiny,
+)
 from .mlp import MLP, mnist_mlp  # noqa: F401
 from .olmoe import (  # noqa: F401
     Olmoe,

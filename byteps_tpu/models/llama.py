@@ -23,17 +23,19 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Mapping, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .gpt import lm_loss, token_nll  # shared loss (same LM contract)
 
 __all__ = [
     "LlamaConfig", "Llama", "llama3_8b", "llama_tiny", "lm_loss",
-    "token_nll", "rope_frequencies", "apply_rope",
+    "token_nll", "rope_frequencies", "yarn_inv_freq", "apply_rope",
+    "repeat_kv",
 ]
 
 AttnFn = Callable  # (q, k, v, *, causal, sm_scale) -> out
@@ -86,14 +88,63 @@ def llama_tiny_f32() -> LlamaConfig:
 
 # ----------------------------------------------------------------- rotary
 
-def rope_frequencies(head_dim: int, positions, theta: float):
+def rope_frequencies(head_dim: int, positions, theta: float,
+                     yarn: Optional[Mapping] = None):
     """(cos, sin) tables [*, T, head_dim/2] in f32 for the given absolute
     positions (sharded-sequence callers pass their own offsets, as with
-    GPT's ``positions`` argument)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                           / head_dim))
-    ang = positions[..., None].astype(jnp.float32) * inv  # [*, T, D/2]
-    return jnp.cos(ang), jnp.sin(ang)
+    GPT's ``positions`` argument).  ``yarn`` (the keys of an HF
+    ``rope_type: yarn`` section: ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``) swaps in :func:`yarn_inv_freq` and scales both
+    tables by ``attention_factor``."""
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                               / head_dim))
+        ang = positions[..., None].astype(jnp.float32) * inv  # [*, T, D/2]
+        return jnp.cos(ang), jnp.sin(ang)
+    inv = jnp.asarray(yarn_inv_freq(
+        head_dim, theta, yarn["factor"],
+        yarn["original_max_position_embeddings"], yarn["beta_fast"],
+        yarn["beta_slow"]), jnp.float32)
+    ang = positions[..., None].astype(jnp.float32) * inv
+    scale = jnp.float32(yarn["attention_factor"])
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN's blended inverse frequencies (HF ``_compute_yarn_parameters``),
+    [head_dim/2] float64: pair i keeps its own frequency ``1 / f_i``
+    (``f_i = theta^(2i/D)``) where it turns more than ``beta_fast`` times
+    over the original context, takes the interpolated ``1 / (factor
+    f_i)`` where it turns fewer than ``beta_slow`` times, and a linear
+    blend between the two pairs where those counts fall (floor / ceil,
+    clamped to [0, D - 1])."""
+    half = head_dim // 2
+    f = theta ** (np.arange(half, dtype=np.float64) * 2 / head_dim)
+
+    def pair_turning(turns):
+        return (head_dim * math.log(original_max_position
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(pair_turning(beta_fast)), 0)
+    hi = min(math.ceil(pair_turning(beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001                                  # HF: no singularity
+    keep = 1.0 - np.clip((np.arange(half) - lo) / (hi - lo), 0.0, 1.0)
+    return (1.0 - keep) / (factor * f) + keep / f
+
+
+def repeat_kv(k, v, groups: int):
+    """K/V heads [B, T, Hkv, D] repeated to the query-head count (query
+    head g reads k/v head ``g // groups``): numerically identical to
+    grouped attention, and keeps the pluggable ``attn_fn`` contract
+    (flash / ring / Ulysses) head-uniform."""
+    if groups == 1:
+        return k, v
+    return jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2)
 
 
 def apply_rope(x, cos, sin):
@@ -143,12 +194,7 @@ class LlamaAttention(nn.Module):
         cos, sin = rope_frequencies(hd, positions, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        if groups > 1:
-            # repeat KV heads to the query count: numerically identical to
-            # grouped attention, and keeps the pluggable attn_fn contract
-            # (flash/ring/Ulysses) head-uniform
-            k = jnp.repeat(k, groups, axis=2)
-            v = jnp.repeat(v, groups, axis=2)
+        k, v = repeat_kv(k, v, groups)
         attn = self.attn_fn
         if attn is None:
             from ..parallel.sequence import full_attention as attn

@@ -18,9 +18,11 @@ source's config has no key for, and this file fixes as HF
   ``router_z_loss_coef`` (the paper's 0.001).
 
 bf16 compute over float32 parameters; norms and the router in float32.
-The expert layer is ``parallel/expert.py`` :func:`dropless_moe_mlp`: all
-experts local, no ``ep`` axis (expert parallelism is still switch-only,
-``models/gpt.py`` ``MoEMLP``).  Attention is MHA
+The expert layer is ``parallel/expert.py`` :func:`dropless_moe_mlp` with
+every expert held (``held=None``: each data-parallel replica has all 64;
+``models/mellum.py`` runs the same layer as one chip's share).  The
+exchange of expert parallelism — ``all_to_all`` over an ``ep`` axis — is
+still switch-only (``models/gpt.py`` ``MoEMLP``).  Attention is MHA
 (``num_key_value_heads == num_attention_heads``, as published) through
 the pluggable ``attn_fn`` of the sibling models.
 """

@@ -36,6 +36,17 @@ the MXU's peak only when each is long enough to hide its fill and drain
 cross-lane max per row and sub-block — so at T = 1024 the schedule
 visits 3 of 4 sub-blocks (the triangle is 0.50 + the diagonal blocks'
 upper halves), at T = 4096 36 of 64.  Numbers: PERF.md section 6, PR 28.
+A sliding ``window`` (row i sees keys ``i - window < j <= i``) bounds each
+loop from the other side too: a q sub-block's key loop starts at the
+sub-block that holds its first row's oldest key, a k/v sub-block's q loop
+ends at the last sub-block whose first row still reaches its last column;
+the mask takes one more compare.  At T = 8192 a causal call visits 136 of
+256 sub-blocks, a window of 1024 visits 45 (and needs 45); where the grid
+walks spans of the other side, a span wholly outside the band is a step
+of zero trips (its DMA still runs).  ``window`` is static: the windowed
+kernels are a second specialisation, and with ``window=None`` every
+kernel's jaxpr is what it was before (tests/test_flash_attention.py
+pins the equation counts: a kernel's size is set-up time).
 
 Layout notes (Mosaic): all kernel operands are [BH, T, D] with D padded
 to a lane multiple (128) and T padded to whole sub-blocks; the per-row
@@ -130,33 +141,43 @@ def _div(a, b):
     return a // b if _ints(a, b) else jax.lax.div(a, b)
 
 
-def _live_keys(row0, bq, col0, n, bk, kv_len, causal):
-    """How many of the ``n`` key sub-blocks ``[col0 + j*bk, +bk)`` hold
-    a live score for q rows ``[row0, row0 + bq)``: the first ``hi``; the
-    rest lie wholly above the diagonal or in the padded key tail and are
-    not visited."""
+def _live_keys(row0, bq, col0, n, bk, kv_len, causal, window=None):
+    """``(lo, hi)``: of the ``n`` key sub-blocks ``[col0 + j*bk, +bk)``
+    those with ``lo <= j < hi`` hold a live score for q rows ``[row0,
+    row0 + bq)``.  From ``hi`` on they lie wholly above the diagonal or in
+    the padded key tail; under a ``window`` the first ``lo`` lie wholly
+    before the first row's window (the sub-block that holds key ``row0 -
+    window + 1`` is the first visited).  ``lo`` may pass ``hi``: nothing
+    is live."""
     hi = _min(n, _div(_max(kv_len - col0 + bk - 1, 0), bk))
     if causal:
         hi = _min(hi, _div(_max(row0 + bq - 1 - col0 + bk, 0), bk))
-    return hi
+    if window is None:
+        return 0, hi
+    return _min(n, _div(_max(row0 - window + 1 - col0, 0), bk)), hi
 
 
-def _live_queries(col0, bk, row0, n, bq, tail, causal):
-    """The mirror, for dK/dV: of the ``n`` q sub-blocks ``[row0 + i*bq,
-    +bq)`` the first ``lo`` hold no live score against key columns
-    ``[col0, col0 + bk)`` and are not visited (all ``n`` where the columns
-    lie wholly in the padded tail).  The same set of sub-blocks as
-    ``_live_keys`` leaves, cut by columns: tests/test_flash_attention.py
-    holds the two to each other."""
+def _live_queries(col0, bk, row0, n, bq, tail, causal, window=None):
+    """The mirror, for dK/dV: ``(lo, hi)``, of the ``n`` q sub-blocks
+    ``[row0 + i*bq, +bq)`` those with ``lo <= i < hi`` hold a live score
+    against key columns ``[col0, col0 + bk)``.  The first ``lo`` lie
+    above the diagonal (all ``n`` where the columns lie wholly in the
+    padded tail); under a ``window`` those from ``hi`` on have left the
+    columns behind (their first row's window starts past the last
+    column).  The same set of sub-blocks as ``_live_keys`` leaves, cut by
+    columns: tests/test_flash_attention.py holds the two to each other."""
     lo = _min(n, _div(_max(col0 - row0, 0), bq)) if causal else 0
     if tail is not None:
         # 1 where col0 >= tail, else 0
         lo = _max(lo, n * _min(_div(_max(col0 - tail + bk, 0), bk), 1))
-    return lo
+    if window is None:
+        return lo, n
+    return lo, _min(n, _div(_max(col0 + bk + window - 2 - row0 + bq, 0), bq))
 
 
 def block_schedule(tq: int, tk: int, causal: bool, q_off: int = 0, *,
-                   block_q: int = _SUB, block_k: int = _SUB) -> dict:
+                   block_q: int = _SUB, block_k: int = _SUB,
+                   window: Optional[int] = None) -> dict:
     """Score sub-blocks the kernels run at this shape: ``{"visited",
     "total", "needed"}``.
 
@@ -166,7 +187,8 @@ def block_schedule(tq: int, tk: int, causal: bool, q_off: int = 0, *,
     compute: counted here by q rows (``_live_keys``, the forward and dQ
     loops); dK/dV cuts the same set by key columns (``_live_queries``).  ``q_off`` is the global row of
     the first q row against key column 0 (``Tk - Tq`` for the public
-    entry's decode alignment).  Pure arithmetic on the shapes — the
+    entry's decode alignment); ``window`` as ``flash_attention`` takes
+    it.  Pure arithmetic on the shapes — the
     manner of ``parallel.collective_schedule``: it reads, and changes no
     program."""
     bq, bk, tq_p, tk_p = _blocks(tq, tk, block_q, block_k)
@@ -174,10 +196,17 @@ def block_schedule(tq: int, tk: int, causal: bool, q_off: int = 0, *,
     visited = needed = 0
     for i in range(nq):
         row0 = q_off + i * bq
-        visited += _live_keys(row0, bq, 0, nk, bk, tk, causal)
+        lo, hi = _live_keys(row0, bq, 0, nk, bk, tk, causal, window)
+        visited += max(hi - lo, 0)
         last_row = q_off + min((i + 1) * bq, tq) - 1
-        needed += sum(1 for j in range(nk) if j * bk < tk
-                      and (not causal or j * bk <= last_row))
+        for j in range(nk):
+            first_col, last_col = j * bk, min((j + 1) * bk, tk) - 1
+            # some row r of the sub-block sees some column c of it:
+            # c <= r (causal) and r - window < c
+            first_r = max(row0, first_col) if causal else row0
+            last_r = last_row if window is None else min(
+                last_row, last_col + window - 1)
+            needed += first_col < tk and first_r <= last_r
     return {"visited": visited, "total": nq * nk, "needed": needed}
 
 
@@ -193,15 +222,17 @@ def _blocks(tq, tk, block_q, block_k):
     return bq, bk, _ceil_to(tq, bq), _ceil_to(tk, bk)
 
 
-def _mask(s, row0, col0, tail, causal):
-    """Causal and key-tail masks of one score sub-block whose corner is
-    ``(row0, col0)``.  ``tail`` is the key length where the keys are
-    padded beyond it, None where they are not."""
+def _mask(s, row0, col0, tail, causal, window=None):
+    """Causal, window and key-tail masks of one score sub-block whose
+    corner is ``(row0, col0)``.  ``tail`` is the key length where the
+    keys are padded beyond it, None where they are not."""
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = None
     if causal:
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         valid = (row - col) >= (col0 - row0)
+        if window is not None:
+            valid &= (row - col) < (col0 - row0 + window)
     if tail is not None:
         inside = col < (tail - col0)
         valid = inside if valid is None else valid & inside
@@ -222,17 +253,18 @@ def _loop(lo, hi, body):
     jax.lax.fori_loop(lo, hi, lambda j, c: (body(j), c)[1], 0)
 
 
-def _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, body):
+def _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, window,
+                   body):
     """``body(cols, col0)`` over the key sub-blocks of one resident span
     that hold a live score for q rows ``[row0, +bq)``."""
     n = sk // bk
-    hi = _live_keys(row0, bq, col_base, n, bk, kv_len, causal)
+    lo, hi = _live_keys(row0, bq, col_base, n, bk, kv_len, causal, window)
 
     def at(j):
         c = _at(j, bk, n)
         body(pl.ds(c, bk), col_base + c)
 
-    _loop(0, hi, at)
+    _loop(lo, hi, at)
 
 
 def _fold_lanes(x):
@@ -258,7 +290,8 @@ def _scores(q, k, scale):
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, kv_len, tail, bq, bk):
+                m_scr, l_scr, acc_scr, *, scale, causal, kv_len, tail, bq, bk,
+                window=None):
     sq, sk = q_ref.shape[1], k_ref.shape[1]
     ik, nk = pl.program_id(2), pl.num_programs(2)
     row_base = qoff_ref[0] + pl.program_id(1) * sq
@@ -279,9 +312,14 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         def attend(cols, col0):
             v = v_ref[0, cols, :]
             s = _mask(_scores(q_ref[0, rows, :], k_ref[0, cols, :], scale),
-                      row0, col0, tail, causal)
+                      row0, col0, tail, causal, window)
             m_prev = m_scr[rows, :1]                       # (bq, 1)
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            if window is not None:
+                # a visited sub-block may hold rows whose window has
+                # already left it (the diagonal rule never does): their
+                # masked scores must not become weights exp(0) = 1
+                m_new = jnp.maximum(m_new, _NEG / 2)
             p = jnp.exp(s - m_new)                         # (bq, bk)
             alpha = jnp.exp(m_prev - m_new)                # (bq, 1)
             # the running sum stays spread over the 128 lanes (VPU adds of
@@ -293,7 +331,8 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 preferred_element_type=jnp.float32)
             m_scr[rows, :] = jnp.broadcast_to(m_new, (bq, _LANES))
 
-        _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, attend)
+        _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, window,
+                       attend)
 
         @pl.when(ik == nk - 1)
         def _finish():
@@ -318,7 +357,8 @@ def _call(kern, grid, in_specs, out_specs, out_shape, scratch, interpret):
         interpret=interpret)
 
 
-def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret):
+def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret,
+         window=None):
     """[BH, Tq, D] x [BH, Tk, D] (padded to whole ``bq`` / ``bk``
     sub-blocks) -> (out, lse[BH, Tq, 128])."""
     from jax.experimental.pallas import tpu as pltpu
@@ -329,7 +369,7 @@ def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret):
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              kv_len=kv_len, tail=_tail(kv_len, tk),
-                             bq=bq, bk=bk)
+                             bq=bq, bk=bk, window=window)
     return _call(
         kern, (bh, nq, nk),
         [
@@ -358,10 +398,11 @@ def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret):
 # backward
 # --------------------------------------------------------------------------
 
-def _p_ds(q, k, v, do, lse, delta, scale, row0, col0, tail, causal):
+def _p_ds(q, k, v, do, lse, delta, scale, row0, col0, tail, causal,
+          window=None):
     """Recompute the probabilities of the sub-block at (row0, col0) from
     (q, k, lse), and dS = P * (dO V^T - delta) * scale."""
-    s = _mask(_scores(q, k, scale), row0, col0, tail, causal)
+    s = _mask(_scores(q, k, scale), row0, col0, tail, causal, window)
     p = jnp.exp(s - lse)                                   # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -370,7 +411,7 @@ def _p_ds(q, k, v, do, lse, delta, scale, row0, col0, tail, causal):
 
 def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *refs, scale, causal, kv_len, tail, bq, bk,
-                    fused):
+                    fused, window=None):
     """dK/dV of one k/v span against the resident q side, looped from the
     diagonal on.  ``fused`` (the whole q side is resident): dQ too, in a
     float32 scratch that lives across the head's k/v spans, so P and dS
@@ -407,7 +448,7 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             p, ds = _p_ds(
                 q, k, v_ref[0, cols, :], do,
                 lse_ref[0, rows, :1], delta_ref[0, rows, :1], scale,
-                row_base + r, col0, tail, causal)
+                row_base + r, col0, tail, causal, window)
             ds = ds.astype(q.dtype)
             # dV += P^T dO; dK += dS^T Q; dQ += dS K
             dv_scr[cols, :] += jax.lax.dot_general(
@@ -421,8 +462,8 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     ds, k, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
 
-        _loop(_live_queries(col0, bk, row_base, n, bq, tail, causal), n,
-              accum)
+        _loop(*_live_queries(col0, bk, row_base, n, bq, tail, causal,
+                             window), accum)
 
         @pl.when(iq == nq - 1)
         def _finish():
@@ -439,7 +480,7 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_scr,
-                   *, scale, causal, kv_len, tail, bq, bk):
+                   *, scale, causal, kv_len, tail, bq, bk, window=None):
     sq, sk = q_ref.shape[1], k_ref.shape[1]
     ik, nk = pl.program_id(2), pl.num_programs(2)
     row_base = qoff_ref[0] + pl.program_id(1) * sq
@@ -459,12 +500,13 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             _, ds = _p_ds(
                 q_ref[0, rows, :], k, v_ref[0, cols, :], do_ref[0, rows, :],
                 lse_ref[0, rows, :1], delta_ref[0, rows, :1], scale,
-                row0, col0, tail, causal)
+                row0, col0, tail, causal, window)
             dq_scr[rows, :] += jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, accum)
+        _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, window,
+                       accum)
 
         @pl.when(ik == nk - 1)
         def _finish():
@@ -473,11 +515,12 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     _loop(0, sq // bq, q_block)
 
 
-def _bwd(res, g, scale, causal, q_off, kv_len, bq, bk, interpret):
+def _bwd(res, g, scale, causal, q_off, kv_len, bq, bk, interpret,
+         window=None):
     q, k, v, out, lse = res
     delta = _delta(g, out)
     return _bwd_impl(q, k, v, g, lse, delta, scale, causal, q_off,
-                     kv_len, bq, bk, interpret)
+                     kv_len, bq, bk, interpret, window)
 
 
 def _delta(do, out):
@@ -489,14 +532,14 @@ def _delta(do, out):
 
 
 def _bwd_impl(q, k, v, do, lse, delta, scale, causal, q_off, kv_len,
-              bq, bk, interpret):
+              bq, bk, interpret, window=None):
     from jax.experimental.pallas import tpu as pltpu
     bh, tq, d = q.shape
     tk = k.shape[1]
     (sq, rq), (sk, rk) = _spans(tq, tk, d, q.dtype.itemsize, bq, bk)
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
     static = dict(scale=scale, causal=causal, kv_len=kv_len,
-                  tail=_tail(kv_len, tk), bq=bq, bk=bk)
+                  tail=_tail(kv_len, tk), bq=bq, bk=bk, window=window)
     # one kernel, five matmuls a sub-block, where the whole q side (q, dO,
     # lse, delta and a float32 dQ) fits in VMEM beside a k/v span; two
     # kernels, seven, where the context is too long for that
@@ -550,22 +593,25 @@ def _bwd_impl(q, k, v, do, lse, delta, scale, causal, q_off, kv_len,
 # custom-vjp core on padded [BH, T, D] arrays
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, scale, causal, q_off, kv_len, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, scale, causal, q_off, kv_len, blocks, interpret,
+           window=None):
     out, _ = _fwd(q, k, v, scale, causal, q_off, kv_len,
-                  blocks[0], blocks[1], interpret)
+                  blocks[0], blocks[1], interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, q_off, kv_len, blocks, interpret):
+def _flash_fwd(q, k, v, scale, causal, q_off, kv_len, blocks, interpret,
+               window):
     out, lse = _fwd(q, k, v, scale, causal, q_off, kv_len,
-                    blocks[0], blocks[1], interpret)
+                    blocks[0], blocks[1], interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, q_off, kv_len, blocks, interpret, res, g):
+def _flash_bwd(scale, causal, q_off, kv_len, blocks, interpret, window,
+               res, g):
     return _bwd(res, g, scale, causal, q_off, kv_len,
-                blocks[0], blocks[1], interpret)
+                blocks[0], blocks[1], interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -579,7 +625,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False,
                     sm_scale: Optional[float] = None,
                     block_q: int = _SUB, block_k: int = _SUB,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention.  [B, Tq, H, D] x [B, Tk, H, D] -> [B, Tq, H, D].
 
     Same contract as parallel/sequence.py full_attention (including the
@@ -588,12 +635,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kernels.  ``block_q`` / ``block_k`` are the edges of one score
     sub-block, the unit the kernels visit, skip or mask; how many of them
     one grid step holds follows from the shape (module docstring).
+    ``window`` (a Python int, with ``causal=True`` only) is a sliding
+    window in HF's convention: row i attends keys j with ``i - window <
+    j <= i``, the row itself and the ``window - 1`` before it; sub-blocks
+    wholly outside that band are skipped by trip count as those above
+    the diagonal are.  ``window=None`` is the kernels as they were,
+    equation for equation.
     ``interpret=None`` engages the Mosaic path on a real TPU backend and
     the interpreter elsewhere (CPU tests).  Tracing a call sets the gauge
-    ``flash.visited_block_share`` (``block_schedule``'s visited / total).
+    ``flash.visited_block_share`` (``block_schedule``'s visited / total;
+    a windowed call also ``flash.visited_block_share.window``).
     """
     if interpret is None:
         interpret = not on_tpu()
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"flash_attention(window={window}) is a causal sliding window "
+            f"of at least one key: it needs causal=True and window >= 1")
     b, tq, h, d = q.shape
     tk = k.shape[1]
     if causal and tq > tk:
@@ -610,10 +668,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     bq, bk, tq_p, tk_p = _blocks(tq, tk, block_q, block_k)
     d_p = _ceil_to(d, _LANES)
-    sched = block_schedule(tq, tk, causal, q_off,
-                           block_q=block_q, block_k=block_k)
+    sched = block_schedule(tq, tk, causal, q_off, block_q=block_q,
+                           block_k=block_k, window=window)
     from ..common.metrics import gauges
-    gauges.set("flash.visited_block_share",
+    gauges.set("flash.visited_block_share.window" if window is not None
+               else "flash.visited_block_share",
                sched["visited"] / sched["total"])
 
     def to3(x, t_p):
@@ -622,6 +681,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     q3, k3, v3 = to3(q, tq_p), to3(k, tk_p), to3(v, tk_p)
     out = _flash(q3, k3, v3, scale, causal, q_off, tk, (bq, bk),
-                 bool(interpret))
+                 bool(interpret), window)
     out = out[:, :tq, :d].reshape(b, h, tq, d)
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)

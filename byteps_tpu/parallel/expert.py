@@ -1,5 +1,5 @@
 """Expert MLPs: a switch MoE over an ``ep`` mesh axis, and a dropless
-top-k MoE over local experts.
+top-k MoE over the experts a chip holds.
 
 Two entries, two designs; they share nothing but this file.
 
@@ -25,18 +25,26 @@ tests/test_expert_parallel.py.  Routing semantics are shard-local
 (capacity applies per token shard), so the math does not depend on the
 mesh size — only the placement does.
 
-**Dropless top-k (local experts)** — :func:`dropless_moe_mlp`, what
-``models/olmoe.py`` builds: softmax over all experts, the k largest
-kept with their weights as they are, no capacity and no dropped token,
-bias-free SiLU-gated experts.  The token–expert pairs are sorted by
-expert and the three expert matmuls run as grouped matmuls over the
-ragged groups (``_grouped_matmul``: JAX's Pallas megablox kernels; the
-interpreter off the TPU), so the work is k experts a token
-and no tensor grows with ``E x C``.  Every expert is local: there is NO
-``ep`` axis on this path yet (top-k dispatch by ``all_to_all`` is
-ROADMAP R1's next step), so under data parallelism each replica holds
-all experts and routing, both router losses and the counts are
-shard-local, as the switch path's are.
+**Dropless top-k (the experts held here)** — :func:`dropless_moe_mlp`,
+what ``models/olmoe.py`` and ``models/mellum.py`` build: softmax over all
+experts, the k largest kept (their weights as they are, or renormalised
+to sum to one), no capacity and no dropped token, bias-free SiLU-gated
+experts.  The token–expert pairs are sorted by expert and the three
+expert matmuls run as grouped matmuls over the ragged groups
+(``_grouped_matmul``: JAX's Pallas megablox kernels; the interpreter off
+the TPU), so the work is k experts a token and no tensor grows with
+``E x C``.  By default every expert is local (``models/olmoe.py``: each
+data-parallel replica holds all experts).  With ``held=(first, count)``
+the layer is one chip's share of an expert-parallel deployment: it
+routes over all E experts, holds the stacks of ``count`` consecutive
+ones, and returns the part of the sum that those give for the pairs
+routed to them — the local half of expert parallelism.  The other half,
+the exchange (top-k dispatch by ``all_to_all`` over an ``ep`` axis, so
+that a chip's experts see the tokens of every chip and a token the
+experts of every chip), is NOT here yet (ROADMAP R1): a one-chip share
+runs without it, and nothing stands in for the absent chips.  Routing,
+the router loss and the counts are of the token shard, as the switch
+path's are.
 """
 
 from __future__ import annotations
@@ -248,12 +256,19 @@ def make_dp_ep_train_step(mesh: Mesh, num_experts: int,
 _GMM_TILE = (512, 1024, 1024)
 
 
-def _grouped_matmul(x, w, group_sizes, interpret: bool):
+def _grouped_matmul(x, w, group_sizes, interpret: bool, first=None):
     """Rows of ``x`` [M, a], sorted into ``len(group_sizes)`` consecutive
     groups, times each group's own matrix of ``w`` [G, a, b] -> [M, b]:
     JAX's Pallas grouped matmul (megablox ``gmm``; its VJP is ``gmm``
     with the matrices transposed for the rows and ``tgmm`` for the
-    matrices).  A group may be empty."""
+    matrices).  A group may be empty.  With ``first`` (an int32 scalar)
+    ``w`` holds only the groups ``first .. first + G - 1`` of
+    ``len(group_sizes)``: the kernels' grids cover those groups' row
+    tiles alone (work in proportion to the live rows; ``tgmm`` returns
+    ``G`` matrices), and megablox itself zeroes every row of the other
+    groups in what ``gmm`` returns, forward and row gradient
+    (``gmm.py`` ``_zero_uninitialized_memory``: one ``where`` over the
+    result; tests/test_mellum.py pins it)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     m, a = x.shape
     rows = math.gcd(m, _GMM_TILE[0])
@@ -263,7 +278,7 @@ def _grouped_matmul(x, w, group_sizes, interpret: bool):
             f"blocks of a multiple of 8 rows that divides them; {m} has "
             f"none")
     tile = (rows, min(a, _GMM_TILE[1]), min(w.shape[-1], _GMM_TILE[2]))
-    return gmm(x, w, group_sizes, x.dtype, tile, interpret=interpret)
+    return gmm(x, w, group_sizes, x.dtype, tile, first, interpret=interpret)
 
 
 @jax.custom_vjp
@@ -288,22 +303,38 @@ _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
 def dropless_moe_mlp(x, params, top_k: int,
-                     interpret: Optional[bool] = None):
+                     interpret: Optional[bool] = None, *,
+                     held: Optional[Tuple[int, int]] = None,
+                     renormalize: bool = False):
     """Dropless top-k MoE MLP over a token shard ``x`` [N, h].
 
-    params: ``{"router": [h, E] float32, "gate": [E, h, f], "up":
-    [E, h, f], "down": [E, f, h]}`` — the FULL expert stacks, all local
-    (no ``ep`` axis on this path; module docstring).  No biases.
+    params: ``{"router": [h, E] float32, "gate": [G, h, f], "up":
+    [G, h, f], "down": [G, f, h]}``.  No biases.  ``held=None``: the
+    stacks are all E experts (``G = E``).  ``held=(first, G)``: they are
+    experts ``first .. first + G - 1`` of the E the router knows, one
+    chip's share of an expert-parallel layer (module docstring).
 
         p      = softmax(x_f32 @ router)            over all E
-        w, idx = top_k(p, k)                        w NOT renormalised
+        w, idx = top_k(p, k)                        renormalize: w /= sum_j w
         y      = sum_j w[:, j] * down_idx_j(silu(gate_idx_j x) * up_idx_j x)
+                 over the j whose expert idx_j is held
+
+    The weights are the model's: renormalised over the k chosen BEFORE the
+    held experts are selected, so the shares of a layer add up to the
+    whole layer; a token none of whose k experts is held gets exactly
+    zero.  No pair routed to a held expert is ever dropped: the pair rows
+    are the worst case, all ``N * k`` (every token could choose held
+    experts only), the rows of pairs routed elsewhere ride along dead (the
+    grouped matmuls' grids skip them and zero their results), and what
+    their gathers cost is the price of the static shape (gauge
+    ``moe.held_pair_share``).
 
     Returns ``(y [N, h] in x.dtype, aux, z, counts [E] int32)``:
     ``aux = E * sum_e f_e P_e`` with ``f_e`` = pairs routed to e / N and
     ``P_e`` = mean router probability (the Switch load-balance loss
     summed over the k choices), ``z = mean(logsumexp(logits)^2)``
-    (ST-MoE router z-loss), ``counts`` the pairs each expert received.
+    (ST-MoE router z-loss), ``counts`` the pairs each expert received —
+    all three over all E experts, whatever is held.
     Router arithmetic is float32; the experts compute in ``x.dtype``.
     Shapes are static: exactly ``N * k`` pair rows, so dropless needs no
     padding and an expert may receive none.  ``interpret=None`` runs the
@@ -315,12 +346,23 @@ def dropless_moe_mlp(x, params, top_k: int,
         interpret = not on_tpu()
     n, h = x.shape
     e = params["router"].shape[-1]
+    first = None
+    if held is not None:
+        start, count = held
+        if not (0 <= start and 1 <= count and start + count <= e
+                and params["gate"].shape[0] == count):
+            raise ValueError(
+                f"held={held}: the stacks carry {params['gate'].shape[0]} "
+                f"experts and the router knows {e}")
+        first = jnp.asarray(start, jnp.int32)
     with jax.named_scope("bps.moe.route"):
         logits = jnp.dot(x.astype(jnp.float32),
                          params["router"].astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)       # [N, E]
         probs = jax.nn.softmax(logits, axis=-1)
         weights, idx = lax.top_k(probs, top_k)                  # [N, k]
+        if renormalize:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         pair_expert = idx.reshape(n * top_k)
         counts = jnp.bincount(pair_expert, length=e).astype(jnp.int32)
         aux = e * jnp.sum(counts.astype(jnp.float32) / n
@@ -335,25 +377,37 @@ def dropless_moe_mlp(x, params, top_k: int,
     with jax.named_scope("bps.moe.experts"):
         dt = x.dtype
         gate = _grouped_matmul(xs, params["gate"].astype(dt), counts,
-                               interpret)
-        up = _grouped_matmul(xs, params["up"].astype(dt), counts, interpret)
+                               interpret, first)
+        up = _grouped_matmul(xs, params["up"].astype(dt), counts, interpret,
+                             first)
         ys = _grouped_matmul(jax.nn.silu(gate) * up,
-                             params["down"].astype(dt), counts, interpret)
+                             params["down"].astype(dt), counts, interpret,
+                             first)
     with jax.named_scope("bps.moe.combine"):
         pairs = _permute_rows(ys, inverse, order).reshape(n, top_k, h)
         y = jnp.sum(pairs.astype(jnp.float32) * weights[..., None], axis=1)
     return y.astype(x.dtype), aux, z, counts
 
 
-def publish_moe_stats(counts) -> None:
+def publish_moe_stats(counts, held: Optional[Tuple[int, int]] = None
+                      ) -> None:
     """Set the load gauges ``bps.metrics_snapshot()`` reads from the
     per-expert pair counts of one batch: ``counts`` [E] or [layers, E]
-    (``dropless_moe_mlp``'s fourth result; ``models/olmoe.py`` sows it
-    into ``moe_stats``).  Host side: it reads the values, so call it
-    outside any jitted step and off the step's critical path."""
+    (``dropless_moe_mlp``'s fourth result; the models sow it into
+    ``moe_stats``).  With ``held=(first, count)`` also the share's own:
+    ``moe.held_pair_share`` (pairs routed to held experts over all pairs
+    = the live share of the layer's ``N * k`` pair rows) and
+    ``moe.held_load_max_over_mean`` (the fullest held expert over the held
+    experts' mean, worst layer).  Host side: it reads the values, so call
+    it outside any jitted step and off the step's critical path."""
     from ..common.metrics import gauges
     c = np.asarray(counts, np.float64).reshape(-1, np.shape(counts)[-1])
     gauges.set("moe.load_max_over_mean",
                float(np.max(c.max(axis=1) / c.mean(axis=1))))
     gauges.set("moe.tokens_per_expert_min", float(c.min()))
     gauges.set("moe.tokens_per_expert_max", float(c.max()))
+    if held is not None:
+        mine = c[:, held[0]:held[0] + held[1]]
+        gauges.set("moe.held_pair_share", float(mine.sum() / c.sum()))
+        gauges.set("moe.held_load_max_over_mean",
+                   float(np.max(mine.max(axis=1) / mine.mean(axis=1))))

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from .jaxpr_count import kernel_equations
 from byteps_tpu.ops.flash_attention import block_schedule, flash_attention
 from byteps_tpu.parallel import full_attention
 
@@ -268,10 +269,10 @@ def test_dkv_mirror_visits_the_counted_set(tq, tk, q_off, bq, bk, causal):
     bq, bk, tq_p, tk_p = _fa._blocks(tq, tk, bq, bk)
     nq, nk = tq_p // bq, tk_p // bk
     by_rows = {(i, j) for i in range(nq) for j in range(
-        _fa._live_keys(q_off + i * bq, bq, 0, nk, bk, tk, causal))}
+        *_fa._live_keys(q_off + i * bq, bq, 0, nk, bk, tk, causal))}
     by_cols = {(i, j) for j in range(nk) for i in range(
-        _fa._live_queries(j * bk, bk, q_off, nq, bq, _fa._tail(tk, tk_p),
-                          causal), nq)}
+        *_fa._live_queries(j * bk, bk, q_off, nq, bq, _fa._tail(tk, tk_p),
+                           causal))}
     assert by_rows == by_cols
     got = block_schedule(tq, tk, causal, q_off, block_q=bq, block_k=bk)
     assert got["visited"] == len(by_rows) and got["total"] == nq * nk
@@ -280,6 +281,208 @@ def test_dkv_mirror_visits_the_counted_set(tq, tk, q_off, bq, bk, causal):
     live = (col < tk) & ((col <= row) | (not causal))
     live = live.reshape(nq, bq, nk, bk).any(axis=(1, 3))
     assert all((i, j) in by_rows for i, j in zip(*np.nonzero(live)))
+
+
+# ------------------------------------------------------ sliding window
+
+def _banded(q, k, v, window, q_off=None):
+    """Exact attention [B, T, H, D] under HF's sliding-window convention:
+    row i (global: ``q_off`` + its index) attends keys j with ``i - window
+    < j <= i``: itself and the ``window - 1`` before it."""
+    tq, tk = q.shape[1], k.shape[1]
+    i = (tk - tq if q_off is None else q_off) + jnp.arange(tq)[:, None]
+    j = jnp.arange(tk)[None, :]
+    keep = (j <= i) & (i - j < window)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", jnp.where(keep, p, 0.0), v)
+
+
+@pytest.mark.parametrize("window", [7, 128, 1024])
+@pytest.mark.parametrize("case", sorted(_SCHEDULE_CASES))
+def test_window_matches_exact_banded_attention(case, window, form):
+    """Forward and all three gradients over the schedule's shapes, where
+    the window's edge cuts through sub-blocks (7), spans several (128
+    covers some shapes whole) and covers every shape (1024 = causal), in
+    both forms of the kernels (``form``: the long form's k/v spans and
+    its two-kernel backward forced at test sizes)."""
+    tq, tk, bq, bk = _SCHEDULE_CASES[case]
+    b, h, d = 1, 2, 32
+    q = _rand((b, tq, h, d), jnp.float32, 20)
+    k = _rand((b, tk, h, d), jnp.float32, 21)
+    v = _rand((b, tk, h, d), jnp.float32, 22)
+    w = _rand((b, tq, h, d), jnp.float32, 23)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                               interpret=True, window=window)
+
+    def exact(q, k, v):
+        return _banded(q, k, v, window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(exact(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    g_got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    g_want = jax.grad(lambda *a: jnp.sum(exact(*a) * w), (0, 1, 2))(q, k, v)
+    for a, b_ in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4)
+    if window >= tk:                         # the band is the triangle
+        np.testing.assert_allclose(
+            np.asarray(flash(q, k, v)),
+            np.asarray(full_attention(q, k, v, causal=True)),
+            rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 7, 24, 128])
+@pytest.mark.parametrize("q_off", [-64, 0, 32, 64, 96])
+def test_runtime_q_off_with_a_window(q_off, window, form):
+    """The ring's contract holds under a window: ``q_off`` is a runtime
+    scalar, forward and backward bounds come from it inside the kernels.
+    Rows whose window has left the block (``q_off`` past it, or a block
+    wholly in the future) return out 0 and lse < -1e29 — weight 0 in a
+    merge — and take no gradient."""
+    bh, t, d, blk = 2, 64, 128, 16
+    q, k, v, do = (_rand((bh, t, d), jnp.float32, s) for s in (30, 31, 32,
+                                                               33))
+
+    @jax.jit
+    def run(off):
+        out, lse = _fa._fwd(q, k, v, 0.1, True, off, t, blk, blk, True,
+                            window)
+        grads = _fa._bwd_impl(q, k, v, do, lse, _fa._delta(do, out), 0.1,
+                              True, off, t, blk, blk, True, window)
+        return out, lse, grads
+
+    out, lse, grads = run(jnp.int32(q_off))
+    row = q_off + jnp.arange(t)[:, None]
+    col = jnp.arange(t)[None]
+    keep = col <= row
+    if window is not None:
+        keep &= row - col < window
+    dead = ~np.asarray(keep.any(axis=1))
+
+    def exact(q, k, v):
+        s = jnp.einsum("bqd,bkd->bqk", q, k) * 0.1
+        p = jax.nn.softmax(jnp.where(keep, s, -1e30), -1)
+        return jnp.einsum("bqk,bkd->bqd", jnp.where(keep, p, 0.0), v)
+
+    assert np.all(np.asarray(out)[:, dead] == 0.0)
+    assert np.all(np.asarray(lse)[:, dead] < -1e29)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exact(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    want = jax.grad(lambda *a: jnp.sum(exact(*a) * do), (0, 1, 2))(q, k, v)
+    for a, b_ in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4)
+    if not dead.all():
+        s = jnp.where(keep, jnp.einsum("bqd,bkd->bqk", q, k) * 0.1, -jnp.inf)
+        np.testing.assert_allclose(
+            np.asarray(lse[:, ~dead, 0]),
+            np.asarray(jax.scipy.special.logsumexp(s, -1))[:, ~dead],
+            rtol=2e-5, atol=2e-5)
+
+
+def _live_by_mask(tq, tk, q_off, bq, bk, window):
+    """[nq, nk] bool: sub-blocks of the padded square that hold at least
+    one unmasked entry, counted from the band mask itself."""
+    tq_p, tk_p = -(-tq // bq) * bq, -(-tk // bk) * bk
+    row = q_off + np.arange(tq_p)[:, None]
+    col = np.arange(tk_p)[None, :]
+    live = (np.arange(tq_p)[:, None] < tq) & (col < tk) & (col <= row)
+    if window is not None:
+        live &= row - col < window
+    return live.reshape(tq_p // bq, bq, tk_p // bk, bk).any(axis=(1, 3))
+
+
+@pytest.mark.parametrize("window", [None, 1, 7, 128, 512, 1024, 1025])
+@pytest.mark.parametrize("tq,tk,q_off,bq,bk", [
+    (8192, 8192, 0, 512, 512),       # mellum2_12b.fused_1c
+    (1024, 1024, 0, 512, 512),       # gpt2_medium.fused_1c
+    (4096, 4096, 0, 512, 512),       # olmoe_1b_7b.fused_1c
+    (1000, 1000, 0, 512, 512),       # ragged: a key tail inside the last
+    (200, 520, 320, 64, 128),        # decode-aligned, a whole tail block
+    (4096, 4096, 4096, 512, 512),    # a ring block wholly in the past
+    (4096, 4096, -4096, 512, 512),   # ... and wholly in the future
+    (256, 384, 64, 32, 64)])
+def test_window_schedule_against_the_mask(tq, tk, q_off, bq, bk, window):
+    """``block_schedule`` with a window equals a brute-force count of the
+    sub-blocks that hold an unmasked entry; ``_live_keys`` (by q rows) and
+    ``_live_queries`` (by key columns) leave the same set, and it holds
+    every such sub-block."""
+    bq, bk, tq_p, tk_p = _fa._blocks(tq, tk, bq, bk)
+    nq, nk = tq_p // bq, tk_p // bk
+    by_rows = {(i, j) for i in range(nq) for j in range(
+        *_fa._live_keys(q_off + i * bq, bq, 0, nk, bk, tk, True, window))}
+    by_cols = {(i, j) for j in range(nk) for i in range(
+        *_fa._live_queries(j * bk, bk, q_off, nq, bq, _fa._tail(tk, tk_p),
+                           True, window))}
+    assert by_rows == by_cols
+    got = block_schedule(tq, tk, True, q_off, block_q=bq, block_k=bk,
+                         window=window)
+    live = _live_by_mask(tq, tk, q_off, bq, bk, window)
+    assert got == {"visited": len(by_rows), "total": nq * nk,
+                   "needed": int(live.sum())}
+    assert all((i, j) in by_rows for i, j in zip(*np.nonzero(live)))
+    # a sub-block of 512 > window - 1 keys can be visited without need
+    # only through the padded rows of a ragged q side
+    if tq == tq_p and window is not None:
+        assert got["visited"] == got["needed"]
+
+
+def test_window_schedule_at_the_mellum_cell():
+    assert block_schedule(8192, 8192, True, window=1024) == {
+        "visited": 45, "total": 256, "needed": 45}
+    assert block_schedule(8192, 8192, True) == {
+        "visited": 136, "total": 256, "needed": 136}
+    # the whole band in one span: a window as long as the context is causal
+    assert block_schedule(8192, 8192, True, window=8192) == block_schedule(
+        8192, 8192, True)
+
+
+def test_window_is_causal_and_at_least_one_key():
+    q = _rand((1, 32, 1, 32), jnp.float32, 50)
+    with pytest.raises(ValueError, match="causal=True"):
+        flash_attention(q, q, q, causal=False, window=8, interpret=True)
+    with pytest.raises(ValueError, match="window >= 1"):
+        flash_attention(q, q, q, causal=True, window=0, interpret=True)
+    # window 1: every row attends itself alone
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, q, q, causal=True, window=1,
+                                   interpret=True)), np.asarray(q),
+        rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("t,kv_len,want", [
+    (1024, 1024, ([99], [86])),          # gpt2_medium: one-kernel backward
+    (4096, 4096, ([99], [86])),          # olmoe
+    (8192, 8192, ([99], [71, 71])),      # the long form: dK/dV, dQ
+    (1024, 1000, ([102], [96]))])        # a padded key tail
+def test_window_none_leaves_the_kernels_jaxprs_as_they_were(t, kv_len, want):
+    """A kernel's size in jaxpr equations is set-up time (~1 ms an
+    equation a layer on the chip host, PERF.md section 6 PR 28 (5)), and
+    ``gpt2_medium.fused_1c`` has 24 layers of them: with ``window=None``
+    each kernel is the parent commit's (PR 28), equation for equation.
+    The numbers are that commit's; the windowed specialisation adds its
+    few equations (two compares a mask, one bound a loop) on top."""
+    q = jnp.zeros((1, t, 128), jnp.bfloat16)
+    lse = jnp.zeros((1, t, 128), jnp.float32)
+
+    def fwd(window):
+        return kernel_equations(
+            lambda q: _fa._fwd(q, q, q, 0.1, True, 0, kv_len, 512, 512,
+                               False, window), q)
+
+    def bwd(window):
+        return kernel_equations(
+            lambda q, lse: _fa._bwd_impl(q, q, q, q, lse, lse, 0.1, True, 0,
+                                         kv_len, 512, 512, False, window),
+            q, lse)
+
+    assert (fwd(None), bwd(None)) == want
+    windowed = fwd(1024) + bwd(1024)
+    assert all(0 < w - n <= 16 for w, n in zip(windowed, want[0] + want[1]))
 
 
 def test_visited_block_share_gauge():
@@ -298,6 +501,12 @@ def test_visited_block_share_gauge():
                     interpret=True)
     assert bps.metrics_snapshot()["gauges"][
         "flash.visited_block_share"] == 1.0
+    # a windowed call has a gauge of its own: one step holds both kinds
+    flash_attention(q, q, q, causal=True, block_q=32, block_k=32,
+                    interpret=True, window=20)
+    gauges = bps.metrics_snapshot()["gauges"]
+    assert gauges["flash.visited_block_share.window"] == pytest.approx(5 / 9)
+    assert gauges["flash.visited_block_share"] == 1.0
 
 
 def test_long_context_flash_mode():

@@ -64,6 +64,31 @@ def test_layer_compiles_for_a_v5e_at_the_published_widths(one_chip):
         assert scope in text
 
 
+def test_held_share_compiles_for_a_v5e_at_the_published_widths(one_chip):
+    """Mellum2-12B-A2.5B's expert layer as one chip of four holds it (16
+    of 64 experts, renormalised top-8) at 2 x 8192 tokens: the grouped
+    matmuls take a group offset (``gmm``) and a count of local groups
+    (``tgmm``) — nine kernels, none interpreted or replaced."""
+    n, h, f, e, g, k = 2 * 8192, 2304, 896, 64, 16, 8
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"router": shaped((h, e), jnp.float32),
+              "gate": shaped((g, h, f), jnp.float32),
+              "up": shaped((g, h, f), jnp.float32),
+              "down": shaped((g, f, h), jnp.float32)}
+
+    def objective(params, x):
+        y, aux, _, _ = dropless_moe_mlp(x, params, k, interpret=False,
+                                        held=(16, g), renormalize=True)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    text = jax.jit(jax.grad(objective, argnums=(0, 1))).lower(
+        params, shaped((n, h), jnp.bfloat16)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+
+
 # ---------------------------------------------------- the flash kernels
 
 @pytest.mark.parametrize("shape,dtype,causal,kernels", [
@@ -83,6 +108,28 @@ def test_flash_kernels_compile_for_a_v5e(one_chip, shape, dtype, causal,
 
     def objective(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
+                                       interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(objective, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+@pytest.mark.parametrize("shape,window,kernels", [
+    ((2, 8192, 32, 128), 1024, 3),     # mellum2_12b.fused_1c: the long form
+    ((2, 8192, 32, 128), None, 3),     # ... and its full layer
+    ((4, 4096, 16, 128), 1024, 2),     # a window in the resident form
+], ids=["mellum_swa_8192", "mellum_full_8192", "window_4096"])
+def test_windowed_flash_kernels_compile_for_a_v5e(one_chip, shape, window,
+                                                  kernels):
+    """The windowed specialisation of all three kernels at the new cell's
+    shape (two loop bounds from the runtime ``q_off``, one more compare a
+    mask): Mosaic takes it in the long form (two-kernel backward) and in
+    the resident one."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def objective(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
                                        interpret=False).astype(jnp.float32))
 
     text = jax.jit(jax.grad(objective, argnums=(0, 1, 2))).lower(
