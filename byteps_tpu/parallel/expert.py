@@ -38,7 +38,8 @@ data-parallel replica holds all experts).  With ``held=(first, count)``
 the layer is one chip's share of an expert-parallel deployment: it
 routes over all E experts, holds the stacks of ``count`` consecutive
 ones, and returns the part of the sum that those give for the pairs
-routed to them — the local half of expert parallelism.  The other half,
+routed to them — the local half of expert parallelism, its row passes in proportion to the
+rows that land here (:func:`row_schedule`).  The other half,
 the exchange (top-k dispatch by ``all_to_all`` over an ``ep`` axis, so
 that a chip's experts see the tokens of every chip and a token the
 experts of every chip), is NOT here yet (ROADMAP R1): a one-chip share
@@ -49,6 +50,7 @@ path's are.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Optional, Tuple
 
@@ -57,6 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh_util import jit_mapped_step, make_2d_mesh
@@ -265,10 +269,11 @@ def _grouped_matmul(x, w, group_sizes, interpret: bool, first=None):
     ``w`` holds only the groups ``first .. first + G - 1`` of
     ``len(group_sizes)``: the kernels' grids cover those groups' row
     tiles alone (work in proportion to the live rows; ``tgmm`` returns
-    ``G`` matrices), and megablox itself zeroes every row of the other
-    groups in what ``gmm`` returns, forward and row gradient
-    (``gmm.py`` ``_zero_uninitialized_memory``: one ``where`` over the
-    result; tests/test_mellum.py pins it)."""
+    ``G`` matrices) and every row of the other groups comes back zero:
+    forward the kernels write over a zero buffer (``existing_out``), in
+    the row gradient megablox zeroes them itself (``gmm.py``
+    ``_zero_uninitialized_memory``: one ``where`` over the result;
+    tests/test_mellum.py pins both)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     m, a = x.shape
     rows = math.gcd(m, _GMM_TILE[0])
@@ -278,7 +283,11 @@ def _grouped_matmul(x, w, group_sizes, interpret: bool, first=None):
             f"blocks of a multiple of 8 rows that divides them; {m} has "
             f"none")
     tile = (rows, min(a, _GMM_TILE[1]), min(w.shape[-1], _GMM_TILE[2]))
-    return gmm(x, w, group_sizes, x.dtype, tile, first, interpret=interpret)
+    # over zeros, the kernels write the held groups' rows and megablox
+    # makes no pass of its own over the result
+    zeros = None if first is None else jnp.zeros((m, w.shape[-1]), x.dtype)
+    return gmm(x, w, group_sizes, x.dtype, tile, first, zeros,
+               interpret=interpret)
 
 
 @jax.custom_vjp
@@ -300,6 +309,318 @@ def _permute_rows_bwd(res, g):
 
 
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+# ------------------------------------ a held share's row passes (live rows)
+
+# Pair rows one grid step of a held layer's row kernels moves: twice the
+# grouped matmul's row tile, clipped to a divisor of the rows — Mosaic lays
+# a 1-D int32 SMEM block (the rows' tokens) out in 1 024s, and that many
+# rows of 2304 columns, double-buffered in and out beside a 512-row
+# float32 landing buffer, fit the VMEM asked for below (Mosaic's default
+# 16 MiB does not hold them; PERF.md section 6, PR 30).
+_ROW_CHUNK = 2 * _GMM_TILE[0]
+_ROW_VMEM_BYTES = 40 * 2 ** 20
+_DMA_GROUP = 4          # row DMAs started, and waited for, a loop trip
+
+
+def row_schedule(counts, held: Tuple[int, int], chunk: int) -> dict:
+    """Which rows of the sorted order a held layer's row passes visit:
+    ``{"lo", "hi", "first", "end"}``.
+
+    Pairs are sorted by expert, so the rows of the experts ``held =
+    (start, count)`` are ONE range ``[lo, hi)`` of the sorted order, read
+    off the per-expert pair ``counts`` [E] before any row moves.  A pass
+    works on the row chunks ``first .. end - 1`` (``chunk`` rows each):
+    those that meet the range, none when it is empty — ``(end - first) *
+    chunk`` rows visited of ``sum(counts)``; every other chunk is written
+    as zeros and nothing of it is read.  Pure arithmetic on ``counts``,
+    host integers (numpy) or traced ones alike: the kernels take their
+    bounds from here and ``publish_moe_stats`` its gauge (the manner of
+    ``ops.flash_attention.block_schedule``)."""
+    xp = jnp if isinstance(counts, jax.Array) else np
+    start, count = held
+    lo = xp.sum(counts[:start])
+    hi = lo + xp.sum(counts[start:start + count])
+    first = lo // chunk
+    end = xp.where(hi > lo, (hi + chunk - 1) // chunk, first)
+    return {"lo": lo, "hi": hi, "first": first, "end": end}
+
+
+def _sched_words(sched):
+    return jnp.stack([sched[k] for k in ("lo", "hi", "first", "end")]
+                     ).astype(jnp.int32)
+
+
+def _live_chunk(n_chunks):
+    """Index map of an input the dead chunks do not need: their steps name
+    the nearest live chunk's block, which the pipeline has already (or
+    fetches once), so nothing of a dead chunk is read."""
+    def index(c, words):
+        last = jnp.maximum(words[3] - 1, words[2])
+        return jnp.minimum(jnp.clip(c, words[2], last), n_chunks - 1), 0
+    return index
+
+
+def _spread_kernel(words, tok, src, *refs, chunk, part, sub, scaled):
+    """One chunk of ``_spread_rows``, ``part`` rows at a time: the live
+    rows' sources come by one DMA each from ``src`` [N, 1, h] float32 in
+    HBM (a row of its own tile: Mosaic slices no single row off a 2-D
+    array) into ``buf``, then leave in ``sub``-row pieces, masked to the
+    range, scaled and dotted where asked.  Loops, not unrolled code: every
+    layer's kernels are traced and lowered anew, and their size is set-up
+    time (PERF.md section 6, PR 28 (5))."""
+    if scaled:
+        weight, dot, out, d, buf, sem = refs
+    else:
+        out, buf, sem = refs
+    group = math.gcd(part, _DMA_GROUP)
+    start = pl.program_id(0) * chunk
+
+    def loop(trips, body):
+        def trip(i, carry):
+            body(i)
+            return carry
+        lax.fori_loop(0, trips, trip, 0)
+
+    def one_part(p):
+        base = pl.multiple_of(p * part, part)
+        r0 = lax.min(lax.max(words[0] - (start + base), 0), part)
+        r1 = lax.min(lax.max(words[1] - (start + base), 0), part)
+        here = pl.ds(base, part)
+
+        @pl.when(r1 <= r0)
+        def _():
+            out[here, :] = jnp.zeros((part, out.shape[1]), out.dtype)
+            if scaled:
+                d[here, :] = jnp.zeros((part, 1), d.dtype)
+
+        @pl.when(r1 > r0)
+        def _():
+            # whole groups of rows that cover the live ones: a row too
+            # many is a row of this chunk, fetched and masked
+            first = lax.div(r0, group)
+            groups = lax.div(r1 + group - 1, group) - first
+
+            def fetch(g):
+                for i in range(group):
+                    r = (first + g) * group + i
+                    pltpu.make_async_copy(src.at[tok[base + r]], buf.at[r],
+                                          sem).start()
+
+            def land(g):
+                # a wait counts bytes: one for a group's worth
+                pltpu.make_async_copy(src.at[pl.ds(0, group)],
+                                      buf.at[pl.ds(0, group)], sem).wait()
+
+            loop(groups, fetch)
+            loop(groups, land)
+
+            def piece(i):
+                s = pl.multiple_of(i * sub, sub)
+                at = pl.ds(base + s, sub)
+                rows = buf[pl.ds(s, sub), 0, :]
+                row = s + lax.broadcasted_iota(jnp.int32, (sub, 1), 0)
+                live = (row >= r0) & (row < r1)
+                if scaled:
+                    d[at, :] = jnp.where(live, jnp.sum(
+                        rows * dot[at, :].astype(jnp.float32), axis=1,
+                        keepdims=True), 0.0)
+                    rows = rows * weight[at, :]
+                out[at, :] = jnp.where(live, rows, 0.0).astype(out.dtype)
+
+            loop(part // sub, piece)
+
+    loop(chunk // part, one_part)
+
+
+# jitted: the layers' calls share ONE traced and lowered copy of each kernel
+# (a kernel's size is set-up time, every instance anew; XLA inlines the call)
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _spread_rows(src, token, sched, chunk, interpret, scale=None, dot=None):
+    """Sorted order from token order, over the live rows alone:
+    ``out[r] = src[token[r]]`` for ``lo <= r < hi`` and exactly zero
+    elsewhere; [N, h] -> [N k, h], gathered from ``src`` by token with no
+    ``repeat`` of it in between.  With ``scale`` [N k] float32 (a weight a
+    pair, sorted order) and ``dot`` [N k, h] (sorted order) the row is
+    scaled in float32 before it is rounded, and ``d[r] = <src[token[r]],
+    dot[r]>`` in float32 (zero outside the range) comes with it ->
+    ``(out, d [N k])``, ``out`` written over ``dot``."""
+    m, h = token.shape[0], src.shape[1]
+    n_chunks = m // chunk
+    scaled = scale is not None
+    part, sub = math.gcd(chunk, 512), math.gcd(chunk, 128)
+    if scaled:
+        # the float32 copy of ``src`` and the weights' column wait for
+        # ``dot``: made as soon as ``src`` exists they sit through the
+        # recomputed forward (0.2 GiB of the step's scratch)
+        src, scale, dot = lax.optimization_barrier((src, scale, dot))
+    block = pl.BlockSpec((chunk, h), lambda c, words: (c, 0))
+    column = pl.BlockSpec((chunk, 1), lambda c, words: (c, 0))
+    in_specs = [pl.BlockSpec((chunk,), lambda c, words: (c,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    args = [_sched_words(sched), token, src.astype(jnp.float32)[:, None, :]]
+    out_specs, out_shape = block, jax.ShapeDtypeStruct((m, h), src.dtype)
+    if scaled:
+        in_specs += [column, pl.BlockSpec((chunk, h), _live_chunk(n_chunks))]
+        args += [scale[:, None], dot]
+        out_specs = (block, column)
+        out_shape = (out_shape, jax.ShapeDtypeStruct((m, 1), jnp.float32))
+    got = pl.pallas_call(
+        functools.partial(_spread_kernel, chunk=chunk, part=part, sub=sub,
+                          scaled=scaled),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_chunks,), in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((part, 1, h), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        # the scaled rows are written over ``dot`` (dead rows: zeros already)
+        input_output_aliases={4: 0} if scaled else {},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ROW_VMEM_BYTES),
+        name="bps_moe_spread_scaled" if scaled else "bps_moe_spread",
+        interpret=interpret)(*args)
+    return (got[0], got[1].reshape(m)) if scaled else got
+
+
+def _gather_sum_rows(rows, inverse, top_k, weights=None):
+    """Token order from sorted order, the transpose of ``_spread_rows``:
+    ``out[n] = sum_j weights[n, j] * rows[inverse[n k + j]]``, a float32
+    sum in slot order (``weights=None``: ones) -> [N, h] in ``rows.dtype``.
+    A dead pair's row is exactly zero, so nothing is selected: all ``N k``
+    rows are fetched, the one row pass that does not follow the live rows
+    yet (PERF.md section 7, PR 30: a fetch that skips the dead ones wants
+    single-row DMAs off a 2-D bfloat16 array, which Mosaic refuses)."""
+    m, h = rows.shape
+    pairs = rows[inverse].reshape(m // top_k, top_k, h).astype(jnp.float32)
+    if weights is not None:
+        pairs = pairs * weights[..., None]
+    return jnp.sum(pairs, axis=1).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _dispatch_rows(x, token, inverse, sched, top_k, chunk, interpret):
+    """``xs``: each token's row at its live pairs' places in the sorted
+    order (``_spread_rows``); backward, a token's row gradient is the sum
+    of its k pairs' (``_gather_sum_rows``)."""
+    del inverse, top_k
+    return _spread_rows(x, token, sched, chunk, interpret)
+
+
+def _dispatch_rows_fwd(x, token, inverse, sched, top_k, chunk, interpret):
+    return _spread_rows(x, token, sched, chunk, interpret), inverse
+
+
+def _dispatch_rows_bwd(top_k, chunk, interpret, inverse, g):
+    return _gather_sum_rows(g, inverse, top_k), None, None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _combine_rows(ys, weights, scale, token, inverse, sched, top_k, chunk,
+                  interpret):
+    """``y``: each token's weighted sum of its pairs' rows
+    (``_gather_sum_rows``); ``scale`` is ``weights`` in sorted order.
+    Backward runs in SORTED order over the live rows (one
+    ``_spread_rows``): a pair's row gradient is its token's times its
+    weight, its weight's gradient the dot of its row with its token's
+    gradient — so the residuals are the sorted rows themselves and a
+    recomputed forward has no gather to repeat."""
+    del scale, token, sched
+    return _gather_sum_rows(ys, inverse, top_k, weights)
+
+
+def _combine_rows_fwd(ys, weights, scale, token, inverse, sched, top_k, chunk,
+                      interpret):
+    return (_gather_sum_rows(ys, inverse, top_k, weights),
+            (ys, scale, token, inverse, sched))
+
+
+def _combine_rows_bwd(top_k, chunk, interpret, res, g):
+    ys, scale, token, inverse, sched = res
+    g_ys, d = _spread_rows(g, token, sched, chunk, interpret, scale, dot=ys)
+    g_w = d[inverse].reshape(-1, top_k)
+    return g_ys, g_w, None, None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _gate_kernel(words, gate, up, *refs, sub, backward):
+    """One chunk of ``silu(gate) * up`` (float32, rounded once), or of its
+    two gradients; zeros where the schedule has no live row."""
+    c = pl.program_id(0)
+    live = (c >= words[2]) & (c < words[3])
+    outs = refs[1:] if backward else refs
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        for out in outs:
+            out[...] = jnp.zeros_like(out)
+
+    @pl.when(live)
+    def _():
+        def piece(i, carry):
+            at = pl.ds(pl.multiple_of(i * sub, sub), sub)
+            a = gate[at, :].astype(jnp.float32)
+            b = up[at, :].astype(jnp.float32)
+            sig = 1.0 / (1.0 + jnp.exp(-a))
+            if backward:
+                g = refs[0][at, :].astype(jnp.float32)
+                outs[0][at, :] = (g * b * sig * (1.0 + a * (1.0 - sig))
+                                  ).astype(outs[0].dtype)
+                outs[1][at, :] = (g * a * sig).astype(outs[1].dtype)
+            else:
+                outs[0][at, :] = (a * sig * b).astype(outs[0].dtype)
+            return carry
+        lax.fori_loop(0, gate.shape[0] // sub, piece, 0)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2),
+                   static_argnames=("backward",))
+def _gate_call(sched, chunk, interpret, *rows, backward):
+    m, f = rows[0].shape
+    n_chunks = m // chunk
+    shape = jax.ShapeDtypeStruct((m, f), rows[0].dtype)
+    out = pl.BlockSpec((chunk, f), lambda c, words: (c, 0))
+    return pl.pallas_call(
+        functools.partial(_gate_kernel, sub=math.gcd(chunk, 256),
+                          backward=backward),
+        out_shape=(shape, shape) if backward else shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_chunks,),
+            in_specs=[pl.BlockSpec((chunk, f), _live_chunk(n_chunks))
+                      ] * len(rows),
+            out_specs=(out, out) if backward else out),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ROW_VMEM_BYTES),
+        name="bps_moe_gate_bwd" if backward else "bps_moe_gate",
+        interpret=interpret)(_sched_words(sched), *rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _silu_gate_rows(gate, up, sched, chunk, interpret):
+    """``silu(gate) * up`` over the live chunks, zero elsewhere (the dead
+    rows of both are exact zeros, and so is their product)."""
+    return _gate_call(sched, chunk, interpret, gate, up, backward=False)
+
+
+def _silu_gate_rows_fwd(gate, up, sched, chunk, interpret):
+    return (_silu_gate_rows(gate, up, sched, chunk, interpret),
+            (gate, up, sched))
+
+
+def _silu_gate_rows_bwd(chunk, interpret, res, g):
+    gate, up, sched = res
+    return (*_gate_call(sched, chunk, interpret, gate, up, g,
+                        backward=True), None)
+
+
+_silu_gate_rows.defvjp(_silu_gate_rows_fwd, _silu_gate_rows_bwd)
 
 
 def dropless_moe_mlp(x, params, top_k: int,
@@ -324,10 +645,17 @@ def dropless_moe_mlp(x, params, top_k: int,
     whole layer; a token none of whose k experts is held gets exactly
     zero.  No pair routed to a held expert is ever dropped: the pair rows
     are the worst case, all ``N * k`` (every token could choose held
-    experts only), the rows of pairs routed elsewhere ride along dead (the
-    grouped matmuls' grids skip them and zero their results), and what
-    their gathers cost is the price of the static shape (gauge
-    ``moe.held_pair_share``).
+    experts only), and the rows of pairs routed elsewhere ride along dead.
+    The shape is the contract; the work follows the live rows: the held
+    experts' rows are one range of the sorted order (``row_schedule``),
+    the grouped matmuls' grids cover it alone, and so do the row passes
+    around them — the spread into sorted order and its weighted backward
+    form (``_spread_rows``: one DMA a live row, zeros written for every
+    other chunk, nothing of it read) and the gate product.  Gauges
+    ``moe.held_pair_share`` (live rows) and ``moe.visited_row_share``
+    (rows visited, in whole chunks).  The token-order half — the combine's
+    gather and the dispatch's backward (``_gather_sum_rows``) — still
+    fetches every row (PERF.md section 7).
 
     Returns ``(y [N, h] in x.dtype, aux, z, counts [E] int32)``:
     ``aux = E * sum_e f_e P_e`` with ``f_e`` = pairs routed to e / N and
@@ -368,24 +696,52 @@ def dropless_moe_mlp(x, params, top_k: int,
         aux = e * jnp.sum(counts.astype(jnp.float32) / n
                           * jnp.mean(probs, axis=0))
         z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    if held is not None:
+        # the row passes below visit the chunks that meet the held
+        # experts' rows, not all N k (``row_schedule``)
+        chunk = math.gcd(n * top_k, _ROW_CHUNK)
+        sched = row_schedule(counts, held, chunk)
     with jax.named_scope("bps.moe.dispatch"):
         # pairs sorted by expert (stable: a token's order within its
         # group is its arrival order); each token's row gathered k times
-        order = jnp.argsort(pair_expert, stable=True)           # [N k]
-        inverse = jnp.argsort(order)
-        xs = _permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
+        if held is None:
+            order = jnp.argsort(pair_expert, stable=True)       # [N k]
+            inverse = jnp.argsort(order)
+            xs = _permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
+        else:
+            # the pairs' weights ride through the same sort
+            _, order, scale = lax.sort(
+                (pair_expert, lax.iota(jnp.int32, n * top_k),
+                 lax.stop_gradient(weights).reshape(n * top_k)),
+                num_keys=1, is_stable=True)
+            inverse = jnp.argsort(order)
+            token = order // top_k
+            xs = _dispatch_rows(x, token, inverse, sched, top_k, chunk,
+                                interpret)
     with jax.named_scope("bps.moe.experts"):
         dt = x.dtype
         gate = _grouped_matmul(xs, params["gate"].astype(dt), counts,
                                interpret, first)
         up = _grouped_matmul(xs, params["up"].astype(dt), counts, interpret,
                              first)
-        ys = _grouped_matmul(jax.nn.silu(gate) * up,
-                             params["down"].astype(dt), counts, interpret,
-                             first)
+        if held is None:
+            act = jax.nn.silu(gate) * up
+    if held is not None:
+        # a kernel of its own scope: the readers of the grouped matmuls'
+        # time take every ``pallas_call`` under ``bps.moe.experts``
+        with jax.named_scope("bps.moe.gate"):
+            act = _silu_gate_rows(gate, up, sched, chunk, interpret)
+    with jax.named_scope("bps.moe.experts"):
+        ys = _grouped_matmul(act, params["down"].astype(dt), counts,
+                             interpret, first)
     with jax.named_scope("bps.moe.combine"):
-        pairs = _permute_rows(ys, inverse, order).reshape(n, top_k, h)
-        y = jnp.sum(pairs.astype(jnp.float32) * weights[..., None], axis=1)
+        if held is None:
+            pairs = _permute_rows(ys, inverse, order).reshape(n, top_k, h)
+            y = jnp.sum(pairs.astype(jnp.float32) * weights[..., None],
+                        axis=1)
+        else:
+            y = _combine_rows(ys, weights, scale, token, inverse, sched,
+                              top_k, chunk, interpret)
     return y.astype(x.dtype), aux, z, counts
 
 
@@ -398,8 +754,11 @@ def publish_moe_stats(counts, held: Optional[Tuple[int, int]] = None
     ``moe.held_pair_share`` (pairs routed to held experts over all pairs
     = the live share of the layer's ``N * k`` pair rows) and
     ``moe.held_load_max_over_mean`` (the fullest held expert over the held
-    experts' mean, worst layer).  Host side: it reads the values, so call
-    it outside any jitted step and off the step's critical path."""
+    experts' mean, worst layer) and ``moe.visited_row_share`` (pair rows
+    the layer's row passes visit over all of them: ``row_schedule``'s live
+    chunks, the share rounded up to ``_ROW_CHUNK`` rows at either end).
+    Host side: it reads the values, so call it outside any jitted step and
+    off the step's critical path."""
     from ..common.metrics import gauges
     c = np.asarray(counts, np.float64).reshape(-1, np.shape(counts)[-1])
     gauges.set("moe.load_max_over_mean",
@@ -411,3 +770,9 @@ def publish_moe_stats(counts, held: Optional[Tuple[int, int]] = None
         gauges.set("moe.held_pair_share", float(mine.sum() / c.sum()))
         gauges.set("moe.held_load_max_over_mean",
                    float(np.max(mine.max(axis=1) / mine.mean(axis=1))))
+        visited = 0
+        for layer in c.astype(np.int64):
+            chunk = math.gcd(int(layer.sum()), _ROW_CHUNK)
+            sched = row_schedule(layer, held, chunk)
+            visited += int(sched["end"] - sched["first"]) * chunk
+        gauges.set("moe.visited_row_share", visited / float(c.sum()))

@@ -9,6 +9,8 @@ fixture would skip all of its tests in silence.  The topology is described
 inside a fixture only, never while a module is imported.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import optax
@@ -68,7 +70,11 @@ def test_held_share_compiles_for_a_v5e_at_the_published_widths(one_chip):
     """Mellum2-12B-A2.5B's expert layer as one chip of four holds it (16
     of 64 experts, renormalised top-8) at 2 x 8192 tokens: the grouped
     matmuls take a group offset (``gmm``) and a count of local groups
-    (``tgmm``) — nine kernels, none interpreted or replaced."""
+    (``tgmm``) — nine kernels under ``bps.moe.experts``, none interpreted
+    or replaced — and the row passes that follow the live rows (a range
+    that is NOT a prefix of the sorted order) are the repo's own four, each
+    under its stage's scope: Mosaic takes the single-row DMAs off the
+    ``[N, 1, h]`` float32 source and the 1 024-row SMEM index block."""
     n, h, f, e, g, k = 2 * 8192, 2304, 896, 64, 16, 8
 
     def shaped(shape, dtype):
@@ -86,7 +92,19 @@ def test_held_share_compiles_for_a_v5e_at_the_published_widths(one_chip):
 
     text = jax.jit(jax.grad(objective, argnums=(0, 1))).lower(
         params, shaped((n, h), jnp.bfloat16)).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("bps.moe.experts" in c for c in calls) == 9
+    own = [c for c in calls if "bps.moe.experts" not in c]
+    assert sorted(
+            re.search(r"(bps\.moe\.\w+)\)*/jit\(\w+\)/(\w+)/pallas_call$",
+                      c).groups()
+            for c in own) == [
+        ("bps.moe.combine", "bps_moe_spread_scaled"),
+        ("bps.moe.dispatch", "bps_moe_spread"),
+        ("bps.moe.gate", "bps_moe_gate"),
+        ("bps.moe.gate", "bps_moe_gate_bwd")], own
 
 
 # ---------------------------------------------------- the flash kernels
