@@ -21,6 +21,12 @@ from .olmoe import (  # noqa: F401
     olmoe_loss,
     olmoe_tiny,
 )
+from .zaya import (  # noqa: F401
+    Zaya,
+    ZayaConfig,
+    zaya_loss,
+    zaya_tiny,
+)
 from .resnet import (  # noqa: F401
     ResNet,
     VGG,

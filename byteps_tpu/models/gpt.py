@@ -15,12 +15,14 @@ optional per-layer remat.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 AttnFn = Callable  # (q, k, v, *, causal, sm_scale) -> out
 
@@ -196,4 +198,117 @@ def lm_loss(logits, labels, ignore: int = -1):
     """Next-token cross-entropy; ``labels == ignore`` positions skipped.
     Callers shift: labels[t] is the target for logits[t]."""
     s, c = token_nll(logits, labels, ignore)
+    return s / jnp.maximum(c, 1.0)
+
+
+# ------------------------------------------- a head that never holds [N, V]
+
+# float32 logits of one block of rows: the most the blocked head keeps of
+# the [N, V] square at a time (1 GiB = 2048 rows of a 131 072-row table)
+_LOGIT_BLOCK_BYTES = 2 ** 30
+
+
+def logit_block_rows(n: int, vocab: int) -> int:
+    """Rows of one block of :func:`blocked_token_nll`, from the shapes
+    alone: the largest power-of-two divisor of ``n`` whose float32 logits
+    [rows, vocab] stay within ``_LOGIT_BLOCK_BYTES`` (at least one row)."""
+    rows = n & -n                       # largest power of two dividing n
+    while rows > 1 and rows * vocab * 4 > _LOGIT_BLOCK_BYTES:
+        rows //= 2
+    return rows
+
+
+def _block_logits(xb, w):
+    """float32 logits [rows, V] of one block from ``x.dtype`` operands."""
+    return lax.dot_general(xb, w, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _nll_blocks(x, table, labels, ignore: int, grads: bool):
+    """The head in blocks of rows.  Per block: float32 logits from
+    ``x.dtype`` operands, the log-sum-exp, the label's logit; with
+    ``grads`` also the block's cotangent ``softmax - onehot`` (rounded to
+    ``x.dtype`` for the two matmuls that consume it at once: the rows'
+    gradient, and the table's, accumulated in float32 over the blocks)."""
+    n, h = x.shape
+    v = table.shape[0]
+    rows = logit_block_rows(n, v)
+    from ..common.metrics import gauges
+    gauges.set("head.logit_block_bytes", float(rows * v * 4))
+    gauges.set("head.logit_blocks", float(n // rows))
+    def one_block(g_table, block):
+        xb, lb = block
+        valid = lb != ignore
+        safe = jnp.where(valid, lb, 0)
+        logits = _block_logits(xb, w)
+        top = jnp.max(logits, axis=-1, keepdims=True)
+        e = jnp.exp(logits - top)
+        total = jnp.sum(e, axis=-1, keepdims=True)
+        lse = (top + jnp.log(total))[:, 0]
+        ll = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0] - lse
+        m = valid.astype(jnp.float32)
+        out = (-(ll * m).sum(), m.sum())
+        if not grads:
+            return g_table, out
+        hit = lax.broadcasted_iota(jnp.int32, logits.shape, 1) == safe[:, None]
+        d = ((e / total - hit.astype(jnp.float32)) * m[:, None]
+             ).astype(x.dtype)                                  # [rows, V]
+        g_x = jnp.dot(d, w, preferred_element_type=jnp.float32)
+        g_table = g_table + lax.dot_general(
+            d, xb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return g_table, out + (g_x.astype(x.dtype),)
+
+    with jax.named_scope("bps.head"):
+        w = table.astype(x.dtype)                               # [V, h]
+        g_table, out = lax.scan(
+            one_block, jnp.zeros((v, h) if grads else (), jnp.float32),
+            (x.reshape(n // rows, rows, h), labels.reshape(n // rows, rows)))
+    nll, count = out[0].sum(), out[1].sum()
+    if not grads:
+        return nll, count
+    return nll, count, out[2].reshape(n, h), g_table
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def blocked_token_nll(x, table, labels, ignore: int = -1):
+    """:func:`token_nll` of the logits ``x @ table.T`` without ever holding
+    them: ``x`` [N, h] the final hidden rows, ``table`` [V, h] the (tied)
+    embedding, ``labels`` [N] -> (sum of per-token NLL over valid
+    positions, valid-token count).
+
+    For a vocabulary of 100 k rows and more the float32 logits of a step
+    are gigabytes (16 384 tokens x 131 136 rows: 8.6 GB, and as much again
+    for their cotangent).  Here they exist one block of rows at a time
+    (:func:`logit_block_rows`: the block's float32 logits <= 1 GiB, from
+    the shapes; gauges ``head.logit_block_bytes`` / ``head.logit_blocks``),
+    in float32 from ``x.dtype`` operands, forward AND backward: under
+    differentiation the forward pass forms both gradients block by block
+    — each block's cotangent is made and consumed inside the block — and
+    the backward pass only scales them by the incoming cotangent, so the
+    head costs three matmuls, not the four of recomputing a block's logits
+    in the backward pass.  The table's gradient [V, h] float32 is the one
+    full-size array, and the step has to hold it anyway."""
+    return _nll_blocks(x, table, labels, ignore, grads=False)
+
+
+def _blocked_token_nll_fwd(x, table, labels, ignore):
+    nll, count, g_x, g_table = _nll_blocks(x, table, labels, ignore,
+                                           grads=True)
+    return (nll, count), (g_x, g_table)
+
+
+def _blocked_token_nll_bwd(ignore, res, g):
+    g_x, g_table = res
+    g_nll = g[0]                         # the count has no gradient
+    return ((g_x.astype(jnp.float32) * g_nll).astype(g_x.dtype),
+            g_table * g_nll, None)
+
+
+blocked_token_nll.defvjp(_blocked_token_nll_fwd, _blocked_token_nll_bwd)
+
+
+def blocked_lm_loss(x, table, labels, ignore: int = -1):
+    """:func:`lm_loss` through :func:`blocked_token_nll`."""
+    s, c = blocked_token_nll(x, table, labels, ignore)
     return s / jnp.maximum(c, 1.0)

@@ -147,13 +147,23 @@ def repeat_kv(k, v, groups: int):
     return jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2)
 
 
-def apply_rope(x, cos, sin):
+def apply_rope(x, cos, sin, rotary_dim: Optional[int] = None):
     """Rotate pairs (x[i], x[i + D/2]) — the *rotate-half* convention used
     by Llama checkpoints as distributed (HF ``rotate_half``), so pretrained
     q/k projections import without permutation.  x is [B, T, H, D], tables
     broadcast over the head axis.  (The interleaved (x[2i], x[2i+1])
     convention is the same rotation under a fixed channel permutation; we
-    pin the checkpoint-compatible one.)"""
+    pin the checkpoint-compatible one.)
+
+    ``rotary_dim`` is the rotary width (HF ``partial_rotary_factor`` x
+    head size): only the first ``rotary_dim`` of a head's D channels turn —
+    pairs (x[i], x[i + rotary_dim/2]), tables [*, T, rotary_dim/2] from
+    ``rope_frequencies(rotary_dim, ...)`` — and the rest pass through as
+    they are.  ``None`` (or D) turns the whole head."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary_dim], cos, sin),
+             x[..., rotary_dim:]], axis=-1)
     d2 = x.shape[-1] // 2
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :d2], xf[..., d2:]

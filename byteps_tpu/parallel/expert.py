@@ -26,8 +26,10 @@ tests/test_expert_parallel.py.  Routing semantics are shard-local
 mesh size — only the placement does.
 
 **Dropless top-k (the experts held here)** — :func:`dropless_moe_mlp`,
-what ``models/olmoe.py`` and ``models/mellum.py`` build: softmax over all
-experts, the k largest kept (their weights as they are, or renormalised
+what ``models/olmoe.py``, ``models/mellum.py`` and ``models/zaya.py``
+build: softmax over all experts (the layer's own linear router, or
+probabilities and a selection bias handed in from outside: ``routing=``),
+the k largest kept (their weights as they are, or renormalised
 to sum to one), no capacity and no dropped token, bias-free SiLU-gated
 experts.  The token–expert pairs are sorted by expert and the three
 expert matmuls run as grouped matmuls over the ragged groups
@@ -586,15 +588,24 @@ def _gate_call(sched, chunk, interpret, *rows, backward):
     m, f = rows[0].shape
     n_chunks = m // chunk
     shape = jax.ShapeDtypeStruct((m, f), rows[0].dtype)
-    out = pl.BlockSpec((chunk, f), lambda c, words: (c, 0))
+    # columns a grid step: all of them where every operand's two buffers
+    # fit three quarters of the VMEM asked for (an expert width of 896:
+    # 17.5 MiB), else halves of them (2048 backward: 40 MiB -> 20)
+    width, blocks = f, len(rows) + (2 if backward else 1)
+    while (2 * blocks * chunk * width * rows[0].dtype.itemsize
+           > 3 * _ROW_VMEM_BYTES // 4 and width % 256 == 0):
+        width //= 2
+    live = _live_chunk(n_chunks)
+    out = pl.BlockSpec((chunk, width), lambda c, j, words: (c, j))
     return pl.pallas_call(
         functools.partial(_gate_kernel, sub=math.gcd(chunk, 256),
                           backward=backward),
         out_shape=(shape, shape) if backward else shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(n_chunks,),
-            in_specs=[pl.BlockSpec((chunk, f), _live_chunk(n_chunks))
-                      ] * len(rows),
+            num_scalar_prefetch=1, grid=(n_chunks, f // width),
+            in_specs=[pl.BlockSpec(
+                (chunk, width),
+                lambda c, j, words: (live(c, words)[0], j))] * len(rows),
             out_specs=(out, out) if backward else out),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_ROW_VMEM_BYTES),
@@ -626,7 +637,8 @@ _silu_gate_rows.defvjp(_silu_gate_rows_fwd, _silu_gate_rows_bwd)
 def dropless_moe_mlp(x, params, top_k: int,
                      interpret: Optional[bool] = None, *,
                      held: Optional[Tuple[int, int]] = None,
-                     renormalize: bool = False):
+                     renormalize: bool = False,
+                     routing: Optional[Tuple] = None):
     """Dropless top-k MoE MLP over a token shard ``x`` [N, h].
 
     params: ``{"router": [h, E] float32, "gate": [G, h, f], "up":
@@ -639,6 +651,16 @@ def dropless_moe_mlp(x, params, top_k: int,
         w, idx = top_k(p, k)                        renormalize: w /= sum_j w
         y      = sum_j w[:, j] * down_idx_j(silu(gate_idx_j x) * up_idx_j x)
                  over the j whose expert idx_j is held
+
+    ``routing=(p, beta)``: the probabilities come from OUTSIDE — a router
+    of the model's own (an MLP, a state carried from layer to layer) — as
+    ``p`` [N, E] float32, with a selection bias ``beta`` [E] or ``None``:
+    the stage ``bps.moe.route`` then takes ``idx = top_k(p + beta)`` and
+    reads the weights from ``p`` at ``idx`` (the bias chooses and is not
+    weighed: no gradient reaches it; ``p``'s reaches the caller's router
+    through the weights); ``params`` needs no ``router``; everything after
+    is the same code.  ``top_k = 1`` is covered like any k (``N`` pair
+    rows, a token's one pair live iff its expert is held).
 
     The weights are the model's: renormalised over the k chosen BEFORE the
     held experts are selected, so the shares of a layer add up to the
@@ -661,7 +683,8 @@ def dropless_moe_mlp(x, params, top_k: int,
     ``aux = E * sum_e f_e P_e`` with ``f_e`` = pairs routed to e / N and
     ``P_e`` = mean router probability (the Switch load-balance loss
     summed over the k choices), ``z = mean(logsumexp(logits)^2)``
-    (ST-MoE router z-loss), ``counts`` the pairs each expert received —
+    (ST-MoE router z-loss; exactly 0 with ``routing``, whose logits stay
+    with the caller), ``counts`` the pairs each expert received —
     all three over all E experts, whatever is held.
     Router arithmetic is float32; the experts compute in ``x.dtype``.
     Shapes are static: exactly ``N * k`` pair rows, so dropless needs no
@@ -673,7 +696,7 @@ def dropless_moe_mlp(x, params, top_k: int,
         from ..ops.pallas_kernels import on_tpu
         interpret = not on_tpu()
     n, h = x.shape
-    e = params["router"].shape[-1]
+    e = (params["router"] if routing is None else routing[0]).shape[-1]
     first = None
     if held is not None:
         start, count = held
@@ -684,18 +707,29 @@ def dropless_moe_mlp(x, params, top_k: int,
                 f"experts and the router knows {e}")
         first = jnp.asarray(start, jnp.int32)
     with jax.named_scope("bps.moe.route"):
-        logits = jnp.dot(x.astype(jnp.float32),
-                         params["router"].astype(jnp.float32),
-                         precision=lax.Precision.HIGHEST)       # [N, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, idx = lax.top_k(probs, top_k)                  # [N, k]
+        if routing is None:
+            logits = jnp.dot(x.astype(jnp.float32),
+                             params["router"].astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)   # [N, E]
+            probs = jax.nn.softmax(logits, axis=-1)
+            weights, idx = lax.top_k(probs, top_k)              # [N, k]
+        else:
+            probs, bias = routing
+            if probs.shape != (n, e) or probs.dtype != jnp.float32:
+                raise ValueError(
+                    f"routing: probabilities must be float32 [{n}, E], got "
+                    f"{probs.dtype} {probs.shape}")
+            chooser = probs if bias is None else probs + bias
+            _, idx = lax.top_k(lax.stop_gradient(chooser), top_k)
+            weights = jnp.take_along_axis(probs, idx, axis=-1)
         if renormalize:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         pair_expert = idx.reshape(n * top_k)
         counts = jnp.bincount(pair_expert, length=e).astype(jnp.int32)
         aux = e * jnp.sum(counts.astype(jnp.float32) / n
                           * jnp.mean(probs, axis=0))
-        z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+        z = (jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+             if routing is None else jnp.zeros((), jnp.float32))
     if held is not None:
         # the row passes below visit the chunks that meet the held
         # experts' rows, not all N k (``row_schedule``)

@@ -200,3 +200,67 @@ def test_dp_step_on_four_chips_reduces_asynchronously(topo):
 def test_dp_step_on_one_chip_holds_no_collective(topo):
     assert collective_schedule(_compiled_dp_step(topo.devices[:1])) == {
         "sync": 0, "async": 0}
+
+
+# ------------------------------------------- a whole cell's step: ZAYA1-8B
+
+def test_zaya_cell_step_fits_a_v5e(topo, monkeypatch):
+    """``zaya1_8b.fused_1c``'s step as ``benchmarks/paths/fused.py`` builds
+    it (``make_dp_train_step`` over the family's loss, AdamW, per-block
+    ``remat``) at the published widths and 16 384 positions, for ONE
+    described chip: the compiler takes it (the long-form flash kernels at
+    8 heads, the top-1 share's row kernels at 16 chunks of 1 024 rows, the
+    gate kernel's column halves at an expert width of 2048), its memory
+    stays under the chip's 15.75 GiB, and no ``[tokens, vocabulary]``
+    logits array exists in it — the head's blocks of 1 024 rows do.  (The
+    models ask ``on_tpu()`` whether to interpret their kernels; a
+    described chip is no backend, so the test answers for it.)"""
+    import os
+    import sys
+    import byteps_tpu.ops.pallas_kernels as kernels
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    # the module, not the function ``byteps_tpu.ops`` exports under its name
+    monkeypatch.setattr(sys.modules["byteps_tpu.ops.flash_attention"],
+                        "on_tpu", lambda: True)
+    monkeypatch.syspath_prepend(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks"))
+    from harness import spec
+    found = spec.resolve(spec.load_benchmark(), "zaya1_8b.fused_1c")
+    config, traffic = found["config"], found["traffic"]
+    family = spec.load_module("families", config["family"]).build(
+        config, traffic)
+    comm = CommContext(mesh=_build_mesh(topo.devices[:1], 1), n_dcn=1,
+                       n_ici=1)
+    rep = comm.replicated_sharding()
+
+    def shaped(tree, sharding):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    tx = getattr(optax, traffic["optimizer"]["name"])(
+        traffic["optimizer"]["learning_rate"])
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(family.init_params, key)
+    batch = jax.eval_shape(
+        lambda k: family.make_batch(k, traffic["seqs_per_chip"]), key)
+    compiled = make_dp_train_step(comm, family.loss_fn, tx).lower(
+        shaped(params, rep), shaped(jax.eval_shape(tx.init, params), rep),
+        shaped(batch, NamedSharding(comm.mesh, P(comm.dp_axes)))).compile()
+    memory = compiled.memory_analysis()
+    # weights and two moments: 3 x 696,182,859 x 4 B = 7.78 GiB
+    assert 7.7 < memory.argument_size_in_bytes / 2 ** 30 < 7.9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.generated_code_size_in_bytes) < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    tokens, vocab = traffic["seq_len"], config["vocab_size"]
+    assert f"[{tokens},{vocab}]" not in text
+    assert f"[1,{tokens},{vocab}]" not in text
+    assert f"f32[1024,{vocab}]" in text              # a block of the head
+    # a layer: flash forward, its recomputation, two backward kernels; the
+    # spread (+ its recomputation), the scaled spread, the gate (+ its
+    # recomputation) and its backward; twelve grouped matmuls
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 * 22
+    for scope in ("attn_cca/pallas_call", "bps.cca.mix", "bps.zaya.router",
+                  "bps.head", "bps.moe.experts"):
+        assert scope in text
