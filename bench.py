@@ -271,16 +271,18 @@ def _bench_push_pull(devices, on_tpu, emit=None):
 
     def dispatch_amortization(nchunks=64):
         """Deterministic dispatch-count datum (VERDICT r4 task 3): the
-        same multi-chunk push unmerged and at the default group size,
-        with the dispatcher paused until the queue holds every chunk, so
-        the merge width is the setting's property, not a race."""
+        same multi-chunk push one chunk a program and as the engine's
+        dispatch units (a bucket's worth of queued columns a program:
+        64 chunks -> 4), with the dispatcher paused until the queue
+        holds every chunk."""
         counts = {}
         chunk_elems = 65536 // 4
         x = np.zeros(nchunks * chunk_elems, np.float32)
-        for label, gs in (("group1", 1), ("group4", 4)):
+        for label, one_chunk in (("chunked", True), ("units", False)):
             cfg = Config(telemetry_on=False, trace_on=False,
-                         group_size=gs, partition_bytes=65536)
+                         partition_bytes=65536)
             eng = PushPullEngine(comm, cfg)
+            eng._one_chunk_units = one_chunk
             try:
                 eng.pause_dispatch()
                 h = eng.push_pull_local_async(x, "bench.amort")

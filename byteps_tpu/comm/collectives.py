@@ -438,12 +438,13 @@ def aot_warm_buffer_programs(comm: CommContext, *, col_layout, C: int,
                              n: int, out_shape, dtype_name: str,
                              local: bool, scaled: bool, denom: int,
                              shard_out: bool, scale_value=None,
-                             merge_widths=(), max_programs: int = 24,
+                             units=(), max_programs: int = 24,
                              assembled: bool = True) -> int:
     """Pre-compile the persistent program set for one buffer-mode tensor;
-    returns the number of executables AOT-compiled.  ``merge_widths``:
-    the run widths the dispatcher can form (engine-supplied:
-    1..group_size).  ``assembled=False``: a
+    returns the number of executables AOT-compiled.  ``units``: the
+    column ranges ``(col_off, width)`` the dispatcher can launch as one
+    program (engine-supplied, from the rule its pop follows); default:
+    one per chunk.  ``assembled=False``: a
     bucket tensor, which arrives packed and padded and leaves through
     its unpack program -- only the chunk programs and their scalars."""
     from jax.sharding import NamedSharding
@@ -463,37 +464,19 @@ def aot_warm_buffer_programs(comm: CommContext, *, col_layout, C: int,
     off_struct = _struct((), jnp.int32, rep)
     buf_struct = _struct((n_ici, C), acc,
                          NamedSharding(comm.mesh, P(ICI_AXIS)))
-    nchunks = len(col_layout)
-    tail_w = col_layout[-1][1]
-    body_ws = sorted({w for _, w in col_layout[:-1]})
-    # A tail whose width matches the body merges into body runs, so the
-    # longest run then spans ALL chunks; otherwise the tail always rides
-    # its own width-1 run.
-    uniform = nchunks == 1 or body_ws == [tail_w]
-    max_run = nchunks if uniform else nchunks - 1
-    widths = sorted({tail_w} if uniform else set(body_ws))
     compiled = 0
-    # Chunk-scatter executables.  init=True serves the first-dispatched
-    # run of a push (accumulator creation); with priority order that run
-    # starts at chunk 0, so every reachable width needs both variants
-    # except a distinct tail (always dispatched last unless the tensor is
-    # a single chunk).
-    want = []
-    for w in widths:
-        for k in sorted(set(merge_widths) or {1}):
-            if k <= max_run:
-                want.append((w, k, True))
-                if nchunks > 1:
-                    want.append((w, k, False))
-    if not uniform:
-        want.append((tail_w, 1, False))
-    seen = set()
-    want = [x for x in want if not (x in seen or seen.add(x))]
-    for w, k, init in want[:max_programs]:
-        _chunk_scatter_program(comm, w, k, C, init, local)
-        args = [flat_struct, off_struct] + ([] if init else [buf_struct])
+    units = units or col_layout
+    # Chunk-scatter executables, one per distinct (width, init): the
+    # range at column 0 is the first a push dispatches (priority order
+    # within a tensor is chunk order) and creates the accumulator.
+    want = list(dict.fromkeys((w, off == 0) for off, w in units))
+    for w, init in want[:max_programs]:
+        _chunk_scatter_program(comm, w, C, init, local)
+        args = [flat_struct]
+        if w != C:
+            args += [off_struct] + ([] if init else [buf_struct])
         compiled += aot_compile(
-            comm, ("chunk_scatter", w, k, C, init, local), args)
+            comm, ("chunk_scatter", w, C, init, local), args)
     # Pad program (scatter layout needs n divisible by the mesh).  The
     # sharded staging pads on the host inside its one memcpy, so only
     # the replicated/stacked layouts dispatch a device pad.
@@ -517,8 +500,9 @@ def aot_warm_buffer_programs(comm: CommContext, *, col_layout, C: int,
     # Device scalars: one transfer per column offset / fused scale now,
     # zero per dispatch later.  The scale's cache key carries the jnp
     # class, exactly as assemble_scatter passes it at dispatch.
-    for col_off, _ in col_layout:
-        _cached_scalar(comm, int(col_off), jnp.int32)
+    for col_off, w in units:
+        if w != C:
+            _cached_scalar(comm, int(col_off), jnp.int32)
     if scaled and scale_value is not None:
         _cached_scalar(comm, float(scale_value),
                        jnp.float64 if acc == np.float64 else jnp.float32)
@@ -577,9 +561,10 @@ def aot_warm_single_program(comm: CommContext, *, n: int, dtype_name: str,
 #   the chunk shards into tensor order, and applies scale / divisor /
 #   dtype restore — the only pass over replicated memory in the whole path.
 #
-# The chunk offset and accumulator position are traced scalars, so one
-# compilation serves every (chunk-length, group-width) pair; the assemble
-# program compiles once per tensor layout.
+# The column offset is a traced scalar, so one compilation serves every
+# range of one width, whichever chunks make it up; a range that is the
+# whole row needs no offset, no slice and no accumulator to write into.
+# The assemble program compiles once per tensor layout.
 # ---------------------------------------------------------------------------
 
 
@@ -614,15 +599,22 @@ def scatter_layout(chunk_bounds, n_ici: int):
     return layout, C
 
 
-def _chunk_scatter_program(comm: CommContext, w: int, k: int, C: int,
+def _chunk_scatter_program(comm: CommContext, width: int, C: int,
                            init: bool, local=False):
-    """Chunk-group reduce-scatter program over a column slab.
-
-    Handles ``k`` contiguous equal-width (``w`` columns) chunks in one
-    program (reference NCCL group batching, nccl_manager.cc:130-134).
+    """Reduce-scatter program over a range of ``width`` columns: one
+    chunk, or a contiguous run of chunks of any widths dispatched
+    together (reference NCCL group batching, nccl_manager.cc:130-134).
 
     init=True:  (flat [R, n_pad], col_off) -> (buf [n_ici, C], token)
     init=False: (flat [R, n_pad], col_off, buf) -> (buf, token), donated.
+    width == C: (flat [R, n_pad]) -> (buf, token) -- the range is the
+    whole row, so nothing is sliced, no accumulator is zero-filled and
+    nothing is written into one: the reduce-scatter's result IS the
+    accumulator.
+
+    Every value is the same reduction over the same ranks whatever range
+    carries it, so how a tensor's columns were cut into programs can
+    never change a result.
 
     ``local`` selects the single-process local-contribution staging:
 
@@ -645,40 +637,40 @@ def _chunk_scatter_program(comm: CommContext, w: int, k: int, C: int,
     f16/bf16 sums are stored as f32; assemble restores the dtype.
     """
     n_ici = comm.n_ici
+    whole = width == C
+    init = init or whole
 
     def build():
-        def body(x, col_off, *maybe_buf):
+        def body(x, *rest):
             if local == "sharded":
                 row = lax.all_gather(x, (DCN_AXIS, ICI_AXIS), tiled=True)
             else:
                 row = x if local else x[0]
-            xr = row.reshape(n_ici, C)           # free: row is contiguous
-            slab = lax.dynamic_slice(
-                xr, (jnp.zeros((), col_off.dtype), col_off),
-                (n_ici, k * w))
+            slab = row.reshape(n_ici, C)         # free: row is contiguous
+            if not whole:
+                col_off = rest[0]
+                zero = jnp.zeros((), col_off.dtype)
+                slab = lax.dynamic_slice(slab, (zero, col_off),
+                                         (n_ici, width))
             s = lax.psum_scatter(_acc(slab), ICI_AXIS,
-                                 scatter_dimension=0, tiled=True)  # [1, kw]
+                                 scatter_dimension=0, tiled=True)  # [1, w]
             if comm.n_dcn > 1:
                 s = lax.psum(s, DCN_AXIS)
-            if init:
-                buf = jnp.zeros((1, C), s.dtype)
+            if whole:
+                buf = s
             else:
-                buf = maybe_buf[0]
-            buf = lax.dynamic_update_slice(
-                buf, s, (jnp.zeros((), col_off.dtype), col_off))
+                buf = jnp.zeros((1, C), s.dtype) if init else rest[1]
+                buf = lax.dynamic_update_slice(buf, s, (zero, col_off))
             # token stays ICI-sharded — never replicated, never read;
             # only blocked on
             return buf, s[:1, :1]
 
-        if local == "sharded":
-            x_spec = P(comm.dp_axes)   # 1-D block-sharded contribution
-        elif local:
-            x_spec = P()
-        else:
-            x_spec = P(comm.dp_axes)
-        specs = [x_spec, P()]
-        if not init:
-            specs.append(P(ICI_AXIS))
+        # the contribution: rank-stacked, or 1-D replicated / block-sharded
+        specs = [P() if local is True else P(comm.dp_axes)]
+        if not whole:
+            specs.append(P())
+            if not init:
+                specs.append(P(ICI_AXIS))
         fn = jax.shard_map(
             body, mesh=comm.mesh, in_specs=tuple(specs),
             out_specs=(P(ICI_AXIS), P(ICI_AXIS)), check_vma=False)
@@ -686,15 +678,15 @@ def _chunk_scatter_program(comm: CommContext, w: int, k: int, C: int,
             return jax.jit(fn)
         return jax.jit(fn, donate_argnums=(2,))
 
-    return _cached(comm, ("chunk_scatter", w, k, C, init, local), build)
+    return _cached(comm, ("chunk_scatter", width, C, init, local), build)
 
 
 def push_pull_chunk_scatter(comm: CommContext, flat, buf, col_off: int,
-                            w: int, k: int, C: int, local=None):
-    """Dispatch one chunk-group: reduce-scatter ``k`` contiguous ``w``-column
-    slabs of ``flat`` (viewed as [R, n_ici, C]) starting at column
-    ``col_off`` into the block-sharded accumulator.  ``buf=None`` creates
-    the accumulator.  ``local`` as in :func:`_chunk_scatter_program`;
+                            width: int, C: int, local=None):
+    """Dispatch one column range: reduce-scatter the ``width`` columns of
+    ``flat`` (viewed as [R, n_ici, C]) that start at column ``col_off``
+    into the block-sharded accumulator.  ``buf=None`` creates the
+    accumulator.  ``local`` as in :func:`_chunk_scatter_program`;
     ``None`` infers replicated-local from a 1-D ``flat`` (callers using
     the sharded staging pass ``"sharded"`` explicitly — the two are both
     1-D).  Returns (buf, token)."""
@@ -702,8 +694,10 @@ def push_pull_chunk_scatter(comm: CommContext, flat, buf, col_off: int,
         _fault.fire("dcn")
     if local is None:
         local = flat.ndim == 1
-    fn = _chunk_scatter_program(comm, w, k, C, init=buf is None,
+    fn = _chunk_scatter_program(comm, width, C, init=buf is None,
                                 local=local)
+    if width == C:
+        return fn(flat)
     offa = _cached_scalar(comm, int(col_off), jnp.int32)
     if buf is None:
         return fn(flat, offa)

@@ -170,11 +170,18 @@ class Config:
     partition_bytes: int = PARTITION_BYTES_DEFAULT  # BYTEPS_PARTITION_BYTES
     scheduling_credit: int = 0       # BYTEPS_SCHEDULING_CREDIT; 0 = unlimited window
     enable_priority: bool = True     # priority ordering of chunk dispatch
-    group_size: int = 4              # BYTEPS_GROUP_SIZE: chunks per device
-    #                                  program (reference BYTEPS_NCCL_GROUP_SIZE
-    #                                  batching, nccl_manager.cc:130-134);
-    #                                  a count: 0 is read as 1, negative is
-    #                                  rejected
+    group_size: int = 4              # BYTEPS_GROUP_SIZE: tasks the dispatcher
+    #                                  pops at a time where they are not a
+    #                                  buffer-mode tensor's chunks: parts-mode
+    #                                  chunks merged ACROSS tensors into one
+    #                                  program, compressed chunks (reference
+    #                                  BYTEPS_NCCL_GROUP_SIZE batching,
+    #                                  nccl_manager.cc:130-134); a count: 0 is
+    #                                  read as 1, negative is rejected.  It no
+    #                                  longer caps a multi-chunk tensor's own
+    #                                  run: that is one program per bucket's
+    #                                  worth of queued columns, whatever this
+    #                                  says (core/engine.py _unit_layout)
     autotune: bool = True            # BYTEPS_AUTOTUNE: online chunk-size /
     #                                  credit-window planner
     #                                  (common/scheduler.py ChunkPlanner).

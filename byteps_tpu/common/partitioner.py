@@ -84,6 +84,25 @@ def bucket_bounds(sigs, nbytes, cap_bytes: int) -> List[Tuple[int, int]]:
     return runs
 
 
+def unit_bounds(chunk_nbytes, cap_bytes: int) -> List[Tuple[int, int]]:
+    """Carve a tensor's chunks into dispatch units: runs ``[(start,
+    stop)]`` of CONSECUTIVE chunks whose bytes sum to at most
+    ``cap_bytes``, greedy from chunk 0, a chunk over the cap (or any
+    chunk under ``cap_bytes <= 0``) a unit by itself.  A pure function
+    of the chunk sizes and the cap: the engine's dispatcher launches one
+    program per unit and the declare-time warm compiles one per unit,
+    both from this list."""
+    units, start, total = [], 0, 0
+    for i, nbytes in enumerate(chunk_nbytes):
+        if i > start and total + nbytes > cap_bytes:
+            units.append((start, i))
+            start, total = i, 0
+        total += nbytes
+    if len(chunk_nbytes) > start:
+        units.append((start, len(chunk_nbytes)))
+    return units
+
+
 def num_chunks(num_elems: int, itemsize: int, partition_bytes: int) -> int:
     return len(chunk_bounds(num_elems, itemsize, partition_bytes))
 

@@ -48,9 +48,21 @@ class ChunkScheduler:
 
     # -- producer side -----------------------------------------------------
     def add_task(self, task: ChunkTask) -> None:
+        self.add_tasks((task,))
+
+    def add_tasks(self, tasks) -> None:
+        """Hand over a list of tasks in ONE step: one hold of the lock,
+        one wake-up.  The queue then holds all of them or none, so a
+        consumer never sees a prefix of a tensor's chunks -- which is
+        what makes the runs the dispatcher forms from them
+        (:meth:`pop_while`) a property of the tensor and not of timing.
+        Order and credit accounting are those of adding them one by
+        one."""
         with self._cv:
-            heapq.heappush(self._heap, (task.sort_tuple(), self._seq, task))
-            self._seq += 1
+            for task in tasks:
+                heapq.heappush(self._heap,
+                               (task.sort_tuple(), self._seq, task))
+                self._seq += 1
             self._cv.notify()
 
     # -- consumer side -----------------------------------------------------
@@ -95,6 +107,24 @@ class ChunkScheduler:
             _, _, task = heapq.heappop(self._heap)
             self._in_flight += task.nbytes
             return task
+
+    def pop_while(self, more, limit: Optional[int] = None
+                  ) -> List[ChunkTask]:
+        """Pop the tasks that follow a popped one, in priority order and
+        in one hold of the lock: each for as long as the credit window
+        admits it (checked per task, as :meth:`get_task` checks it) and
+        ``more(task)`` says it belongs; at most ``limit`` of them.
+        Never blocks.  ``more`` sees only the head of the queue, so only
+        neighbours in priority order are ever taken together."""
+        out: List[ChunkTask] = []
+        with self._cv:
+            while ((limit is None or len(out) < limit)
+                   and self._eligible_locked()
+                   and more(self._heap[0][2])):
+                _, _, task = heapq.heappop(self._heap)
+                self._in_flight += task.nbytes
+                out.append(task)
+        return out
 
     def interrupt(self) -> None:
         """One-shot wakeup: the next (or currently blocked) get_task

@@ -205,10 +205,12 @@ class StepStats:
     # push (from where the call that made the step's first push began:
     # ``open_call``) and so holds the user's gradient program and apply too
     push_pull_ms: float = 0.0
-    # device programs launched / chunk tasks they consumed this step
-    # (the step's deltas of ``PushPullEngine.stats``)
+    # device programs launched / chunk tasks they consumed this step /
+    # the launches that carried a whole tensor (the step's deltas of
+    # ``PushPullEngine.stats``)
     dispatches: int = 0
     chunks: int = 0
+    whole_units: int = 0
     # ISSUE 24: of this step's ``pushes``, the bucket tensors (a run of
     # leaves pushed as one), and the leaves that rode them; the other
     # ``pushes - buckets`` tensors were leaves that went alone
@@ -249,10 +251,11 @@ class StepStatsTracker:
         self._stall_ms = 0.0
         self._push_pull_ms = 0.0
         self._wire = 0
-        # the engine's live {"dispatches", "chunks"} totals, read at
-        # each step boundary (None: a tracker with no engine behind it)
+        # the engine's live {"dispatches", "chunks", "whole_units"}
+        # totals, read at each step boundary (None: a tracker with no
+        # engine behind it)
         self._engine_stats = engine_stats
-        self._units0 = (0, 0)
+        self._units0 = (0, 0, 0)
         self._retx0 = counters.get("integrity.retransmit")
         self._history: Deque[StepStats] = collections.deque(maxlen=history)
         # step-attribution state (ISSUE 12): baseline of the process-wide
@@ -371,7 +374,8 @@ class StepStatsTracker:
         # docs/observability.md.
         now_tot = attribution.totals()
         es = self._engine_stats
-        units = (es["dispatches"], es["chunks"]) if es else (0, 0)
+        units = ((es["dispatches"], es["chunks"], es["whole_units"])
+                 if es else (0, 0, 0))
         attrib: Dict[str, float] = {}
         for k in set(now_tot) | set(self._attrib0):
             d = now_tot.get(k, 0.0) - self._attrib0.get(k, 0.0)
@@ -398,6 +402,7 @@ class StepStatsTracker:
             push_pull_ms=round(self._push_pull_ms, 3),
             dispatches=units[0] - self._units0[0],
             chunks=units[1] - self._units0[1],
+            whole_units=units[2] - self._units0[2],
             buckets=self._buckets,
             bucketed_leaves=self._bucketed_leaves,
         )
@@ -427,6 +432,7 @@ class StepStatsTracker:
         gauges.set("step.push_pull_ms", stats.push_pull_ms)
         gauges.set("step.dispatches", stats.dispatches)
         gauges.set("step.chunks", stats.chunks)
+        gauges.set("step.whole_units", stats.whole_units)
         gauges.set("step.buckets", stats.buckets)
         gauges.set("step.bucketed_leaves", stats.bucketed_leaves)
         for comp, ms in stats.attrib.items():

@@ -45,7 +45,7 @@ from ..compression import registry as compression_registry
 from ..common.config import Config
 from ..common.handles import Handle, HandleManager, TreeHandle
 from ..common.logging import get_logger
-from ..common.partitioner import bucket_bounds, chunk_bounds
+from ..common.partitioner import bucket_bounds, chunk_bounds, unit_bounds
 from ..common.registry import TensorRegistry
 from ..common.scheduler import ChunkPlanner, ChunkScheduler
 from ..common import flight_recorder as _flight
@@ -80,11 +80,63 @@ def _stale_epoch_error(task, epoch: int) -> StaleEpochError:
         f"epoch {epoch}; chunk dropped, re-push under the new epoch")
 
 
+def _unit_stop(task) -> int:
+    """The column at which the dispatch unit that starts at this chunk
+    ends (0: the chunk starts none, it lies inside one)."""
+    return task.pending.unit_stops.get(task.offset_elems, 0)
+
+
+def _unit_followers(head):
+    """Predicate for ``ChunkScheduler.pop_while``: the tasks that, one
+    after the other, continue the dispatch unit a buffer-mode ``head``
+    starts -- the next columns of the same push, short of the unit's
+    end."""
+    stop, pending = _unit_stop(head), head.pending
+    end = head.offset_elems + head.num_elems
+
+    def more(task):
+        nonlocal end
+        if (end >= stop or task.pending is not pending
+                or task.offset_elems != end):
+            return False
+        end += task.num_elems
+        return True
+    return more
+
+
+def _buffered(task) -> bool:
+    return task.pending is not None and task.pending.use_buffer
+
+
+def _pop_batch(scheduler, task, group_size: int):
+    """The popped ``task`` and what goes with it, taken in one hold of
+    the queue's lock.  Popping preserves priority order, and only
+    neighbours in that order are ever taken together.
+
+    A chunk of a buffer-mode tensor brings the rest of its dispatch
+    unit: the queued chunks that continue it, of any widths, up to the
+    unit's end (``PushPullEngine._unit_layout``), each gated by the
+    credit window as the first was.  Anything else brings up to
+    ``group_size`` tasks in all that are not buffer-mode chunks
+    (reference BYTEPS_NCCL_GROUP_SIZE, nccl_manager.cc:130-134):
+    parts-mode chunks, which ``_plan_batch`` merges across tensors where
+    their shapes agree, and compressed ones."""
+    if _buffered(task):
+        return [task] + scheduler.pop_while(_unit_followers(task))
+    if group_size <= 1:
+        return [task]
+    return [task] + scheduler.pop_while(lambda t: not _buffered(t),
+                                        group_size - 1)
+
+
 def _plan_batch(batch):
     """Group a popped priority-ordered task batch into dispatch units:
 
-    - ``("run", tasks)``: contiguous equal-width column slabs of ONE
-      buffer-mode tensor — one chunk-scatter program.
+    - ``("run", tasks)``: a contiguous column range of ONE buffer-mode
+      tensor, chunks of any widths, the tail included -- one
+      chunk-scatter program.  The range is one of the tensor's dispatch
+      units (``PushPullEngine._unit_layout``) or, where the batch holds
+      only part of one, each of its chunks by itself.
     - ``("group", tasks)``: consecutive uncompressed equal-shape chunks of
       DISTINCT tensors — one batched-collective program (the cross-tensor
       half of the reference's NCCL group batching).
@@ -99,17 +151,15 @@ def _plan_batch(batch):
     i = 0
     while i < len(batch):
         t = batch[i]
-        if t.pending is not None and t.pending.use_buffer:
-            run = [t]
-            j = i + 1
-            while (j < len(batch)
-                   and batch[j].pending is t.pending
-                   and batch[j].num_elems == t.num_elems
-                   and batch[j].offset_elems
-                   == run[-1].offset_elems + run[-1].num_elems):
-                run.append(batch[j])
+        if _buffered(t):
+            more, j = _unit_followers(t), i + 1
+            while j < len(batch) and more(batch[j]):
                 j += 1
-            units.append(("run", run))
+            run = batch[i:j]
+            if run[-1].offset_elems + run[-1].num_elems == _unit_stop(t):
+                units.append(("run", run))
+            else:
+                units.extend(("run", [c]) for c in run)
             i = j
             continue
         if t.compression is None:
@@ -117,8 +167,7 @@ def _plan_batch(batch):
             j = i + 1
             while (j < len(batch)
                    and batch[j].compression is None
-                   and not (batch[j].pending is not None
-                            and batch[j].pending.use_buffer)
+                   and not _buffered(batch[j])
                    and batch[j].data.shape == t.data.shape
                    and batch[j].data.dtype == t.data.dtype
                    and batch[j].scale == t.scale):
@@ -202,18 +251,21 @@ class _PendingTensor:
     - **parts** (single-chunk, compressed, or debug-sample tensors): each
       finished chunk is kept and concatenated at the end — the round-2
       design.
-    - **buffer** (uncompressed multi-chunk, the hot path): each chunk's
-      compiled program reduce-scatters its slice into a sharded accumulator
-      in place (donated between dispatches); one assemble program
+    - **buffer** (uncompressed multi-chunk, the hot path): each dispatch
+      unit's compiled program reduce-scatters its column range into a
+      sharded accumulator in place (donated between dispatches; a unit
+      that is the whole tensor makes it outright); one assemble program
       all-gathers, re-orders, scales and reshapes.  ``buf`` is only ever
       touched by the single dispatcher thread until the final callback
-      fires, after which it is immutable.
+      fires, after which it is immutable.  ``unit_stops`` maps the
+      column each dispatch unit starts at to the column it ends at
+      (``PushPullEngine._unit_layout``).
     """
 
     def __init__(self, handle: Handle, ctx: TensorContext, out_shape, op: str,
                  denom: int, use_buffer: bool = False, comm=None,
                  scale=None, shard_out: bool = False, slot=None,
-                 bucket: Optional[_Bucket] = None):
+                 bucket: Optional[_Bucket] = None, unit_stops=None):
         self.handle = handle
         self.ctx = ctx
         self.out_shape = out_shape
@@ -222,6 +274,7 @@ class _PendingTensor:
         self.parts: Dict[int, Any] = {}
         self.total = len(ctx.chunk_bounds)
         self.use_buffer = use_buffer
+        self.unit_stops: Dict[int, int] = unit_stops or {}
         self.buf = None          # dispatcher-owned until completion
         self.comm = comm
         self.scale = scale       # fused scale, applied by assemble
@@ -256,11 +309,15 @@ class _PendingTensor:
 
     def complete_part(self, part_idx: int, data) -> bool:
         with self.lock:
-            if self.use_buffer:
-                self._done += 1
-                return self._done == self.total
             self.parts[part_idx] = data
             return len(self.parts) == self.total
+
+    def complete_run(self, chunks: int) -> bool:
+        """Buffer mode: a dispatch unit of ``chunks`` chunks landed in
+        the accumulator."""
+        with self.lock:
+            self._done += chunks
+            return self._done == self.total
 
     def assemble(self):
         if self.use_buffer:
@@ -338,7 +395,8 @@ class PushPullEngine:
         # dispatch amortization accounting: programs launched vs chunk
         # tasks consumed (the bench's engine_grouped_* evidence; the
         # tracker publishes each step's deltas)
-        self.stats = {"dispatches": 0, "chunks": 0}
+        # whole_units: the dispatches that carried ONE whole tensor
+        self.stats = {"dispatches": 0, "chunks": 0, "whole_units": 0}
         self.step_stats = StepStatsTracker(engine_stats=self.stats)
         # Where each phase of the step (common/tracing.py ``phase``)
         # sends its milliseconds: the step's attribution component of
@@ -348,12 +406,17 @@ class PushPullEngine:
             for c in ("push_pull", "enqueue", "submit", "wait", "plan",
                       "dispatch", "compile", "sync", "assemble")}
         self._sync_q: "queue.Queue" = queue.Queue()
-        # Chunk tasks popped per dispatch iteration.  Multi-host stays at
-        # 1: merging is timing-dependent and SPMD processes must dispatch
-        # identical programs in identical order (the reference pins
-        # followers to the root's order via DO_* socket signals,
-        # communicator.h:43).
-        self._group_size = (1 if jax.process_count() > 1
+        # Tasks popped per dispatch iteration where they are not a
+        # buffer-mode tensor's chunks (_pop_batch).  Multi-host stays at
+        # 1, and at one chunk a buffer-mode unit: SPMD processes must
+        # dispatch identical programs in identical order (the reference
+        # pins followers to the root's order via DO_* socket signals,
+        # communicator.h:43), and merging what the queue held was
+        # timing-dependent.  Buffer-mode units no longer are, since a
+        # tensor's chunks enter the queue in one step
+        # (ChunkScheduler.add_tasks): the multi-process mesh could follow.
+        self._one_chunk_units = jax.process_count() > 1
+        self._group_size = (1 if self._one_chunk_units
                             else max(1, cfg.group_size))
         # Auto-tuned chunk/credit planner: measures completed push_pulls
         # and re-carves partition bounds per tensor-size bucket; inert
@@ -599,10 +662,13 @@ class PushPullEngine:
             # buffer mode? block-sharded output?  (_route_shape)
             use_buffer, shard_out = self._route(ctx, out_shape,
                                                 replicate_out)
-            pending = _PendingTensor(handle, ctx, out_shape, op, denom,
-                                     use_buffer, comm=self.comm, scale=scale,
-                                     shard_out=shard_out, slot=update_slot,
-                                     bucket=bucket)
+            pending = _PendingTensor(
+                handle, ctx, out_shape, op, denom, use_buffer,
+                comm=self.comm, scale=scale, shard_out=shard_out,
+                slot=update_slot, bucket=bucket,
+                unit_stops={off: off + w
+                            for off, w in self._unit_layout(ctx)}
+                if use_buffer else None)
             if self.tracer.active:
                 # windowed AND/OR sampled capture decided here; tctx is
                 # None for pushes that record nothing
@@ -687,6 +753,7 @@ class PushPullEngine:
                                 self.phase_feeds["submit"]) as ph_sub:
                 if ph_sub.ann is not None:
                     ph_sub.note(step=step, tensor=name)
+                tasks = []
                 for part_idx, (off, ln) in enumerate(bounds):
                     # uncompressed parts mode (debug-sample, odd shapes)
                     # needs the materialized chunk; buffer mode,
@@ -699,7 +766,7 @@ class PushPullEngine:
                                  else flat[:, off:off + ln])
                     else:
                         chunk = flat
-                    task = ChunkTask(
+                    tasks.append(ChunkTask(
                         name=name, key=ctx.key_list[part_idx],
                         priority=prio, version=version, offset_elems=off,
                         num_elems=ln,
@@ -712,10 +779,14 @@ class PushPullEngine:
                         pending=pending,
                         step=step, t_enqueue=t_enq,
                         trace_id=tctx.trace_id if tctx is not None else 0,
-                    )
-                    task.callback = self._make_chunk_callback(pending,
-                                                              part_idx)
-                    self.scheduler.add_task(task)
+                        # a buffer-mode unit retires in one pass
+                        # (_finish_batch), not chunk by chunk
+                        callback=None if use_buffer else
+                        self._make_chunk_callback(pending, part_idx),
+                    ))
+                # in ONE step: the dispatcher never sees half a tensor,
+                # so which units form does not depend on timing
+                self.scheduler.add_tasks(tasks)
             # Auto-release on completion: the manager tracks only outstanding
             # work, so direct handle.wait() users don't leak table entries.
             # The same hook closes the planner's measurement window and frees
@@ -811,10 +882,9 @@ class PushPullEngine:
         (``-declared_key``) follows flattening order across buckets and
         lone leaves.  A bucket compiles its programs at its first push
         (_warm_bucket), and a leaf that goes alone for its size or dtype
-        is declared here with its geometry
-        (declare_tensor: every chunk program the dispatcher can form) --
-        a 125 MB embedding's 31 chunks otherwise bring up new run widths
-        and offsets for as long as timing finds new ones."""
+        is declared here with its geometry (declare_tensor: one program
+        per dispatch unit), so the step after the plan compiles
+        nothing."""
         comm, cfg = self.comm, self.cfg
         R = comm.num_ranks
         per_leaf = self.planner.compress_active or cfg.debug_sample_tensor
@@ -882,11 +952,9 @@ class PushPullEngine:
                      use_buffer: bool, scale, op: str) -> None:
         """A bucket's first push declares it: compile its pack and
         unpack programs and, as declare_tensor does for a declared
-        tensor, every chunk program the dispatcher can form for it --
-        which run widths occur is a matter of timing, and a bucket's
-        dozen chunks would otherwise bring new ones up for many steps
-        (single process only: SPMD processes compile lazily, in
-        lockstep)."""
+        tensor, the program of each of its dispatch units -- for a
+        bucket, one (single process only: SPMD processes compile
+        lazily, in lockstep)."""
         bucket.warmed = True
         if jax.process_count() > 1:
             return
@@ -973,6 +1041,35 @@ class PushPullEngine:
                     ctx.scatter_layout = layout
         return use_buffer, shard_out
 
+    def _unit_layout(self, ctx: TensorContext) -> List[tuple]:
+        """The dispatch units of a buffer-mode tensor, as column ranges
+        ``[(col_off, width)]`` of its scatter layout: THE rule for what
+        the dispatcher launches as one program, asked by the push (the
+        pop takes a unit's chunks together: ``_PendingTensor.
+        unit_stops``) and by the declare-time warm (one program per
+        distinct unit), so the two cannot differ.
+
+        A unit is a run of consecutive chunks (``unit_bounds``) holding
+        at most what a bucket holds, ``BUCKET_CAP_PARTITIONS x
+        partition_bytes``, and no more than the credit window where one
+        is set: a unit larger than the window could only ever be popped
+        in part.  Where the window still cuts a pop short (bytes of
+        other tensors in flight), or two pushes of one tensor interleave
+        in the queue, what was popped of the unit is launched chunk by
+        chunk (``_plan_batch``): a cut always lands on a unit's or a
+        chunk's width, and under a window the warm compiles both.  A
+        mesh of several processes keeps one chunk a unit."""
+        col_layout, _ = ctx.scatter_layout
+        cap = 0
+        if not self._one_chunk_units:
+            cap = BUCKET_CAP_PARTITIONS * self.cfg.partition_bytes
+            cap = min(cap, self.scheduler.credit_bytes or cap)
+        itemsize = np.dtype(ctx.dtype_name).itemsize
+        return [(col_layout[a][0],
+                 sum(w for _, w in col_layout[a:b]))
+                for a, b in unit_bounds(
+                    [ln * itemsize for _, ln in ctx.chunk_bounds], cap)]
+
     def _sharded_staging_ok(self, col_layout, C: int) -> bool:
         """Sharded local staging is worth it only for SINGLE-run
         layouts (each dispatched run re-gathers the whole flat tensor
@@ -1058,8 +1155,8 @@ class PushPullEngine:
 
         ``bps.declare(name)`` only reserves the key; given shape/dtype the
         engine can additionally pre-lower and compile every program the
-        tensor's pushes will dispatch — chunk-scatter executables for each
-        reachable merge width (donated accumulator), the pad and assembly
+        tensor's pushes will dispatch — one chunk-scatter executable per
+        dispatch unit (_unit_layout), the pad and assembly
         programs, the single-chunk collective — and pre-stage the device
         scalars, so the first push_pull runs at steady-state speed and a
         declared stream compiles nothing afterwards.
@@ -1274,14 +1371,17 @@ class PushPullEngine:
             local_eff = local
             if local and self._sharded_staging_ok(col_layout, C):
                 local_eff = "sharded"
-            # run widths the dispatcher can form: up to the group cap
-            ks = set(range(1, self._group_size + 1))
+            # what the dispatcher can launch: the tensor's units and,
+            # where a credit window can cut a pop short, its chunks
+            units = self._unit_layout(ctx)
+            if self.scheduler.credit_bytes:
+                units = units + list(col_layout)
             return aot_warm_buffer_programs(
                 self.comm, col_layout=col_layout, C=C, n=ctx.num_elems,
                 out_shape=ctx.shape, dtype_name=ctx.dtype_name,
                 local=local_eff, scaled=scaled, denom=denom,
                 shard_out=shard_out,
-                scale_value=scale_value, merge_widths=ks,
+                scale_value=scale_value, units=units,
                 assembled=assembled)
         if nchunks == 1:
             return aot_warm_single_program(
@@ -1344,13 +1444,25 @@ class PushPullEngine:
         def cb(data, status: Status):
             if status.code.name != "OK":
                 pending.handle.set_result(None, status)
-                return
-            if pending.complete_part(part_idx, data):
-                try:
-                    pending.handle.set_result(pending.assemble(), Status.ok())
-                except Exception as e:  # noqa: BLE001
-                    pending.handle.set_result(None, Status.error(str(e)))
+            elif pending.complete_part(part_idx, data):
+                self._resolve(pending)
         return cb
+
+    def _complete_run(self, pending: _PendingTensor, chunks: int,
+                      err) -> None:
+        """A buffer-mode dispatch unit of ``chunks`` chunks retired."""
+        if err is not None:
+            pending.handle.set_result(None, self._chunk_status(err))
+        elif pending.complete_run(chunks):
+            self._resolve(pending)
+
+    @staticmethod
+    def _resolve(pending: _PendingTensor) -> None:
+        """Every chunk of the push has landed: assemble, resolve."""
+        try:
+            pending.handle.set_result(pending.assemble(), Status.ok())
+        except Exception as e:  # noqa: BLE001
+            pending.handle.set_result(None, Status.error(str(e)))
 
     def _debug_sample(self, task, out) -> None:
         """Stage-wise tensor sampling (reference BYTEPS_DEBUG_SAMPLE_TENSOR,
@@ -1379,14 +1491,15 @@ class PushPullEngine:
 
     def pause_dispatch(self, timeout: float = 10.0):
         """Hold the dispatcher: tasks enqueue but nothing pops until
-        :meth:`resume_dispatch`.  Used where the drain/merge width must
-        be deterministic (the multichip dry-run's amortization assertion,
-        tests) — merge width is otherwise a race between enqueue and
-        dispatch.  Event handshake, not a timed sleep: the gate is
-        cleared, a blocked pop is interrupted (one-shot scheduler
-        wakeup), and this call returns only once the dispatcher has
-        parked — any pop already in flight finishes its dispatch first,
-        so after return nothing pops until resume."""
+        :meth:`resume_dispatch`.  Used where what a pop finds must be
+        deterministic across TENSORS (the cross-tensor merge of
+        parts-mode chunks, priority order between pushes: tests, the
+        multichip dry-run) — one tensor's own units never were a race
+        since its chunks enter the queue together.  Event handshake, not
+        a timed sleep: the gate is cleared, a blocked pop is interrupted
+        (one-shot scheduler wakeup), and this call returns only once the
+        dispatcher has parked — any pop already in flight finishes its
+        dispatch first, so after return nothing pops until resume."""
         self._dispatch_enabled.clear()
         self.scheduler.interrupt()
         if not self._parked.wait(timeout=timeout) and self._running:
@@ -1468,19 +1581,7 @@ class PushPullEngine:
         if _fault.ENABLED:
             # chaos site "dispatch": delay/straggler stalls issue order
             _fault.fire("dispatch")
-        # Chunk-group batching (reference BYTEPS_NCCL_GROUP_SIZE,
-        # nccl_manager.cc:130-134): opportunistically pop whatever else
-        # is already eligible, then merge neighbors into the fewest
-        # device programs (_plan_batch).  Popping preserves priority
-        # order; merging only ever joins neighbors in that order.
-        # group_size caps the pop count (the credit window, when set,
-        # additionally gates each pop inside get_task).
-        batch = [task]
-        while len(batch) < self._group_size:
-            t2 = self.scheduler.get_task(block=False)
-            if t2 is None:
-                break
-            batch.append(t2)
+        batch = _pop_batch(self.scheduler, task, self._group_size)
         # Membership-epoch guard: chunks enqueued before a world
         # change (elastic shrink/rejoin, fault/membership.py) must
         # not be issued into a mesh that no longer exists — they are
@@ -1515,18 +1616,22 @@ class PushPullEngine:
     def _dispatch_buffer_run(self, run: List[ChunkTask], now: float):
         """One device program for a contiguous run of column-slab chunks:
         slice -> reduce-scatter -> write shards into the tensor's
-        block-sharded accumulator (donated, in place)."""
+        block-sharded accumulator (donated, in place); a run that is the
+        whole tensor reduce-scatters it into the accumulator outright."""
         t0 = run[0]
         pending = t0.pending
         for t in run:
             t.t_dispatch = now
         self.stats["dispatches"] += 1
         self.stats["chunks"] += len(run)
+        self.stats["whole_units"] += len(run) == pending.total
         try:
             _, C = pending.scatter_layout_snap
+            width = (run[-1].offset_elems + run[-1].num_elems
+                     - t0.offset_elems)
             buf, token = push_pull_chunk_scatter(
                 self.comm, t0.data, pending.buf, t0.offset_elems,
-                t0.num_elems, len(run), C, local=pending.local_mode)
+                width, C, local=pending.local_mode)
             pending.buf = buf
             self._sync_q.put((run, token, None, None,
                               time.monotonic()))
@@ -1562,6 +1667,7 @@ class PushPullEngine:
         task.t_dispatch = now
         self.stats["dispatches"] += 1
         self.stats["chunks"] += 1
+        self.stats["whole_units"] += task.total_parts == 1
         try:
             slot = task.compression
             rollback = None
@@ -1765,7 +1871,15 @@ class PushPullEngine:
                                    exc_info=True)
 
     def _finish_batch(self, tasks, out, err):
+        """Retire one dispatch unit.  A buffer run -- chunks of ONE push
+        behind one token -- retires in one pass: its chunks' wire bytes
+        are summed and counted once, and the push hears of all of them
+        at once (``_complete_run``); the other kinds call back per
+        task."""
         ep = _membership.current_epoch()
+        run = _buffered(tasks[0])
+        telemetry = self.cfg.telemetry_on
+        wire_sum = pull_sum = param_sum = 0
         for idx, task in enumerate(tasks):
             # parts-group dispatches carry one output PER task
             out_t = out[idx] if isinstance(out, list) else out
@@ -1777,8 +1891,7 @@ class PushPullEngine:
                 # — drop it (credits still return below)
                 counters.inc("membership.stale_chunks_dropped")
                 err_t = _stale_epoch_error(task, ep)
-            if err_t is None and not (task.pending is not None
-                                      and task.pending.use_buffer):
+            if err_t is None and not run:
                 self._debug_sample(task, out_t)
             # credits for this task were returned in the sync loop's bulk
             # report_finish — nothing per-chunk here
@@ -1813,7 +1926,7 @@ class PushPullEngine:
                     if p.trace_left == 0:
                         self.tracer.flow(task.trace_id, "f", task.name,
                                          t_done)
-            if self.cfg.telemetry_on:
+            if telemetry:
                 # push + pull wire bytes; compressed chunks report
                 # payload size, which is the point of the feature.
                 # Under a sharded-update slot the pull leg ships only
@@ -1821,22 +1934,21 @@ class PushPullEngine:
                 # the halved-wire claim, measured per leg so /metrics
                 # and bps_top can assert it (wire_bytes{leg=}: labeled
                 # series beside the KV store's unlabeled total, which
-                # stays the async-PS figure)
+                # stays the async-PS figure).  Summed here, counted once
+                # a unit below.
                 wire = (task.compression.worker.payload_nbytes()
                         if task.compression is not None else task.nbytes)
                 p = task.pending
                 slot = p.slot if p is not None else None
                 pull = (slot.pull_share(task.nbytes, p.use_buffer)
                         if slot is not None else wire)
-                self.speed.record(wire + pull)
-                counters.inc("wire_bytes", wire, leg="push")
-                counters.inc("wire_bytes", pull, leg="pull")
-                self.step_stats.add_wire(wire + pull)
+                wire_sum += wire
+                pull_sum += pull
                 if (slot is not None and slot.codec is not None
                         and err_t is None and p.use_buffer):
                     # quantized parameter leg: reported separately from
                     # the gradient ladder's compression.wire_bytes
-                    counters.inc("compression.param_wire_bytes", pull)
+                    param_sum += pull
                 if task.compression is not None and err_t is None:
                     # quantized-wire accounting (ISSUE 11): what the
                     # reduce leg actually shipped, and the raw bytes it
@@ -1847,18 +1959,30 @@ class PushPullEngine:
                                  max(0, task.nbytes - wire))
                     counters.inc("compression.compressed_chunks")
             if task.callback is not None:
-                if err_t is not None:
-                    # stale-epoch drops carry ABORTED (a recognizable,
-                    # retryable outcome); real failures stay errors
-                    task.callback(None,
-                                  Status(StatusCode.ABORTED, str(err_t))
-                                  if isinstance(err_t, StaleEpochError)
-                                  else Status.error(str(err_t)))
-                else:
-                    # Average is applied at assembly granularity: the
-                    # reference divides in the done-callback too
-                    # (torch/__init__.py task callback output.div_(size)).
-                    task.callback(out_t, Status.ok())
+                task.callback(None if err_t is not None else out_t,
+                              self._chunk_status(err_t))
+        if telemetry:
+            self.speed.record(wire_sum + pull_sum)
+            counters.inc("wire_bytes", wire_sum, leg="push")
+            counters.inc("wire_bytes", pull_sum, leg="pull")
+            self.step_stats.add_wire(wire_sum + pull_sum)
+            if param_sum:
+                counters.inc("compression.param_wire_bytes", param_sum)
+        if run:
+            # one pending, so one epoch: the last chunk's verdict is
+            # every chunk's
+            self._complete_run(tasks[0].pending, len(tasks), err_t)
+
+    @staticmethod
+    def _chunk_status(err) -> Status:
+        """What a chunk's owner hears: stale-epoch drops carry ABORTED
+        (a recognizable, retryable outcome); real failures stay
+        errors."""
+        if err is None:
+            return Status.ok()
+        if isinstance(err, StaleEpochError):
+            return Status(StatusCode.ABORTED, str(err))
+        return Status.error(str(err))
 
     # ---------------------------------------------------------- lifecycle
     def shutdown(self, wait: bool = True):

@@ -213,6 +213,95 @@ def test_blocking_get_wakes_on_add():
     assert not t.is_alive() and got[0].name == "late"
 
 
+def test_add_tasks_is_add_task_for_every_task(monkeypatch):
+    """The list hand-over gives the order and the credit accounting of
+    adding the same tasks one by one -- with one wake-up, not one each."""
+    arrivals = [(30, -3), (10, -1), (21, -2), (20, -2), (11, -1), (5, -3)]
+    one, many = ChunkScheduler(credit_bytes=250), ChunkScheduler(
+        credit_bytes=250)
+    wakes = []
+    monkeypatch.setattr(many._cv, "notify",
+                        lambda n=1: wakes.append(n), raising=False)
+    for key, priority in arrivals:
+        one.add_task(_task("t", key=key, priority=priority))
+    many.add_tasks([_task("t", key=key, priority=priority)
+                    for key, priority in arrivals])
+    assert wakes == [1]
+    assert many.pending == one.pending == len(arrivals)
+    got = []
+    for s in (one, many):
+        keys = []
+        while s.pending:
+            t = s.get_task()
+            if t is None:               # window full: two tasks are out
+                assert s.bytes_in_flight == 200
+                s.report_finish(200)
+                continue
+            keys.append((t.key, s.bytes_in_flight))
+        got.append(keys)
+    assert got[0] == got[1]
+    assert [k for k, _ in got[1]] == [10, 11, 20, 21, 5, 30]
+    many.add_tasks([])                  # an empty list adds nothing
+    assert many.pending == 0
+
+
+def test_add_tasks_wakes_a_blocked_consumer_once():
+    s = ChunkScheduler()
+    got = []
+    t = threading.Thread(
+        target=lambda: got.append(s.get_task(block=True, timeout=5.0)))
+    t.start()
+    s.add_tasks([_task("late", k, 0, nbytes=8) for k in (2, 1, 3)])
+    t.join(timeout=10)
+    assert not t.is_alive() and got[0].key == 1 and s.pending == 2
+
+
+def test_a_concurrent_consumer_never_sees_a_prefix_of_the_list():
+    """While lists of 13 are handed over, whoever holds the queue's lock
+    finds a multiple of 13 in it: all of a tensor's chunks or none."""
+    s = ChunkScheduler()
+    n, rounds = 13, 200
+    seen, stop = [], threading.Event()
+
+    def consumer():
+        popped = 0
+        while popped < n * rounds and not stop.is_set():
+            head = s.get_task(block=True, timeout=5.0)
+            if head is None:
+                continue
+            # the head and every follower, in one hold of the lock
+            rest = s.pop_while(lambda t: t.name == head.name)
+            with s._cv:
+                seen.append((1 + len(rest), len(s._heap)))
+            popped += 1 + len(rest)
+
+    t = threading.Thread(target=consumer)
+    t.start()
+    for r in range(rounds):
+        s.add_tasks([_task(f"t{r}", make_key(r, i), -r, nbytes=8)
+                     for i in range(n)])
+    t.join(timeout=30)
+    stop.set()
+    assert not t.is_alive()
+    assert sum(took for took, _ in seen) == n * rounds
+    assert all(took == n and left % n == 0 for took, left in seen), seen[:5]
+
+
+def test_pop_while_takes_neighbours_under_the_window():
+    s = ChunkScheduler(credit_bytes=350)
+    s.add_tasks([_task("a", k, 0) for k in range(5)] + [_task("b", 9, 0)])
+    head = s.get_task()
+    # the window admits three 100-byte tasks in all
+    assert [t.key for t in s.pop_while(lambda t: t.name == "a")] == [1, 2]
+    assert s.bytes_in_flight == 300
+    s.report_finish(300)
+    # a limit, then a task that does not belong: it stays the head
+    assert [t.key for t in s.pop_while(lambda t: t.name == "a", 1)] == [3]
+    assert [t.key for t in s.pop_while(lambda t: t.name == "a")] == [4]
+    assert s.pop_while(lambda t: t.name == "a") == []
+    assert head.key == 0 and s.get_task().name == "b"
+
+
 def test_drain_returns_remaining():
     s = ChunkScheduler()
     for i in range(5):

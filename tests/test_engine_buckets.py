@@ -404,11 +404,100 @@ def test_epoch_change_aborts_every_leaf_of_a_bucket():
         bps.shutdown()
 
 
+def _unit_tree(ranks, dtype=np.float32, seed=11):
+    """A bucket of 13 chunks (four distinct leaves, 12.6 partitions) and
+    a leaf of 31 (30.5 partitions: over the cap, so it goes alone and
+    makes two dispatch units, 16 + 15 chunks)."""
+    rng = np.random.RandomState(seed)
+    per = PART // np.dtype(jnp.dtype(dtype)).itemsize   # elements a chunk
+    return {"a": _stack(rng, ranks, (3, per), dtype),
+            "b": _stack(rng, ranks, (4, per), dtype),
+            "c": _stack(rng, ranks, (5, per), dtype),
+            "d": _stack(rng, ranks, (10, 1000), dtype),
+            "e_big": _stack(rng, ranks, (61, per // 2), dtype)}
+
+
+def test_a_bucket_is_one_dispatch_and_a_31_chunk_tensor_two():
+    eng = _init(8)
+    try:
+        tree = _unit_tree(8)
+        for _ in range(2):
+            push_pull(tree, "g")
+        step = _last_step(eng)
+        assert (step.pushes, step.buckets, step.bucketed_leaves) == (2, 1, 4)
+        assert (step.chunks, step.dispatches, step.whole_units) == (
+            13 + 31, 1 + 2, 1), step
+        assert bps.metrics_snapshot()["step"]["whole_units"] == 1
+        widths = sorted(k[1] for k in eng.comm.jit_cache
+                        if k[0] == "chunk_scatter")
+        per = PART // 4 // 8                    # columns a chunk, 8 ranks
+        # the bucket's whole row, and the leaf's 16 and 15 chunks (the
+        # tail chunk is half a partition)
+        assert widths == [12 * per + 10_000 // 8, 14 * per + per // 2,
+                          16 * per], widths
+    finally:
+        bps.shutdown()
+
+
+def _chunked_engine(ranks, **cfg):
+    """The parent's dispatch, through the programs it had: one chunk a
+    unit -- and one chunk in flight.  Dozens of collective programs
+    queued at once can starve XLA:CPU's thread pool (one thread a
+    virtual device: a later program's device takes the thread an earlier
+    one's still needs, and the rendezvous aborts the process after 40
+    s; seen on the DCN mesh, on the parent too at group_size 1)."""
+    eng = _init(ranks, scheduling_credit=PART, **cfg)
+    eng._one_chunk_units = True
+    return eng
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16, "int32"])
+@pytest.mark.parametrize("mesh", ["ranks1", "ranks2", "ranks4", "ranks8",
+                                  "dcn2x4", "ranks6_parts_mode"])
+def test_whole_range_units_equal_the_chunked_path_bit_for_bit(mesh, dtype):
+    """A unit's program reduces every value over the same ranks in the
+    same order as the chunk programs it replaces: normal data (sums that
+    round), a bucket and a two-unit leaf, on every mesh these files
+    build.  (The int leaves go alone: three more multi-unit tensors;
+    six ranks take parts mode, which the rule leaves alone.)"""
+    ranks = int(mesh[5]) if mesh.startswith("ranks") else 8
+    cfg = {"dcn_size": 2} if mesh == "dcn2x4" else {}
+    rng = np.random.RandomState(17)
+    if dtype == "int32":
+        tree = {k: jnp.asarray(rng.randint(-1000, 1000, v.shape), jnp.int32)
+                for k, v in _unit_tree(ranks).items()}
+    else:
+        tree = jax.tree.map(
+            lambda v: jnp.asarray(rng.standard_normal(v.shape),
+                                  jnp.float32).astype(dtype),
+            _unit_tree(ranks))
+    got = {}
+    for how in ("chunked", "units"):
+        eng = (_chunked_engine if how == "chunked" else _init)(ranks, **cfg)
+        try:
+            out = push_pull(tree, "g")
+            got[how] = (jax.tree.map(np.asarray, out),
+                        _last_step(eng))
+        finally:
+            bps.shutdown()
+    for k in tree:
+        assert got["units"][0][k].dtype == got["chunked"][0][k].dtype
+        np.testing.assert_array_equal(
+            got["units"][0][k].view(np.uint8),
+            got["chunked"][0][k].view(np.uint8), err_msg=k)
+    chunked, units = got["chunked"][1], got["units"][1]
+    assert chunked.chunks == units.chunks
+    if mesh != "ranks6_parts_mode":
+        assert chunked.dispatches == chunked.chunks
+        assert 4 * units.dispatches < chunked.dispatches
+        assert units.whole_units > chunked.whole_units
+
+
 def test_second_step_compiles_nothing():
-    """Pack and unpack are compiled when a bucket is first pushed; with
-    one chunk per unit (no timing-dependent run widths) every later step
-    finds every program in the cache."""
-    eng = _init(8, group_size=1)
+    """Pack, unpack and one program a dispatch unit are compiled when a
+    bucket is first pushed (a leaf that goes alone: when the plan is
+    made); every later step finds every program in the cache."""
+    eng = _init(8)
     try:
         tree = _model_tree(bps.size())
         push_pull(tree, "g")
@@ -422,6 +511,26 @@ def test_second_step_compiles_nothing():
         assert len(eng.comm.jit_cache) == programs
     finally:
         bps.shutdown()
+
+
+def test_the_step_after_the_plan_compiles_nothing_whatever_the_timing():
+    """Twenty fresh engines: the first step declares the plan and warms
+    exactly the programs the unit rule can form, so the second step --
+    dispatcher, syncer and caller racing as they will -- adds none."""
+    tree = _unit_tree(8)
+    for attempt in range(20):
+        eng = _init(8)
+        try:
+            push_pull(tree, "g")
+            misses = counters.get("engine.compile_cache_miss")
+            programs = set(eng.comm.jit_cache)
+            push_pull(tree, "g")
+            assert set(eng.comm.jit_cache) == programs, attempt
+            assert counters.get("engine.compile_cache_miss") == misses
+            step = _last_step(eng)
+            assert (step.dispatches, step.whole_units) == (3, 1), attempt
+        finally:
+            bps.shutdown()
 
 
 def test_identical_buckets_share_their_programs():

@@ -371,6 +371,7 @@ def test_fused_step_leaves_step_stats_untouched():
         assert eng.step_stats.current_step == 0
         assert eng.step_stats.flush() is None
         assert eng.step_stats.history() == []
-        assert eng.stats == {"dispatches": 0, "chunks": 0}
+        assert eng.stats == {"dispatches": 0, "chunks": 0,
+                             "whole_units": 0}
     finally:
         bps.shutdown()
