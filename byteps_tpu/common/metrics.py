@@ -128,6 +128,17 @@ class MetricsRegistry:
             buckets[b] = buckets.get(b, 0) + n
             self._hist_sum[key] = self._hist_sum.get(key, 0.0) + value * n
 
+    def observe_many(self, name: str, values,
+                     labels: Optional[Dict[str, object]] = None) -> None:
+        """:meth:`observe` each of ``values`` under one lock."""
+        key = (name, _labels_of(labels))
+        with self._lock:
+            buckets = self._hist.setdefault(key, {})
+            for value in values:
+                b = pow2_bucket(value)
+                buckets[b] = buckets.get(b, 0) + 1
+            self._hist_sum[key] = self._hist_sum.get(key, 0.0) + sum(values)
+
     # -- reads -------------------------------------------------------------
 
     def get_counter(self, name: str,
@@ -291,6 +302,9 @@ class Histograms:
 
     def observe(self, name: str, value: float, n: int = 1, **labels) -> None:
         self._r.observe(name, value, n, labels or None)
+
+    def observe_many(self, name: str, values, **labels) -> None:
+        self._r.observe_many(name, values, labels or None)
 
     def snapshot(self) -> Dict[str, Dict[int, int]]:
         return self._r.snapshot()["histograms"]

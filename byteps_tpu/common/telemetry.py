@@ -60,7 +60,29 @@ ATTRIB_GAUGE_NAMES = {
     "plan": "step.attrib_plan_ms",
     "dispatch": "step.attrib_dispatch_ms",
     "assemble": "step.attrib_assemble_ms",
+    "tx_update": "step.attrib_tx_update_ms",
     "other": "step.attrib_other_ms",
+}
+
+# ISSUE 33: the CPU milliseconds inside each component a
+# ``tracing.phase`` feeds its second clock to (``StepStats.attrib_cpu``),
+# and each thread's whole CPU over the step (``StepStats.thread_cpu``) —
+# same pattern, one literal a name.
+ATTRIB_CPU_GAUGE_NAMES = {
+    "enqueue": "step.attrib_cpu_enqueue_ms",
+    "submit": "step.attrib_cpu_submit_ms",
+    "wait": "step.attrib_cpu_wait_ms",
+    "plan": "step.attrib_cpu_plan_ms",
+    "dispatch": "step.attrib_cpu_dispatch_ms",
+    "compile": "step.attrib_cpu_compile_ms",
+    "sync": "step.attrib_cpu_sync_ms",
+    "assemble": "step.attrib_cpu_assemble_ms",
+    "tx_update": "step.attrib_cpu_tx_update_ms",
+}
+THREAD_CPU_GAUGE_NAMES = {
+    "caller": "step.thread_cpu_caller_ms",
+    "dispatcher": "step.thread_cpu_dispatcher_ms",
+    "syncer": "step.thread_cpu_syncer_ms",
 }
 
 
@@ -216,6 +238,35 @@ class StepStats:
     # ``pushes - buckets`` tensors were leaves that went alone
     buckets: int = 0
     bucketed_leaves: int = 0
+    # ISSUE 33: the second clock.  ``attrib_cpu[c]``: of ``attrib[c]``,
+    # the milliseconds the phase's thread was RUNNING (its CPU clock,
+    # from the same enter/exit pair) — a key for every component whose
+    # phases READ that clock this step: ``wait`` and ``tx_update``
+    # always (once a step), the per-tensor and per-unit ones
+    # (``enqueue``, ``submit``, ``plan``, ``dispatch``, ``compile``,
+    # ``sync``, ``assemble``) only in a step under a profiler session
+    # (``tracing.phase`` has why); never ``queue``, ``credit``, ``wire``,
+    # ``merge``, ``other``.  ``attrib[c] - attrib_cpu[c]`` of a working
+    # phase is time spent waiting: for the interpreter lock, or inside
+    # a runtime call that released it.
+    attrib_cpu: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # of ``push_pull_ms``, the caller's CPU milliseconds (the
+    # ``bps.push_pull`` phase's second clock): its enqueue + submit work
+    # and what its wait burns
+    push_pull_cpu_ms: float = 0.0
+    # each thread's WHOLE CPU milliseconds over the step, in or out of a
+    # span, read at the step's two boundaries: ``caller`` (the thread
+    # that finalizes the step — where several threads push, whichever
+    # made the next step's first push; left out where that was another
+    # thread than the one that began this step), ``dispatcher``,
+    # ``syncer``.  A key is left out, never 0, where the platform has no
+    # per-thread CPU clock or the thread had not registered / has gone.
+    thread_cpu: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # wall inside ``DistributedOptimizer.update()`` (span
+    # ``bps.adapter.update``), summed over the step's calls: holds
+    # ``push_pull_ms`` and ``attrib["tx_update"]``; the rest is the
+    # adapter's own (flatten, leaf names, unflatten, its lock)
+    update_ms: float = 0.0
 
     def as_dict(self) -> Dict[str, float]:
         return dataclasses.asdict(self)
@@ -265,6 +316,20 @@ class StepStatsTracker:
         self._comp: Dict[str, float] = {}
         self._last_retired: Optional[str] = None
         self._pub_attrib: set = set()   # gauge keys published last step
+        # the second clock (ISSUE 33): CPU ms inside each fed component;
+        # wall of the adapter's update(); the CPU clocks of the engine's
+        # threads by role (register_thread) and every thread's reading
+        # at the step's start, ``role -> (whose clock, seconds)``; the
+        # caller's reading where its open tree-level call began; the
+        # step's unit latencies, for the histogram
+        self._comp_cpu: Dict[str, float] = {}
+        self._push_pull_cpu_ms = 0.0
+        self._update_ms = 0.0
+        self._thread_clocks: Dict[str, int] = {}
+        self._cpu0: Dict[str, Tuple[int, float]] = {}
+        self._call_c0: Optional[Tuple[int, float]] = None
+        self._pub_attrib_cpu: set = set()
+        self._unit_sync_ms: List[float] = []
         if recorder is None:
             from . import flight_recorder as _flight
             recorder = _flight.recorder
@@ -280,6 +345,7 @@ class StepStatsTracker:
             self._counts[name] = self._counts.get(name, 0) + 1
             step = self._counts[name]
             call_t0, self._call_t0 = self._call_t0, None
+            call_c0, self._call_c0 = self._call_c0, None
             if step > self._step:
                 # the step's wall starts where the call that makes its
                 # first push began, so the call's own span
@@ -288,13 +354,18 @@ class StepStatsTracker:
                 t0 = time.monotonic()
                 if call_t0 is not None and call_t0 >= self._t0:
                     t0 = call_t0
+                # the threads' CPU clocks, read once a boundary: the
+                # step's CPU runs as its wall does, from where the call
+                # began
+                cpu = self._cpu_now(call_c0)
                 if self._step > 0 and self._pushes:
                     # published under the lock: two concurrent pushers
                     # finalizing steps N and N+1 must land their gauge
                     # writes and flight events in step order (the gauge
                     # and recorder locks never take this one, so there
                     # is no ordering cycle to invert)
-                    self._publish(self._finalize_locked(end=t0))
+                    self._publish(self._finalize_locked(end=t0, cpu=cpu))
+                self._cpu0 = cpu
                 self._step = step
                 self._t0 = t0
                 # flight-recorder stamp: every recorded event from here
@@ -311,17 +382,55 @@ class StepStatsTracker:
         """Caller feed: a tree-level push_pull begins now, before its
         ``bps.push_pull`` span opens.  If its first push starts a step,
         the step's wall starts here."""
+        c0 = (threading.get_ident(), time.thread_time())
         with self._lock:
             self._call_t0 = time.monotonic()
+            self._call_c0 = c0
 
-    def add_stall(self, ms: float) -> None:
+    def register_thread(self, role: str) -> None:
+        """An engine thread (``dispatcher``, ``syncer``) names itself as
+        it starts: its CPU clock is read at every step boundary from
+        then on (``StepStats.thread_cpu``).  Nothing where the platform
+        cannot name a thread's clock."""
+        try:
+            clock = time.pthread_getcpuclockid(threading.get_ident())
+        except (AttributeError, OSError):
+            return
+        with self._lock:
+            self._thread_clocks[role] = clock
+
+    def _cpu_now(self, caller: Optional[Tuple[int, float]] = None
+                 ) -> Dict[str, Tuple[int, float]]:
+        """``role -> (whose clock, CPU seconds)`` now; the caller is the
+        calling thread unless a reading of it is handed in."""
+        now = {"caller": caller or (threading.get_ident(),
+                                   time.thread_time())}
+        for role, clock in self._thread_clocks.items():
+            try:
+                now[role] = (clock, time.clock_gettime(clock))
+            except OSError:   # the thread has gone (shutdown's flush)
+                pass
+        return now
+
+    def add_stall(self, ms: float, cpu_ms: Optional[float] = None) -> None:
         with self._lock:
             self._stall_ms += ms
+            if cpu_ms is not None:
+                self._comp_cpu["sync"] = (
+                    self._comp_cpu.get("sync", 0.0) + cpu_ms)
 
-    def add_push_pull(self, ms: float) -> None:
-        """Caller feed: wall of one whole tree-level push_pull."""
+    def add_push_pull(self, ms: float,
+                      cpu_ms: Optional[float] = None) -> None:
+        """Caller feed: wall (and the caller's CPU) of one whole
+        tree-level push_pull."""
         with self._lock:
             self._push_pull_ms += ms
+            self._push_pull_cpu_ms += cpu_ms or 0.0
+
+    def add_update(self, ms: float, cpu_ms: Optional[float] = None) -> None:
+        """Caller feed: wall of one whole ``DistributedOptimizer.update``."""
+        with self._lock:
+            self._update_ms += ms
 
     def add_wire(self, nbytes: int) -> None:
         """Syncer feed: wire bytes (push + pull legs) of each retired
@@ -329,41 +438,70 @@ class StepStatsTracker:
         with self._lock:
             self._wire += int(nbytes)
 
-    def add_component(self, component: str, ms: float) -> None:
-        """Engine-local attribution feed (e.g. ``queue`` — scheduler
-        wait of each retired unit's head chunk)."""
+    def add_component(self, component: str, ms: float,
+                      cpu_ms: Optional[float] = None) -> None:
+        """A phase's feed: the component's wall milliseconds and, where
+        the phase read the thread's clock, the CPU milliseconds inside
+        them."""
         with self._lock:
             self._comp[component] = self._comp.get(component, 0.0) + ms
+            if cpu_ms is not None:
+                self._comp_cpu[component] = (
+                    self._comp_cpu.get(component, 0.0) + cpu_ms)
 
-    def feed(self, component: str) -> Callable[[float], None]:
-        """Where a ``tracing.phase`` of this name sends its
+    def feed(self, component: str
+             ) -> Callable[[float, Optional[float]], None]:
+        """Where a ``tracing.phase`` of this name sends its wall and CPU
         milliseconds: ``add_component`` bound to the component, but
-        :meth:`add_stall` for ``sync`` (which is ``sync_stall_ms`` too)
-        and :meth:`add_push_pull` for ``push_pull`` (a field of its own,
-        not a component)."""
+        :meth:`add_stall` for ``sync`` (which is ``sync_stall_ms`` too),
+        and :meth:`add_push_pull` / :meth:`add_update` for ``push_pull``
+        / ``update`` (fields of their own, not components)."""
         if component == "sync":
             return self.add_stall
         if component == "push_pull":
             return self.add_push_pull
+        if component == "update":
+            return self.add_update
         return functools.partial(self.add_component, component)
 
-    def note_retire(self, name: str) -> None:
-        """The syncer names each retired unit's tensor; the last one
-        standing when the step finalizes is the lagging tensor."""
+    def retire_unit(self, name: str, queue_ms: Optional[float] = None,
+                    sync_ms: Optional[float] = None) -> None:
+        """The syncer's one call a retired unit, under one lock: the
+        unit's tensor (the last one standing when the step finalizes is
+        the lagging tensor), its head chunk's wait in the priority queue
+        (the ``queue`` component) and its dispatch -> retire latency
+        (the ``engine.unit_sync_ms`` histogram, observed at the step's
+        boundary with the step's other units)."""
         with self._lock:
             self._last_retired = name
+            if queue_ms is not None:
+                self._comp["queue"] = self._comp.get("queue", 0.0) + queue_ms
+            if sync_ms is not None:
+                self._unit_sync_ms.append(sync_ms)
 
     # -- finalization ------------------------------------------------------
 
-    def _finalize_locked(self, end: Optional[float] = None) -> StepStats:
+    def _finalize_locked(self, end: Optional[float] = None,
+                         cpu: Optional[Dict[str, Tuple[int, float]]] = None
+                         ) -> StepStats:
         if end is None:
             end = time.monotonic()
+        if cpu is None:
+            cpu = self._cpu_now()
+        # a thread's CPU over the step: its clock now minus the same
+        # clock at the step's start (another clock under the role — the
+        # caller changed, a thread registered late: no reading)
+        thread_cpu = {
+            role: round((secs - self._cpu0[role][1]) * 1e3, 3)
+            for role, (who, secs) in cpu.items()
+            if self._cpu0.get(role, (None,))[0] == who}
         wall_ms = max((end - self._t0) * 1e3, 1e-6)
         retx = counters.get("integrity.retransmit")
         # Per-step attribution (ISSUE 12): deltas of the process-wide
         # sink (wire / merge / credit) + the engine's phases (enqueue /
-        # submit / wait / plan / dispatch / compile / assemble, fed by
-        # tracing.phase) + queue + the syncer's block time (sync).
+        # submit / wait / plan / dispatch / compile / assemble and the
+        # adapter's tx_update, fed by tracing.phase) + queue + the
+        # syncer's block time (sync).
         # "other" is max(0, wall - sum) over everything but ``wait`` —
         # a blocked caller is the other threads' work seen from outside,
         # and counting it twice would zero the residual: components
@@ -405,7 +543,18 @@ class StepStatsTracker:
             whole_units=units[2] - self._units0[2],
             buckets=self._buckets,
             bucketed_leaves=self._bucketed_leaves,
+            attrib_cpu={k: round(v, 3) for k, v in self._comp_cpu.items()},
+            push_pull_cpu_ms=round(self._push_pull_cpu_ms, 3),
+            thread_cpu=thread_cpu,
+            update_ms=round(self._update_ms, 3),
         )
+        if self._unit_sync_ms:
+            histograms.observe_many("engine.unit_sync_ms",
+                                    self._unit_sync_ms)
+            self._unit_sync_ms = []
+        self._comp_cpu = {}
+        self._push_pull_cpu_ms = 0.0
+        self._update_ms = 0.0
         self._units0 = units
         self._push_pull_ms = 0.0
         self._bytes = 0
@@ -435,18 +584,14 @@ class StepStatsTracker:
         gauges.set("step.whole_units", stats.whole_units)
         gauges.set("step.buckets", stats.buckets)
         gauges.set("step.bucketed_leaves", stats.bucketed_leaves)
-        for comp, ms in stats.attrib.items():
-            # KeyError here is deliberate: a new attribution component
-            # must be added to ATTRIB_GAUGE_NAMES (and the doc table) —
-            # an f-string fallback would silently bypass the bpslint
-            # metric-name check the map exists for
-            gauges.set(ATTRIB_GAUGE_NAMES[comp], ms)
-        # zero components absent THIS step (a step-5 compile stall must
-        # not haunt every later scrape — the gauge set always describes
-        # ONE step, summing to its wall_ms)
-        for comp in self._pub_attrib - set(stats.attrib):
-            gauges.set(ATTRIB_GAUGE_NAMES[comp], 0.0)
-        self._pub_attrib = set(stats.attrib)
+        self._pub_attrib = self._set_components(
+            ATTRIB_GAUGE_NAMES, stats.attrib, self._pub_attrib)
+        gauges.set("step.update_ms", stats.update_ms)
+        gauges.set("step.push_pull_cpu_ms", stats.push_pull_cpu_ms)
+        self._pub_attrib_cpu = self._set_components(
+            ATTRIB_CPU_GAUGE_NAMES, stats.attrib_cpu, self._pub_attrib_cpu)
+        for role, ms in stats.thread_cpu.items():
+            gauges.set(THREAD_CPU_GAUGE_NAMES[role], ms)
         counters.inc("step.completed")
         # the flight event names the lagging tensor and this rank — a
         # crash black box says WHO the dying step was waiting on
@@ -456,6 +601,24 @@ class StepStatsTracker:
         except Exception:  # noqa: BLE001 — publishing must never raise
             rank = 0
         self._recorder.record("step_stats", rank=rank, **stats.as_dict())
+
+    @staticmethod
+    def _set_components(names: Dict[str, str], values: Dict[str, float],
+                        published: set) -> set:
+        """One gauge a component of ``values``; returns the components
+        set, for the next step's call."""
+        for comp, ms in values.items():
+            # KeyError here is deliberate: a new attribution component
+            # must be added to the name table (and the doc table) — an
+            # f-string fallback would silently bypass the bpslint
+            # metric-name check the tables exist for
+            gauges.set(names[comp], ms)
+        # zero components absent THIS step (a step-5 compile stall must
+        # not haunt every later scrape — the gauge set always describes
+        # ONE step, summing to its wall_ms)
+        for comp in published - set(values):
+            gauges.set(names[comp], 0.0)
+        return set(values)
 
     def flush(self) -> Optional[StepStats]:
         """Finalize the in-progress step (engine shutdown: the tail step

@@ -50,6 +50,13 @@ the work, so it lands in whatever profiler session the process is under
 on the clock the device's ops are on, and as milliseconds fed to the
 step's record in ``StepStatsTracker``.  The chrome timeline above is a
 separate, per-push operator's view and is untouched by it.
+
+ISSUE 33 — a phase can read two clocks from that one enter/exit pair:
+wall, and the calling thread's CPU time, so that it says how much of
+itself was running and how much was waiting (for the interpreter lock,
+or inside a runtime call); the feed gets both, the span carries
+``cpu_us``.  Every phase does inside a profiler session, the
+once-a-step phases always (the clock is a system call).
 """
 
 from __future__ import annotations
@@ -172,20 +179,44 @@ def clock_offset() -> Dict[str, object]:
 
 # -- the step's phases: profiler span + per-step counter ---------------------
 
+# the calling thread's CPU clock; a module name so that a test can count
+# the calls
+_thread_time = time.thread_time
+
 
 class phase:
-    """One phase of the engine-mode step, recorded twice.
+    """One phase of the engine-mode step, recorded twice, on two clocks.
 
     ``with phase("bps.engine.submit", feed) as ph:`` opens a
     ``jax.profiler.TraceAnnotation`` of that name on the calling thread
     when a profiler session is recording (``ph.ann``; None, and nothing
-    built, otherwise) and, on exit, hands the phase's milliseconds to
-    ``ph.feed`` (a bound ``StepStatsTracker`` method, or None with
-    telemetry off).  TraceMe stamps its own clock and takes no
-    timestamps from outside, so the two records are taken back to back
-    from one enter/exit pair: the annotation opens just before the
-    ``time.monotonic`` stamp ``t0`` and closes just after ``t1``, and
-    the two agree to about a microsecond.
+    built, otherwise) and, on exit, hands ``ph.feed`` (a bound
+    ``StepStatsTracker`` method, or None with telemetry off) the phase's
+    wall milliseconds and the milliseconds of them the thread was
+    actually RUNNING: ``feed(wall_ms, cpu_ms)``.  TraceMe stamps its own
+    clock and takes no timestamps from outside, so the records are taken
+    back to back from one enter/exit pair: the annotation opens just
+    before the ``time.monotonic`` stamp ``t0`` and closes just after
+    ``t1``, and the two agree to about a microsecond.
+
+    The second clock is the calling thread's CPU time
+    (``time.thread_time``, ``CLOCK_THREAD_CPUTIME_ID``), stamped just
+    inside ``t0`` / ``t1`` (so CPU never reads above wall).  Unlike
+    ``time.monotonic`` it is a system call — 0.35 us on a plain Linux
+    host, 5.8 us under the sandboxed kernel of the v5e benchmark host,
+    where ~180 phases a step cost 1.7-2.1 % of the step (PERF.md §6,
+    PR 33) — so it is read only where it is asked for: inside a
+    profiler session, by every phase, whose span then carries the
+    number as the argument ``cpu_us``; outside one, only by a phase
+    built with ``cpu=True`` that has a feed (the phases that run ONCE a
+    step: ``bps.push_pull``, ``bps.engine.wait``,
+    ``bps.adapter.tx_update``).  Every other phase then feeds
+    ``cpu_ms=None`` and makes no call beyond the two ``time.monotonic``
+    stamps.  Wall minus CPU of a WORKING phase is time the thread held
+    the span open and was not running: waiting for the interpreter
+    lock, or blocked inside a runtime call that released it.  CPU of a
+    BLOCKED phase (``wait``, ``sync``) is near zero; where it is not,
+    the "block" is a poll.
 
     Arguments (the step, the tensor) go on with :meth:`note`, any time
     before exit — TraceMe fixes an event's NAME at construction, so what
@@ -198,23 +229,33 @@ class phase:
     (PERF.md §6, PR 23) — which is also why this takes no ``**args``
     and computes nothing it is not asked for."""
 
-    __slots__ = ("feed", "ann", "t0", "t1")
+    __slots__ = ("feed", "ann", "t0", "t1", "c0")
 
     def __init__(self, name: str,
-                 feed: Optional[Callable[[float], None]] = None):
+                 feed: Optional[Callable[[float, Optional[float]],
+                                         None]] = None,
+                 cpu: bool = False):
         self.feed = feed
         self.ann = _TraceMe(name) if _TraceMe.is_enabled() else None
+        # None: this phase leaves the thread clock alone
+        self.c0 = (0.0 if self.ann is not None
+                   or (cpu and feed is not None) else None)
 
     def __enter__(self) -> "phase":
         self.t0 = time.monotonic()
+        if self.c0 is not None:
+            self.c0 = _thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        c0 = self.c0
+        cpu = None if c0 is None else (_thread_time() - c0) * 1e3
         self.t1 = t1 = time.monotonic()
         if self.ann is not None:
+            self.ann.set_metadata(cpu_us=int(cpu * 1e3))
             self.ann.__exit__(exc_type, exc, tb)
         if self.feed is not None:
-            self.feed((t1 - self.t0) * 1e3)
+            self.feed((t1 - self.t0) * 1e3, cpu)
         return False
 
     def note(self, **args) -> None:

@@ -403,8 +403,9 @@ class PushPullEngine:
         # the same name; nowhere with telemetry off.
         self.phase_feeds = {
             c: self.step_stats.feed(c) if cfg.telemetry_on else None
-            for c in ("push_pull", "enqueue", "submit", "wait", "plan",
-                      "dispatch", "compile", "sync", "assemble")}
+            for c in ("update", "push_pull", "enqueue", "submit", "wait",
+                      "tx_update", "plan", "dispatch", "compile", "sync",
+                      "assemble")}
         self._sync_q: "queue.Queue" = queue.Queue()
         # Tasks popped per dispatch iteration where they are not a
         # buffer-mode tensor's chunks (_pop_batch).  Multi-host stays at
@@ -686,8 +687,14 @@ class PushPullEngine:
             pending.trace = tctx
             local_mode = local
             if bucket is not None:
-                if not bucket.warmed:
-                    self._warm_bucket(bucket, ctx, use_buffer, scale, op)
+                if (not bucket.warmed and self._warm_bucket(
+                        bucket, ctx, use_buffer, scale, op)):
+                    # the bucket's first push compiled its programs in
+                    # here: as the dispatcher's unit that crosses a
+                    # cache miss, this enqueue feeds "compile" and its
+                    # span says so
+                    ph_enq.feed = self.phase_feeds["compile"]
+                    ph_enq.note(compiled=1)
                 # one program: flat, stacked-sharded and padded to the
                 # scatter layout, in place of the staging below for
                 # every leaf
@@ -949,16 +956,17 @@ class PushPullEngine:
             chunk_bounds(n, itemsize, self.cfg.partition_bytes))[1]
 
     def _warm_bucket(self, bucket: _Bucket, ctx: TensorContext,
-                     use_buffer: bool, scale, op: str) -> None:
+                     use_buffer: bool, scale, op: str) -> int:
         """A bucket's first push declares it: compile its pack and
         unpack programs and, as declare_tensor does for a declared
         tensor, the program of each of its dispatch units -- for a
         bucket, one (single process only: SPMD processes compile
-        lazily, in lockstep)."""
+        lazily, in lockstep).  Returns how many programs it compiled."""
         bucket.warmed = True
         if jax.process_count() > 1:
-            return
+            return 0
         t0 = time.monotonic()
+        n_compiled = 0
         try:
             n_compiled = aot_warm_bucket_programs(
                 self.comm, shapes=bucket.shapes,
@@ -970,12 +978,14 @@ class PushPullEngine:
                     ctx, bucket.dtype, op=op, local=False, assembled=False)
             if n_compiled and self.tracer.active:
                 # as declare_tensor: the stall in the timeline where it
-                # was paid (it is inside this push's "enqueue")
+                # was paid (inside this push's "enqueue" span, which
+                # then feeds "compile")
                 self.tracer.record_span(
                     "engine.aot_warm", t0, time.monotonic(),
                     tensor=bucket.name, programs=n_compiled)
         except Exception as e:  # noqa: BLE001 — lazy jit is the fallback
             self._aot_warm_failed("bucket", bucket.name, e)
+        return n_compiled
 
     @staticmethod
     def _est_nbytes(shape, dtype) -> int:
@@ -1512,6 +1522,7 @@ class PushPullEngine:
 
     # ---------------------------------------------------------- loops
     def _dispatch_loop(self):
+        self.step_stats.register_thread("dispatcher")
         while self._running:
             if not self._dispatch_enabled.is_set():
                 # parked: zero-CPU wait on the resume event (the old
@@ -1720,6 +1731,7 @@ class PushPullEngine:
         # gate's handle sat unresolved until its batch's laggard
         # finished, which is exactly the just-in-time latency the xb
         # design sells.
+        self.step_stats.register_thread("syncer")
         shutdown = False
         while not shutdown:
             # Retired units are dropped as they go (popleft) and before
@@ -1804,21 +1816,20 @@ class PushPullEngine:
                 # whole run: the dispatcher can launch the next window
                 # while this thread runs assembly.
                 self.scheduler.report_finish(sum(t.nbytes for t in tasks))
-                if self.cfg.telemetry_on and t_disp:
-                    histograms.observe(
-                        "engine.unit_sync_ms",
-                        (time.monotonic() - t_disp) * 1e3)
                 if self.cfg.telemetry_on:
-                    # queue-wait attribution: how long this unit's head
-                    # chunk sat in the priority queue before dispatch —
-                    # plus the lagging-tensor bookkeeping (the LAST
-                    # retired unit before a step finalizes names the
-                    # chain the step actually waited on)
-                    if head.t_dispatch and head.t_enqueue:
-                        self.step_stats.add_component(
-                            "queue",
-                            (head.t_dispatch - head.t_enqueue) * 1e3)
-                    self.step_stats.note_retire(tasks[-1].name)
+                    # one call, one lock a retired unit: the
+                    # lagging-tensor bookkeeping (the LAST retired unit
+                    # before a step finalizes names the chain the step
+                    # actually waited on), queue-wait attribution (how
+                    # long this unit's head chunk sat in the priority
+                    # queue before dispatch) and the unit's dispatch ->
+                    # retire latency (engine.unit_sync_ms)
+                    self.step_stats.retire_unit(
+                        tasks[-1].name,
+                        (head.t_dispatch - head.t_enqueue) * 1e3
+                        if head.t_dispatch and head.t_enqueue else None,
+                        (time.monotonic() - t_disp) * 1e3
+                        if t_disp else None)
                 # "assemble": assembly + callback wall, the retirement
                 # work after the device block — the tail segment of a
                 # push's critical path (step attribution, ISSUE 12)

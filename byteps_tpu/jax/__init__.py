@@ -68,16 +68,21 @@ def _enqueue_and_wait(enqueue) -> tuple:
     """``enqueue(engine)`` the tree, then block on its handles: the
     caller thread's two halves of a tree-level push_pull, as the phases
     ``bps.push_pull`` (whole; ``StepStats.push_pull_ms``) and
-    ``bps.engine.wait`` (blocked; ``attrib["wait"]``).  ``enqueue``
+    ``bps.engine.wait`` (blocked; ``attrib["wait"]``); each feeds its
+    wall and, of it, the thread's CPU milliseconds
+    (``push_pull_cpu_ms``, ``attrib_cpu["wait"]``).  ``enqueue``
     returns a ``TreeHandle``; returns ``(tree_handle, results)``."""
     eng = _api._require()
     feeds = eng.phase_feeds
-    eng.step_stats.open_call()
-    with _tracing.phase("bps.push_pull", feeds["push_pull"]) as ph:
+    if feeds["push_pull"] is not None:    # telemetry on: a step to open
+        eng.step_stats.open_call()
+    # once-a-step phases: each reads the thread's CPU clock too
+    with _tracing.phase("bps.push_pull", feeds["push_pull"], cpu=True) as ph:
         pushed = enqueue(eng)
         step = eng.step_stats.current_step
         ph.note(step=step)
-        with _tracing.phase("bps.engine.wait", feeds["wait"]) as ph_wait:
+        with _tracing.phase("bps.engine.wait", feeds["wait"],
+                            cpu=True) as ph_wait:
             ph_wait.note(step=step)
             outs = pushed.wait()
     return pushed, outs
@@ -252,14 +257,18 @@ class DistributedOptimizer:
         updates are zeros (parameters unchanged), matching the reference's
         deferral of push_pull until the boundary pass.
         """
-        with _tracing.phase("bps.adapter.update") as ph:
-            out = self._update(grads, state, params)
+        # the feeds as _enqueue_and_wait reaches them; with no engine,
+        # nothing (push_pull below then says "not initialized")
+        eng = _api._engine
+        feeds = eng.phase_feeds if eng is not None else {}
+        with _tracing.phase("bps.adapter.update", feeds.get("update")) as ph:
+            out = self._update(grads, state, params, feeds.get("tx_update"))
             # the engine step the update landed in is known only now
             if _api._engine is not None:
                 ph.note(step=_api._engine.step_stats.current_step)
         return out
 
-    def _update(self, grads, state, params):
+    def _update(self, grads, state, params, tx_feed=None):
         with self._lock:
             if self._bpps > 1:
                 self._accum = grads if self._accum is None else jax.tree.map(
@@ -301,7 +310,12 @@ class DistributedOptimizer:
                 eng.handles.release(h.id)
             return jax.tree_util.tree_unflatten(treedef, outs), state
         reduced = push_pull(grads, self._prefix, op=self._op)
-        return self._tx.update(reduced, state, params)
+        # the caller's own optax update: lands in the step whose
+        # push_pull this was (the NEXT step's first push finalizes it)
+        with _tracing.phase("bps.adapter.tx_update", tx_feed, cpu=True) as ph:
+            if ph.ann is not None:
+                ph.note(step=_api._require().step_stats.current_step)
+            return self._tx.update(reduced, state, params)
 
 
 class DistributedGradientTape:

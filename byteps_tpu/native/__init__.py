@@ -34,10 +34,20 @@ def _build_path() -> str:
 
 
 def _compile(out: str) -> None:
+    # a temporary file of this builder's own: in a fresh checkout several
+    # processes build at once (every test worker imports this while it
+    # collects), and on ONE shared ".tmp" the first os.replace took the
+    # file from under the others, whose own replace then failed and
+    # latched the library unavailable in their process
+    tmp = f"{out}.{os.getpid()}.{threading.get_ident()}.tmp"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           _SRC, "-o", out + ".tmp"]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
-    os.replace(out + ".tmp", out)  # atomic: parallel builders race safely
+           _SRC, "-o", tmp]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, out)  # atomic: parallel builders race safely
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def load() -> Optional[ctypes.CDLL]:

@@ -85,3 +85,43 @@ def test_bf16_reduce_round_to_nearest_even():
         src.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
         dst.size, 2)
     np.testing.assert_array_equal(dst.view(ml_dtypes.bfloat16), expect)
+
+
+def test_parallel_builders_each_write_a_file_of_their_own(tmp_path,
+                                                          monkeypatch):
+    """Several builders at once (a fresh checkout under six test
+    workers): every one ends with the library in place and none fails —
+    on one shared temporary file the first ``os.replace`` took it from
+    under the others (ISSUE 33: the eighteen tests that wandered)."""
+    import threading
+    out = str(tmp_path / "_lib.so")
+    n = 4
+    mid_write = threading.Barrier(n)
+
+    def fake_gxx(cmd, **kw):
+        target = cmd[cmd.index("-o") + 1]
+        body = target.encode() * 64
+        with open(target, "wb") as f:
+            f.write(body[:len(body) // 2])
+            f.flush()
+            mid_write.wait(timeout=30)        # all builders are mid-file
+            f.write(body[len(body) // 2:])
+
+    monkeypatch.setattr(native.subprocess, "run", fake_gxx)
+    errors = []
+
+    def build():
+        try:
+            native._compile(out)
+        except Exception as e:  # noqa: BLE001 — the assertion names it
+            errors.append(e)
+
+    threads = [threading.Thread(target=build) for _ in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert errors == []
+    assert [p.name for p in tmp_path.iterdir()] == ["_lib.so"]
+    body = (tmp_path / "_lib.so").read_bytes()
+    assert body == body[:len(body) // 64] * 64     # one builder's, whole

@@ -178,12 +178,21 @@ def test_dispatch_events_equal_dispatch_count(traced):
     for n in ("bps.engine.sync", "bps.engine.assemble"):
         assert len(_named(spans, n)) == len(units)
     # making the tree's plan declared its tensors, every program the
-    # dispatcher can form for them compiled on the caller's thread (a
-    # bucket's inside its first "enqueue"): no unit ever compiles
+    # dispatcher can form for them compiled on the caller's thread: no
+    # unit ever compiles.  A bucket's programs compile inside its first
+    # "enqueue", which then feeds "compile" and says ``compiled=1``
+    # (ISSUE 33)
     assert not any(u.args.get("compiled") for u in units)
-    assert all("compile" not in s.attrib for s in steps.values())
+    compiled = [s for s in _named(spans, "bps.engine.enqueue")
+                if s.args.get("compiled")]
+    assert [s.args["step"] for s in compiled] == [1] * shape["buckets"]
+    assert all("compile" not in s.attrib
+               for n, s in steps.items() if n > 1)
     if shape["buckets"]:
-        assert steps[1].attrib["enqueue"] > 10 * steps[3].attrib["enqueue"]
+        assert steps[1].attrib["compile"] > 10 * steps[3].attrib["enqueue"]
+        assert "enqueue" not in steps[1].attrib   # its one tensor compiled
+    else:
+        assert "compile" not in steps[1].attrib
 
 
 def test_a_unit_that_compiles_feeds_compile(tmp_path):
@@ -219,7 +228,8 @@ def test_a_unit_that_compiles_feeds_compile(tmp_path):
     assert all("leaves" not in u for u in units)
 
 
-@pytest.mark.parametrize("component", PHASES + ("compile", "push_pull"))
+@pytest.mark.parametrize("component", PHASES + ("compile", "push_pull",
+                                                "tx_update", "update"))
 def test_span_durations_are_the_counters(traced, component):
     """(d) per step, a phase's spans sum to its counter: both come from
     one enter/exit pair (the TraceMe opens a moment before the
@@ -234,9 +244,20 @@ def test_span_durations_are_the_counters(traced, component):
         if component == "push_pull":
             got = _named(spans, "bps.push_pull")
             want = stats.push_pull_ms
-        elif component in ("dispatch", "compile"):
-            got = [s for s in _named(spans, "bps.engine.dispatch")
-                   if bool(s.args.get("compiled")) == (component == "compile")]
+        elif component == "update":
+            got = _named(spans, "bps.adapter.update")
+            want = stats.update_ms
+        elif component == "tx_update":
+            got = _named(spans, "bps.adapter.tx_update")
+            want = stats.attrib.get(component, 0.0)
+        elif component in ("dispatch", "enqueue", "compile"):
+            # a launch, or a bucket's first enqueue, that compiled feeds
+            # "compile" and carries ``compiled=1``
+            names = (("bps.engine.dispatch", "bps.engine.enqueue")
+                     if component == "compile"
+                     else (f"bps.engine.{component}",))
+            got = [s for s in spans if s.name in names
+                   and bool(s.args.get("compiled")) == (component == "compile")]
             want = stats.attrib.get(component, 0.0)
         else:
             got = _named(spans, f"bps.engine.{component}")
@@ -296,9 +317,10 @@ def test_without_a_session_nothing_is_recorded(shape):
     push_pull (within 5 %)."""
     assert not jax.profiler.TraceAnnotation.is_enabled()
     fed = []
-    with tracing.phase("bps.test.none", fed.append) as ph:
+    with tracing.phase("bps.test.none", lambda *ms: fed.append(ms)) as ph:
         ph.note(step=1)
-    assert ph.ann is None and fed == [(ph.t1 - ph.t0) * 1e3]
+    (wall, cpu), = fed          # no session, not a once-a-step phase
+    assert ph.ann is None and wall == (ph.t1 - ph.t0) * 1e3 and cpu is None
     steps = _engine_steps(10, leaf_elems=shape["leaf_elems"])[2:]
     assert len(steps) == 8
     for s in steps:
@@ -317,9 +339,9 @@ def test_phase_late_facts_and_feeds():
     """The feed can be switched before exit (dispatch -> compile) and a
     phase with no feed records nothing anywhere."""
     a, b = [], []
-    with tracing.phase("bps.test.switch", a.append) as ph:
-        ph.feed = b.append
-    assert a == [] and b == [(ph.t1 - ph.t0) * 1e3]
+    with tracing.phase("bps.test.switch", lambda *ms: a.append(ms)) as ph:
+        ph.feed = lambda *ms: b.append(ms)
+    assert a == [] and [wall for wall, _ in b] == [(ph.t1 - ph.t0) * 1e3]
     with tracing.phase("bps.test.nofeed") as ph:
         pass
     assert ph.t1 >= ph.t0 > 0.0
@@ -344,7 +366,8 @@ def test_phase_annotates_inside_a_session(tmp_path):
     got = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
            for line in plane.lines for ev in line.events
            if ev.name == "bps.test.session"]
-    assert got == [{"step": 7, "late": 1}]
+    cpu_us = got[0].pop("cpu_us")        # ISSUE 33: every span's CPU time
+    assert got == [{"step": 7, "late": 1}] and 0 <= cpu_us < 10_000
 
 
 def test_fused_step_leaves_step_stats_untouched():
@@ -375,3 +398,414 @@ def test_fused_step_leaves_step_stats_untouched():
                              "whole_units": 0}
     finally:
         bps.shutdown()
+
+
+# -- ISSUE 33: every phase reads two clocks ----------------------------------
+
+# the components a ``phase`` feeds, by the thread whose CPU they are
+CPU_COMPONENTS = ("enqueue", "submit", "wait", "plan", "dispatch", "compile",
+                  "sync", "assemble", "tx_update")
+# the once-a-step phases read the thread clock in every step, the
+# per-tensor and per-unit ones only under a profiler session
+ONCE_A_STEP = ("wait", "tx_update")
+THREAD_PHASES = {"caller": ("enqueue", "submit", "wait", "tx_update"),
+                 "dispatcher": ("plan", "dispatch"),
+                 "syncer": ("sync", "assemble")}
+CPU_SLACK_MS = 0.5        # clock granularity, the two stamps' own cost
+
+
+def _fed():
+    got = []
+    return got, lambda wall, cpu: got.append((wall, cpu))
+
+
+def test_phase_feeds_wall_and_cpu():
+    """The feed gets both clocks of the one enter/exit pair: the wall is
+    the monotonic stamps', the CPU lies inside it."""
+    got, feed = _fed()
+    with tracing.phase("bps.test.two_clocks", feed, cpu=True) as ph:
+        sum(range(20_000))
+    (wall, cpu), = got
+    assert wall == (ph.t1 - ph.t0) * 1e3
+    assert 0 < cpu <= wall + 0.01
+
+
+@pytest.mark.parametrize("how", ["sleeps", "spins"])
+def test_cpu_is_the_running_part_of_the_wall(how):
+    """A phase that sleeps reads CPU ~ 0 and one that spins reads CPU ~
+    wall (best of five: another process may hold the core once)."""
+    shares = []
+    for _ in range(5):
+        got, feed = _fed()
+        with tracing.phase("bps.test." + how, feed, cpu=True):
+            if how == "sleeps":
+                time.sleep(0.02)
+            else:
+                end = time.thread_time() + 0.02
+                while time.thread_time() < end:
+                    pass
+        (wall, cpu), = got
+        assert wall >= 20.0 and cpu <= wall + 0.01
+        shares.append(cpu / wall)
+    if how == "sleeps":
+        assert min(shares) < 0.05, shares
+    else:
+        assert max(shares) > 0.9, shares
+
+
+@pytest.fixture
+def clock_calls(monkeypatch):
+    """Counts every read of the thread CPU clock a ``phase`` makes."""
+    calls = []
+
+    def counted():
+        calls.append(1)
+        return time.thread_time()
+    monkeypatch.setattr(tracing, "_thread_time", counted)
+    return calls
+
+
+@pytest.mark.parametrize("fed,cpu,reads", [
+    (False, False, 0), (False, True, 0), (True, False, 0), (True, True, 2)],
+    ids=["no_feed", "no_feed_cpu", "feed", "feed_cpu"])
+def test_the_thread_clock_is_read_only_for_a_reader(clock_calls, fed, cpu,
+                                                    reads):
+    """Outside a session a phase reads the thread clock (a system call)
+    only where it was asked to AND something will take the number: no
+    feed, no call at all; a feed without ``cpu=True`` gets ``None``."""
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    got, feed = _fed()
+    with tracing.phase("bps.test.cost", feed if fed else None, cpu=cpu) as ph:
+        pass
+    assert len(clock_calls) == reads
+    assert len(got) == (1 if fed else 0) and ph.t1 >= ph.t0
+    if fed:
+        assert (got[0][1] is not None) == cpu
+
+
+def test_a_session_reads_the_clock_for_every_phase(tmp_path, clock_calls):
+    """Inside a session every phase reads the clock, fed or not, asked
+    or not: its span carries ``cpu_us``."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        got, feed = _fed()
+        with tracing.phase("bps.test.in_session", feed):
+            pass
+        with tracing.phase("bps.test.in_session_unfed"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert len(clock_calls) == 4
+    (_, cpu), = got
+    assert cpu is not None and cpu >= 0
+
+
+@pytest.mark.parametrize("telemetry", [False, True], ids=["off", "on"])
+def test_clock_reads_a_step_without_a_session(clock_calls, monkeypatch,
+                                              telemetry):
+    """Whole engine-mode steps with no session.  ``BYTEPS_TELEMETRY_ON=0``:
+    every phase is entered and none reads the thread clock, no step is
+    recorded.  On: the three once-a-step phases read it (six calls a
+    step), the ~10 per-tensor and per-unit ones do not."""
+    set_config(Config(telemetry_on=telemetry, partition_bytes=PART,
+                      partition_pinned=True))
+    bps.init()
+    try:
+        eng = bps.core.api._require()
+        params, grads = _tree(bps.size(), BUCKETED["leaf_elems"])
+        opt = DistributedOptimizer(optax.sgd(0.1))
+        state = opt.init(params)
+        for _ in range(3):
+            updates, state = opt.update(grads, state, params)
+        jax.block_until_ready(updates)
+        assert eng.stats["dispatches"] > 0
+        if not telemetry:
+            assert set(eng.phase_feeds.values()) == {None}
+            assert eng.step_stats.history() == []
+    finally:
+        bps.shutdown()
+    assert len(clock_calls) == (3 * 6 if telemetry else 0)
+
+
+@pytest.fixture(scope="module")
+def cpu_steps():
+    """StepStats of eight untraced engine-mode steps on the bucketed
+    tree; the first one compiles (inside the bucket's first enqueue)."""
+    steps = _engine_steps(8, leaf_elems=BUCKETED["leaf_elems"])
+    assert [s.step for s in steps] == list(range(1, 9))
+    return steps
+
+
+@pytest.mark.parametrize("component", CPU_COMPONENTS)
+def test_every_fed_component_has_its_cpu(traced, cpu_steps, component):
+    """On a real engine step under a session each component a phase
+    feeds carries the CPU milliseconds inside its wall — present
+    wherever the wall is, and never above it.  Without a session only
+    the once-a-step phases read the clock."""
+    _, steps, shape = traced
+    have = [s for s in steps.values() if component in s.attrib_cpu]
+    if component == "compile":
+        # a bucket's first push, inside its enqueue
+        assert [s.step for s in have] == [1] * shape["buckets"]
+    else:
+        assert len(have) >= TRACED_STEPS - 1, (component, steps)
+    for s in have:
+        assert 0 <= s.attrib_cpu[component] <= (
+            s.attrib[component] + CPU_SLACK_MS), (component, s)
+    for s in steps.values():
+        assert set(s.attrib_cpu) <= set(CPU_COMPONENTS)
+        assert (component in s.attrib_cpu) == (
+            s.attrib.get(component, 0.0) > 0 or component == "sync"), s
+    for s in cpu_steps:
+        assert (component in s.attrib_cpu) == (component in ONCE_A_STEP), s
+        if component in ONCE_A_STEP:
+            assert 0 <= s.attrib_cpu[component] <= (
+                s.attrib[component] + CPU_SLACK_MS), (component, s)
+
+
+def test_a_blocked_phase_is_not_a_poll(traced, cpu_steps):
+    """``wait`` and ``sync`` are blocked phases: over the steady steps
+    their CPU is a small part of their wall (the caller parks on the
+    handles' events, the syncer in ``block_until_ready``)."""
+    _, steps, _ = traced
+    for c, some in (("wait", cpu_steps[2:]), ("sync", list(steps.values()))):
+        wall = sum(s.attrib[c] for s in some)
+        cpu = sum(s.attrib_cpu[c] for s in some)
+        assert cpu <= 0.5 * wall + CPU_SLACK_MS, (c, cpu, wall)
+
+
+@pytest.mark.parametrize("thread", sorted(THREAD_PHASES))
+def test_thread_cpu_covers_the_threads_phases(traced, thread):
+    """``thread_cpu`` is the thread's WHOLE CPU over the step, read at
+    its two boundaries: never less than the CPU inside that thread's
+    phases (what is over is the CPU outside any span)."""
+    _, steps, _ = traced
+    for n, s in steps.items():
+        assert sorted(s.thread_cpu) == sorted(THREAD_PHASES), s
+        if n == 1:
+            continue                      # compiles, on either thread
+        inside = sum(s.attrib_cpu.get(c, 0.0) for c in THREAD_PHASES[thread])
+        assert s.thread_cpu[thread] >= inside - CPU_SLACK_MS, (thread, s)
+        assert s.thread_cpu[thread] <= s.wall_ms + CPU_SLACK_MS, (thread, s)
+
+
+def test_push_pull_cpu_is_the_callers_cpu_inside_the_call(traced, cpu_steps):
+    """``push_pull_cpu_ms``: of ``push_pull_ms``, what the caller ran —
+    in every step, session or none; it holds the CPU of the caller's
+    phases inside the call and lies inside the caller's whole CPU."""
+    _, steps, _ = traced
+    for s in list(steps.values()) + cpu_steps:
+        assert 0 < s.push_pull_cpu_ms <= s.push_pull_ms + CPU_SLACK_MS, s
+        assert s.push_pull_cpu_ms + s.attrib_cpu["tx_update"] <= (
+            s.thread_cpu["caller"] + CPU_SLACK_MS), s
+        assert s.push_pull_cpu_ms >= s.attrib_cpu["wait"] - CPU_SLACK_MS, s
+    for n, s in steps.items():
+        if n > 1:
+            inside = sum(s.attrib_cpu[c] for c in ("enqueue", "submit",
+                                                   "wait"))
+            assert s.push_pull_cpu_ms >= inside - CPU_SLACK_MS, s
+
+
+def test_update_ms_holds_push_pull_and_tx_update(cpu_steps):
+    """The adapter's three program spans: ``update_ms`` (the whole
+    ``update()``) holds ``push_pull_ms`` and ``attrib["tx_update"]``;
+    what is left is the adapter's own (flatten, names, unflatten)."""
+    for s in cpu_steps:
+        own = s.update_ms - s.push_pull_ms - s.attrib["tx_update"]
+        assert own >= -0.005, s
+        assert s.update_ms <= s.wall_ms + 0.005, s
+        assert s.attrib["tx_update"] > 0
+
+
+def test_the_first_enqueue_of_a_bucket_feeds_compile(cpu_steps):
+    """A bucket's first push compiles its programs inside
+    ``bps.engine.enqueue``: that enqueue feeds ``compile``, and
+    ``enqueue`` is back from the second step on."""
+    first, second = cpu_steps[0], cpu_steps[1]
+    assert "enqueue" not in first.attrib
+    assert first.attrib["compile"] > 10 * second.attrib["enqueue"] > 0
+    assert all("compile" not in s.attrib for s in cpu_steps[1:])
+
+
+def test_tx_update_span_sits_after_push_pull_in_its_step(traced):
+    """``bps.adapter.tx_update`` nests inside ``bps.adapter.update``,
+    after that update's ``bps.push_pull``, on the caller's line, and
+    carries the step of the push_pull that preceded it — the step whose
+    ``attrib["tx_update"]`` it feeds (a step is finalized by the NEXT
+    step's first push)."""
+    spans, steps, _ = traced
+    updates = _named(spans, "bps.adapter.update")
+    pushes = {s.args["step"]: s for s in _named(spans, "bps.push_pull")}
+    txs = _named(spans, "bps.adapter.tx_update")
+    assert sorted(s.args["step"] for s in txs) == sorted(steps)
+    for tx in txs:
+        push = pushes[tx.args["step"]]
+        assert tx.line == push.line and tx.start >= push.end
+        outer, = [u for u in updates if _inside(tx, [u])]
+        assert _inside(push, [outer]) and outer.args["step"] == tx.args["step"]
+        got_ms = (tx.end - tx.start) / 1e6
+        want = steps[tx.args["step"]].attrib["tx_update"]
+        assert want - 0.01 <= got_ms <= 1.1 * want + PREEMPTION_MS
+
+
+def test_every_span_carries_its_cpu_time(traced):
+    """Inside a session every ``bps.*`` span carries ``cpu_us`` beside
+    ``step``: the CPU microseconds inside the span, never above its
+    duration; per step and phase they sum to the counter's CPU."""
+    spans, steps, _ = traced
+    assert spans
+    for s in spans:
+        assert isinstance(s.args.get("cpu_us"), int), s
+        assert 0 <= s.args["cpu_us"] <= (s.end - s.start) / 1e3 + 50, s
+    for n, stats in steps.items():
+        for c in ("sync", "assemble", "plan", "wait", "submit"):
+            us = sum(s.args["cpu_us"] for s in _named(spans, f"bps.engine.{c}")
+                     if s.args["step"] == n)
+            assert abs(us / 1e3 - stats.attrib_cpu[c]) <= 0.002 * (
+                len(spans) + 1), (n, c)
+        us, = [s.args["cpu_us"] for s in _named(spans, "bps.push_pull")
+               if s.args["step"] == n]
+        assert abs(us / 1e3 - stats.push_pull_cpu_ms) <= 0.002
+
+
+def test_sharded_update_feeds_no_tx_update():
+    """The sharded-update branch runs no ``tx.update`` on the caller:
+    ``update_ms`` fills, ``tx_update`` is in neither dict."""
+    set_config(Config(telemetry_on=True, sharded_update=True))
+    bps.init()
+    try:
+        eng = bps.core.api._require()
+        params, grads = _tree(bps.size(), 1 << 10, leaves=2)
+        opt = DistributedOptimizer(optax.sgd(0.1))
+        state = opt.init(params)
+        for _ in range(3):
+            updates, state = opt.update(grads, state, params)
+            jax.block_until_ready(updates)
+            time.sleep(0.002)
+        eng.step_stats.flush()
+        steps = eng.step_stats.history()
+    finally:
+        bps.shutdown()
+    assert len(steps) == 3
+    for s in steps:
+        assert "tx_update" not in s.attrib and "tx_update" not in s.attrib_cpu
+        assert s.update_ms >= s.push_pull_ms > 0
+
+
+def test_update_before_init_feeds_nothing():
+    """With no engine the adapter has no feeds: ``update()`` opens its
+    span unfed and fails where it always did."""
+    assert bps.core.api._engine is None
+    opt = DistributedOptimizer(optax.sgd(0.1))
+    with pytest.raises(RuntimeError, match="not initialized"):
+        opt.update({"w": jnp.ones((1, 4))}, optax.EmptyState())
+
+
+def _tracker():
+    from byteps_tpu.common.telemetry import StepStatsTracker
+    return StepStatsTracker(recorder=type(
+        "R", (), {"record": lambda self, *a, **k: None})())
+
+
+def test_a_feed_without_a_clock_reading_leaves_no_cpu_key():
+    """``cpu_ms=None`` (a phase that did not read the clock) adds wall
+    only: the component has no ``attrib_cpu`` key, never a 0."""
+    tr = _tracker()
+    tr.on_push("a", 8)
+    tr.feed("dispatch")(2.0, None)
+    tr.feed("dispatch")(3.0, 1.25)
+    tr.feed("assemble")(4.0, None)
+    tr.feed("sync")(1.0, None)
+    tr.feed("push_pull")(9.0, 2.5)
+    tr.feed("update")(12.0, None)
+    done = tr.flush()
+    assert done.attrib["dispatch"] == 5.0 and done.attrib["assemble"] == 4.0
+    assert done.attrib_cpu == {"dispatch": 1.25}
+    assert (done.push_pull_ms, done.push_pull_cpu_ms, done.update_ms) == (
+        9.0, 2.5, 12.0)
+    assert done.sync_stall_ms == done.attrib["sync"] == 1.0
+
+
+def test_one_tracker_call_retires_a_unit():
+    """``retire_unit``: the lagging tensor, the ``queue`` component and
+    the unit's latency in one call; the latencies reach the
+    ``engine.unit_sync_ms`` histogram at the step's boundary."""
+    from byteps_tpu.common.telemetry import histograms
+    tr = _tracker()
+    n0 = histograms.count("engine.unit_sync_ms")
+    tr.on_push("a", 8)
+    tr.retire_unit("a", 1.5, 3.0)
+    tr.retire_unit("b", None, 5.0)
+    tr.retire_unit("c", 2.0, None)
+    assert histograms.count("engine.unit_sync_ms") == n0
+    done = tr.flush()
+    assert done.lagging_tensor == "c" and done.attrib["queue"] == 3.5
+    assert "queue" not in done.attrib_cpu
+    assert histograms.count("engine.unit_sync_ms") == n0 + 2
+    tr.on_push("a", 8)
+    assert tr.flush().lagging_tensor is None
+    assert histograms.count("engine.unit_sync_ms") == n0 + 2
+
+
+def test_thread_cpu_leaves_out_what_it_cannot_read():
+    """No engine thread registered: ``thread_cpu`` has the caller only.
+    A step that another thread finalizes has no ``caller`` (two clocks
+    cannot be subtracted): a key is left out, never 0."""
+    import threading
+    tr = _tracker()
+    tr.open_call()
+    tr.on_push("a", 8)
+    sum(range(50_000))
+    tr.open_call()
+    tr.on_push("a", 8)                       # finalizes step 1, here
+    first, = tr.history()
+    assert list(first.thread_cpu) == ["caller"] and first.thread_cpu[
+        "caller"] > 0
+    t = threading.Thread(target=lambda: tr.on_push("a", 8))
+    t.start()
+    t.join()                                 # step 2 finalized over there
+    assert tr.history()[1].thread_cpu == {}
+    tr.register_thread("syncer")             # this thread, as the syncer
+    tr.on_push("a", 8)                       # step 3 ends: registered late
+    assert "syncer" not in tr.history()[2].thread_cpu
+    sum(range(50_000))
+    tr.on_push("a", 8)
+    fourth = tr.history()[3]
+    assert fourth.thread_cpu["syncer"] > 0
+    assert fourth.thread_cpu["syncer"] == pytest.approx(
+        fourth.thread_cpu["caller"], abs=0.5)
+
+
+def test_cpu_gauges_describe_the_last_step():
+    """The step's CPU readings are published like its walls: one gauge
+    a component / thread, named by the literal tables."""
+    from byteps_tpu.common import telemetry
+    last = _engine_steps(3, leaf_elems=BUCKETED["leaf_elems"])[-1]
+    g = bps.metrics_snapshot()["gauges"]
+    assert sorted(last.attrib_cpu) == sorted(ONCE_A_STEP)
+    for comp, ms in last.attrib_cpu.items():
+        assert g[telemetry.ATTRIB_CPU_GAUGE_NAMES[comp]] == ms
+    for role, ms in last.thread_cpu.items():
+        assert g[telemetry.THREAD_CPU_GAUGE_NAMES[role]] == ms
+    assert g["step.update_ms"] == last.update_ms
+    assert g["step.push_pull_cpu_ms"] == last.push_pull_cpu_ms
+    assert g["step.attrib_tx_update_ms"] == last.attrib["tx_update"]
+
+
+def _new_gauge_names():
+    from byteps_tpu.common import telemetry
+    return (sorted(telemetry.ATTRIB_CPU_GAUGE_NAMES.values())
+            + sorted(telemetry.THREAD_CPU_GAUGE_NAMES.values())
+            + ["step.update_ms", "step.push_pull_cpu_ms",
+               "step.attrib_tx_update_ms"])
+
+
+@pytest.mark.parametrize("name", _new_gauge_names())
+def test_every_new_gauge_is_in_the_established_names_table(name):
+    """bpslint's metric-name rule reads docs/observability.md's table:
+    each gauge ISSUE 33 publishes has its row there."""
+    from tools.bpslint.rules_metrics import doc_names
+    with open(os.path.join(REPO, "docs", "observability.md")) as f:
+        assert name in doc_names(f.read().splitlines())

@@ -32,8 +32,8 @@ from .core import Finding, LintTree, call_target, first_str_arg
 
 _FACADES = {"counters": {"inc"},
             "gauges": {"set"},
-            "histograms": {"observe"},
-            "registry": {"inc", "set", "observe"}}
+            "histograms": {"observe", "observe_many"},
+            "registry": {"inc", "set", "observe", "observe_many"}}
 
 _NAME_SPAN = re.compile(r"`([^`]+)`")
 _METRIC_SHAPE = re.compile(r"^[a-z0-9_.]+$")
