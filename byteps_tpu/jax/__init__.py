@@ -19,6 +19,7 @@ Two modes, mirroring the reference's two integration styles:
 
 from __future__ import annotations
 
+import collections
 import threading
 from typing import Any, Dict, Optional
 
@@ -29,6 +30,7 @@ import optax
 
 from ..common import tracing as _tracing
 from ..common.handles import TreeHandle
+from ..common.metrics import gauges as _gauges
 from ..core import api as _api
 from ..ops import push_pull_tree as _traced_push_pull_tree
 
@@ -171,6 +173,22 @@ def distributed_optimizer(tx: optax.GradientTransformation,
     return optax.GradientTransformation(init_fn, update_fn)
 
 
+def _donating(tx_update):
+    """``tx_update(reduced, state, params)`` as ONE program that donates
+    the leaves of ``(reduced, state)`` handed over in ``donated``: every
+    output of an optax update has the shape of a gradient or of a state
+    leaf, so each is written into a buffer that dies in the call and
+    nothing is allocated.  ``donated`` and ``kept`` are the flattened
+    pair, each with None where the other holds the leaf.  A
+    ``tx_update`` that is jitted already is inlined: one executable, one
+    launch.  No ``out_shardings``: outputs follow the inputs."""
+    def run(treedef, donated, kept, params):
+        leaves = [k if d is None else d for d, k in zip(donated, kept)]
+        reduced, state = jax.tree_util.tree_unflatten(treedef, leaves)
+        return tx_update(reduced, state, params)
+    return jax.jit(run, static_argnums=(0,), donate_argnums=(1,))
+
+
 class DistributedOptimizer:
     """Engine-mode optimizer wrapper (imperative, host-driven).
 
@@ -195,8 +213,25 @@ class DistributedOptimizer:
     receives the owner-computed optax UPDATES back (pull leg N/R
     instead of N); every leaf is then its own tensor (a slot is
     per-tensor state), never bucketed.  The returned ``(updates, state)`` contract is
-    unchanged, and the trajectory is bit-for-bit the unsharded one
-    (tests/test_sharded_update.py).
+    unchanged, and the trajectory is bit-for-bit that of the optax update
+    run op by op on the reduced gradients (tests/test_sharded_update.py;
+    the unsharded mode COMPILES its update, below, and follows the same
+    trajectory to float32 rounding, as any jitted update does).
+
+    **``update(grads, state, params)`` consumes ``state``** (unsharded
+    mode, every backend): the optax update runs as one program of the
+    adapter's own in which the reduced gradients and ``state`` are
+    DONATED, so the updates and the new state are written into their
+    buffers and the step allocates nothing.  Rebind it, as the
+    reference's ``step()`` updates its state in place:
+    ``updates, state = opt.update(grads, state, params)``; reading the
+    old ``state`` afterwards raises JAX's "Array has been deleted".
+    ``grads`` and ``params`` stay the caller's.  What stays alive is
+    seen in the call itself: a state leaf that IS a leaf of ``params``
+    (the same array) or that the state mentions twice, leaves that are
+    not ``jax.Array``s, and the whole ``state`` on accumulation
+    micro-steps, which return it untouched.  How much of the donation
+    the program could use: gauge ``adapter.tx_update_donated_share``.
     """
 
     def __init__(self, tx: optax.GradientTransformation,
@@ -214,6 +249,7 @@ class DistributedOptimizer:
         self._micro = 0
         self._lock = threading.Lock()
         self._sharded = sharded_update
+        self._tx_program = _donating(tx.update)
         self._leaf_meta = None      # [(name, shape, dtype)] once declared
         self._declared_engine = None
 
@@ -253,9 +289,11 @@ class DistributedOptimizer:
     def update(self, grads, state, params=None):
         """grads: rank-stacked pytree ([R, ...] leaves).
 
-        Returns (updates, new_state).  On accumulation micro-steps the
-        updates are zeros (parameters unchanged), matching the reference's
-        deferral of push_pull until the boundary pass.
+        Returns (updates, new_state) and CONSUMES ``state`` (its arrays
+        are donated to the update's program: see the class).  On
+        accumulation micro-steps the updates are zeros (parameters
+        unchanged) and ``state`` comes back as it was, matching the
+        reference's deferral of push_pull until the boundary pass.
         """
         # the feeds as _enqueue_and_wait reaches them; with no engine,
         # nothing (push_pull below then says "not initialized")
@@ -315,7 +353,34 @@ class DistributedOptimizer:
         with _tracing.phase("bps.adapter.tx_update", tx_feed, cpu=True) as ph:
             if ph.ann is not None:
                 ph.note(step=_api._require().step_stats.current_step)
-            return self._tx.update(reduced, state, params)
+            return self._tx_update(reduced, state, params)
+
+    def _tx_update(self, reduced, state, params):
+        """The optax update over buffers that die in it: ``reduced`` is
+        the engine's (``push_pull`` handed over the only reference) and
+        ``state`` the caller's to give (class docstring)."""
+        leaves, treedef = jax.tree_util.tree_flatten((reduced, state))
+        # XLA refuses a buffer that is donated and passed again in the
+        # same call: an array ``params`` holds too, or one the state
+        # mentions twice, stays the caller's
+        mentions = collections.Counter(map(id, leaves))
+        mentions.update(map(id, jax.tree_util.tree_leaves(params)))
+        give = [isinstance(leaf, jax.Array) and mentions[id(leaf)] == 1
+                for leaf in leaves]
+        donated = [leaf if g else None for leaf, g in zip(leaves, give)]
+        kept = [None if g else leaf for leaf, g in zip(leaves, give)]
+        program = self._tx_program
+        compiled = program._cache_size()
+        out = program(treedef, donated, kept, params)
+        if program._cache_size() != compiled:
+            # a new signature's first call: what of the donation the
+            # program used (a backend without donation, or outputs of
+            # other shapes, leave the inputs alive)
+            consumed = sum(leaf.is_deleted() for leaf in donated
+                           if leaf is not None)
+            _gauges.set("adapter.tx_update_donated_share",
+                        consumed / max(len(jax.tree_util.tree_leaves(out)), 1))
+        return out
 
 
 class DistributedGradientTape:

@@ -146,10 +146,13 @@ def test_fused_mode_close_but_single_dispatch():
 
 
 def test_adapter_parity_and_elastic_shrink():
-    """DistributedOptimizer(sharded_update=True) == unsharded bit-for-
-    bit over 4 steps INCLUDING an 8 -> 4 suspend/resume at step 2: the
-    suspend stash -> declare_update(restore=) re-pad re-shards the
-    owner-resident optimizer state with no lost or doubled update."""
+    """DistributedOptimizer(sharded_update=True) == the optax update run
+    op by op on the reduced gradients, bit-for-bit over 4 steps
+    INCLUDING an 8 -> 4 suspend/resume at step 2: the suspend stash ->
+    declare_update(restore=) re-pad re-shards the owner-resident
+    optimizer state with no lost or doubled update.  The unsharded mode
+    COMPILES the update (one donating program), so it follows the same
+    trajectory to float32 rounding, as any jitted update does."""
     rng = np.random.RandomState(1)
     params = {"w": rng.randn(64, 33).astype(np.float32),
               "b": rng.randn(33).astype(np.float32)}
@@ -157,12 +160,21 @@ def test_adapter_parity_and_elastic_shrink():
         {"w": rng.randn(8, 64, 33).astype(np.float32),
          "b": rng.randn(8, 33).astype(np.float32)} for _ in range(4)]
 
-    def run(sharded, shrink_at=None):
+    class Eager:
+        """push_pull, then the update op by op: what the sharded mode
+        must reproduce to the bit."""
+        tx = optax.adam(1e-2)
+        init = staticmethod(tx.init)
+
+        def update(self, g, s, p):
+            return self.tx.update(bpsjax.push_pull(g, "g"), s, p)
+
+    def run(mode, shrink_at=None):
+        sharded = mode == "sharded"
         bps.init(config=Config(sharded_update=sharded),
                  devices=jax.devices())
-        opt = bpsjax.DistributedOptimizer(optax.adam(1e-2),
-                                          name_prefix="g",
-                                          sharded_update=sharded)
+        opt = Eager() if mode == "eager" else bpsjax.DistributedOptimizer(
+            optax.adam(1e-2), name_prefix="g", sharded_update=sharded)
         p = jax.tree.map(jnp.asarray, params)
         s = opt.init(p)
         if sharded:
@@ -186,10 +198,13 @@ def test_adapter_parity_and_elastic_shrink():
         return out
 
     for shrink_at in (None, 2):
-        ref = run(False, shrink_at)
-        got = run(True, shrink_at)
+        ref = run("eager", shrink_at)
+        got = run("sharded", shrink_at)
+        compiled = run("compiled", shrink_at)
         for k in ref:
             assert np.array_equal(ref[k], got[k]), (shrink_at, k)
+            np.testing.assert_allclose(compiled[k], ref[k], rtol=1e-6,
+                                       atol=1e-7, err_msg=f"{shrink_at} {k}")
 
 
 def test_async_adapter_parity():
