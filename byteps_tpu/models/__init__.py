@@ -2,6 +2,12 @@
 directory (PyTorch MNIST, synthetic ResNet-50, GluonNLP BERT-large —
 SURVEY.md §6 configs)."""
 
+from .glm_lite import (  # noqa: F401
+    GlmLite,
+    GlmLiteConfig,
+    glm_lite_loss,
+    glm_lite_tiny,
+)
 from .llama import (  # noqa: F401
     Llama,
     LlamaConfig,

@@ -659,8 +659,12 @@ def dropless_moe_mlp(x, params, top_k: int,
     reads the weights from ``p`` at ``idx`` (the bias chooses and is not
     weighed: no gradient reaches it; ``p``'s reaches the caller's router
     through the weights); ``params`` needs no ``router``; everything after
-    is the same code.  ``top_k = 1`` is covered like any k (``N`` pair
-    rows, a token's one pair live iff its expert is held).
+    is the same code, at any k: ``p`` need not sum to one (sigmoid scores:
+    ``models/glm_lite.py``, k = 4), and ``renormalize`` then divides the k
+    weights read by their sum + 1e-20 (as that family's code has it; a
+    softmax's own weights keep the bare sum).  ``top_k = 1`` is covered
+    like any k (``N`` pair rows, a token's one pair live iff its expert is
+    held).
 
     The weights are the model's: renormalised over the k chosen BEFORE the
     held experts are selected, so the shares of a layer add up to the
@@ -723,7 +727,10 @@ def dropless_moe_mlp(x, params, top_k: int,
             _, idx = lax.top_k(lax.stop_gradient(chooser), top_k)
             weights = jnp.take_along_axis(probs, idx, axis=-1)
         if renormalize:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            total = jnp.sum(weights, axis=-1, keepdims=True)
+            if routing is not None:
+                total = total + 1e-20
+            weights = weights / total
         pair_expert = idx.reshape(n * top_k)
         counts = jnp.bincount(pair_expert, length=e).astype(jnp.int32)
         aux = e * jnp.sum(counts.astype(jnp.float32) / n

@@ -202,19 +202,15 @@ def test_dp_step_on_one_chip_holds_no_collective(topo):
         "sync": 0, "async": 0}
 
 
-# ------------------------------------------- a whole cell's step: ZAYA1-8B
+# ------------------------------------------------- a whole cell's step
 
-def test_zaya_cell_step_fits_a_v5e(topo, monkeypatch):
-    """``zaya1_8b.fused_1c``'s step as ``benchmarks/paths/fused.py`` builds
-    it (``make_dp_train_step`` over the family's loss, AdamW, per-block
-    ``remat``) at the published widths and 16 384 positions, for ONE
-    described chip: the compiler takes it (the long-form flash kernels at
-    8 heads, the top-1 share's row kernels at 16 chunks of 1 024 rows, the
-    gate kernel's column halves at an expert width of 2048), its memory
-    stays under the chip's 15.75 GiB, and no ``[tokens, vocabulary]``
-    logits array exists in it — the head's blocks of 1 024 rows do.  (The
-    models ask ``on_tpu()`` whether to interpret their kernels; a
-    described chip is no backend, so the test answers for it.)"""
+def _compiled_cell_step(topo, monkeypatch, cell):
+    """``cell``'s step as ``benchmarks/paths/fused.py`` builds it
+    (``make_dp_train_step`` over the family's loss, its optimizer, per-block
+    ``remat``) at the published widths, compiled for ONE described chip ->
+    (compiled, config, traffic).  (The models ask ``on_tpu()`` whether to
+    interpret their kernels; a described chip is no backend, so the test
+    answers for it.)"""
     import os
     import sys
     import byteps_tpu.ops.pallas_kernels as kernels
@@ -226,7 +222,7 @@ def test_zaya_cell_step_fits_a_v5e(topo, monkeypatch):
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "benchmarks"))
     from harness import spec
-    found = spec.resolve(spec.load_benchmark(), "zaya1_8b.fused_1c")
+    found = spec.resolve(spec.load_benchmark(), cell)
     config, traffic = found["config"], found["traffic"]
     family = spec.load_module("families", config["family"]).build(
         config, traffic)
@@ -247,11 +243,27 @@ def test_zaya_cell_step_fits_a_v5e(topo, monkeypatch):
     compiled = make_dp_train_step(comm, family.loss_fn, tx).lower(
         shaped(params, rep), shaped(jax.eval_shape(tx.init, params), rep),
         shaped(batch, NamedSharding(comm.mesh, P(comm.dp_axes)))).compile()
+    return compiled, config, traffic
+
+
+def _used_gib(memory) -> float:
+    return (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.generated_code_size_in_bytes) / 2 ** 30
+
+
+def test_zaya_cell_step_fits_a_v5e(topo, monkeypatch):
+    """``zaya1_8b.fused_1c``'s step at the published widths and 16 384
+    positions: the compiler takes it (the long-form flash kernels at 8
+    heads, the top-1 share's row kernels at 16 chunks of 1 024 rows, the
+    gate kernel's column halves at an expert width of 2048), its memory
+    stays under the chip's 15.75 GiB, and no ``[tokens, vocabulary]``
+    logits array exists in it — the head's blocks of 1 024 rows do."""
+    compiled, config, traffic = _compiled_cell_step(topo, monkeypatch,
+                                                    "zaya1_8b.fused_1c")
     memory = compiled.memory_analysis()
     # weights and two moments: 3 x 696,182,859 x 4 B = 7.78 GiB
     assert 7.7 < memory.argument_size_in_bytes / 2 ** 30 < 7.9
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            + memory.generated_code_size_in_bytes) < 15.75 * 2 ** 30
+    assert _used_gib(memory) < 15.75
     text = compiled.as_text()
     tokens, vocab = traffic["seq_len"], config["vocab_size"]
     assert f"[{tokens},{vocab}]" not in text
@@ -263,4 +275,42 @@ def test_zaya_cell_step_fits_a_v5e(topo, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 4 * 22
     for scope in ("attn_cca/pallas_call", "bps.cca.mix", "bps.zaya.router",
                   "bps.head", "bps.moe.experts"):
+        assert scope in text
+
+
+def test_glm47_flash_cell_step_fits_a_v5e(topo, monkeypatch):
+    """``glm47_flash.fused_1c``'s step at the published widths, 2 x 8 192
+    positions: Mosaic takes the flash kernels at head size 256 in the long
+    form (never lowered at that width before: four spans of 2 048 resident
+    rows) in all six blocks and the top-4 share's row kernels over 65 536
+    pair rows; the program's memory stays under the chip's 15.75 GiB; and
+    neither head's ``[tokens, vocabulary]`` logits exist in it — the
+    blocks of 8 192 rows do."""
+    compiled, config, traffic = _compiled_cell_step(topo, monkeypatch,
+                                                    "glm47_flash.fused_1c")
+    memory = compiled.memory_analysis()
+    # weights and two moments: 3 x 706,518,848 x 4 B = 7.90 GiB
+    assert 7.85 < memory.argument_size_in_bytes / 2 ** 30 < 7.95
+    assert _used_gib(memory) < 15.75
+    text = compiled.as_text()
+    tokens = traffic["seq_len"] * traffic["seqs_per_chip"]
+    vocab = config["vocab_size"]
+    for whole in (f"[{tokens},{vocab}]",
+                  f"[{traffic['seqs_per_chip']},{traffic['seq_len']},{vocab}]"):
+        assert whole not in text
+    assert f"f32[8192,{vocab}]" in text              # a block of a head
+    # a block: flash forward, its recomputation, two backward kernels; a
+    # sparse block besides: twelve grouped matmuls, the spread (+ its
+    # recomputation), the scaled spread, the gate (+ its recomputation)
+    # and its backward
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        6 * 4 + 5 * 18)
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum(c.endswith("/attn_mla/pallas_call") for c in calls) == 24
+    assert sum("/mtp/" in c for c in calls) == 22
+    assert sum("bps.moe.experts" in c for c in calls) == 60
+    for scope in ("bps.mla.latent", "bps.moe.score", "bps.moe.shared",
+                  "bps.head", "/mtp/"):
         assert scope in text
