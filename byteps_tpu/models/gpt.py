@@ -218,29 +218,36 @@ def logit_block_rows(n: int, vocab: int) -> int:
     return rows
 
 
-def _block_logits(xb, w):
-    """float32 logits [rows, V] of one block from ``x.dtype`` operands."""
-    return lax.dot_general(xb, w, (((1,), (1,)), ((), ())),
+def _block_logits(xb, w, kernel: bool = False):
+    """float32 logits [rows, V] of one block from ``x.dtype`` operands:
+    ``w`` a table [V, h], or with ``kernel`` a dense kernel [h, V]."""
+    return lax.dot_general(xb, w, (((1,), (0 if kernel else 1,)), ((), ())),
                            preferred_element_type=jnp.float32)
 
 
-def _nll_blocks(x, table, labels, ignore: int, grads: bool):
+def _nll_blocks(x, head, labels, ignore: int, kernel: bool, grads: bool):
     """The head in blocks of rows.  Per block: float32 logits from
     ``x.dtype`` operands, the log-sum-exp, the label's logit; with
     ``grads`` also the block's cotangent ``softmax - onehot`` (rounded to
     ``x.dtype`` for the two matmuls that consume it at once: the rows'
-    gradient, and the table's, accumulated in float32 over the blocks)."""
+    gradient, and the head matrix's, accumulated in float32 over the
+    blocks).  ``head`` is read as it lies, [V, h] or with ``kernel``
+    [h, V]: the two layouts are the same three matmuls under other
+    dimension numbers, and its gradient comes back in its own layout."""
     n, h = x.shape
-    v = table.shape[0]
+    v = head.shape[1 if kernel else 0]
     rows = logit_block_rows(n, v)
     from ..common.metrics import gauges
     gauges.set("head.logit_block_bytes", float(rows * v * 4))
     gauges.set("head.logit_blocks", float(n // rows))
-    def one_block(g_table, block):
+    def one_block(g_head, block):
         xb, lb = block
         valid = lb != ignore
         safe = jnp.where(valid, lb, 0)
-        logits = _block_logits(xb, w)
+        # two arguments in the table's layout: the benchmarks' breaks
+        # patch ``_block_logits`` with a function of (xb, w)
+        logits = (_block_logits(xb, w, kernel=True) if kernel
+                  else _block_logits(xb, w))
         top = jnp.max(logits, axis=-1, keepdims=True)
         e = jnp.exp(logits - top)
         total = jnp.sum(e, axis=-1, keepdims=True)
@@ -249,33 +256,40 @@ def _nll_blocks(x, table, labels, ignore: int, grads: bool):
         m = valid.astype(jnp.float32)
         out = (-(ll * m).sum(), m.sum())
         if not grads:
-            return g_table, out
+            return g_head, out
         hit = lax.broadcasted_iota(jnp.int32, logits.shape, 1) == safe[:, None]
         d = ((e / total - hit.astype(jnp.float32)) * m[:, None]
              ).astype(x.dtype)                                  # [rows, V]
-        g_x = jnp.dot(d, w, preferred_element_type=jnp.float32)
-        g_table = g_table + lax.dot_general(
-            d, xb, (((0,), (0,)), ((), ())),
+        g_x = lax.dot_general(d, w, (((1,), (1 if kernel else 0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        g_head = g_head + lax.dot_general(
+            *((xb, d) if kernel else (d, xb)), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return g_table, out + (g_x.astype(x.dtype),)
+        return g_head, out + (g_x.astype(x.dtype),)
 
     with jax.named_scope("bps.head"):
-        w = table.astype(x.dtype)                               # [V, h]
-        g_table, out = lax.scan(
-            one_block, jnp.zeros((v, h) if grads else (), jnp.float32),
+        w = head.astype(x.dtype)
+        g_head, out = lax.scan(
+            one_block, jnp.zeros(head.shape if grads else (), jnp.float32),
             (x.reshape(n // rows, rows, h), labels.reshape(n // rows, rows)))
     nll, count = out[0].sum(), out[1].sum()
     if not grads:
         return nll, count
-    return nll, count, out[2].reshape(n, h), g_table
+    return nll, count, out[2].reshape(n, h), g_head
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def blocked_token_nll(x, table, labels, ignore: int = -1):
-    """:func:`token_nll` of the logits ``x @ table.T`` without ever holding
-    them: ``x`` [N, h] the final hidden rows, ``table`` [V, h] the (tied)
-    embedding, ``labels`` [N] -> (sum of per-token NLL over valid
-    positions, valid-token count).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def blocked_token_nll(x, table, labels, ignore: int = -1,
+                      kernel: bool = False):
+    """:func:`token_nll` of the head's logits without ever holding them:
+    ``x`` [N, h] the final hidden rows, ``table`` the head matrix as its
+    parameter lies — [V, h], a (tied) embedding (logits ``x @ table.T``),
+    or with ``kernel=True`` [h, V], the kernel of an untied ``nn.Dense``
+    head (logits ``x @ table``) — ``labels`` [N] -> (sum of per-token NLL
+    over valid positions, valid-token count).  The layout is said, never
+    guessed from the shapes (``V == h`` is legal), and nothing is
+    transposed for it: the matrix, its gradient and so its optimizer
+    moments keep the parameter's layout.
 
     For a vocabulary of 100 k rows and more the float32 logits of a step
     are gigabytes (16 384 tokens x 131 136 rows: 8.6 GB, and as much again
@@ -287,18 +301,18 @@ def blocked_token_nll(x, table, labels, ignore: int = -1):
     — each block's cotangent is made and consumed inside the block — and
     the backward pass only scales them by the incoming cotangent, so the
     head costs three matmuls, not the four of recomputing a block's logits
-    in the backward pass.  The table's gradient [V, h] float32 is the one
+    in the backward pass.  The matrix's float32 gradient is the one
     full-size array, and the step has to hold it anyway."""
-    return _nll_blocks(x, table, labels, ignore, grads=False)
+    return _nll_blocks(x, table, labels, ignore, kernel, grads=False)
 
 
-def _blocked_token_nll_fwd(x, table, labels, ignore):
-    nll, count, g_x, g_table = _nll_blocks(x, table, labels, ignore,
+def _blocked_token_nll_fwd(x, table, labels, ignore, kernel):
+    nll, count, g_x, g_table = _nll_blocks(x, table, labels, ignore, kernel,
                                            grads=True)
     return (nll, count), (g_x, g_table)
 
 
-def _blocked_token_nll_bwd(ignore, res, g):
+def _blocked_token_nll_bwd(ignore, kernel, res, g):
     g_x, g_table = res
     g_nll = g[0]                         # the count has no gradient
     return ((g_x.astype(jnp.float32) * g_nll).astype(g_x.dtype),
@@ -308,7 +322,8 @@ def _blocked_token_nll_bwd(ignore, res, g):
 blocked_token_nll.defvjp(_blocked_token_nll_fwd, _blocked_token_nll_bwd)
 
 
-def blocked_lm_loss(x, table, labels, ignore: int = -1):
+def blocked_lm_loss(x, table, labels, ignore: int = -1,
+                    kernel: bool = False):
     """:func:`lm_loss` through :func:`blocked_token_nll`."""
-    s, c = blocked_token_nll(x, table, labels, ignore)
+    s, c = blocked_token_nll(x, table, labels, ignore, kernel)
     return s / jnp.maximum(c, 1.0)

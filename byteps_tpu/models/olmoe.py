@@ -17,6 +17,12 @@ source's config has no key for, and this file fixes as HF
   ``router_aux_loss_coef`` (HF's default 0.01) and the router z-loss x
   ``router_z_loss_coef`` (the paper's 0.001).
 
+The head is untied: ``lm_head/kernel`` [h, V].  A plain ``apply`` returns
+float32 logits [B, T, V]; the training loss never holds them — it takes
+the normed hidden rows (``logits=False``) and the kernel as it lies
+through ``models/gpt.py`` :func:`blocked_token_nll` (``kernel=True``),
+one block of rows' float32 logits at a time.
+
 bf16 compute over float32 parameters; norms and the router in float32.
 The expert layer is ``parallel/expert.py`` :func:`dropless_moe_mlp` with
 every expert held (``held=None``: each data-parallel replica has all 64;
@@ -37,7 +43,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..parallel.expert import dropless_moe_mlp
-from .gpt import lm_loss
+from .gpt import blocked_lm_loss
 from .llama import AttnFn, RMSNorm, apply_rope, rope_frequencies
 
 __all__ = ["OlmoeConfig", "Olmoe", "olmoe_tiny", "olmoe_loss",
@@ -165,13 +171,16 @@ class OlmoeBlock(nn.Module):
 
 class Olmoe(nn.Module):
     """Decoder-only OLMoE: ``wte`` -> blocks -> RMSNorm -> untied
-    ``lm_head``; float32 logits."""
+    ``lm_head``; float32 logits [B, T, V] — or, with ``logits=False``,
+    the normed hidden rows [B, T, h] the head would read
+    (:func:`olmoe_loss` computes the head in blocks from them; ``init``
+    makes ``lm_head/kernel`` either way)."""
 
     cfg: OlmoeConfig
     attn_fn: Optional[AttnFn] = None
 
     @nn.compact
-    def __call__(self, input_ids, positions=None):
+    def __call__(self, input_ids, positions=None, *, logits: bool = True):
         cfg = self.cfg
         b, t = input_ids.shape
         if positions is None:
@@ -184,9 +193,11 @@ class Olmoe(nn.Module):
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, self.attn_fn, name=f"h{i}")(x, positions)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                        name="lm_head")
+        if not logits and not self.is_initializing():
+            return x
+        return head(x).astype(jnp.float32)
 
 
 def _sown(model: "Olmoe", tree, key: str):
@@ -202,13 +213,18 @@ def olmoe_loss(model: Olmoe, params, batch):
     layers of the router z-loss.  ``batch``: ``input_ids`` [B, T] and
     ``labels`` (already shifted; -1 = ignored).  Both router terms are
     of THIS token shard (``parallel/moe_lm.py`` documents the same for
-    the switch path)."""
+    the switch path).  The cross-entropy is :func:`~byteps_tpu.models.gpt.
+    blocked_lm_loss` of the final hidden rows and ``lm_head``'s kernel
+    [h, V] as the parameter lies: no [tokens, vocabulary] logits."""
     cfg = model.cfg
-    logits, sown = model.apply(params, batch["input_ids"],
-                               mutable=["moe_aux"])
+    x, sown = model.apply(params, batch["input_ids"], logits=False,
+                          mutable=["moe_aux"])
     aux = sum(_sown(model, sown["moe_aux"], "aux"))
     z = sum(_sown(model, sown["moe_aux"], "z"))
-    return (lm_loss(logits, batch["labels"])
+    b, t, h = x.shape
+    return (blocked_lm_loss(x.reshape(b * t, h),
+                            params["params"]["lm_head"]["kernel"],
+                            batch["labels"].reshape(b * t), kernel=True)
             + cfg.router_aux_loss_coef * aux + cfg.router_z_loss_coef * z)
 
 

@@ -33,3 +33,19 @@ def kernel_equations(fn, *args) -> list:
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return found
+
+
+def scan_bodies(fn, *args) -> list:
+    """The body jaxpr of each ``scan`` ``fn(*args)`` traces to, in order,
+    nested ones included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                found.append(eqn.params["jaxpr"].jaxpr)
+            for inner in _inner_jaxprs(eqn):
+                walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found

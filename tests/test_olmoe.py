@@ -23,10 +23,13 @@ import numpy as np
 import optax
 import pytest
 
+from . import jaxpr_count
 from . import olmoe_reference as ref
 from byteps_tpu.comm.mesh import CommContext, _build_mesh
-from byteps_tpu.models.olmoe import (Olmoe, OlmoeConfig, expert_counts,
-                                     olmoe_loss, olmoe_tiny)
+from byteps_tpu.models import gpt
+from byteps_tpu.models.gpt import lm_loss
+from byteps_tpu.models.olmoe import (Olmoe, OlmoeConfig, _sown,
+                                     expert_counts, olmoe_loss, olmoe_tiny)
 from byteps_tpu.parallel import make_dp_train_step, replicate
 from byteps_tpu.parallel.expert import dropless_moe_mlp, publish_moe_stats
 
@@ -188,6 +191,107 @@ def test_model_logits_and_counts_match_the_reference():
     counts = expert_counts(model, params, batch["input_ids"])
     assert counts.shape == (cfg.num_hidden_layers, cfg.num_experts)
     np.testing.assert_array_equal(counts, want_counts)
+
+
+# ----------------------------------------- the loss through the blocked head
+
+def loss_on_whole_logits(model, params, batch):
+    """``olmoe_loss`` as it was before the head went through
+    ``blocked_token_nll``: ``lm_loss`` on the [B, T, V] float32 logits of
+    the default ``apply``, plus the two router terms."""
+    cfg = model.cfg
+    logits, sown = model.apply(params, batch["input_ids"],
+                               mutable=["moe_aux"])
+    return (lm_loss(logits, batch["labels"])
+            + cfg.router_aux_loss_coef * sum(_sown(model, sown["moe_aux"],
+                                                   "aux"))
+            + cfg.router_z_loss_coef * sum(_sown(model, sown["moe_aux"],
+                                                 "z")))
+
+
+@pytest.mark.parametrize("labels", ["shifted", "some_ignored",
+                                    "all_ignored"])
+@pytest.mark.parametrize("blocks", [1, 4])
+def test_loss_through_the_blocked_head_is_lm_loss_on_whole_logits(
+        monkeypatch, labels, blocks):
+    """Value and every parameter's gradient — ``lm_head/kernel`` [h, V] in
+    its own layout, and everything below the hidden rows — in one block
+    and in four."""
+    import byteps_tpu as bps
+    model, params, batch = model_and_batch()
+    v = model.cfg.vocab_size
+    monkeypatch.setattr(gpt, "_LOGIT_BLOCK_BYTES", 32 // blocks * v * 4)
+    if labels == "some_ignored":
+        keep = jax.random.uniform(jax.random.PRNGKey(3), (2, 16)) < 0.6
+        batch["labels"] = jnp.where(keep, batch["labels"], -1)
+    elif labels == "all_ignored":
+        batch["labels"] = jnp.full((2, 16), -1)
+    loss, grads = jax.jit(jax.value_and_grad(
+        functools.partial(olmoe_loss, model)))(params, batch)
+    want, want_grads = jax.value_and_grad(
+        functools.partial(loss_on_whole_logits, model))(params, batch)
+    assert_close(loss, want)
+    assert_trees_close(grads, want_grads)
+    kernel = grads["params"]["lm_head"]["kernel"]
+    assert kernel.shape == (32, v) and kernel.dtype == jnp.float32
+    assert bool(jnp.any(kernel != 0)) == (labels != "all_ignored")
+    gauges = bps.metrics_snapshot()["gauges"]
+    assert gauges["head.logit_blocks"] == blocks
+    assert gauges["head.logit_block_bytes"] == 32 // blocks * v * 4
+
+
+def test_the_loss_holds_no_whole_logits():
+    """Nothing [tokens, vocabulary]-sized outside the head's scan, forward
+    or backward; the default ``apply`` still makes them."""
+    model, params, batch = model_and_batch(seqs=3)   # 48 rows: not h
+    n, v = 48, model.cfg.vocab_size
+
+    def sizes(jaxpr, out, inside=False):
+        for eqn in jaxpr.eqns:
+            if not inside:
+                out.update(var.aval.shape for var in eqn.outvars)
+            for inner in jaxpr_count._inner_jaxprs(eqn):
+                sizes(inner, out, inside or eqn.primitive.name == "scan")
+        return out
+
+    loss = functools.partial(olmoe_loss, model)
+    seen = sizes(jax.make_jaxpr(jax.value_and_grad(loss))(params, batch).jaxpr,
+                 set())
+    assert not [s for s in seen if s[-1:] == (v,) and np.prod(s) == n * v]
+    seen = sizes(jax.make_jaxpr(
+        lambda p: model.apply(p, batch["input_ids"]))(params).jaxpr, set())
+    assert (3, 16, v) in seen
+
+
+def test_default_apply_and_init_are_what_they_were():
+    """float32 logits [B, T, V] unless asked for the hidden rows, and the
+    parameter tree of ``init`` key for key and shape for shape (the
+    checkpoints' and the benchmark family's), however ``init`` is
+    called."""
+    model, params, batch = model_and_batch()
+    cfg, ids = model.cfg, batch["input_ids"]
+    logits = model.apply(params, ids)
+    assert logits.shape == (2, 16, cfg.vocab_size)
+    assert logits.dtype == jnp.float32
+    rows = model.apply(params, ids, logits=False)
+    assert rows.shape == (2, 16, cfg.hidden_size)
+    with jax.default_matmul_precision("highest"):
+        assert_close(rows @ params["params"]["lm_head"]["kernel"], logits)
+    h, f, e = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    layer = {"attn_norm": {"scale": (h,)}, "moe_norm": {"scale": (h,)},
+             "attn": {**{f"{p}_proj": {"kernel": (h, h)} for p in "qkvo"},
+                      "q_norm": {"scale": (h,)}, "k_norm": {"scale": (h,)}},
+             "moe": {"router": (h, e), "gate": (e, h, f), "up": (e, h, f),
+                     "down": (e, f, h)}}
+    want = {"params": {"wte": {"embedding": (cfg.vocab_size, h)},
+                       "h0": layer, "h1": layer,
+                       "norm_f": {"scale": (h,)},
+                       "lm_head": {"kernel": (h, cfg.vocab_size)}}}
+    for kw in ({}, {"logits": False}):
+        made = model.init(jax.random.PRNGKey(1), ids, **kw)
+        assert jax.tree.map(lambda a: a.shape, made) == want
+        assert {a.dtype for a in jax.tree.leaves(made)} == {
+            jnp.dtype("float32")}
 
 
 def test_config_refuses_grouped_kv_heads():

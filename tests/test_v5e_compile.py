@@ -314,3 +314,34 @@ def test_glm47_flash_cell_step_fits_a_v5e(topo, monkeypatch):
     for scope in ("bps.mla.latent", "bps.moe.score", "bps.moe.shared",
                   "bps.head", "/mtp/"):
         assert scope in text
+
+
+def test_olmoe_cell_step_holds_no_whole_logits(topo, monkeypatch):
+    """``olmoe_1b_7b.fused_1c``'s step at the published widths, 4 x 4 096
+    positions: the untied head goes through ``blocked_token_nll`` with
+    ``lm_head``'s kernel read as it lies, [h, V].  No ``[tokens,
+    vocabulary]`` logits exist in the program — four blocks of 4 096 rows
+    do — which is 1.8 GiB of the 14.73 the step took on whole logits; and
+    nothing copies or transposes a kernel-sized array: handing the head
+    ``kernel.T`` costs six such copies a step (the kernel, ``mu`` and
+    ``nu`` to [V, h] and back, 4.9 GB moved) that one fusion each would
+    otherwise read in place."""
+    compiled, config, traffic = _compiled_cell_step(topo, monkeypatch,
+                                                    "olmoe_1b_7b.fused_1c")
+    memory = compiled.memory_analysis()
+    # weights and two moments: 3 x 625,674,240 x 4 B = 6.99 GiB
+    assert 6.95 < memory.argument_size_in_bytes / 2 ** 30 < 7.05
+    assert _used_gib(memory) < 13.2
+    text = compiled.as_text()
+    seqs, seq_len = traffic["seqs_per_chip"], traffic["seq_len"]
+    hidden, vocab = config["hidden_size"], config["vocab_size"]
+    for whole in (f"[{seqs * seq_len},{vocab}]", f"[{seqs},{seq_len},{vocab}]"):
+        assert whole not in text
+    assert f"f32[4096,{vocab}]" in text              # a block of the head
+    assert "bps.head" in text
+    # flash forward and backward, nine grouped matmuls
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 9
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= \w+\[({hidden},{vocab}|{vocab},{hidden})\]"
+                          r"\S* (copy|transpose)\(", line)]
+    assert not moved, moved

@@ -27,6 +27,7 @@ import numpy as np
 import optax
 import pytest
 
+from . import jaxpr_count
 from . import zaya_reference as ref
 from byteps_tpu.comm.mesh import CommContext, _build_mesh
 from byteps_tpu.models import gpt
@@ -521,57 +522,142 @@ def head_inputs(n=128, h=32, v=300, seed=0):
             jax.random.randint(k[2], (n,), -1, v))
 
 
+# the head matrix as its parameter lies: a (tied) table [V, h], or the
+# kernel [h, V] of an untied ``nn.Dense`` head (``kernel=True``)
+LAYOUTS = pytest.mark.parametrize("kernel", [False, True],
+                                  ids=["table_Vh", "kernel_hV"])
+
+
+def lay(table, kernel):
+    """``table`` [V, h] laid out as the head is told to read it."""
+    return table.T if kernel else table
+
+
+@LAYOUTS
 @pytest.mark.parametrize("block_bytes,blocks", [(32 * 300 * 4, 4),
                                                 (2 ** 30, 1)])
 def test_blocked_head_equals_lm_loss_on_whole_logits(monkeypatch,
-                                                     block_bytes, blocks):
+                                                     block_bytes, blocks,
+                                                     kernel):
     """Values and both gradients, to float32 rounding, whatever the
     cotangent that comes in (the forward pass forms both gradients; the
-    backward pass scales them)."""
+    backward pass scales them); in the kernel's layout also against the
+    same matrix transposed through the table's."""
     import byteps_tpu as bps
     monkeypatch.setattr(gpt, "_LOGIT_BLOCK_BYTES", block_bytes)
     x, table, labels = head_inputs()
     assert 128 // logit_block_rows(128, 300) == blocks
 
-    def blocked(x, table):
-        return 3.0 * blocked_lm_loss(x, table, labels)
+    def blocked(x, w, kernel=kernel):
+        return 3.0 * blocked_lm_loss(x, w, labels, kernel=kernel)
 
     def whole(x, table):
         with jax.default_matmul_precision("highest"):
             return 3.0 * lm_loss(x @ table.T, labels)
 
-    got, (g_x, g_t) = jax.jit(jax.value_and_grad(blocked, (0, 1)))(x, table)
+    got, (g_x, g_w) = jax.jit(jax.value_and_grad(blocked, (0, 1)))(
+        x, lay(table, kernel))
     want, (w_x, w_t) = jax.value_and_grad(whole, (0, 1))(x, table)
+    assert g_w.shape == ((32, 300) if kernel else (300, 32))
     assert_close(got, want)
     assert_close(g_x, w_x)
-    assert_close(g_t, w_t)
+    assert_close(g_w, lay(w_t, kernel))
     assert bps.metrics_snapshot()["gauges"]["head.logit_blocks"] == blocks
     assert bps.metrics_snapshot()["gauges"]["head.logit_block_bytes"] == (
         128 // blocks * 300 * 4)
+    if kernel:
+        t_got, (t_x, t_t) = jax.jit(jax.value_and_grad(
+            functools.partial(blocked, kernel=False), (0, 1)))(x, table)
+        assert_close(got, t_got)
+        assert_close(g_x, t_x)
+        assert_close(g_w, t_t.T)
     # without differentiation: the same sums, no gradient formed
-    nll, count = jax.jit(blocked_token_nll)(x, table, labels)
+    nll, count = jax.jit(functools.partial(blocked_token_nll, kernel=kernel))(
+        x, lay(table, kernel), labels)
     with jax.default_matmul_precision("highest"):
         want_nll, want_count = token_nll(x @ table.T, labels)
     assert_close(nll, want_nll)
     assert float(count) == float(want_count) == float((labels >= 0).sum())
 
 
-def test_blocked_head_with_the_table_tied():
+@LAYOUTS
+def test_blocked_head_with_the_table_tied(kernel):
     """One leaf receives the gather's gradient and the head's."""
     _, table, labels = head_inputs(v=64)
     ids = jnp.clip(labels, 0)
 
-    def tied(head):
-        def loss(table):
-            x = jnp.tanh(table[ids])                 # the gather's side
-            return head(x, table)
-        return jax.value_and_grad(loss)(table)
+    def tied(head, kernel=False):
+        def loss(w):
+            rows = w.T if kernel else w
+            x = jnp.tanh(rows[ids])                  # the gather's side
+            return head(x, w)
+        return jax.value_and_grad(loss)(lay(table, kernel))
 
-    got, g = tied(lambda x, t: blocked_lm_loss(x, t, labels))
+    got, g = tied(lambda x, w: blocked_lm_loss(x, w, labels, kernel=kernel),
+                  kernel)
     with jax.default_matmul_precision("highest"):
         want, w = tied(lambda x, t: lm_loss(x @ t.T, labels))
     assert_close(got, want)
-    assert_close(g, w)
+    assert_close(g, lay(w, kernel))
+
+
+def test_the_layout_is_said_not_read_from_the_shapes():
+    """``V == h`` is legal: a square matrix is a table unless told."""
+    x, table, labels = head_inputs(h=32, v=32)
+    with jax.default_matmul_precision("highest"):
+        as_table = lm_loss(x @ table.T, labels)
+        as_kernel = lm_loss(x @ table, labels)
+    assert abs(float(as_table) - float(as_kernel)) > 1e-2
+    assert_close(blocked_lm_loss(x, table, labels), as_table)
+    assert_close(blocked_lm_loss(x, table, labels, kernel=True), as_kernel)
+    g = jax.grad(lambda w: blocked_lm_loss(x, w, labels, kernel=True))(table)
+    with jax.default_matmul_precision("highest"):
+        assert_close(g, jax.grad(lambda w: lm_loss(x @ w, labels))(table))
+
+
+def _head_scan(kernel, grads, dtype=jnp.float32):
+    """The blocked head's ``scan`` body at [128, 32] x 300 (one block)."""
+    x, table, labels = head_inputs()
+
+    def f(x, w):
+        return blocked_lm_loss(x, w, labels, kernel=kernel)
+
+    body, = jaxpr_count.scan_bodies(
+        jax.value_and_grad(f, (0, 1)) if grads else f,
+        x.astype(dtype), lay(table, kernel))
+    return body
+
+
+def _matmuls(body):
+    return [(eqn.params["dimension_numbers"][0], eqn.outvars[0].aval.shape,
+             str(eqn.outvars[0].aval.dtype))
+            for eqn in body.eqns if eqn.primitive.name == "dot_general"]
+
+
+def test_the_table_layout_traces_to_the_program_it_was():
+    """ZAYA's and GLM's heads do not move: the [V, h] form's scan body is
+    what it was before the head learnt the kernel's layout, equation for
+    equation (42 / 44 with gradients in float32 / bfloat16, 31 without)
+    and matmul for matmul; the [h, V] form is the same body under other
+    dimension numbers."""
+    for kernel in (False, True):
+        assert jaxpr_count.equations(_head_scan(kernel, True)) == 42
+        assert jaxpr_count.equations(
+            _head_scan(kernel, True, jnp.bfloat16)) == 44
+        assert jaxpr_count.equations(_head_scan(kernel, False)) == 31
+    # logits, the rows' gradient, the matrix's gradient
+    assert _matmuls(_head_scan(False, True)) == [
+        (((1,), (1,)), (128, 300), "float32"),
+        (((1,), (0,)), (128, 32), "float32"),
+        (((0,), (0,)), (300, 32), "float32")]
+    assert _matmuls(_head_scan(True, True)) == [
+        (((1,), (0,)), (128, 300), "float32"),
+        (((1,), (1,)), (128, 32), "float32"),
+        (((0,), (0,)), (32, 300), "float32")]
+    # nothing is transposed for either layout
+    for kernel in (False, True):
+        assert not [eqn for eqn in _head_scan(kernel, True).eqns
+                    if eqn.primitive.name == "transpose"]
 
 
 def test_block_rows_follow_the_shapes():
