@@ -21,6 +21,12 @@ from .mellum import (  # noqa: F401
     mellum_tiny,
 )
 from .mlp import MLP, mnist_mlp  # noqa: F401
+from .nemotron_h import (  # noqa: F401
+    NemotronH,
+    NemotronHConfig,
+    nemotron_h_tiny,
+    nemotron_loss,
+)
 from .olmoe import (  # noqa: F401
     Olmoe,
     OlmoeConfig,

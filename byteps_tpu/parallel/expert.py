@@ -26,13 +26,16 @@ tests/test_expert_parallel.py.  Routing semantics are shard-local
 mesh size — only the placement does.
 
 **Dropless top-k (the experts held here)** — :func:`dropless_moe_mlp`,
-what ``models/olmoe.py``, ``models/mellum.py`` and ``models/zaya.py``
-build: softmax over all experts (the layer's own linear router, or
+what ``models/olmoe.py``, ``models/mellum.py``, ``models/zaya.py``,
+``models/glm_lite.py`` and ``models/nemotron_h.py`` build: softmax over
+all experts (the layer's own linear router, or
 probabilities and a selection bias handed in from outside: ``routing=``),
 the k largest kept (their weights as they are, or renormalised
-to sum to one), no capacity and no dropped token, bias-free SiLU-gated
-experts.  The token–expert pairs are sorted by expert and the three
-expert matmuls run as grouped matmuls over the ragged groups
+to sum to one), no capacity and no dropped token, bias-free experts —
+SiLU-gated (three matrices) or, where ``params`` holds no ``gate``,
+ungated with ``relu(.)^2`` (two).  The token–expert pairs are sorted by
+expert and the expert matmuls run as grouped matmuls over the ragged
+groups
 (``_grouped_matmul``: JAX's Pallas megablox kernels; the interpreter off
 the TPU), so the work is k experts a token and no tensor grows with
 ``E x C``.  By default every expert is local (``models/olmoe.py``: each
@@ -582,35 +585,76 @@ def _gate_kernel(words, gate, up, *refs, sub, backward):
         lax.fori_loop(0, gate.shape[0] // sub, piece, 0)
 
 
+def _relu2_kernel(words, x, *refs, sub, backward):
+    """One chunk of ``relu(x)^2`` (float32, rounded once) or, backward, of
+    its gradient FROM ITS RESULT: ``x`` is then ``act = relu(up)^2`` and
+    the gradient ``2 sqrt(act) g`` (``sqrt(act) = relu(up)``), so that
+    ``up`` is no residual; zeros where the schedule has no live row."""
+    c = pl.program_id(0)
+    live = (c >= words[2]) & (c < words[3])
+    out = refs[-1]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        out[...] = jnp.zeros_like(out)
+
+    @pl.when(live)
+    def _():
+        def piece(i, carry):
+            at = pl.ds(pl.multiple_of(i * sub, sub), sub)
+            a = x[at, :].astype(jnp.float32)
+            if backward:
+                a = 2.0 * jnp.sqrt(a) * refs[0][at, :].astype(jnp.float32)
+            else:
+                a = jnp.square(jnp.maximum(a, 0.0))
+            out[at, :] = a.astype(out.dtype)
+            return carry
+        lax.fori_loop(0, x.shape[0] // sub, piece, 0)
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2),
-                   static_argnames=("backward",))
-def _gate_call(sched, chunk, interpret, *rows, backward):
+                   static_argnames=("backward", "gated"))
+def _gate_call(sched, chunk, interpret, *rows, backward, gated=True):
     m, f = rows[0].shape
     n_chunks = m // chunk
     shape = jax.ShapeDtypeStruct((m, f), rows[0].dtype)
+    pair = backward and gated           # two gradients: the gate's, up's
     # columns a grid step: all of them where every operand's two buffers
     # fit three quarters of the VMEM asked for (an expert width of 896:
-    # 17.5 MiB), else halves of them (2048 backward: 40 MiB -> 20)
-    width, blocks = f, len(rows) + (2 if backward else 1)
-    while (2 * blocks * chunk * width * rows[0].dtype.itemsize
-           > 3 * _ROW_VMEM_BYTES // 4 and width % 256 == 0):
+    # 17.5 MiB), else halves of them (2048 backward: 40 MiB -> 20), else
+    # the largest share of them in whole lane tiles (2688 -> 896)
+    width, blocks = f, len(rows) + (2 if pair else 1)
+
+    def fits(width):
+        return (2 * blocks * chunk * width * rows[0].dtype.itemsize
+                <= 3 * _ROW_VMEM_BYTES // 4)
+
+    while not fits(width) and width % 256 == 0:
         width //= 2
+    if not fits(width):
+        width = max([w for w in range(128, width, 128)
+                     if f % w == 0 and fits(w)], default=width)
     live = _live_chunk(n_chunks)
     out = pl.BlockSpec((chunk, width), lambda c, j, words: (c, j))
+    name = ("bps_moe_gate" if gated else "bps_moe_act") + (
+        "_bwd" if backward else "")
     return pl.pallas_call(
-        functools.partial(_gate_kernel, sub=math.gcd(chunk, 256),
-                          backward=backward),
-        out_shape=(shape, shape) if backward else shape,
+        functools.partial(_gate_kernel if gated else _relu2_kernel,
+                          sub=math.gcd(chunk, 256), backward=backward),
+        out_shape=(shape, shape) if pair else shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n_chunks, f // width),
             in_specs=[pl.BlockSpec(
                 (chunk, width),
                 lambda c, j, words: (live(c, words)[0], j))] * len(rows),
-            out_specs=(out, out) if backward else out),
+            out_specs=(out, out) if pair else out),
+        # ungated, backward: the gradient is written over the incoming one,
+        # which nothing reads again (forward, writing over ``up`` costs the
+        # compiled step 0.9 GiB more: compile-only, PR 39)
+        input_output_aliases={len(rows): 0} if backward and not gated else {},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_ROW_VMEM_BYTES),
-        name="bps_moe_gate_bwd" if backward else "bps_moe_gate",
-        interpret=interpret)(_sched_words(sched), *rows)
+        name=name, interpret=interpret)(_sched_words(sched), *rows)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -634,6 +678,31 @@ def _silu_gate_rows_bwd(chunk, interpret, res, g):
 _silu_gate_rows.defvjp(_silu_gate_rows_fwd, _silu_gate_rows_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _relu2_rows(up, sched, chunk, interpret):
+    """``relu(up)^2`` over the live chunks, zero elsewhere: the activation
+    of experts WITHOUT a gate (two matrices an expert).  Its backward
+    reads the RESULT (``2 sqrt(act) g``, written over ``g``):
+    the one ``[N k, f]`` residual is the one the ``down`` matmul keeps
+    anyway (at 22 pairs a token such an array is 0.9 GiB a block)."""
+    return _gate_call(sched, chunk, interpret, up, backward=False,
+                      gated=False)
+
+
+def _relu2_rows_fwd(up, sched, chunk, interpret):
+    act = _relu2_rows(up, sched, chunk, interpret)
+    return act, (act, sched)
+
+
+def _relu2_rows_bwd(chunk, interpret, res, g):
+    act, sched = res
+    return (_gate_call(sched, chunk, interpret, act, g, backward=True,
+                       gated=False), None)
+
+
+_relu2_rows.defvjp(_relu2_rows_fwd, _relu2_rows_bwd)
+
+
 def dropless_moe_mlp(x, params, top_k: int,
                      interpret: Optional[bool] = None, *,
                      held: Optional[Tuple[int, int]] = None,
@@ -642,7 +711,8 @@ def dropless_moe_mlp(x, params, top_k: int,
     """Dropless top-k MoE MLP over a token shard ``x`` [N, h].
 
     params: ``{"router": [h, E] float32, "gate": [G, h, f], "up":
-    [G, h, f], "down": [G, f, h]}``.  No biases.  ``held=None``: the
+    [G, h, f], "down": [G, f, h]}`` (``gate`` absent: ungated experts,
+    below).  No biases.  ``held=None``: the
     stacks are all E experts (``G = E``).  ``held=(first, G)``: they are
     experts ``first .. first + G - 1`` of the E the router knows, one
     chip's share of an expert-parallel layer (module docstring).
@@ -665,6 +735,15 @@ def dropless_moe_mlp(x, params, top_k: int,
     softmax's own weights keep the bare sum).  ``top_k = 1`` is covered
     like any k (``N`` pair rows, a token's one pair live iff its expert is
     held).
+
+    Experts WITHOUT a gate: where ``params`` holds ``up`` and ``down`` and
+    no ``gate``, an expert is two matrices, ``down_e(relu(up_e x)^2)``
+    (``models/nemotron_h.py``: two grouped matmuls a pass instead of
+    three; with ``held`` the activation is a row kernel over the live
+    chunks, ``bps_moe_act`` / ``bps_moe_act_bwd`` under the scope
+    ``bps.moe.act``, as the gate product is under ``bps.moe.gate``).  What
+    ``params`` holds says which: the squared ReLU is the one ungated
+    activation computed here, so there is no argument to name it.
 
     The weights are the model's: renormalised over the k chosen BEFORE the
     held experts are selected, so the shares of a layer add up to the
@@ -701,13 +780,14 @@ def dropless_moe_mlp(x, params, top_k: int,
         interpret = not on_tpu()
     n, h = x.shape
     e = (params["router"] if routing is None else routing[0]).shape[-1]
+    gated = "gate" in params
     first = None
     if held is not None:
         start, count = held
         if not (0 <= start and 1 <= count and start + count <= e
-                and params["gate"].shape[0] == count):
+                and params["up"].shape[0] == count):
             raise ValueError(
-                f"held={held}: the stacks carry {params['gate'].shape[0]} "
+                f"held={held}: the stacks carry {params['up'].shape[0]} "
                 f"experts and the router knows {e}")
         first = jnp.asarray(start, jnp.int32)
     with jax.named_scope("bps.moe.route"):
@@ -761,17 +841,23 @@ def dropless_moe_mlp(x, params, top_k: int,
                                 interpret)
     with jax.named_scope("bps.moe.experts"):
         dt = x.dtype
-        gate = _grouped_matmul(xs, params["gate"].astype(dt), counts,
-                               interpret, first)
+        if gated:
+            gate = _grouped_matmul(xs, params["gate"].astype(dt), counts,
+                                   interpret, first)
         up = _grouped_matmul(xs, params["up"].astype(dt), counts, interpret,
                              first)
         if held is None:
-            act = jax.nn.silu(gate) * up
+            act = (jax.nn.silu(gate) * up if gated
+                   else jnp.square(jax.nn.relu(up)))
     if held is not None:
         # a kernel of its own scope: the readers of the grouped matmuls'
         # time take every ``pallas_call`` under ``bps.moe.experts``
-        with jax.named_scope("bps.moe.gate"):
-            act = _silu_gate_rows(gate, up, sched, chunk, interpret)
+        if gated:
+            with jax.named_scope("bps.moe.gate"):
+                act = _silu_gate_rows(gate, up, sched, chunk, interpret)
+        else:
+            with jax.named_scope("bps.moe.act"):
+                act = _relu2_rows(up, sched, chunk, interpret)
     with jax.named_scope("bps.moe.experts"):
         ys = _grouped_matmul(act, params["down"].astype(dt), counts,
                              interpret, first)

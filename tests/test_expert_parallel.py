@@ -7,6 +7,8 @@ experts both update), capacity-drop semantics, and gradient parity of
 the full (dp, ep) step against a hand-computed mean-of-shards objective.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,8 +18,11 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from byteps_tpu.parallel.expert import (
-    DP_AXIS, EP_AXIS, init_moe_params, make_dp_ep_train_step, make_ep_mesh,
-    moe_mlp, moe_mlp_reference, shard_moe_params)
+    _ROW_CHUNK, DP_AXIS, EP_AXIS, dropless_moe_mlp, init_moe_params,
+    make_dp_ep_train_step, make_ep_mesh, moe_mlp, moe_mlp_reference,
+    row_schedule, shard_moe_params)
+
+from .jaxpr_count import equations
 
 H, F, E = 16, 32, 8
 
@@ -152,3 +157,167 @@ def test_dp_ep_trains_and_stays_sharded():
     assert w1.addressable_shards[0].data.shape[0] * 4 == w1.shape[0]
     # router actually learned (replicated, updated via summed cotangents)
     assert float(np.abs(np.asarray(p["router"]) - router0).max()) > 0
+
+
+# ------------------------- dropless experts WITHOUT a gate (relu2; PR 39)
+
+_H, _F, _E = 16, 24, 64
+
+
+def _ungated_params(seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return {"router": jax.random.normal(k[0], (_H, _E)),
+            "up": jax.random.normal(k[1], (_E, _H, _F)) / np.sqrt(_H),
+            "down": jax.random.normal(k[2], (_E, _F, _H)) / np.sqrt(_F)}
+
+
+def _one_by_one(x, scores, params, top_k, held, renormalize=True):
+    """Each held expert on every token, times its weight or zero: sigmoid
+    scores from outside, the ``top_k`` largest renormalised (+1e-20), two
+    matrices an expert with ``relu(.)^2`` between, no gate."""
+    first, count = held or (0, params["up"].shape[0])
+    _, chosen = lax.top_k(scores, top_k)
+    picked = (jnp.arange(scores.shape[-1]) == chosen[..., None]).any(-2)
+    weight = jnp.where(picked, scores, 0.0)
+    if renormalize:
+        weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+    y = jnp.zeros_like(x)
+    for i in range(count):
+        hidden = jnp.maximum(x @ params["up"][i], 0.0) ** 2
+        y = y + weight[:, first + i, None] * (hidden @ params["down"][i])
+    return y
+
+
+def _ungated_case(n, top_k, held, score_shift=None):
+    params = _ungated_params()
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, _H))
+    logits = x @ params["router"]
+    if score_shift is not None:
+        logits = logits + score_shift
+    stacks = {k: (params[k] if held is None
+                  else params[k][held[0]:held[0] + held[1]])
+              for k in ("up", "down")}
+
+    def program(x, stacks, logits):
+        return dropless_moe_mlp(
+            x, stacks, top_k, interpret=True, held=held, renormalize=True,
+            routing=(jax.nn.sigmoid(logits), None))
+
+    def reference(x, stacks, logits):
+        return _one_by_one(x, jax.nn.sigmoid(logits), stacks, top_k, held)
+
+    return program, reference, (x, stacks, logits)
+
+
+@pytest.mark.parametrize("held", [(0, 8), None], ids=["held_0_8", "all"])
+def test_ungated_relu2_experts_match_the_one_by_one_reference(held):
+    """Top-22 of 64 — 1 056 pair rows of which an eighth is live under
+    ``held=(0, 8)`` — value and the gradient of rows, stacks and scores."""
+    program, reference, args = _ungated_case(48, 22, held)
+    weight = jax.random.normal(jax.random.PRNGKey(7), args[0].shape)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(program(*a)[0] * weight), (0, 1, 2)))(*args)
+        want = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(reference(*a) * weight), (0, 1, 2)))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-5, atol=2e-5 * float(jnp.max(jnp.abs(w))))
+    counts = program(*args)[3]
+    assert int(counts.sum()) == 48 * 22
+
+
+@pytest.mark.parametrize("shift,live", [
+    # the held experts' scores pushed down: a few pairs, inside ONE chunk
+    (-2.0, "fraction_of_a_chunk"),
+    # ... and out of every token's top 22: an empty range, all zeros
+    (-50.0, "empty")], ids=["fraction_of_a_chunk", "empty"])
+def test_ungated_live_range_inside_one_chunk_or_empty(shift, live):
+    held = (8, 8)
+    bias = jnp.zeros((_E,)).at[held[0]:held[0] + held[1]].set(shift)
+    program, reference, args = _ungated_case(512, 22, held, bias)
+    with jax.default_matmul_precision("highest"):
+        (y, _, _, counts), want = (jax.jit(program)(*args),
+                                   jax.jit(reference)(*args))
+        g = jax.jit(jax.grad(lambda *a: program(*a)[0].sum(), (0, 1)))(*args)
+        gw = jax.jit(jax.grad(lambda *a: reference(*a).sum(), (0, 1)))(*args)
+    chunk = math.gcd(512 * 22, _ROW_CHUNK)
+    sched = row_schedule(np.asarray(counts), held, chunk)
+    rows = int(sched["hi"] - sched["lo"])
+    if live == "empty":
+        assert rows == 0 and sched["end"] == sched["first"]
+        assert not np.asarray(y).any()
+    else:
+        assert 0 < rows < chunk and sched["end"] - sched["first"] <= 2
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-6)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gw)):
+        np.testing.assert_allclose(
+            a, b, rtol=2e-5, atol=2e-5 * max(float(jnp.max(jnp.abs(b))), 1e-6))
+
+
+def test_what_params_holds_says_whether_the_experts_are_gated():
+    """The same ``up`` and ``down`` with and without a ``gate`` stack, both
+    experts chosen (k = E = 2, so the weights are the softmax itself):
+    ``down(relu(up x)^2)`` without, ``down(silu(gate x) * up x)`` with."""
+    k = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(k[0], (8, _H))
+    params = {"router": jax.random.normal(k[1], (_H, 2)),
+              "up": jax.random.normal(k[2], (2, _H, _F)) / np.sqrt(_H),
+              "down": jax.random.normal(k[3], (2, _F, _H)) / np.sqrt(_F)}
+    gate = jax.random.normal(k[4], (2, _H, _F)) / np.sqrt(_H)
+    w = jax.nn.softmax(x @ params["router"])
+    for stacks, act in (
+            (params, lambda e: jnp.maximum(x @ params["up"][e], 0.0) ** 2),
+            (dict(params, gate=gate),
+             lambda e: jax.nn.silu(x @ gate[e]) * (x @ params["up"][e]))):
+        with jax.default_matmul_precision("highest"):
+            y = dropless_moe_mlp(x, stacks, 2, interpret=True)[0]
+            want = sum(w[:, e, None] * (act(e) @ params["down"][e])
+                       for e in range(2))
+        np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-6)
+
+
+def _gated(g, router=True):
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    p = {"gate": jax.random.normal(k[0], (g, 32, 16)),
+         "up": jax.random.normal(k[1], (g, 32, 16)),
+         "down": jax.random.normal(k[2], (g, 16, 32))}
+    if router:
+        p["router"] = jax.random.normal(k[3], (32, 8))
+    return p
+
+
+_SCORES = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(2), (48, 8)))
+_BIAS = jnp.zeros((8,))
+
+
+@pytest.mark.parametrize("model,params,kwargs,forward,backward", [
+    ("olmoe_1b_7b", _gated(8), dict(top_k=2), (59, 1074), (155, 3306)),
+    ("mellum2_12b", _gated(2), dict(top_k=2, held=(2, 2), renormalize=True),
+     (73, 1291), (172, 3748)),
+    ("zaya1_8b", _gated(4, router=False),
+     dict(top_k=1, held=(4, 4), routing=(_SCORES, _BIAS)),
+     (47, 1270), (88, 3668)),
+    ("glm47_flash", _gated(2, router=False),
+     dict(top_k=4, held=(2, 2), renormalize=True, routing=(_SCORES, _BIAS)),
+     (51, 1274), (92, 3672)),
+], ids=["olmoe_1b_7b", "mellum2_12b", "zaya1_8b", "glm47_flash"])
+def test_gated_calls_trace_to_the_programs_they_were(model, params, kwargs,
+                                                     forward, backward):
+    """The call each of the four models with SiLU-gated experts makes is,
+    equation for equation, what it was before experts without a gate
+    existed: (top-level equations, all equations) of forward and of
+    forward + backward, counted on the parent commit (PR 38)."""
+    x = jax.random.normal(jax.random.PRNGKey(1), (48, 32))
+
+    def layer(x, p):
+        return dropless_moe_mlp(x, p, interpret=True, **kwargs)
+
+    def grad(x, p):
+        return jax.grad(lambda x, p: layer(x, p)[0].sum(), (0, 1))(x, p)
+
+    fwd = jax.make_jaxpr(layer)(x, params).jaxpr
+    assert (len(fwd.eqns), equations(fwd)) == forward
+    bwd = jax.make_jaxpr(grad)(x, params).jaxpr
+    assert (len(bwd.eqns), equations(bwd)) == backward

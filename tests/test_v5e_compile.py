@@ -345,3 +345,57 @@ def test_olmoe_cell_step_holds_no_whole_logits(topo, monkeypatch):
              if re.search(rf"= \w+\[({hidden},{vocab}|{vocab},{hidden})\]"
                           r"\S* (copy|transpose)\(", line)]
     assert not moved, moved
+
+
+def test_nemotron3_super_cell_step_fits_a_v5e(topo, monkeypatch):
+    """``nemotron3_super.fused_1c``'s step at the published widths, rung
+    (b) of ISSUE 39's memory ladder (1 x 8 192 positions with the module):
+    Mosaic takes the state-space scan's kernels (``bps_ssd_fwd`` twice a
+    block — the forward and, under ``remat``, the one that stores the
+    chunk-start states — and ``bps_ssd_bwd`` once), the ungated experts'
+    grouped matmuls at 180 224 pair rows and the ``relu2`` row kernel in
+    thirds of its 2 688 columns; the program's memory stays under the
+    chip's 15.75 GiB (15.09 by ``memory_analysis``, 14.89 by the buffer
+    assignment the chip allocates: over the issue's 14.9 by the former,
+    as the configuration's ``notes`` say); neither head's ``[tokens,
+    vocabulary]`` logits exist outside a block; and no decay matrix ``L``
+    ([.., 128, 128] float32 a chunk and head) exists outside a kernel."""
+    compiled, config, traffic = _compiled_cell_step(
+        topo, monkeypatch, "nemotron3_super.fused_1c")
+    memory = compiled.memory_analysis()
+    # weights and two moments: 3 x 838,249,968 x 4 B = 9.37 GiB
+    assert 9.3 < memory.argument_size_in_bytes / 2 ** 30 < 9.45
+    assert _used_gib(memory) < 15.2
+    text = compiled.as_text()
+    tokens = traffic["seq_len"] * traffic["seqs_per_chip"]
+    vocab = config["vocab_size"]
+    # one block of the head IS all 8 192 rows here (0.5 GiB of float32
+    # logits: ``logit_block_rows``); two sequences' would not be
+    assert f"[{2 * tokens},{vocab}]" not in text
+    assert "bps.head" in text
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum(c.endswith("bps_ssd_fwd/pallas_call") for c in calls) == 10
+    assert sum(c.endswith("bps_ssd_bwd/pallas_call") for c in calls) == 5
+    assert all("bps.ssm.scan" in c for c in calls if "bps_ssd" in c)
+    # per * block: flash forward, its recomputation, two backward kernels
+    assert sum(c.endswith("/attn/pallas_call") for c in calls) == 8
+    # per E block: two grouped matmuls forward, two recomputed, four
+    # backward; the activation (+ its recomputation) and its backward; the
+    # spread (+ its recomputation) and the scaled spread
+    assert sum("bps.moe.experts" in c for c in calls) == 6 * 8
+    assert sum(c.endswith("bps_moe_act/pallas_call") for c in calls) == 12
+    assert sum(c.endswith("bps_moe_act_bwd/pallas_call") for c in calls) == 6
+    assert not any("bps_moe_gate" in c for c in calls)     # no gate
+    assert len(calls) == 15 + 8 + 6 * (8 + 3 + 3)
+    for scope in ("bps.ssm.in_proj", "bps.ssm.conv", "bps.ssm.gate_norm",
+                  "bps.ssm.out_proj", "bps.moe.latent_down",
+                  "bps.moe.latent_up", "bps.moe.shared", "bps.moe.score",
+                  "/mtp/", "/mixer_ssm/"):
+        assert scope in text
+    # the decay matrix of a chunk lives in VMEM only
+    chunks = traffic["seq_len"] // config["chunk_size"]
+    assert not re.search(rf"f32\[[\d,]*{chunks},\d+,128,128\]", text)
+    assert not re.search(r"f32\[[\d,]*,128,128\]\S* (fusion|exponential)\(",
+                         text)
