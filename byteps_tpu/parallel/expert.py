@@ -44,7 +44,8 @@ the layer is one chip's share of an expert-parallel deployment: it
 routes over all E experts, holds the stacks of ``count`` consecutive
 ones, and returns the part of the sum that those give for the pairs
 routed to them — the local half of expert parallelism, its row passes in proportion to the
-rows that land here (:func:`row_schedule`).  The other half,
+rows that land here (:func:`row_schedule`; a thin share works in windows
+of its live range, :func:`window_rows`).  The other half,
 the exchange (top-k dispatch by ``all_to_all`` over an ``ep`` axis, so
 that a chip's experts see the tokens of every chip and a token the
 experts of every chip), is NOT here yet (ROADMAP R1): a one-chip share
@@ -314,6 +315,19 @@ def _permute_rows_bwd(res, g):
 
 
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _sorted_pairs(pair_expert, weights):
+    """A held layer's pairs sorted by expert (stable: a token's order
+    within its group is its arrival order) -> (``order`` [N k], the pairs
+    in sorted order; ``scale`` [N k], their weights, which ride through
+    the same sort and carry no gradient)."""
+    rows = pair_expert.shape[0]
+    _, order, scale = lax.sort(
+        (pair_expert, lax.iota(jnp.int32, rows),
+         lax.stop_gradient(weights).reshape(rows)),
+        num_keys=1, is_stable=True)
+    return order, scale
 
 
 # ------------------------------------ a held share's row passes (live rows)
@@ -703,6 +717,226 @@ def _relu2_rows_bwd(chunk, interpret, res, g):
 _relu2_rows.defvjp(_relu2_rows_fwd, _relu2_rows_bwd)
 
 
+# ------------------------------- a thin held share: windows of the live range
+
+# A held layer works in windows where one window (twice the expected live
+# rows, in whole chunks) is at most this share of the pair rows.  Measured
+# at one shape below it: 8 of 512 experts held at top-22 over 8 192 tokens
+# (windows of 6 144 of 180 224 rows, a 29th) ran forward + backward in 11.8
+# ms where the whole arrays took 35.9 (v5e; PERF.md section 6, PR 40).  The
+# next shape up the models have, an eighth live (a window a quarter of the
+# rows), is not measured; the constant lies between.
+_WINDOW_SHARE = 1 / 16
+
+
+def window_rows(n: int, top_k: int, held_count: int, experts: int
+                ) -> Optional[int]:
+    """Rows ``W`` of a window of a held layer's sorted order, or ``None``
+    where the layer works on all ``n * top_k`` pair rows at once.
+
+    The rows that land on the ``held_count`` held experts of ``experts``
+    are ``L = n k G / E`` under a balanced router: a window is the multiple
+    of the row chunk that holds ``2 L``, so a batch near the expectation
+    runs ONE window and a heavier one more (``window_trips``).  ``None``
+    where such a window is more than ``_WINDOW_SHARE`` of the pair rows
+    (the whole arrays then cost little more than the windows' glue).  Pure
+    arithmetic on shapes, in the manner of ``row_schedule``: nothing
+    chooses it but ``(N, k, G, E)``."""
+    rows = n * top_k
+    chunk = math.gcd(rows, _ROW_CHUNK)
+    if chunk % 8:
+        return None
+    window = -(-2 * rows * held_count // (experts * chunk)) * chunk
+    return window if window <= _WINDOW_SHARE * rows else None
+
+
+def window_trips(counts, held: Tuple[int, int], window: int):
+    """Windows of ``window`` rows that cover the held experts' live range
+    ``[lo, hi)`` of the sorted order: the trip count of a windowed layer's
+    loops (0 where nobody routed here, ``N k / window`` where everybody
+    did), host integers or traced ones alike."""
+    sched = row_schedule(counts, held, window)
+    return (sched["hi"] - sched["lo"] + window - 1) // window
+
+
+def _window_rows_of(order, scale, counts, held, window, i):
+    """Window ``i`` of the live range: rows ``[s, s + W)`` of the sorted
+    order with ``s = min(lo + i W, N k - W)`` -> their pairs (``order``),
+    their weights (``scale``) and their group sizes ``[dead head, the held
+    experts' counts clipped to the window's own rows ``[lo + i W, lo +
+    (i + 1) W)``, dead tail]``: a window that the clamp moved back overlaps
+    its neighbour, and holds the shared rows dead."""
+    start, count = held
+    lo = jnp.sum(counts[:start])
+    edges = lo + jnp.concatenate([jnp.zeros((1,), counts.dtype),
+                                  jnp.cumsum(counts[start:start + count])])
+    a = lo + i * window
+    b = jnp.minimum(a + window, edges[-1])
+    s = jnp.minimum(a, order.shape[0] - window)
+    sizes = jnp.concatenate([(a - s)[None], jnp.diff(jnp.clip(edges, a, b)),
+                             (s + window - b)[None]]).astype(jnp.int32)
+    return (lax.dynamic_slice(order, (s,), (window,)),
+            lax.dynamic_slice(scale, (s,), (window,)), sizes)
+
+
+def _sum_rows_by_token(rows, token, n):
+    """Token order from a window's rows: ``out[t] = sum of rows[r] over the
+    r with token[r] == t``, float32 [n, h].  Dead rows are exact zeros and
+    add nothing.  XLA's scatter-add: 0.36 ms at [6144, 1024] -> [8192, 1024]
+    on a v5e, where a one-hot matmul in the three bfloat16 passes float32
+    rows need took 1.67 (PERF.md section 6, PR 40)."""
+    return jnp.zeros((n, rows.shape[1]), jnp.float32).at[token].add(
+        rows.astype(jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _dispatch_window(x32, token, sched, dtype, chunk, interpret):
+    """A window's ``xs`` (``_spread_rows`` of the rows rounded to
+    ``dtype``); backward, a token's float32 row gradient is the sum of its
+    pairs' in the window."""
+    return _spread_rows(x32.astype(dtype), token, sched, chunk, interpret)
+
+
+def _dispatch_window_fwd(x32, token, sched, dtype, chunk, interpret):
+    return (_dispatch_window(x32, token, sched, dtype, chunk, interpret),
+            (token, x32))
+
+
+def _dispatch_window_bwd(dtype, chunk, interpret, res, g):
+    token, x32 = res                  # the rows' count: nothing of it read
+    return _sum_rows_by_token(g, token, x32.shape[0]), None, None
+
+
+_dispatch_window.defvjp(_dispatch_window_fwd, _dispatch_window_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _combine_window(ys, scale, token, sched, n, chunk, interpret):
+    """A window's part of ``y``: each token's float32 sum of its pairs'
+    rows in the window, each times its weight.  Backward is
+    ``_combine_rows``'s, at the window's rows: one scaled ``_spread_rows``."""
+    return _sum_rows_by_token(ys.astype(jnp.float32) * scale[:, None], token,
+                              n)
+
+
+def _combine_window_fwd(ys, scale, token, sched, n, chunk, interpret):
+    return (_combine_window(ys, scale, token, sched, n, chunk, interpret),
+            (ys, scale, token, sched))
+
+
+def _combine_window_bwd(n, chunk, interpret, res, g):
+    ys, scale, token, sched = res
+    g_ys, d = _spread_rows(g.astype(ys.dtype), token, sched, chunk, interpret,
+                           scale, dot=ys)
+    return g_ys, d, None, None
+
+
+_combine_window.defvjp(_combine_window_fwd, _combine_window_bwd)
+
+
+def _window_part(x32, scale, stacks, token, sizes, interpret):
+    """One window's part of ``y`` [N, h] float32: the held layer's code at
+    ``W`` rows.  ``x32`` [N, h] float32 holds the rows' ``stacks``-dtype
+    values; ``scale``, ``token`` [W] and ``sizes`` [G + 2] are the window's
+    (``_window_rows_of``).  The grouped matmuls see ``G + 2`` groups of
+    which the stacks hold ``1 .. G``, as the whole layer's see ``E`` of
+    which they hold ``first .. first + G - 1``.  One outer scope: a
+    transform (the backward loop's ``jax.vjp``) wraps the first scope
+    entered after it, and the readers of the grouped matmuls' time look
+    for ``bps.moe.experts/``, not ``jvp(bps.moe.experts)/``."""
+    count, dt = stacks["up"].shape[0], stacks["up"].dtype
+    chunk = math.gcd(token.shape[0], _ROW_CHUNK)
+    first = jnp.asarray(1, jnp.int32)
+    with jax.named_scope("bps.moe.window"):
+        sched = row_schedule(sizes, (1, count), chunk)
+        with jax.named_scope("bps.moe.dispatch"):
+            xs = _dispatch_window(x32, token, sched, dt, chunk, interpret)
+        with jax.named_scope("bps.moe.experts"):
+            if "gate" in stacks:
+                gate = _grouped_matmul(xs, stacks["gate"], sizes, interpret,
+                                       first)
+            up = _grouped_matmul(xs, stacks["up"], sizes, interpret, first)
+        if "gate" in stacks:
+            with jax.named_scope("bps.moe.gate"):
+                act = _silu_gate_rows(gate, up, sched, chunk, interpret)
+        else:
+            with jax.named_scope("bps.moe.act"):
+                act = _relu2_rows(up, sched, chunk, interpret)
+        with jax.named_scope("bps.moe.experts"):
+            ys = _grouped_matmul(act, stacks["down"], sizes, interpret, first)
+        with jax.named_scope("bps.moe.combine"):
+            return _combine_window(ys, scale, token, sched, x32.shape[0],
+                                   chunk, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _windowed_experts(x, weights, stacks, scale, order, counts, held, top_k,
+                      window, interpret):
+    """The held experts' share of ``y`` [N, h] (``x.dtype``) in windows of
+    ``window`` rows of the live range, ``window_trips`` of them: a loop
+    with a runtime trip count, which JAX does not reverse — so ONE
+    ``custom_vjp`` from ``(x, weights, stacks)``: forward adds each
+    window's part (``_window_part``) to a float32 carry, backward takes
+    ``jax.vjp`` of the same window and accumulates the gradients of ``x``
+    and the stacks in float32 and the weights' at their ``[N, k]`` places
+    (``order``).  ``scale`` [N k] is ``weights`` in sorted order, ``order``
+    [N k] the sorted pairs: residuals are these, ``x`` and ``counts``, and
+    the backward recomputes its windows' forward."""
+    return _windowed_fwd(x, weights, stacks, scale, order, counts, held,
+                         top_k, window, interpret)[0]
+
+
+def _windowed_fwd(x, weights, stacks, scale, order, counts, held, top_k,
+                  window, interpret):
+    del weights                       # their values ride in ``scale``
+    x32 = x.astype(jnp.float32)
+    cast = {k: v.astype(x.dtype) for k, v in stacks.items()}
+
+    def body(i, y):
+        pairs, scale_w, sizes = _window_rows_of(order, scale, counts, held,
+                                                window, i)
+        return y + _window_part(x32, scale_w, cast, pairs // top_k, sizes,
+                                interpret)
+
+    y = lax.fori_loop(0, window_trips(counts, held, window), body,
+                      jnp.zeros(x.shape, jnp.float32))
+    return y.astype(x.dtype), (x, stacks, scale, order, counts)
+
+
+def _windowed_bwd(held, top_k, window, interpret, res, g):
+    x, stacks, scale, order, counts = res
+    x32, g32 = x.astype(jnp.float32), g.astype(jnp.float32)
+    cast = {k: v.astype(x.dtype) for k, v in stacks.items()}
+
+    def body(i, carry):
+        g_x, g_stacks, g_scale = carry
+        pairs, scale_w, sizes = _window_rows_of(order, scale, counts, held,
+                                                window, i)
+        _, pull = jax.vjp(
+            lambda x32, scale_w, cast: _window_part(
+                x32, scale_w, cast, pairs // top_k, sizes, interpret),
+            x32, scale_w, cast)
+        d_x, d_scale, d_stacks = pull(g32)
+        # dead rows give exact zeros, so a row two windows share adds once
+        return (g_x + d_x,
+                jax.tree.map(lambda a, d: a + d.astype(a.dtype), g_stacks,
+                             d_stacks),
+                g_scale.at[pairs].add(d_scale))
+
+    g_x, g_stacks, g_scale = lax.fori_loop(
+        0, window_trips(counts, held, window), body,
+        (jnp.zeros(x.shape, jnp.float32),
+         jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32), stacks),
+         jnp.zeros(scale.shape, jnp.float32)))
+    return (g_x.astype(x.dtype),
+            g_scale.reshape(-1, top_k).astype(scale.dtype),
+            jax.tree.map(lambda g, s: g.astype(s.dtype), g_stacks, stacks),
+            None, None, None)
+
+
+_windowed_experts.defvjp(_windowed_fwd, _windowed_bwd)
+
+
 def dropless_moe_mlp(x, params, top_k: int,
                      interpret: Optional[bool] = None, *,
                      held: Optional[Tuple[int, int]] = None,
@@ -759,8 +993,21 @@ def dropless_moe_mlp(x, params, top_k: int,
     other chunk, nothing of it read) and the gate product.  Gauges
     ``moe.held_pair_share`` (live rows) and ``moe.visited_row_share``
     (rows visited, in whole chunks).  The token-order half — the combine's
-    gather and the dispatch's backward (``_gather_sum_rows``) — still
-    fetches every row (PERF.md section 7).
+    gather and the dispatch's backward (``_gather_sum_rows``) — fetches
+    every row, and every array above is written whole, zeros and all.
+
+    Where the held share is thin — ``window_rows(N, k, G, E)`` says so
+    from the shapes alone: a window of twice the expected live rows is at
+    most a 16th of ``N k`` — none of those arrays exists.  The layer is
+    then ``_windowed_experts``: the same pieces on ``W`` rows of the
+    sorted order at a time, ``window_trips`` windows over the live range
+    by a runtime trip count (one for a batch near the expectation, ``N k /
+    W`` if every token chose held experts: nothing is dropped, the layer
+    runs longer), and the token-order half is a float32 sum over the
+    window's rows by token (``_sum_rows_by_token``) in place of the
+    gathers.  Same result, same precision (rows in ``x.dtype``, scaling
+    and sums in float32).  Gauges ``moe.window_trips`` and, for such a
+    layer, ``moe.visited_row_share`` = trips x W / N k.
 
     Returns ``(y [N, h] in x.dtype, aux, z, counts [E] int32)``:
     ``aux = E * sum_e f_e P_e`` with ``f_e`` = pairs routed to e / N and
@@ -817,6 +1064,15 @@ def dropless_moe_mlp(x, params, top_k: int,
                           * jnp.mean(probs, axis=0))
         z = (jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
              if routing is None else jnp.zeros((), jnp.float32))
+    window = None if held is None else window_rows(n, top_k, held[1], e)
+    if window is not None:
+        # a thin share: no array of N k rows but the sort's three columns
+        with jax.named_scope("bps.moe.dispatch"):
+            order, scale = _sorted_pairs(pair_expert, weights)
+        stacks = {k: v for k, v in params.items() if k != "router"}
+        y = _windowed_experts(x, weights, stacks, scale, order, counts, held,
+                              top_k, window, interpret)
+        return y, aux, z, counts
     if held is not None:
         # the row passes below visit the chunks that meet the held
         # experts' rows, not all N k (``row_schedule``)
@@ -830,11 +1086,7 @@ def dropless_moe_mlp(x, params, top_k: int,
             inverse = jnp.argsort(order)
             xs = _permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
         else:
-            # the pairs' weights ride through the same sort
-            _, order, scale = lax.sort(
-                (pair_expert, lax.iota(jnp.int32, n * top_k),
-                 lax.stop_gradient(weights).reshape(n * top_k)),
-                num_keys=1, is_stable=True)
+            order, scale = _sorted_pairs(pair_expert, weights)
             inverse = jnp.argsort(order)
             token = order // top_k
             xs = _dispatch_rows(x, token, inverse, sched, top_k, chunk,
@@ -883,7 +1135,10 @@ def publish_moe_stats(counts, held: Optional[Tuple[int, int]] = None
     ``moe.held_load_max_over_mean`` (the fullest held expert over the held
     experts' mean, worst layer) and ``moe.visited_row_share`` (pair rows
     the layer's row passes visit over all of them: ``row_schedule``'s live
-    chunks, the share rounded up to ``_ROW_CHUNK`` rows at either end).
+    chunks, the share rounded up to ``_ROW_CHUNK`` rows at either end; for
+    a layer that works in windows, ``window_rows``, the windows it ran
+    times their rows, with ``moe.window_trips`` = the windows of the worst
+    layer).
     Host side: it reads the values, so call it outside any jitted step and
     off the step's critical path."""
     from ..common.metrics import gauges
@@ -897,9 +1152,17 @@ def publish_moe_stats(counts, held: Optional[Tuple[int, int]] = None
         gauges.set("moe.held_pair_share", float(mine.sum() / c.sum()))
         gauges.set("moe.held_load_max_over_mean",
                    float(np.max(mine.max(axis=1) / mine.mean(axis=1))))
-        visited = 0
+        visited, trips = 0, []
         for layer in c.astype(np.int64):
+            # the rule reads ``n * top_k`` alone: the layer's pair rows
+            window = window_rows(int(layer.sum()), 1, held[1], len(layer))
+            if window is not None:
+                trips.append(int(window_trips(layer, held, window)))
+                visited += trips[-1] * window
+                continue
             chunk = math.gcd(int(layer.sum()), _ROW_CHUNK)
             sched = row_schedule(layer, held, chunk)
             visited += int(sched["end"] - sched["first"]) * chunk
         gauges.set("moe.visited_row_share", visited / float(c.sum()))
+        if trips:
+            gauges.set("moe.window_trips", float(max(trips)))

@@ -3,22 +3,31 @@ the CPU, through the Pallas interpreter: the spread into sorted order over
 the live rows alone, against ``x[perm]``; its scaled form with the
 row-wise dot, against the dense weighted arithmetic; both as transposes of
 the token-order gather-sum (``jax.vjp``); the gate product's kernels; the
-schedule against a brute-force count; the gauge.
+schedule against a brute-force count; the gauge.  And the layer in windows
+of its live range (PR 40): against the whole-array held layer and the dense
+arithmetic, the rule that sizes a window, the trip count, and the scopes
+its kernels land under in a differentiated program.
 
 Float32 inputs, so a moved row is compared EXACTLY and a weighted one to
 float32 rounding of one product (rtol 1e-6): a wrong row, a wrong weight or
 a row outside the range fails by orders of magnitude.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from byteps_tpu.parallel.expert import (_combine_rows, _dispatch_rows,
-                                        _gather_sum_rows, _silu_gate_rows,
-                                        _spread_rows, publish_moe_stats,
-                                        row_schedule)
+from byteps_tpu.parallel import expert
+from byteps_tpu.parallel.expert import (_ROW_CHUNK, _combine_rows,
+                                        _dispatch_rows, _gather_sum_rows,
+                                        _silu_gate_rows, _spread_rows,
+                                        dropless_moe_mlp, publish_moe_stats,
+                                        row_schedule, window_rows,
+                                        window_trips)
 
 N, K, H, E, CHUNK = 48, 2, 32, 8, 16
 M = N * K
@@ -215,6 +224,7 @@ def test_publish_moe_stats_sets_the_visited_row_share():
     """Rows visited / ``N k`` at ``_ROW_CHUNK`` (clipped to a divisor of
     the layer's rows), summed over the layers."""
     import byteps_tpu as bps
+    trips_before = bps.metrics_snapshot()["gauges"].get("moe.window_trips")
     counts = np.asarray([[100, 28, 500, 140, 0, 256, 0, 0],    # 1024 pairs
                          [128] * 8])
     publish_moe_stats(counts, held=(2, 2))                     # chunk 1024
@@ -229,3 +239,276 @@ def test_publish_moe_stats_sets_the_visited_row_share():
     assert share == pytest.approx((2 + 2) * 1024 / 8192)
     publish_moe_stats(counts)                  # no share: gauge untouched
     assert bps.metrics_snapshot()["gauges"]["moe.visited_row_share"] == share
+    # layers on whole arrays: the windows' gauge is not theirs to set
+    assert bps.metrics_snapshot()["gauges"].get(
+        "moe.window_trips") == trips_before
+    # a thin share (2 of 128 experts, 32 768 pair rows): windows of 1 024.
+    # Layer 0: 900 live rows from row 5 000 -> 1 trip; layer 1: 2 100 -> 3
+    thin = np.zeros((2, 128), np.int64)
+    thin[0, [0, 9, 10, 127]] = 5000, 600, 300, 32768 - 5900
+    thin[1, [0, 9, 10, 127]] = 100, 2000, 100, 32768 - 2200
+    publish_moe_stats(thin, held=(9, 2))
+    gauges = bps.metrics_snapshot()["gauges"]
+    assert window_rows(32768, 1, 2, 128) == 1024
+    assert gauges["moe.window_trips"] == 3.0
+    assert gauges["moe.visited_row_share"] == pytest.approx(
+        (1 + 3) * 1024 / 65536)
+    assert gauges["moe.held_pair_share"] == pytest.approx(3000 / 65536)
+
+
+# ------------------------------------------ the layer in windows (PR 40)
+
+@pytest.mark.parametrize("n,top_k,held_count,experts,want", [
+    (8192, 22, 8, 512, 6144),         # nemotron3_super.fused_1c
+    (16384, 8, 16, 64, None),         # mellum2_12b.fused_1c: a quarter live
+    (16384, 1, 8, 16, None),          # zaya1_8b.fused_1c: half
+    (16384, 4, 8, 64, None),          # glm47_flash.fused_1c: an eighth
+    (4096, 8, 64, 64, None),          # every expert held
+    (8192, 22, 16, 512, 11264),       # exactly a 16th of 180 224
+    (8192, 22, 17, 512, None),        # 12 288: over it
+    (8192, 8, 1, 64, 2048),
+    (66, 4, 1, 64, 16),               # chunks of 8 rows
+    (33, 1, 1, 64, None),             # no chunk of whole sublane tiles
+], ids=str)
+def test_window_rows_against_a_brute_force_count(n, top_k, held_count,
+                                                 experts, want):
+    """The least whole number of row chunks that holds twice the expected
+    live rows, where that is at most a 16th of the pair rows."""
+    assert window_rows(n, top_k, held_count, experts) == want
+    rows = n * top_k
+    chunk = math.gcd(rows, _ROW_CHUNK)
+    window = chunk
+    while window * experts < 2 * rows * held_count:
+        window += chunk
+    brute = window if chunk % 8 == 0 and 16 * window <= rows else None
+    assert brute == want
+
+
+@pytest.mark.parametrize("counts,held,window,want", [
+    ([40, 0, 0, 56, 0, 0, 0, 0], (1, 2), 16, 0),
+    ([40, 0, 1, 55, 0, 0, 0, 0], (1, 2), 16, 1),
+    ([40, 8, 8, 40, 0, 0, 0, 0], (1, 2), 16, 1),
+    ([40, 8, 9, 39, 0, 0, 0, 0], (1, 2), 16, 2),
+    ([0, 0, 0, 0, 0, 0, 90, 6], (7, 1), 16, 1),
+    ([0, 48, 48, 0, 0, 0, 0, 0], (1, 2), 16, 6),
+], ids=str)
+def test_window_trips_cover_the_live_range(counts, held, window, want):
+    counts = np.asarray(counts, np.int32)
+    assert int(window_trips(counts, held, window)) == want
+    traced = jax.jit(lambda c: window_trips(c, held, window))(
+        jnp.asarray(counts))
+    assert int(traced) == want
+    sched = row_schedule(counts, held, window)
+    assert want * window >= int(sched["hi"] - sched["lo"]) > (
+        want - 1) * window
+
+
+_LH, _LF, _LE, _LN, _LW = 32, 16, 8, 48, 16
+
+
+def _layer_params(count, gated):
+    k = jax.random.split(jax.random.PRNGKey(11), 3)
+    p = {"up": jax.random.normal(k[0], (count, _LH, _LF)) / np.sqrt(_LH),
+         "down": jax.random.normal(k[1], (count, _LF, _LH)) / np.sqrt(_LF)}
+    if gated:
+        p["gate"] = jax.random.normal(k[2], (count, _LH, _LF)) / np.sqrt(_LH)
+    return p
+
+
+# name -> (held, tokens that choose ONE held expert each; None: every
+# token chooses held experts alone)
+_LIVE = {
+    "no_trip": ((2, 4), 0),                   # nobody routed here
+    "one_trip": ((2, 4), 10),                 # ten pairs
+    "worst_case": ((2, 4), None),             # every pair: N k / W trips
+    "clamp_moves_it": ((6, 2), 10),           # the last ten rows: lo + W > N k
+}
+
+
+def _logits(held, chosen):
+    """[N, E] router logits under which exactly ``chosen`` tokens have one
+    held expert among their choices (the others none)."""
+    first, count = held
+    mine = (jnp.arange(_LE) >= first) & (jnp.arange(_LE) < first + count)
+    logits = jax.random.normal(jax.random.PRNGKey(2), (_LN, _LE))
+    if chosen is None:
+        return jnp.where(mine, logits, -50.0)
+    token = jnp.arange(_LN)[:, None]
+    favoured = first + token % count == jnp.arange(_LE)
+    return jnp.where(mine, jnp.where(favoured & (token < chosen), 9.0, -50.0),
+                     logits)
+
+
+def _dense(x, stacks, scores, top_k, held):
+    """The held experts one by one over ALL rows, each weighted by the
+    token's score where the expert is among its ``top_k``."""
+    first, count = held
+    _, chosen = jax.lax.top_k(scores, top_k)
+    picked = (jnp.arange(scores.shape[-1]) == chosen[..., None]).any(-2)
+    weight = jnp.where(picked, scores, 0.0)
+    y = jnp.zeros_like(x)
+    for i in range(count):
+        up = x @ stacks["up"][i]
+        hidden = (jax.nn.silu(x @ stacks["gate"][i]) * up if "gate" in stacks
+                  else jnp.maximum(up, 0.0) ** 2)
+        y = y + weight[:, first + i, None] * (hidden @ stacks["down"][i])
+    return y
+
+
+@pytest.mark.parametrize("top_k,gated", [(1, True), (1, False), (4, True),
+                                         (4, False)],
+                         ids=["top1_gated", "top1_relu2", "top4_gated",
+                              "top4_relu2"])
+@pytest.mark.parametrize("live", _LIVE)
+def test_windowed_layer_is_the_whole_layer_and_the_dense_arithmetic(
+        monkeypatch, live, top_k, gated):
+    """``y`` and the gradients of the rows, both (three) stacks and the
+    scores, through windows of 16 rows of 48 or 192: against the held
+    layer on whole arrays (the rule refusing) and the dense arithmetic."""
+    held, chosen = _LIVE[live]
+    stacks = _layer_params(held[1], gated)
+    x = jax.random.normal(jax.random.PRNGKey(1), (_LN, _LH))
+    logits = _logits(held, chosen)
+    cot = jax.random.normal(jax.random.PRNGKey(3), (_LN, _LH))
+
+    def layer(x, stacks, logits):
+        return dropless_moe_mlp(x, stacks, top_k, interpret=True, held=held,
+                                routing=(jax.nn.sigmoid(logits), None))
+
+    def run(fn):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a) * cot), (0, 1, 2)))(x, stacks,
+                                                               logits)
+
+    rule = []
+    monkeypatch.setattr(expert, "window_rows",
+                        lambda *a: rule.append(a) or _LW)
+    windowed = run(lambda *a: layer(*a)[0])
+    counts = np.asarray(layer(x, stacks, logits)[3])
+    assert rule[0] == (_LN, top_k, held[1], _LE)
+    monkeypatch.setattr(expert, "window_rows", lambda *a: None)
+    whole = run(lambda *a: layer(*a)[0])
+    dense = run(lambda x, s, l: _dense(x, s, jax.nn.sigmoid(l), top_k, held))
+
+    sched = row_schedule(counts, held, _LW)
+    trips = int(window_trips(counts, held, _LW))
+    rows = _LN * top_k
+    if live == "no_trip":
+        assert trips == 0 and not np.asarray(windowed[0]).any()
+    elif live == "worst_case":
+        assert trips == rows // _LW >= 3
+    else:
+        assert trips == 1 and int(sched["hi"] - sched["lo"]) == chosen
+        assert (int(sched["lo"]) + _LW > rows) == (live == "clamp_moves_it")
+    for want in (whole, dense):
+        np.testing.assert_allclose(windowed[0], want[0], rtol=2e-5,
+                                   atol=1e-6)
+        for g, w in zip(jax.tree.leaves(windowed[1]),
+                        jax.tree.leaves(want[1])):
+            np.testing.assert_allclose(
+                g, w, rtol=2e-5,
+                atol=2e-5 * max(float(jnp.max(jnp.abs(w))), 1e-6))
+
+
+def test_windows_that_overlap_count_their_shared_rows_once(monkeypatch):
+    """Three windows of which the clamp moves the last back over the
+    second: 40 live rows from row 152 of 192 in windows of 16 — the third
+    starts at 176, not 184, and holds rows 176..183 dead."""
+    held, top_k = (7, 1), 4
+    stacks = _layer_params(1, False)
+    x = jax.random.normal(jax.random.PRNGKey(1), (_LN, _LH))
+    # 40 tokens choose expert 7 among their four; the sort puts it last
+    logits = _logits(held, 40)
+
+    def layer(x, stacks, logits):
+        return dropless_moe_mlp(x, stacks, top_k, interpret=True, held=held,
+                                routing=(jax.nn.sigmoid(logits), None))
+
+    def run(window):
+        monkeypatch.setattr(expert, "window_rows", lambda *a: window)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(layer(*a)[0] ** 2), (0, 1, 2)))(
+                    x, stacks, logits)
+
+    windowed, whole = run(_LW), run(None)
+    counts = np.asarray(layer(x, stacks, logits)[3])
+    assert counts[7] == 40 and int(window_trips(counts, held, _LW)) == 3
+    assert int(row_schedule(counts, held, _LW)["lo"]) + 3 * _LW > 192
+    np.testing.assert_allclose(windowed[0], whole[0], rtol=2e-5)
+    for g, w in zip(jax.tree.leaves(windowed[1]), jax.tree.leaves(whole[1])):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-5, atol=2e-5 * float(jnp.max(jnp.abs(w))))
+
+
+def kernel_stacks(jaxpr, prefix=""):
+    """The name stack of every ``pallas_call`` equation of a jaxpr, nested
+    ones too, rendered as the lowering renders an HLO ``op_name``."""
+    out = []
+    for eqn in jaxpr.eqns:
+        stack = "/".join(p for p in (prefix, str(eqn.source_info.name_stack))
+                         if p)
+        if eqn.primitive.name == "pallas_call":
+            out.append(stack + "/pallas_call")
+            continue
+        for key, value in eqn.params.items():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if not hasattr(sub, "eqns"):
+                    continue
+                step = {"jit": f"jit({eqn.params.get('name')})",
+                        "while": "while/" + key.split("_")[0]}.get(
+                            eqn.primitive.name, "")
+                out += kernel_stacks(sub, "/".join(
+                    p for p in (stack, step) if p))
+    return out
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
+def test_windowed_kernels_land_under_the_scopes_the_readers_look_for(gated):
+    r"""The readers of the grouped matmuls' time (``latent_moe_ms``,
+    ``held_moe_ms``, ...) take every kernel whose ``op_name`` matches
+    ``bps\.moe\.experts/.*pallas_call$``.  A transform wraps the FIRST
+    scope entered after it: were the window's first scope ``bps.moe.
+    experts``, the backward loop's ``jax.vjp`` would render its kernels
+    ``jvp(bps.moe.experts)/...``, which the rule does not match, and the
+    roofline would read the forward loop's kernels alone.  So: every
+    ``gmm`` / ``tgmm`` of forward loop and backward loop matches, and no
+    row kernel does."""
+    n, top_k, held, e = 66, 4, (5, 1), 64
+    assert window_rows(n, top_k, held[1], e) == 16
+    stacks = _layer_params(1, gated)
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, _LH))
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(2), (n, e)))
+
+    def loss(x, stacks, scores):
+        with jax.named_scope("mtp"):
+            y = dropless_moe_mlp(x, stacks, top_k, interpret=True, held=held,
+                                 routing=(scores, None))[0]
+        return jnp.sum(y), y
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(
+        x, stacks, scores).jaxpr
+    stacks_ = kernel_stacks(jaxpr)
+    experts = [s for s in stacks_
+               if re.search(r"bps\.moe\.experts/.*pallas_call$", s)]
+    own = [s for s in stacks_ if s not in experts]
+    mats = 3 if gated else 2
+    # forward loop; backward loop: the forward again, then a row gradient
+    # (gmm) and a matrix gradient (tgmm) a matrix
+    assert len(experts) == mats + mats + 2 * mats, stacks_
+    assert sum("jit(tgmm)" in s for s in experts) == mats
+    assert all("jit(gmm)" in s or "jit(tgmm)" in s for s in experts)
+    assert all("while/body" in s for s in stacks_)
+    act = "bps_moe_gate" if gated else "bps_moe_act"
+    assert sorted(s.split("/")[-2] for s in own) == sorted(
+        ["bps_moe_spread"] * 2 + [act] * 2 + [act + "_bwd",
+                                              "bps_moe_spread_scaled"]), own
+    assert not any("gmm)" in s for s in own)
+    for s in own:
+        scope = {"bps_moe_spread": "dispatch", "bps_moe_spread_scaled":
+                 "combine"}.get(s.split("/")[-2], "gate" if gated else "act")
+        assert f"/bps.moe.{scope}/" in s, s
+    # the module's own scope reaches every kernel, backward ones too
+    assert all(re.search(r"\(mtp\)+/while/body/", s) for s in stacks_)

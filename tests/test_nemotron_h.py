@@ -51,10 +51,16 @@ def einsum_scan(monkeypatch):
         ssd_scan.ssd_scan_chunked(*a, chunk=chunk))
 
 
-@pytest.mark.parametrize("scan,share", [
-    ("einsum_scan", None), ("einsum_scan", SHARE), (None, SHARE)],
-    ids=["whole-einsum", "share-einsum", "share-kernels"])
-def test_loss_and_gradients_match_the_reference(scan, share, request):
+# one expert of 64 held, 3 x 24 tokens: 216 pair rows a block in chunks of
+# 8, thin enough for ``parallel.expert.window_rows`` (windows of 8 rows)
+THIN = dict(n_routed_experts=64, experts_held=(5, 1))
+
+
+@pytest.mark.parametrize("scan,share,shape", [
+    ("einsum_scan", None, (2, 16)), ("einsum_scan", SHARE, (2, 16)),
+    (None, SHARE, (2, 16)), ("einsum_scan", THIN, (3, 24))],
+    ids=["whole-einsum", "share-einsum", "share-kernels", "thin-windowed"])
+def test_loss_and_gradients_match_the_reference(scan, share, shape, request):
     """Pattern ME*E + the module (*E): every kind of block, float32; the
     program (grouped matmuls, the scan's kernels interpreted, the blocked
     head) against the reference (dense experts, the recurrence position by
@@ -62,7 +68,7 @@ def test_loss_and_gradients_match_the_reference(scan, share, request):
     if scan:
         request.getfixturevalue(scan)
     cfg = nemotron_h_tiny(**SMALL, **(share or {}))
-    model, params, batch = setup(cfg, t=16)
+    model, params, batch = setup(cfg, seqs=shape[0], t=shape[1])
     with jax.default_matmul_precision("highest"):
         loss, grads = jax.jit(jax.value_and_grad(
             lambda p: nemotron_loss(model, p, batch)))(params)
@@ -236,3 +242,39 @@ def test_expert_shares_add_up_to_the_uncut_block():
                                experts_held=(first, 2))
         total = total + one_block("E", held, x, share) - x
     np.testing.assert_allclose(x + total, want, rtol=2e-5, atol=2e-6)
+
+
+def test_a_thin_held_share_runs_in_windows_of_its_live_range(einsum_scan):
+    """One held expert of 64 at top-3 over 3 x 24 tokens: 216 pair rows a
+    block, chunks of 8, ``window_rows`` = 8.  The differentiated loss
+    then holds NO array of 216 rows — no kernel's result, no gather, no
+    zero fill — only columns (the sort's, the count's indices), and the
+    activation's kernels sit inside the loops over the windows
+    (``parallel/expert.py``, PR 40)."""
+    from byteps_tpu.parallel.expert import window_rows
+
+    from .jaxpr_count import _inner_jaxprs
+    cfg = nemotron_h_tiny(**THIN, **SMALL)
+    model, params, batch = setup(cfg, seqs=3, t=24)
+    rows = 3 * 24 * cfg.num_experts_per_tok
+    assert window_rows(3 * 24, cfg.num_experts_per_tok, 1, 64) == 8
+    pair_arrays, act = [], []
+
+    def walk(jaxpr, in_while):
+        for eqn in jaxpr.eqns:
+            pair_arrays.extend(
+                (eqn.primitive.name, v.aval.shape) for v in eqn.outvars
+                if rows in getattr(v.aval, "shape", ())
+                and v.aval.size > rows)         # [216] / [216, 1]: columns
+            if (eqn.primitive.name == "pallas_call"
+                    and "bps_moe_act" in str(eqn.params.get("name"))):
+                act.append(in_while)
+            for inner in _inner_jaxprs(eqn):
+                walk(inner, in_while or eqn.primitive.name == "while")
+
+    walk(jax.make_jaxpr(jax.grad(
+        lambda p: nemotron_loss(model, p, batch)))(params).jaxpr, False)
+    assert not pair_arrays, pair_arrays[:5]
+    # three E blocks (two + the module's): forward, and the backward
+    # loop's forward and backward
+    assert len(act) == 9 and all(act)
