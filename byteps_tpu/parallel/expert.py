@@ -317,6 +317,23 @@ def _permute_rows_bwd(res, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+@jax.custom_vjp
+def _tie_gradients(*xs):
+    """``xs`` as they are; backward, their gradients pass ONE
+    ``optimization_barrier``: none is read before all exist.  Around a
+    grouped matmul's operands that keeps its two gradient kernels together,
+    so the incoming row gradient — an array of all ``N k`` pair rows — dies
+    when both have read it, whatever else the scheduler could run between
+    (it put the matrix gradient last in Mellum's step once the route stage
+    changed: one more ``[131072, 2304]`` array live at the peak, +0.53 GiB;
+    PERF.md section 6, PR 41)."""
+    return xs
+
+
+_tie_gradients.defvjp(lambda *xs: (xs, None),
+                      lambda _, g: lax.optimization_barrier(tuple(g)))
+
+
 def _sorted_pairs(pair_expert, weights):
     """A held layer's pairs sorted by expert (stable: a token's order
     within its group is its arrival order) -> (``order`` [N k], the pairs
@@ -937,6 +954,163 @@ def _windowed_bwd(held, top_k, window, interpret, res, g):
 _windowed_experts.defvjp(_windowed_fwd, _windowed_bwd)
 
 
+# --------------------------------------- the route stage: k of E in one pass
+
+# Tokens (lanes) a grid step of the selection takes, and slices of 8 experts
+# a trip of a round's scan: the fastest of nine pairs measured on a v5e at
+# the four shapes the models send (PERF.md section 6, PR 41) — at [8192, 512]
+# top-22 0.28 ms forward where (512, 4) took 0.37, (128, 4) 0.92 and
+# (2048, 4) 0.36; at 64 and 16 experts every pair from 512 tokens up lies
+# within 0.03 ms.  Each is clipped to the array.
+_SELECT_TOKENS = 1024
+_SELECT_UNROLL = 8
+_TAKEN = np.iinfo(np.int32).min   # below every key (they are clamped above it)
+
+
+def _select_kernel(probs, bias, idx, picked, counts, work, *, top_k, n):
+    """One block of tokens, experts on sublanes and tokens on lanes
+    (``probs`` [E, T]): a max over a token's experts is then elementwise
+    over E / 8 slices and ONE 8-sublane reduction, where tokens on sublanes
+    would pay a cross-lane reduction a round.  The scores are compared as
+    int32 keys in XLA's total order (a sorting top-k's own: -0 below +0, NaN
+    above +inf); a taken expert's key becomes ``_TAKEN``, which no score's
+    key equals, so a row of ``-inf`` or of equal scores still gives k
+    distinct experts.  A round is ONE pass over the slices: mark the
+    previous round's pick, then carry per sublane the best key, its slice
+    and its probability — strictly better only, so the earliest slice wins
+    among equals — and the 8 sublanes are reduced to the lowest expert that
+    holds the maximum.  Loops, but for ``_SELECT_UNROLL`` slices a trip (a
+    kernel's size is set-up time)."""
+    e, t = probs.shape
+    slices = e // 8
+    unroll = math.gcd(slices, _SELECT_UNROLL)
+    sub = lax.broadcasted_iota(jnp.int32, (8, t), 0)
+
+    def keys(i, carry):
+        at = pl.ds(pl.multiple_of(i * 8, 8), 8)
+        bits = lax.bitcast_convert_type(probs[at, :] + bias[at, :],
+                                        jnp.int32)
+        work[at, :] = jnp.maximum(
+            jnp.where(bits < 0, bits ^ np.int32(0x7FFFFFFF), bits),
+            _TAKEN + 1)
+        return carry
+    lax.fori_loop(0, slices, keys, 0)
+
+    def mark(i, prev):
+        """Slice ``i`` of the keys with the expert ``prev + sub`` taken."""
+        at = pl.ds(pl.multiple_of(i * 8, 8), 8)
+        w = jnp.where(prev == i * 8, _TAKEN, work[at, :])
+        work[at, :] = w
+        return at, w
+
+    def one_round(j, prev):
+        prev = prev - sub
+
+        def scan(i, best):
+            for s in range(unroll):       # by hand: Mosaic unrolls all or none
+                key, where, prob = best
+                at, w = mark(i * unroll + s, prev)
+                better = w > key
+                best = (jnp.where(better, w, key),
+                        jnp.where(better, i * unroll + s, where),
+                        jnp.where(better, probs[at, :], prob))
+            return best
+        key, where, prob = lax.fori_loop(
+            0, slices // unroll, scan,
+            (jnp.full((8, t), _TAKEN, jnp.int32), jnp.zeros((8, t), jnp.int32),
+             jnp.zeros((8, t), jnp.float32)))
+        expert = where * 8 + sub
+        top = jnp.max(key, axis=0, keepdims=True)
+        chosen = jnp.min(jnp.where(key == top, expert, e), axis=0,
+                         keepdims=True)                          # [1, T]
+        idx[pl.ds(j, 1), :] = chosen
+        # one sublane holds the chosen expert: the sum is its probability
+        picked[pl.ds(j, 1), :] = jnp.sum(
+            jnp.where(expert == chosen, prob, 0.0), axis=0, keepdims=True)
+        return chosen
+    last = lax.fori_loop(0, top_k, one_round, jnp.full((1, t), -1, jnp.int32))
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        counts[...] = jnp.zeros_like(counts)
+    real = (pl.program_id(0) * t
+            + lax.broadcasted_iota(jnp.int32, (1, t), 1)) < n   # not padding
+
+    last = last - sub
+
+    def count(i, carry):
+        at, w = mark(i, last)
+        took = ((w == _TAKEN) & real).astype(jnp.int32)
+        counts[at, :] += sum(took[:, c:c + 128] for c in range(0, t, 128))
+        return carry
+    lax.fori_loop(0, slices, count, 0)
+
+
+# jitted: a model's layers share ONE traced and lowered copy of the kernel
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _select_call(probs, bias, top_k, interpret):
+    n, e = probs.shape
+    if not 1 <= top_k <= e:
+        raise ValueError(f"top_k={top_k}: the router knows {e} experts")
+    if bias is None:
+        bias = jnp.zeros((e,), jnp.float32)
+    # experts on sublanes (whole slices of 8; a padded expert's -inf loses
+    # every tie to a real one, which has the lower index), tokens on lanes
+    rows, lanes = -(-e // 8) * 8, -(-n // 128) * 128
+    t = math.gcd(lanes, _SELECT_TOKENS)
+    scores = jnp.pad(probs.T, ((0, rows - e), (0, lanes - n)))
+    bias = jnp.pad(bias.astype(jnp.float32), (0, rows - e),
+                   constant_values=-jnp.inf)[:, None]
+    pairs = pl.BlockSpec((top_k, t), lambda i: (0, i))
+    idx, picked, counts = pl.pallas_call(
+        functools.partial(_select_kernel, top_k=top_k, n=n),
+        out_shape=(jax.ShapeDtypeStruct((top_k, lanes), jnp.int32),
+                   jax.ShapeDtypeStruct((top_k, lanes), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, 128), jnp.int32)),
+        grid=(lanes // t,),
+        in_specs=[pl.BlockSpec((rows, t), lambda i: (0, i)),
+                  pl.BlockSpec((rows, 1), lambda i: (0, 0))],
+        # the counts' block stays put: the grid's steps add to it in turn
+        out_specs=(pairs, pairs, pl.BlockSpec((rows, 128), lambda i: (0, 0))),
+        scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="bps_moe_select", interpret=interpret)(scores, bias)
+    return idx[:, :n].T, picked[:, :n].T, jnp.sum(counts[:e], axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _select_experts(probs, bias, top_k, interpret):
+    """The route stage's selection, from ONE pass over the scores ``probs``
+    [N, E] float32: ``(idx [N, k] int32, picked [N, k], counts [E] int32)``
+    — the ``top_k`` largest of ``probs + bias`` a token (``bias`` [E] or
+    ``None``: of ``probs``), descending, the lowest expert first among
+    equals (a stable sort's order, bit for bit), ``probs`` read at them,
+    and the pairs each expert received.  k rounds of max-and-mark over E
+    experts (``_select_kernel``) in place of a sort of E: every model here
+    keeps k <= E / 8.  The gradient reaches ``probs`` through ``picked``
+    alone, as a gather's would: ``g_probs[n, e] = sum_j g[n, j] [idx[n, j]
+    == e]``, a dense compare (a token's experts are distinct: one term at
+    most, the scatter-add's bits); none reaches ``bias``."""
+    return _select_call(probs, bias, top_k, interpret)
+
+
+def _select_experts_fwd(probs, bias, top_k, interpret):
+    out = _select_call(probs, bias, top_k, interpret)
+    return out, out[0]
+
+
+def _select_experts_bwd(top_k, interpret, idx, g):
+    _, g_picked, g_counts = g
+    # experts-major, as the forward reads the scores: [k, E, N] summed over k
+    hit = idx.T[:, None, :] == lax.broadcasted_iota(
+        jnp.int32, (1, g_counts.shape[0], 1), 1)
+    return jnp.sum(jnp.where(hit, g_picked.T[:, None, :], 0.0), axis=0).T, None
+
+
+_select_experts.defvjp(_select_experts_fwd, _select_experts_bwd)
+
+
 def dropless_moe_mlp(x, params, top_k: int,
                      interpret: Optional[bool] = None, *,
                      held: Optional[Tuple[int, int]] = None,
@@ -952,15 +1126,25 @@ def dropless_moe_mlp(x, params, top_k: int,
     chip's share of an expert-parallel layer (module docstring).
 
         p      = softmax(x_f32 @ router)            over all E
-        w, idx = top_k(p, k)                        renormalize: w /= sum_j w
+        w, idx = the k largest of p, and where      renormalize: w /= sum_j w
         y      = sum_j w[:, j] * down_idx_j(silu(gate_idx_j x) * up_idx_j x)
                  over the j whose expert idx_j is held
+
+    How the stage ``bps.moe.route`` selects (``_select_experts``): ONE
+    pass over the [N, E] scores — a kernel, ``bps_moe_select``, experts on
+    sublanes and tokens on lanes, k rounds of max-and-mark a token — gives
+    the k indices (descending by score, the lowest expert first among
+    equals: a stable descending sort's order and bits), the scores read
+    at them and the per-expert counts; its backward is a dense compare.
+    No sort of E, no scalar gather or scatter; k rounds over E beat the
+    sort wherever k <= E / 8, which every model here keeps (PERF.md
+    section 6, PR 41).
 
     ``routing=(p, beta)``: the probabilities come from OUTSIDE — a router
     of the model's own (an MLP, a state carried from layer to layer) — as
     ``p`` [N, E] float32, with a selection bias ``beta`` [E] or ``None``:
-    the stage ``bps.moe.route`` then takes ``idx = top_k(p + beta)`` and
-    reads the weights from ``p`` at ``idx`` (the bias chooses and is not
+    the stage then takes the k largest of ``p + beta`` (formed inside the
+    pass) and reads the weights from ``p`` at them (the bias chooses and is not
     weighed: no gradient reaches it; ``p``'s reaches the caller's router
     through the weights); ``params`` needs no ``router``; everything after
     is the same code, at any k: ``p`` need not sum to one (sigmoid scores:
@@ -1042,24 +1226,20 @@ def dropless_moe_mlp(x, params, top_k: int,
             logits = jnp.dot(x.astype(jnp.float32),
                              params["router"].astype(jnp.float32),
                              precision=lax.Precision.HIGHEST)   # [N, E]
-            probs = jax.nn.softmax(logits, axis=-1)
-            weights, idx = lax.top_k(probs, top_k)              # [N, k]
+            probs, bias = jax.nn.softmax(logits, axis=-1), None
         else:
             probs, bias = routing
             if probs.shape != (n, e) or probs.dtype != jnp.float32:
                 raise ValueError(
                     f"routing: probabilities must be float32 [{n}, E], got "
                     f"{probs.dtype} {probs.shape}")
-            chooser = probs if bias is None else probs + bias
-            _, idx = lax.top_k(lax.stop_gradient(chooser), top_k)
-            weights = jnp.take_along_axis(probs, idx, axis=-1)
+        idx, weights, counts = _select_experts(probs, bias, top_k, interpret)
         if renormalize:
             total = jnp.sum(weights, axis=-1, keepdims=True)
             if routing is not None:
                 total = total + 1e-20
             weights = weights / total
         pair_expert = idx.reshape(n * top_k)
-        counts = jnp.bincount(pair_expert, length=e).astype(jnp.int32)
         aux = e * jnp.sum(counts.astype(jnp.float32) / n
                           * jnp.mean(probs, axis=0))
         z = (jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
@@ -1111,8 +1291,10 @@ def dropless_moe_mlp(x, params, top_k: int,
             with jax.named_scope("bps.moe.act"):
                 act = _relu2_rows(up, sched, chunk, interpret)
     with jax.named_scope("bps.moe.experts"):
-        ys = _grouped_matmul(act, params["down"].astype(dt), counts,
-                             interpret, first)
+        down = params["down"].astype(dt)
+        if held is not None:
+            act, down = _tie_gradients(act, down)
+        ys = _grouped_matmul(act, down, counts, interpret, first)
     with jax.named_scope("bps.moe.combine"):
         if held is None:
             pairs = _permute_rows(ys, inverse, order).reshape(n, top_k, h)

@@ -343,8 +343,9 @@ def test_grouped_matmul_zeroes_the_rows_of_groups_it_does_not_hold():
 
 def test_held_none_traces_to_the_program_it_was():
     """``held=None, renormalize=False`` is OLMoE's layer, equation for
-    equation: the counts are the parent commit's (PR 28), forward and
-    forward + backward, nested jaxprs included."""
+    equation: the counts are PR 41's (the route stage selects in one
+    kernel; before it they were PR 28's 59 / 1074 and 155 / 3306), forward
+    and forward + backward, nested jaxprs included."""
     params, x = layer_params(), tokens(48)
 
     def layer(x, p):
@@ -354,9 +355,9 @@ def test_held_none_traces_to_the_program_it_was():
         return jax.grad(lambda x, p: layer(x, p)[0].sum(), (0, 1))(x, p)
 
     fwd = jax.make_jaxpr(layer)(x, params).jaxpr
-    assert (len(fwd.eqns), equations(fwd)) == (59, 1074)
+    assert (len(fwd.eqns), equations(fwd)) == (51, 1180)
     bwd = jax.make_jaxpr(grad)(x, params).jaxpr
-    assert (len(bwd.eqns), equations(bwd)) == (155, 3306)
+    assert (len(bwd.eqns), equations(bwd)) == (155, 3422)
 
 
 def test_publish_moe_stats_sets_the_share_gauges():

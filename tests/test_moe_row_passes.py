@@ -491,6 +491,12 @@ def test_windowed_kernels_land_under_the_scopes_the_readers_look_for(gated):
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(
         x, stacks, scores).jaxpr
     stacks_ = kernel_stacks(jaxpr)
+    # the selection (PR 41): ONE kernel, before the loops, under the route
+    # stage and the module's scope
+    select = [s for s in stacks_ if "bps_moe_select" in s]
+    assert len(select) == 1 and re.search(
+        r"\(mtp\)+/bps\.moe\.route/", select[0]), stacks_
+    stacks_.remove(select[0])
     experts = [s for s in stacks_
                if re.search(r"bps\.moe\.experts/.*pallas_call$", s)]
     own = [s for s in stacks_ if s not in experts]

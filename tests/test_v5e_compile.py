@@ -40,10 +40,30 @@ def one_chip(topo):
 
 # ------------------------------------------------ the dropless expert layer
 
+def _route_kernels(text):
+    """The ``op_name`` of each Mosaic kernel of a compiled program's text
+    under the stage ``bps.moe.route`` — after checking that NO instruction
+    under that stage is a sort, a gather or a scatter (what ``lax.top_k``,
+    ``take_along_axis`` and ``bincount`` compiled to, PR 41)."""
+    under = [line for line in text.splitlines() if "bps.moe.route" in line]
+    assert under
+    slow = [line.strip()[:200] for line in under
+            if re.search(r" (sort|gather|scatter)\(", line)]
+    assert not slow, slow
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1) for line in under
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert all(c.endswith("/bps_moe_select/pallas_call")
+               and re.findall(r"bps\.moe\.\w+", c) == ["bps.moe.route"]
+               for c in calls), calls
+    return calls
+
+
 def test_layer_compiles_for_a_v5e_at_the_published_widths(one_chip):
     """OLMoE-1B-7B's expert layer, forward and backward, at 4 x 4096
     tokens: Mosaic takes the grouped matmuls at ``_GMM_TILE`` (two larger
-    tiles overflow VMEM) — nine kernels, none interpreted or replaced."""
+    tiles overflow VMEM) — nine kernels, none interpreted or replaced — and
+    the route stage's selection (``bps_moe_select``, top-8 of 64 over
+    16 384 tokens): nothing under that stage sorts, gathers or scatters."""
     n, h, f, e, k = 4 * 4096, 2048, 1024, 64, 8
 
     def shaped(shape, dtype):
@@ -60,7 +80,8 @@ def test_layer_compiles_for_a_v5e_at_the_published_widths(one_chip):
 
     text = jax.jit(jax.grad(objective, argnums=(0, 1))).lower(
         params, shaped((n, h), jnp.bfloat16)).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert text.count('custom_call_target="tpu_custom_call"') == 9 + 1
+    assert len(_route_kernels(text)) == 1
     for scope in ("bps.moe.route", "bps.moe.dispatch", "bps.moe.experts",
                   "bps.moe.combine"):
         assert scope in text
@@ -74,7 +95,8 @@ def test_held_share_compiles_for_a_v5e_at_the_published_widths(one_chip):
     or replaced — and the row passes that follow the live rows (a range
     that is NOT a prefix of the sorted order) are the repo's own four, each
     under its stage's scope: Mosaic takes the single-row DMAs off the
-    ``[N, 1, h]`` float32 source and the 1 024-row SMEM index block."""
+    ``[N, 1, h]`` float32 source and the 1 024-row SMEM index block.  The
+    selection is the route stage's one kernel."""
     n, h, f, e, g, k = 2 * 8192, 2304, 896, 64, 16, 8
 
     def shaped(shape, dtype):
@@ -90,8 +112,14 @@ def test_held_share_compiles_for_a_v5e_at_the_published_widths(one_chip):
                                         held=(16, g), renormalize=True)
         return jnp.sum(y.astype(jnp.float32)) + aux
 
-    text = jax.jit(jax.grad(objective, argnums=(0, 1))).lower(
-        params, shaped((n, h), jnp.bfloat16)).compile().as_text()
+    compiled = jax.jit(jax.grad(objective, argnums=(0, 1))).lower(
+        params, shaped((n, h), jnp.bfloat16)).compile()
+    # the ``down`` matmul's two gradients tied (``_tie_gradients``): 2.69
+    # GiB of temp here, 2.79 where XLA may run the matrix gradient last
+    # and keep the [131072, 2304] row gradient alive meanwhile (in the
+    # cell's whole step that was + 0.53 GiB of ``peak_hbm_GiB``, PR 41)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.75 * 2 ** 30
+    text = compiled.as_text()
     calls = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -104,7 +132,9 @@ def test_held_share_compiles_for_a_v5e_at_the_published_widths(one_chip):
         ("bps.moe.combine", "bps_moe_spread_scaled"),
         ("bps.moe.dispatch", "bps_moe_spread"),
         ("bps.moe.gate", "bps_moe_gate"),
-        ("bps.moe.gate", "bps_moe_gate_bwd")], own
+        ("bps.moe.gate", "bps_moe_gate_bwd"),
+        ("bps.moe.route", "bps_moe_select")], own
+    assert len(_route_kernels(text)) == 1
 
 
 def test_thin_held_share_compiles_for_a_v5e_in_windows(one_chip):
@@ -137,6 +167,9 @@ def test_thin_held_share_compiles_for_a_v5e_in_windows(one_chip):
     calls = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
+    select = _route_kernels(text)              # top-22 of 512: one kernel
+    assert len(select) == 1
+    calls.remove(select[0])
     assert all("/while/body/" in c for c in calls)
     experts = [c for c in calls
                if re.search(r"bps\.moe\.experts/.*pallas_call$", c)]
@@ -320,8 +353,10 @@ def test_zaya_cell_step_fits_a_v5e(topo, monkeypatch):
     assert f"f32[1024,{vocab}]" in text              # a block of the head
     # a layer: flash forward, its recomputation, two backward kernels; the
     # spread (+ its recomputation), the scaled spread, the gate (+ its
-    # recomputation) and its backward; twelve grouped matmuls
-    assert text.count('custom_call_target="tpu_custom_call"') == 4 * 22
+    # recomputation) and its backward; twelve grouped matmuls; the
+    # selection (+ its recomputation)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 * 24
+    assert len(_route_kernels(text)) == 4 * 2
     for scope in ("attn_cca/pallas_call", "bps.cca.mix", "bps.zaya.router",
                   "bps.head", "bps.moe.experts"):
         assert scope in text
@@ -351,14 +386,15 @@ def test_glm47_flash_cell_step_fits_a_v5e(topo, monkeypatch):
     # a block: flash forward, its recomputation, two backward kernels; a
     # sparse block besides: twelve grouped matmuls, the spread (+ its
     # recomputation), the scaled spread, the gate (+ its recomputation)
-    # and its backward
+    # and its backward, the selection (+ its recomputation)
     assert text.count('custom_call_target="tpu_custom_call"') == (
-        6 * 4 + 5 * 18)
+        6 * 4 + 5 * 20)
+    assert len(_route_kernels(text)) == 5 * 2
     calls = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sum(c.endswith("/attn_mla/pallas_call") for c in calls) == 24
-    assert sum("/mtp/" in c for c in calls) == 22
+    assert sum("/mtp/" in c for c in calls) == 22 + 2
     assert sum("bps.moe.experts" in c for c in calls) == 60
     for scope in ("bps.mla.latent", "bps.moe.score", "bps.moe.shared",
                   "bps.head", "/mtp/"):
@@ -388,8 +424,9 @@ def test_olmoe_cell_step_holds_no_whole_logits(topo, monkeypatch):
         assert whole not in text
     assert f"f32[4096,{vocab}]" in text              # a block of the head
     assert "bps.head" in text
-    # flash forward and backward, nine grouped matmuls
-    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 9
+    # flash forward and backward, nine grouped matmuls, the selection
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 9 + 1
+    assert len(_route_kernels(text)) == 1
     moved = [line.strip()[:160] for line in text.splitlines()
              if re.search(rf"= \w+\[({hidden},{vocab}|{vocab},{hidden})\]"
                           r"\S* (copy|transpose)\(", line)]
@@ -434,7 +471,9 @@ def test_nemotron3_super_cell_step_fits_a_v5e(topo, monkeypatch):
     # per E block, each in a loop over the windows: two grouped matmuls
     # forward, two recomputed under ``remat``, and the layer's backward —
     # its own forward again (two) and four gradients; the activation three
-    # times and its backward; the spread three times and the scaled spread.
+    # times and its backward; the spread three times and the scaled spread;
+    # and OUTSIDE the loops the selection and its recomputation, which
+    # ``route_select_ms`` reads (twelve a step).
     # ``latent_moe_ms`` reads the ten by this rule, ``jvp(...)`` or not
     experts = [c for c in calls
                if re.search(r"bps\.moe\.experts/.*pallas_call$", c)]
@@ -444,12 +483,16 @@ def test_nemotron3_super_cell_step_fits_a_v5e(topo, monkeypatch):
     assert sum(c.endswith("bps_moe_act_bwd/pallas_call") for c in calls) == 6
     assert sum(c.endswith("bps_moe_spread/pallas_call") for c in calls) == 18
     assert not any("bps_moe_gate" in c for c in calls)     # no gate
-    assert len(calls) == 15 + 8 + 6 * (10 + 4 + 4)
-    moe = [c for c in calls if "bps.moe." in c]
+    select = _route_kernels(text)
+    assert len(select) == 6 * 2
+    assert len(calls) == 15 + 8 + 6 * (10 + 4 + 4 + 2)
+    moe = [c for c in calls if "bps.moe." in c and c not in select]
     assert all("/while/body/" in c for c in moe)
-    # the module's block: 4 flash calls, its experts' eighteen kernels
+    assert not any("/while/body/" in c for c in select)
+    # the module's block: 4 flash calls, its experts' eighteen kernels, its
+    # selection twice
     assert sum(bool(re.search(r"/mtp/.*pallas_call$", c))
-               for c in calls) == 4 + 18
+               for c in calls) == 4 + 18 + 2
     # no array of all 180 224 pair rows but the sort's columns
     assert not re.search(r"\[180224,\d+\]", text)
     for scope in ("bps.ssm.in_proj", "bps.ssm.conv", "bps.ssm.gate_norm",
