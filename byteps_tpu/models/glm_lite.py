@@ -77,7 +77,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common.metrics import gauges
-from ..parallel.expert import dropless_moe_mlp
+from ..parallel.expert import dropless_moe_mlp, held_range
 from .gpt import blocked_lm_loss
 from .llama import AttnFn, RMSNorm, apply_rope, rope_frequencies
 from .mellum import banded_attention
@@ -128,8 +128,8 @@ class GlmLiteConfig:
 
     def __post_init__(self):
         if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(int(v) for v in self.experts_held))
+            object.__setattr__(self, "experts_held", held_range(
+                self.experts_held, self.n_routed_experts))
         if self.topk_method != "noaux_tc":
             raise ValueError(
                 f"topk_method={self.topk_method!r}: the router computed "
@@ -174,16 +174,11 @@ class GlmLiteConfig:
                              "[1, n_routed_experts]")
         if self.n_shared_experts < 1:
             raise ValueError("n_shared_experts must be at least 1")
-        first, count = self.held
-        if not (0 <= first and 1 <= count
-                and first + count <= self.n_routed_experts):
-            raise ValueError(f"experts_held={self.experts_held} is no range "
-                             f"of the {self.n_routed_experts} routed experts")
 
     @property
     def held(self) -> Tuple[int, int]:
         """(first, count) of the routed experts whose stacks live here."""
-        return self.experts_held or (0, self.n_routed_experts)
+        return held_range(self.experts_held, self.n_routed_experts)
 
     @property
     def qk_head_dim(self) -> int:

@@ -39,7 +39,7 @@ class GPTConfig:
     remat: bool = False
     # Mixture-of-experts (switch) MLPs: 0 = dense everywhere; >0 turns
     # every ``moe_every``-th block's MLP into a switch layer with that
-    # many experts (parallel/expert.py moe_mlp; ep-shardable)
+    # many experts (parallel/switch_moe.py moe_mlp; ep-shardable)
     moe_experts: int = 0
     moe_every: int = 2
     moe_capacity: float = 1.25
@@ -97,7 +97,7 @@ class MoEMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         from jax import lax as _lax
-        from ..parallel.expert import moe_mlp
+        from ..parallel.switch_moe import moe_mlp
         cfg = self.cfg
         h, f, e = cfg.hidden_size, cfg.intermediate_size, cfg.moe_experts
         # declared expert-stack size: the LOCAL shard when running under

@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from flax.core import freeze
 
-from ..parallel.expert import dropless_moe_mlp
+from ..parallel.expert import dropless_moe_mlp, held_range
 from .gpt import lm_loss
 from .llama import AttnFn, RMSNorm, apply_rope, repeat_kv, rope_frequencies
 
@@ -104,8 +104,8 @@ class MellumConfig:
         object.__setattr__(self, "rope_parameters",
                            freeze(dict(self.rope_parameters)))
         if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(int(v) for v in self.experts_held))
+            object.__setattr__(self, "experts_held", held_range(
+                self.experts_held, self.num_experts))
         if len(self.layer_types) != self.num_hidden_layers:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
@@ -123,16 +123,11 @@ class MellumConfig:
         if not 1 <= self.num_experts_per_tok <= self.num_experts:
             raise ValueError("num_experts_per_tok must lie in "
                              "[1, num_experts]")
-        first, count = self.held
-        if not (0 <= first and 1 <= count
-                and first + count <= self.num_experts):
-            raise ValueError(f"experts_held={self.experts_held} is no "
-                             f"range of the {self.num_experts} experts")
 
     @property
     def held(self) -> Tuple[int, int]:
         """(first, count) of the experts whose stacks live here."""
-        return self.experts_held or (0, self.num_experts)
+        return held_range(self.experts_held, self.num_experts)
 
 
 def mellum_tiny(experts_held: Optional[Tuple[int, int]] = None

@@ -87,7 +87,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common.metrics import gauges
-from ..parallel.expert import dropless_moe_mlp
+from ..parallel.expert import dropless_moe_mlp, held_range
 from .glm_lite import join_experts, mtp_labels, next_tokens, router_scores
 from .gpt import blocked_lm_loss
 from .llama import AttnFn, RMSNorm
@@ -161,8 +161,8 @@ class NemotronHConfig:
 
     def __post_init__(self):
         if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(int(v) for v in self.experts_held))
+            object.__setattr__(self, "experts_held", held_range(
+                self.experts_held, self.n_routed_experts))
         pattern = self.hybrid_override_pattern
         if (len(pattern) != self.num_hidden_layers
                 or set(pattern + self.mtp_hybrid_override_pattern)
@@ -240,11 +240,6 @@ class NemotronHConfig:
                 f"equal run of at most {per_kv} query heads of its own "
                 f"group; held query heads that straddle key/value groups "
                 f"are not computed")
-        first, count = self.held
-        if not (0 <= first and 1 <= count
-                and first + count <= self.n_routed_experts):
-            raise ValueError(f"experts_held={self.experts_held} is no range "
-                             f"of the {self.n_routed_experts} routed experts")
 
     @property
     def ssm_heads(self) -> int:
@@ -268,7 +263,7 @@ class NemotronHConfig:
     @property
     def held(self) -> Tuple[int, int]:
         """(first, count) of the routed experts whose stacks live here."""
-        return self.experts_held or (0, self.n_routed_experts)
+        return held_range(self.experts_held, self.n_routed_experts)
 
     @property
     def out_scale(self) -> float:

@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from flax.core import freeze
 from jax import lax
 
-from ..parallel.expert import dropless_moe_mlp
+from ..parallel.expert import dropless_moe_mlp, held_range
 from .gpt import blocked_lm_loss
 from .llama import AttnFn, RMSNorm, apply_rope, repeat_kv, rope_frequencies
 from .mellum import banded_attention
@@ -118,8 +118,8 @@ class ZayaConfig:
         object.__setattr__(self, "rope_parameters",
                            freeze(dict(self.rope_parameters)))
         if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(int(v) for v in self.experts_held))
+            object.__setattr__(self, "experts_held", held_range(
+                self.experts_held, self.num_experts))
         if self.layer_types != (HYBRID,) * self.num_hidden_layers:
             raise ValueError(
                 f"layer_types must name {self.num_hidden_layers} "
@@ -148,16 +148,11 @@ class ZayaConfig:
         if not self.tie_word_embeddings:
             raise ValueError("the head is the embedding's table "
                              "(tie_word_embeddings)")
-        first, count = self.held
-        if not (0 <= first and 1 <= count
-                and first + count <= self.num_experts):
-            raise ValueError(f"experts_held={self.experts_held} is no "
-                             f"range of the {self.num_experts} experts")
 
     @property
     def held(self) -> Tuple[int, int]:
         """(first, count) of the experts whose stacks live here."""
-        return self.experts_held or (0, self.num_experts)
+        return held_range(self.experts_held, self.num_experts)
 
     @property
     def rotary_dim(self) -> int:

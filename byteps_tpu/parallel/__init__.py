@@ -7,7 +7,7 @@ accumulation) plus the scale axes the reference lacks but TPU training
 needs: sequence/context parallelism (sequence.py ring/Ulysses;
 ring_flash.py runs the Pallas flash kernels inside the ring), tensor
 (tensor_parallel.py, GSPMD), pipeline (pipeline.py, GPipe in one
-shard_map), expert (expert.py/moe_lm.py, switch-MoE all_to_all),
+shard_map), expert (switch_moe.py/moe_lm.py, switch-MoE all_to_all),
 ZeRO-1/FSDP/HSDP sharded-optimizer DP (zero.py), the streamed
 (fsdp, tp) Llama composite (fsdp_tp.py, ZeRO-3 by GSPMD annotation),
 and the 3D (dp, pp, tp) composite (three_d.py).  Every axis is pinned
@@ -39,7 +39,7 @@ from .long_context import (  # noqa: F401
     shard_lm_batch,
     synthetic_lm_batch,
 )
-from .expert import (  # noqa: F401
+from .switch_moe import (  # noqa: F401
     init_moe_params,
     make_dp_ep_train_step,
     make_ep_mesh,

@@ -5,7 +5,7 @@ Glues the GPT family's switch-MoE blocks (models/gpt.py
 over BOTH mesh axes (plain data parallelism for the dense layers —
 attention and embeddings see only their own sequences), expert stacks
 are sharded over ``ep``, and every MoE block's token dispatch crosses
-the ep axis as all_to_all (parallel/expert.py).  The Switch aux
+the ep axis as all_to_all (parallel/switch_moe.py).  The Switch aux
 load-balance losses are sown by the model (``moe_aux`` collection) and
 folded into the objective here.
 
@@ -25,7 +25,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.gpt import GPT, GPTConfig, token_nll
-from .expert import DP_AXIS, EP_AXIS, make_ep_mesh  # noqa: F401
+from .switch_moe import DP_AXIS, EP_AXIS, make_ep_mesh  # noqa: F401
 from .mesh_util import jit_mapped_step
 
 

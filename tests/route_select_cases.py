@@ -34,6 +34,21 @@ def with_the_stage_as_it_was(monkeypatch):
         lambda probs, bias, top_k, interpret: selected(probs, bias, top_k))
 
 
+def with_the_window(monkeypatch, window, seen=None):
+    """A held layer takes windows of ``window`` rows (``None``: the whole
+    arrays) whatever ``layer_plan`` says of its shapes; the plan is
+    otherwise the rule's.  ``seen`` collects what the layer asked."""
+    rule = expert.layer_plan
+
+    def plan(*shape):
+        if seen is not None:
+            seen.append(shape)
+        return rule(*shape)._replace(
+            kind="held_rows" if window is None else "held_windows",
+            window=window)
+    monkeypatch.setattr(expert, "layer_plan", plan)
+
+
 def layer_params(count, router=True):
     """SiLU-gated stacks of ``count`` experts (and a router over E)."""
     k = jax.random.split(jax.random.PRNGKey(11), 4)

@@ -17,10 +17,11 @@ import pytest
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from byteps_tpu.parallel.expert import (
-    _ROW_CHUNK, DP_AXIS, EP_AXIS, dropless_moe_mlp, init_moe_params,
-    make_dp_ep_train_step, make_ep_mesh, moe_mlp, moe_mlp_reference,
-    row_schedule, shard_moe_params)
+from byteps_tpu.ops.moe_kernels import _ROW_CHUNK
+from byteps_tpu.parallel.expert import dropless_moe_mlp, row_schedule
+from byteps_tpu.parallel.switch_moe import (
+    DP_AXIS, EP_AXIS, init_moe_params, make_dp_ep_train_step, make_ep_mesh,
+    moe_mlp, moe_mlp_reference, shard_moe_params)
 
 from .jaxpr_count import equations
 

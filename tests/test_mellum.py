@@ -37,9 +37,9 @@ from byteps_tpu.models.mellum import (FULL, SLIDING, Mellum, MellumConfig,
                                       banded_attention, expert_counts,
                                       mellum_loss, mellum_tiny)
 from byteps_tpu.ops import flash_attention
+from byteps_tpu.ops.moe_kernels import _grouped_matmul
 from byteps_tpu.parallel import make_dp_train_step, replicate
-from byteps_tpu.parallel.expert import (_grouped_matmul, dropless_moe_mlp,
-                                        publish_moe_stats)
+from byteps_tpu.parallel.expert import dropless_moe_mlp, publish_moe_stats
 
 RTOL = 1e-5
 GRAD_RTOL = 5e-5

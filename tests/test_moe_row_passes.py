@@ -13,7 +13,9 @@ float32 rounding of one product (rtol 1e-6): a wrong row, a wrong weight or
 a row outside the range fails by orders of magnitude.
 """
 
+import ast
 import math
+import pathlib
 import re
 
 import jax
@@ -21,14 +23,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from byteps_tpu.parallel import expert
-from byteps_tpu.parallel.expert import (_ROW_CHUNK, _combine_rows,
-                                        _dispatch_rows, _gather_sum_rows,
-                                        _silu_gate_rows, _spread_rows,
-                                        dropless_moe_mlp, publish_moe_stats,
-                                        row_schedule, window_rows,
-                                        window_trips)
+from byteps_tpu.ops.moe_kernels import _ROW_CHUNK, _spread_rows
+from byteps_tpu.parallel.expert import (_combine_rows, _dispatch_rows,
+                                        _gather_sum_rows, _silu_gate_rows,
+                                        dropless_moe_mlp, layer_plan,
+                                        publish_moe_stats, row_schedule,
+                                        window_rows, window_trips)
 
+from .route_select_cases import with_the_window
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 N, K, H, E, CHUNK = 48, 2, 32, 8, 16
 M = N * K
 
@@ -256,6 +260,92 @@ def test_publish_moe_stats_sets_the_visited_row_share():
     assert gauges["moe.held_pair_share"] == pytest.approx(3000 / 65536)
 
 
+# ------------------- one plan for the layer and its gauges (PR 42)
+
+# cell -> ((N k, held experts, experts), the plan), from
+# benchmarks/configs/*.json and the traffic's tokens a chip
+CELLS = {
+    "olmoe_1b_7b": ((16384 * 8, None, 64), ("all", 1024, None)),
+    "mellum2_12b": ((16384 * 8, 16, 64), ("held_rows", 1024, None)),
+    "zaya1_8b": ((16384 * 1, 8, 16), ("held_rows", 1024, None)),
+    "glm47_flash": ((16384 * 4, 8, 64), ("held_rows", 1024, None)),
+    "nemotron3_super": ((8192 * 22, 8, 512), ("held_windows", 1024, 6144)),
+}
+# cell -> (visited_row_share, window_trips) on balanced counts, as the rule
+# plans it and with the other kind forced (windows of half the rows; none)
+GAUGES = {
+    "mellum2_12b": ((32768 / 131072, None), (65536 / 131072, 1.0)),
+    "zaya1_8b": ((8192 / 16384, None), (8192 / 16384, 1.0)),
+    "glm47_flash": ((8192 / 65536, None), (32768 / 65536, 1.0)),
+    "nemotron3_super": ((6144 / 180224, 1.0), (3072 / 180224, None)),
+}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_layer_plan_at_the_cells_shapes(cell):
+    shape, want = CELLS[cell]
+    assert tuple(layer_plan(*shape)) == want
+    if shape[1] is not None:
+        assert window_rows(shape[0], 1, *shape[1:]) == want[2]
+
+
+@pytest.mark.parametrize("cell", GAUGES)
+def test_the_gauges_describe_the_plan_the_layer_takes(cell, monkeypatch):
+    """``publish_moe_stats`` has no rule of its own: on balanced counts it
+    sets what ``layer_plan``'s kind implies, and follows the plan where
+    another is forced on the layer."""
+    import byteps_tpu as bps
+    (rows, count, experts), (kind, _, _) = CELLS[cell]
+    counts = np.full((2, experts), rows // experts)
+    forced = rows // 2 if kind == "held_rows" else None
+    for (share, trips), window in zip(GAUGES[cell], ("rule", forced)):
+        before = bps.metrics_snapshot()["gauges"].get("moe.window_trips")
+        with monkeypatch.context() as patch:
+            if window != "rule":
+                with_the_window(patch, window)
+            publish_moe_stats(counts, held=(0, count))
+        gauges = bps.metrics_snapshot()["gauges"]
+        assert gauges["moe.visited_row_share"] == pytest.approx(share)
+        assert gauges.get("moe.window_trips") == (
+            before if trips is None else trips)
+        assert gauges["moe.held_pair_share"] == pytest.approx(count / experts)
+
+
+def _imported(path):
+    """The modules a file of ``byteps_tpu`` imports anywhere in it, relative
+    names resolved against its package (read with ``ast``, as
+    ``tools/bpslint`` reads)."""
+    package = path.relative_to(ROOT).with_suffix("").parts[:-1]
+    found = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            found |= {alias.name for alias in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - node.level + 1] if node.level else ()
+            module = ".".join(base + tuple(filter(None, [node.module])))
+            found |= {module} | {f"{module}.{a.name}" for a in node.names}
+    return found
+
+
+def test_the_expert_layers_imports_point_one_way():
+    """``models/*`` -> ``parallel/expert.py`` -> ``ops/moe_kernels.py``;
+    ``parallel/switch_moe.py`` beside them, importing neither."""
+    pkg = ROOT / "byteps_tpu"
+    kernels = _imported(pkg / "ops" / "moe_kernels.py")
+    assert not [m for m in kernels if m.startswith(
+        ("byteps_tpu.parallel", "byteps_tpu.models"))]
+    layer = _imported(pkg / "parallel" / "expert.py")
+    switch = _imported(pkg / "parallel" / "switch_moe.py")
+    assert "byteps_tpu.ops.moe_kernels" in layer
+    assert not [m for m in layer if "switch_moe" in m or ".models" in m]
+    assert not [m for m in switch if m.startswith(
+        ("byteps_tpu.parallel.expert", "byteps_tpu.ops.moe_kernels",
+         "byteps_tpu.models"))]
+    for model in ("olmoe", "mellum", "zaya", "glm_lite", "nemotron_h"):
+        assert "byteps_tpu.parallel.expert.dropless_moe_mlp" in _imported(
+            pkg / "models" / f"{model}.py")
+
+
 # ------------------------------------------ the layer in windows (PR 40)
 
 @pytest.mark.parametrize("n,top_k,held_count,experts,want", [
@@ -381,13 +471,13 @@ def test_windowed_layer_is_the_whole_layer_and_the_dense_arithmetic(
                 lambda *a: jnp.sum(fn(*a) * cot), (0, 1, 2)))(x, stacks,
                                                                logits)
 
-    rule = []
-    monkeypatch.setattr(expert, "window_rows",
-                        lambda *a: rule.append(a) or _LW)
-    windowed = run(lambda *a: layer(*a)[0])
-    counts = np.asarray(layer(x, stacks, logits)[3])
-    assert rule[0] == (_LN, top_k, held[1], _LE)
-    monkeypatch.setattr(expert, "window_rows", lambda *a: None)
+    asked = []
+    with monkeypatch.context() as patch:
+        with_the_window(patch, _LW, asked)
+        windowed = run(lambda *a: layer(*a)[0])
+        counts = np.asarray(layer(x, stacks, logits)[3])
+    assert asked[0] == (_LN * top_k, held[1], _LE)
+    with_the_window(monkeypatch, None)
     whole = run(lambda *a: layer(*a)[0])
     dense = run(lambda x, s, l: _dense(x, s, jax.nn.sigmoid(l), top_k, held))
 
@@ -426,11 +516,12 @@ def test_windows_that_overlap_count_their_shared_rows_once(monkeypatch):
                                 routing=(jax.nn.sigmoid(logits), None))
 
     def run(window):
-        monkeypatch.setattr(expert, "window_rows", lambda *a: window)
-        with jax.default_matmul_precision("highest"):
-            return jax.jit(jax.value_and_grad(
-                lambda *a: jnp.sum(layer(*a)[0] ** 2), (0, 1, 2)))(
-                    x, stacks, logits)
+        with monkeypatch.context() as patch:
+            with_the_window(patch, window)
+            with jax.default_matmul_precision("highest"):
+                return jax.jit(jax.value_and_grad(
+                    lambda *a: jnp.sum(layer(*a)[0] ** 2), (0, 1, 2)))(
+                        x, stacks, logits)
 
     windowed, whole = run(_LW), run(None)
     counts = np.asarray(layer(x, stacks, logits)[3])

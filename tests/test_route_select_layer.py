@@ -15,9 +15,10 @@ from byteps_tpu.parallel import expert
 from byteps_tpu.parallel.expert import _tie_gradients, dropless_moe_mlp
 
 from .route_select_cases import (E, WINDOW, equation_stacks, layer_params,
-                                 tokens, with_the_stage_as_it_was)
+                                 tokens, with_the_stage_as_it_was,
+                                 with_the_window)
 
-# name -> (held, window_rows' answer or "rule", top_k)
+# name -> (held, the window ``layer_plan`` gives or "rule", top_k)
 LAYERS = {"all_experts": (None, "rule", 2),
           "held_whole_arrays": ((2, 4), None, 2),
           "held_windowed": ((2, 4), WINDOW, 4)}
@@ -41,12 +42,11 @@ def value_and_grads(held, top_k, branch):
 
 
 def test_layer_equals_the_layer_with_the_stage_as_it_was(monkeypatch):
-    rule = expert.window_rows
     for name, (held, window, top_k) in LAYERS.items():
         for branch in ("softmax_router", "routing_with_bias"):
             with monkeypatch.context() as patch:
-                patch.setattr(expert, "window_rows",
-                              rule if window == "rule" else lambda *a: window)
+                if window != "rule":
+                    with_the_window(patch, window)
                 got = value_and_grads(held, top_k, branch)
                 with_the_stage_as_it_was(patch)
                 want = value_and_grads(held, top_k, branch)
