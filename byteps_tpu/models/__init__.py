@@ -14,6 +14,12 @@ from .llama import (  # noqa: F401
     llama3_8b,
     llama_tiny,
 )
+from .ling import (  # noqa: F401
+    Ling,
+    LingConfig,
+    ling_loss,
+    ling_tiny,
+)
 from .mellum import (  # noqa: F401
     Mellum,
     MellumConfig,

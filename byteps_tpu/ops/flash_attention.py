@@ -360,10 +360,11 @@ def _call(kern, grid, in_specs, out_specs, out_shape, scratch, interpret):
 def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret,
          window=None):
     """[BH, Tq, D] x [BH, Tk, D] (padded to whole ``bq`` / ``bk``
-    sub-blocks) -> (out, lse[BH, Tq, 128])."""
+    sub-blocks) -> (out, lse[BH, Tq, 128]); ``v`` [BH, Tk, Dv] and the
+    output keep v's width."""
     from jax.experimental.pallas import tpu as pltpu
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[2]
     (sq, _), (_, sk) = _spans(tq, tk, d, q.dtype.itemsize, bq, bk)
     nq, nk = tq // sq, tk // sk
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
@@ -376,20 +377,20 @@ def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, sq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, sk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, sk, dv), lambda b, i, j: (b, j, 0)),
         ],
         [
-            pl.BlockSpec((1, sq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, sq, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, sq, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         [
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
         ],
         [
             pltpu.VMEM((sq, _LANES), jnp.float32),
             pltpu.VMEM((sq, _LANES), jnp.float32),
-            pltpu.VMEM((sq, d), jnp.float32),
+            pltpu.VMEM((sq, dv), jnp.float32),
         ],
         interpret)(qoff, q, k, v)
 
@@ -535,7 +536,7 @@ def _bwd_impl(q, k, v, do, lse, delta, scale, causal, q_off, kv_len,
               bq, bk, interpret, window=None):
     from jax.experimental.pallas import tpu as pltpu
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, d_v = k.shape[1], v.shape[2]    # v, dO and dV keep v's width
     (sq, rq), (sk, rk) = _spans(tq, tk, d, q.dtype.itemsize, bq, bk)
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
     static = dict(scale=scale, causal=causal, kv_len=kv_len,
@@ -549,23 +550,23 @@ def _bwd_impl(q, k, v, do, lse, delta, scale, causal, q_off, kv_len,
     def q_side(w):
         return pl.BlockSpec((1, rq, w), lambda b, j, i: (b, i, 0))
 
-    def k_side():
-        return pl.BlockSpec((1, sk, d), lambda b, j, i: (b, j, 0))
+    def k_side(w):
+        return pl.BlockSpec((1, sk, w), lambda b, j, i: (b, j, 0))
 
     def like(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
-    def acc(rows):
-        return pltpu.VMEM((rows, d), jnp.float32)
+    def acc(rows, w=d):
+        return pltpu.VMEM((rows, w), jnp.float32)
 
     grads = _call(
         functools.partial(_bwd_dkv_kernel, fused=fused, **static),
         (bh, tk // sk, tq // rq),
-        [pl.BlockSpec(memory_space=pltpu.SMEM), q_side(d), k_side(),
-         k_side(), q_side(d), q_side(_LANES), q_side(_LANES)],
-        [k_side(), k_side()] + [q_side(d)] * fused,
+        [pl.BlockSpec(memory_space=pltpu.SMEM), q_side(d), k_side(d),
+         k_side(d_v), q_side(d_v), q_side(_LANES), q_side(_LANES)],
+        [k_side(d), k_side(d_v)] + [q_side(d)] * fused,
         [like(k), like(v)] + [like(q)] * fused,
-        [acc(sk), acc(sk)] + [acc(rq)] * fused,
+        [acc(sk), acc(sk, d_v)] + [acc(rq)] * fused,
         interpret)(qoff, q, k, v, do, lse, delta)
     if fused:
         dk, dv, dq = grads
@@ -576,14 +577,14 @@ def _bwd_impl(q, k, v, do, lse, delta, scale, causal, q_off, kv_len,
     def q_span(w):
         return pl.BlockSpec((1, sq, w), lambda b, i, j: (b, i, 0))
 
-    def kv_resident():
-        return pl.BlockSpec((1, rk, d), lambda b, i, j: (b, j, 0))
+    def kv_resident(w):
+        return pl.BlockSpec((1, rk, w), lambda b, i, j: (b, j, 0))
 
     dq = _call(
         functools.partial(_bwd_dq_kernel, **static),
         (bh, tq // sq, tk // rk),
-        [pl.BlockSpec(memory_space=pltpu.SMEM), q_span(d), kv_resident(),
-         kv_resident(), q_span(d), q_span(_LANES), q_span(_LANES)],
+        [pl.BlockSpec(memory_space=pltpu.SMEM), q_span(d), kv_resident(d),
+         kv_resident(d_v), q_span(d_v), q_span(_LANES), q_span(_LANES)],
         q_span(d), like(q), [acc(sq)], interpret)(
             qoff, q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -628,6 +629,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
     """Flash attention.  [B, Tq, H, D] x [B, Tk, H, D] -> [B, Tq, H, D].
+    ``v`` may have a head size of its own, [B, Tk, H, Dv] -> [B, Tq, H,
+    Dv] (a latent whose q.k and v widths differ): v, the output, its
+    accumulator, dO, dV and delta keep v's width padded to lanes, q, k, dQ
+    and dK theirs; ``sm_scale`` defaults from q's.
 
     Same contract as parallel/sequence.py full_attention (including the
     decode-style alignment: with causal=True and Tq < Tk the q rows cover
@@ -667,7 +672,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     q_off = tk - tq  # decode alignment (0 when square)
 
     bq, bk, tq_p, tk_p = _blocks(tq, tk, block_q, block_k)
-    d_p = _ceil_to(d, _LANES)
+    d_v = v.shape[-1]
     sched = block_schedule(tq, tk, causal, q_off, block_q=block_q,
                            block_k=block_k, window=window)
     from ..common.metrics import gauges
@@ -676,11 +681,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                sched["visited"] / sched["total"])
 
     def to3(x, t_p):
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, x.shape[1], d)
-        return jnp.pad(x, ((0, 0), (0, t_p - x.shape[1]), (0, d_p - d)))
+        w = x.shape[-1]
+        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, x.shape[1], w)
+        return jnp.pad(x, ((0, 0), (0, t_p - x.shape[1]),
+                           (0, _ceil_to(w, _LANES) - w)))
 
     q3, k3, v3 = to3(q, tq_p), to3(k, tk_p), to3(v, tk_p)
     out = _flash(q3, k3, v3, scale, causal, q_off, tk, (bq, bk),
                  bool(interpret), window)
-    out = out[:, :tq, :d].reshape(b, h, tq, d)
+    out = out[:, :tq, :d_v].reshape(b, h, tq, d_v)
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)

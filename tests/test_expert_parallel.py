@@ -326,3 +326,62 @@ def test_gated_calls_trace_to_the_programs_they_were(model, params, kwargs,
     assert (len(fwd.eqns), equations(fwd)) == forward
     bwd = jax.make_jaxpr(grad)(x, params).jaxpr
     assert (len(bwd.eqns), equations(bwd)) == backward
+
+
+# ------------- gated experts in windows, behind a group limit (PR 43)
+
+def test_gated_experts_in_windows_behind_a_group_limit():
+    """What ``models/ling.py`` asks of the layer: SiLU-GATED experts on the
+    ``held_windows`` plan (8 of 512 held, top-8: 648 pair rows in windows
+    of 24) with ``routing=(masked scores, None)`` — the sigmoid scores
+    inside each token's 4 chosen groups of 8, zero outside — a combination
+    no other model runs.  Value and the gradient of rows, stacks and
+    logits against each held expert on every token."""
+    from byteps_tpu.models.ling import group_limited
+    from byteps_tpu.parallel.expert import layer_plan
+    n, h, f, e, top_k, held = 81, 16, 24, 512, 8, (0, 8)
+    plan = layer_plan(n * top_k, held[1], e)
+    assert (plan.kind, plan.chunk, plan.window) == ("held_windows", 8, 24)
+    k = jax.random.split(jax.random.PRNGKey(4), 6)
+    x = jax.random.normal(k[0], (n, h))
+    # the held group's experts favoured, so that the windows are not empty
+    logits = jax.random.normal(k[1], (n, e)).at[:, :64].add(1.0)
+    stacks = {"gate": jax.random.normal(k[2], (8, h, f)) / np.sqrt(h),
+              "up": jax.random.normal(k[3], (8, h, f)) / np.sqrt(h),
+              "down": jax.random.normal(k[4], (8, f, h)) / np.sqrt(f)}
+    weight = jax.random.normal(k[5], (n, h))
+
+    def masked(logits):
+        return group_limited(jax.nn.sigmoid(logits), jnp.zeros((e,)), 8, 4)[0]
+
+    def program(x, stacks, logits):
+        return dropless_moe_mlp(x, stacks, top_k, interpret=True, held=held,
+                                renormalize=True,
+                                routing=(masked(logits), None))
+
+    def reference(x, stacks, logits):
+        p = masked(logits)
+        _, chosen = lax.top_k(p, top_k)
+        picked = (jnp.arange(e) == chosen[..., None]).any(-2)
+        w = jnp.where(picked, p, 0.0)
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        y = jnp.zeros_like(x)
+        for i in range(held[1]):
+            hidden = jax.nn.silu(x @ stacks["gate"][i]) * (x @ stacks["up"][i])
+            y = y + w[:, held[0] + i, None] * (hidden @ stacks["down"][i])
+        return y
+
+    args = (x, stacks, logits)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(program(*a)[0] * weight), (0, 1, 2)))(*args)
+        want = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(reference(*a) * weight), (0, 1, 2)))(*args)
+        counts = program(*args)[3]
+    assert int(counts.sum()) == n * top_k and int(counts[:8].sum()) > 0
+    # nothing was chosen outside a token's four groups
+    assert int((np.asarray(counts).reshape(8, 64).sum(1) > 0).sum()) >= 4
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-5, atol=2e-5 * float(jnp.max(jnp.abs(w))))

@@ -544,3 +544,75 @@ def test_long_context_flash_mode():
     mesh2 = make_sp_mesh(jax.devices()[:8], n_sp=2)
     with pytest.raises(ValueError, match="needs sp=1"):
         make_dp_sp_train_step(mesh2, cfg, tx, attention="flash")
+
+
+# ------------------------------------------ a value width of its own (PR 43)
+
+def _exact_two_widths(q, k, v):
+    """Causal softmax attention whose q.k and v widths differ."""
+    t = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where(jnp.arange(t)[None, :] <= jnp.arange(t)[:, None], s,
+                  -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("d,dv", [(192, 128), (24, 16), (16, 40)])
+def test_a_value_width_of_its_own_matches_exact(d, dv, form):
+    """``v`` narrower (a latent whose q.k width is 192 and v width 128) or
+    wider than ``q``: the output, dO and dV keep v's width, q, k, dQ and dK
+    theirs; value and all three gradients, causal, in both forms."""
+    b, t, h = 1, 256, 2
+    q = _rand((b, t, h, d), jnp.float32, 50)
+    k = _rand((b, t, h, d), jnp.float32, 51)
+    v = _rand((b, t, h, dv), jnp.float32, 52)
+    w = _rand((b, t, h, dv), jnp.float32, 53)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                               interpret=True)
+
+    got = flash(q, k, v)
+    assert got.shape == (b, t, h, dv)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_exact_two_widths(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    g_got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    g_want = jax.grad(lambda *a: jnp.sum(_exact_two_widths(*a) * w),
+                      (0, 1, 2))(q, k, v)
+    for a, b_ in zip(g_got, g_want):
+        assert a.shape == b_.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4)
+
+
+def test_two_widths_pad_each_to_its_own_lanes():
+    """192 / 128: q and k go in at 256 lanes, v and the output at 128 — not
+    both at 256 (a third of the kernels' ``P V`` work would be zeros)."""
+    q = jnp.zeros((1, 512, 2, 192), jnp.bfloat16)
+    v = jnp.zeros((1, 512, 2, 128), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=True, interpret=False)
+        .astype(jnp.float32)), (0, 1, 2)))(q, q, v))
+    assert "bf16[2,512,256]" in text and "bf16[2,512,128]" in text
+    assert "f32[512,256]" in text            # dQ / dK accumulators
+    assert text.count("bf16[2,512,192]") > 0
+
+
+@pytest.mark.parametrize("shape,dtype,causal,want", [
+    ((1, 256, 2, 64), jnp.float32, True, 209),
+    ((2, 1024, 4, 128), jnp.bfloat16, True, 233),
+    ((1, 8192, 2, 256), jnp.bfloat16, True, 290),
+    ((1, 100, 3, 48), jnp.float32, False, 206)])
+def test_equal_widths_trace_to_the_programs_they_were(shape, dtype, causal,
+                                                      want):
+    """A call whose q.k and v widths are equal — every call the six cells
+    make — traces, forward and backward, to the parent commit's program,
+    equation for equation (the numbers are that commit's, PR 42): the
+    second width is shapes in block specs, nothing in the kernels."""
+    from .jaxpr_count import equations
+    q = jnp.zeros(shape, dtype)
+    program = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=causal, interpret=False)
+        .astype(jnp.float32)), (0, 1, 2)))(q, q, q)
+    assert equations(program.jaxpr) == want

@@ -505,3 +505,60 @@ def test_nemotron3_super_cell_step_fits_a_v5e(topo, monkeypatch):
     assert not re.search(rf"f32\[[\d,]*{chunks},\d+,128,128\]", text)
     assert not re.search(r"f32\[[\d,]*,128,128\]\S* (fusion|exponential)\(",
                          text)
+
+
+def test_ling3_flash_cell_step_fits_a_v5e(topo, monkeypatch):
+    """``ling3_flash.fused_1c``'s step at the published widths on the rung
+    of ISSUE 43's memory ladder the cell takes — (a), 2 x 8 192 positions,
+    the first whose whole step compiles under 15.0 GiB (14.74 by
+    ``memory_analysis``: arguments 8.49 + temp 6.18 + code 0.07) and runs
+    on the chip: Mosaic takes the delta-rule
+    scan's kernels at 32 heads of 128 x 128 in chunks of 128 (``bps_kda_fwd``
+    twice a KDA layer — the forward and, under ``remat``, the one that
+    stores the chunk-start states — and ``bps_kda_bwd`` once, whose body is
+    ``jax.vjp`` of the chunk's text), the flash kernels with q.k at 256
+    lanes and v at 128, and the GATED experts in windows behind the group
+    limit; arguments + temp + code stay under 15.0 GiB; the head's
+    ``[tokens, 19648]`` logits exist only a block at a time; and no
+    ``[.., C, C, 128]`` float32 array (the channel-wise decay of a chunk's
+    score pairs, C = 64 or 128) exists outside a kernel."""
+    compiled, config, traffic = _compiled_cell_step(
+        topo, monkeypatch, "ling3_flash.fused_1c")
+    memory = compiled.memory_analysis()
+    # weights and two moments: 3 x 759,799,584 x 4 B = 8.49 GiB
+    assert 8.45 < memory.argument_size_in_bytes / 2 ** 30 < 8.55
+    assert _used_gib(memory) < 15.0
+    assert traffic["seqs_per_chip"] == 2                   # rung (a)
+    text = compiled.as_text()
+    tokens = traffic["seq_len"] * traffic["seqs_per_chip"]
+    vocab = config["vocab_size"]
+    if tokens * vocab * 4 > 2 ** 30:     # else one block IS all the rows
+        assert f"[{tokens},{vocab}]" not in text
+    assert "bps.head" in text
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kda = [c for c in calls
+           if re.search(r"bps\.kda\.scan\)*/.*pallas_call$", c)]
+    assert sum(c.endswith("bps_kda_fwd/pallas_call") for c in kda) == 10
+    assert sum(c.endswith("bps_kda_bwd/pallas_call") for c in kda) == 5
+    assert len(kda) == 15 == sum("bps_kda" in c for c in calls)
+    # the one MLA layer: flash forward, its recomputation, two backward
+    # kernels (T = 8192 is past the resident form)
+    assert sum(c.endswith("/attn_mla/pallas_call") for c in calls) == 4
+    # four sparse layers: the selection and its recomputation
+    assert len(_route_kernels(text)) == 4 * 2
+    experts = [c for c in calls
+               if re.search(r"bps\.moe\.experts/.*pallas_call$", c)]
+    # per sparse layer, in the loops over the windows: three grouped
+    # matmuls forward, three recomputed under ``remat``, six gradients —
+    # what ``moe_ms`` reads by this rule
+    assert len(experts) == 4 * 12
+    assert all("/while/body/" in c for c in experts)
+    assert any("bps_moe_gate" in c for c in calls)         # gated experts
+    assert not re.search(r"f32\[[\d,]*(64,64|128,128),128\]", text)
+    for scope in ("bps.kda.proj", "bps.kda.conv", "bps.kda.gate",
+                  "bps.kda.out", "bps.mla.latent", "bps.mla.gate",
+                  "bps.moe.group_limit", "bps.moe.score", "bps.moe.shared",
+                  "mixer_kda", "attn_mla"):
+        assert scope in text, scope
