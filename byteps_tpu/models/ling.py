@@ -75,8 +75,13 @@ layers in a form no key gives: a non-zero entry in a layer this model
 builds is refused.  Initialisation: ``A_log = log U[1, 16]``, ``dt_bias``
 the inverse softplus of a log-uniform [0.001, 0.1], normal(0.02)
 elsewhere, norm weights 1.  The recurrence runs as ``ops/kda_scan.py``
-``kda_scan`` (Mosaic kernels, forward and backward, interpreted off the
-TPU).
+``kda_scan`` and the row stages around it — the short convolution, SiLU,
+the L2 norms, ``g``, ``beta`` in front, the head norm times the output
+gate behind — as ``ops/kda_rows.py`` ``kda_pre`` / ``kda_post``: one pass
+over the projection each, float32 inside the kernel (Mosaic kernels,
+forward and backward, interpreted off the TPU).  :func:`l2_normalize` and
+:func:`log_decay` here are the plain text those kernels are tested
+against.
 
 One chip's share (none given: everything): ``experts_held = (first,
 count)`` of the ``num_experts`` the router scores — inside ONE routing
@@ -108,7 +113,6 @@ from .glm_lite import join_experts, router_scores
 from .gpt import blocked_lm_loss
 from .llama import AttnFn, RMSNorm, apply_rope, rope_frequencies
 from .mellum import banded_attention
-from .nemotron_h import causal_conv
 
 __all__ = ["LingConfig", "Ling", "ling_tiny", "ling_loss", "expert_counts",
            "group_hit_share", "publish_group_stats"]
@@ -344,52 +348,58 @@ def group_limited(scores, bias, n_group: int, topk_group: int):
     return jnp.where(inside, scores, 0.0), chosen
 
 
+class _Scale(nn.Module):
+    """A norm's weight [n] by itself (``<name>/scale``, ones), for a norm
+    that a kernel computes."""
+
+    @nn.compact
+    def __call__(self, n):
+        return self.param("scale", nn.initializers.ones, (n,), jnp.float32)
+
+
 class LingKda(nn.Module):
     """The Kimi Delta Attention mixer on the normed rows ``a`` [B, T, h]
-    (module docstring); each stage under a ``bps.kda.*`` scope, the scan's
-    kernels under ``bps.kda.scan``."""
+    (module docstring); each stage under a ``bps.kda.*`` scope: the
+    projection (``proj``), the row stages in front of the scan as ONE pass
+    over it (``pre``: ``ops/kda_rows.py`` ``kda_pre`` — the short
+    convolution, SiLU, the L2 norms, ``g`` and ``beta`` in float32 inside
+    the kernel, q, k, v rounded to ``cfg.dtype`` where the scan reads
+    them), the scan's kernels (``scan``), the head norm times the gate as
+    one pass (``kda_post``) and ``W_o`` (``out``)."""
 
     cfg: LingConfig
 
     @nn.compact
     def __call__(self, a):
+        from ..ops.kda_rows import kda_post, kda_pre
         from ..ops.kda_scan import kda_scan
         cfg = self.cfg
         heads, d = cfg.num_attention_heads, cfg.head_dim
         inner = heads * d
-        b, t, _ = a.shape
+        t = a.shape[1]
         gauges.set("kda.log_decay_floor", float(cfg.kda_lower_bound))
         with jax.named_scope("bps.kda.proj"):
             # [ q | k | v | f | the output gate | beta ]
             proj = _dense(5 * inner + heads, "in_proj", cfg.dtype)(a)
-        with jax.named_scope("bps.kda.conv"):
-            kernel = self.param("conv_kernel", _INIT,
-                                (cfg.short_conv_kernel_size, 3 * inner),
-                                jnp.float32)
-            # float32 up to the L2 norms: rounding the convolution's result
-            # to bfloat16 first read 0.20 against 0.075 on the decays'
-            # leaves (gradcheck_ling.py --rehearsal)
-            qkv = jax.nn.silu(causal_conv(
-                proj[..., :3 * inner].astype(jnp.float32), kernel, 0.0))
-            qkv = qkv.reshape(b, t, 3, heads, d)
+        kernel = self.param("conv_kernel", _INIT,
+                            (cfg.short_conv_kernel_size, 3 * inner),
+                            jnp.float32)
         a_log = self.param("A_log", _a_log_init, (heads,), jnp.float32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (heads, d),
                              jnp.float32)
-        with jax.named_scope("bps.kda.gate"):
-            q = (l2_normalize(qkv[:, :, 0]) / math.sqrt(d)).astype(cfg.dtype)
-            k = l2_normalize(qkv[:, :, 1]).astype(cfg.dtype)
-            v = qkv[:, :, 2].astype(cfg.dtype)
-            g = log_decay(proj[..., 3 * inner:4 * inner].reshape(
-                b, t, heads, d), a_log, dt_bias, cfg.kda_lower_bound)
-            beta = jax.nn.sigmoid(proj[..., 5 * inner:].astype(jnp.float32))
+        with jax.named_scope("bps.kda.pre"):
+            # float32 up to the L2 norms, inside the kernel: rounding the
+            # convolution's result to bfloat16 first read 0.20 against
+            # 0.075 on the decays' leaves (gradcheck_ling.py --rehearsal)
+            q, k, v, g, beta, gate = kda_pre(
+                proj, kernel, a_log, dt_bias,
+                lower_bound=cfg.kda_lower_bound)
         with jax.named_scope("bps.kda.scan"):
             o = kda_scan(q, k, v, g, beta, chunk=math.gcd(t, KDA_CHUNK))
         with jax.named_scope("bps.kda.out"):
             # one weight [head_dim] for every head's norm
-            y = RMSNorm(cfg.rms_norm_eps, jnp.float32, name="o_norm")(o)
-            gate = jax.nn.sigmoid(
-                proj[..., 4 * inner:5 * inner].astype(jnp.float32))
-            y = (y.reshape(b, t, inner) * gate).astype(cfg.dtype)
+            y = kda_post(o, gate, _Scale(name="o_norm")(d),
+                         eps=cfg.rms_norm_eps)
             return _dense(cfg.hidden_size, "o_proj", cfg.dtype)(y)
 
 

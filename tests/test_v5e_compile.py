@@ -516,7 +516,9 @@ def test_ling3_flash_cell_step_fits_a_v5e(topo, monkeypatch):
     scan's kernels at 32 heads of 128 x 128 in chunks of 128 (``bps_kda_fwd``
     twice a KDA layer — the forward and, under ``remat``, the one that
     stores the chunk-start states — and ``bps_kda_bwd`` once, whose body is
-    ``jax.vjp`` of the chunk's text), the flash kernels with q.k at 256
+    ``jax.vjp`` of the chunk's text), the row kernels around the scan
+    (``ops/kda_rows.py``: no float32 ``[2, 8192, 12288]`` array outside a
+    kernel), the flash kernels with q.k at 256
     lanes and v at 128, and the GATED experts in windows behind the group
     limit; arguments + temp + code stay under 15.0 GiB; the head's
     ``[tokens, 19648]`` logits exist only a block at a time; and no
@@ -542,7 +544,21 @@ def test_ling3_flash_cell_step_fits_a_v5e(topo, monkeypatch):
            if re.search(r"bps\.kda\.scan\)*/.*pallas_call$", c)]
     assert sum(c.endswith("bps_kda_fwd/pallas_call") for c in kda) == 10
     assert sum(c.endswith("bps_kda_bwd/pallas_call") for c in kda) == 5
-    assert len(kda) == 15 == sum("bps_kda" in c for c in calls)
+    assert len(kda) == 15
+    # the row stages around the scan (ops/kda_rows.py), what
+    # ``kda_rows_ms`` reads by this rule: a KDA layer's two kernels in
+    # front of the scan and behind it, forward, recomputed and backward
+    rows = [c for c in calls
+            if re.search(r"bps\.kda\.(pre|out)\)*/.*pallas_call$", c)]
+    for name, count in (("bps_kda_pre_fwd", 10), ("bps_kda_pre_bwd", 5),
+                        ("bps_kda_post_fwd", 10), ("bps_kda_post_bwd", 5)):
+        assert sum(c.endswith(f"{name}/pallas_call") for c in rows) == count
+    assert len(rows) == 30
+    assert len(kda) + len(rows) == sum("bps_kda" in c for c in calls)
+    # no float32 copy of the fused q | k | v rows exists outside a kernel
+    # (the parent held ~8 of 805 MB a layer), nor the three by head
+    assert "f32[2,8192,12288]" not in text
+    assert "f32[2,8192,3,32,128]" not in text
     # the one MLA layer: flash forward, its recomputation, two backward
     # kernels (T = 8192 is past the resident form)
     assert sum(c.endswith("/attn_mla/pallas_call") for c in calls) == 4
@@ -557,7 +573,7 @@ def test_ling3_flash_cell_step_fits_a_v5e(topo, monkeypatch):
     assert all("/while/body/" in c for c in experts)
     assert any("bps_moe_gate" in c for c in calls)         # gated experts
     assert not re.search(r"f32\[[\d,]*(64,64|128,128),128\]", text)
-    for scope in ("bps.kda.proj", "bps.kda.conv", "bps.kda.gate",
+    for scope in ("bps.kda.proj", "bps.kda.pre", "bps.kda.scan",
                   "bps.kda.out", "bps.mla.latent", "bps.mla.gate",
                   "bps.moe.group_limit", "bps.moe.score", "bps.moe.shared",
                   "mixer_kda", "attn_mla"):
