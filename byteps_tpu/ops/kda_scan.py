@@ -32,17 +32,32 @@ gate's ``g >= -5``: the score matrices are computed one ROW SUB-BLOCK of
 — the query side's factor ``exp(G_i - G_first)`` is <= 1, the key side's
 ``exp(G_first - G_j)`` is <= 1 for keys before the sub-block and <=
 exp(75) inside it, keys after it are masked to an exact zero (no
-``inf``).  ``(I + A)^-1`` is formed in float32 without a row-by-row
-substitution: the 16 x 16 diagonal blocks' inverses by the nilpotent
-series ``(I + X)(I + X^2)(I + X^4)(I + X^8)``, ``X = -A_diag``, then the
-block-strictly-lower rest ``Y = A_diag^-1 A_rest`` (nilpotent in C / 16
-steps) by the same series: ten [C, C] products, each THREE bfloat16
-passes on float32 operands cut in two (:func:`_dot3`: ~2^-16 relative,
-2e-6 on the inverse of unit keys in general position, where one pass
-reads 8e-4), and its derivative is ``dA = -T^T dT T^T``.  ``G`` is a
-product with the lower-triangular ones on ``g`` cut in three exact pieces.
-The state is kept TRANSPOSED, [d_v, d_k], so that the channel decays scale
-its lanes.
+``inf``).  A sub-block's rows of ``P`` and of ``A`` come out of ONE
+product (:func:`_scores`): its own 16 rows of the query side and of the
+``beta k`` side, stacked to [32, d_k], against those keys — the matrix
+unit latches the keys once and is pushed 32 rows, not 2 x C.  ``(I +
+A)^-1`` is formed in float32 without a row-by-row substitution: the 16 x
+16 diagonal blocks' inverses by the nilpotent series ``(I + X)(I + X^2)(I
++ X^4)(I + X^8)``, ``X = -A_diag``, then the block-strictly-lower rest
+``Y = A_diag^-1 A_rest`` (nilpotent in C / 16 steps) by the same series:
+twelve [C, C] products at C = 128, each THREE bfloat16 passes on float32
+operands cut in two (:func:`_dot3`: ~2^-16 relative, 2e-6 on the inverse
+of unit keys in general position, where one pass reads 8e-4), and its
+derivative is ``dA = -T^T dT T^T``.  ``G`` is a product with the
+lower-triangular ones on ``g`` cut in three exact pieces.  A chunk of one
+head at C = 128 is so 52 products — 36 passes of the inverse and 3 of
+``G`` on float32 operands whose values are bfloat16's, 8 stacked score
+products and the five the algorithm needs (``(Gamma o K) S``, ``T rhs``,
+``(Gamma o Q) S``, ``P R``, the state's update) on ``q.dtype`` — whose
+operands are 5 632 KiB (gauge ``kda.matmul_operand_bytes_per_chunk``; 60
+products and 6 336 KiB before PR 45).  Handing the passes' pieces over AS
+bfloat16 (3 136 KiB; 3 040 matrix-unit operations a grid step for 4 288)
+gave ``T`` and the scan's results bit for bit on the chip and moved the
+kernels' time by +0.4 %: the twelve three-pass products of the inverse
+DEPEND on each other, and it is their latency a grid step waits for, not
+the pushes (PERF.md section 6, PR 45) — so they stay float32.  The state
+is kept TRANSPOSED, [d_v, d_k], so that the channel decays scale its
+lanes.
 
 :func:`kda_scan` runs that as two Mosaic kernels (``bps_kda_fwd``,
 ``bps_kda_bwd``) under one ``jax.custom_vjp``.  A grid step is one
@@ -74,7 +89,12 @@ result does not depend on it; on a v5e at 32 heads of 128 x 128 and 8192
 positions a call's forward / forward + backward read 11.4 / 29.1 ms at
 C = 32, 8.5 / 20.2 at 64, 7.2 / 16.9 at 128 (two heads a grid step; one
 8.9 / 21.4, four 8.1 / 19.7 at C = 64; ``HIGHEST`` in the solve's place
-10.3 / 24.2: PERF.md section 6, PR 43), so ``models/ling.py`` asks for 128.  ``interpret=None`` engages Mosaic on a real
+10.3 / 24.2: PR 43's text, a host clock around a jitted call: PERF.md
+section 6, PR 43), so ``models/ling.py`` asks for 128.  By the device
+trace, C = 128, a kernel alone: forward 5.58 ms, the forward that stores
+the states 5.62, backward 8.81 for PR 43's text; 5.14 / 5.18 / 7.84 for
+this one (PR 45; twice that a call in ``ling3_flash.fused_1c``'s step of
+two sequences: 10.3 and 15.7).  ``interpret=None`` engages Mosaic on a real
 TPU and the Pallas interpreter elsewhere, as ``ops.flash_attention``.
 """
 
@@ -114,8 +134,10 @@ def _pieces(x, n):
     """x (float32) as ``n`` addends, all but the last bfloat16 VALUES in
     float32 (the top 16 bits of what is left, by a mask: a pair of casts is
     what a compiler may fold away as excess precision): the matrix unit
-    multiplies each exactly in one pass.  Only inside a ``custom_vjp``: the
-    mask has no derivative."""
+    multiplies each exactly in one pass (the last it rounds to nearest, as
+    a cast would: handed over AS bfloat16 the pieces gave ``T`` bit for
+    bit, half the pushes and latches, and no time — module docstring).
+    Only inside a ``custom_vjp``: the mask has no derivative."""
     out = []
     for _ in range(n - 1):
         top = lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536)
@@ -232,42 +254,55 @@ def _unit_lower_inverse_bwd(t, dt):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _chunk_forward(q, k, v, g, beta, state):
-    """One chunk of one head (module docstring): q, k [C, d_k]; v
-    [C, d_v]; g [C, d_k] float32; beta [C, 1] float32; ``state`` the
-    TRANSPOSED state [d_v, d_k] float32 the chunk starts from -> (o
-    [C, d_v] float32, the transposed state it hands on)."""
-    f32, lp, c = jnp.float32, q.dtype, q.shape[0]
+def _at(cum, i):
+    """Row i of cum [C, d], [1, d], exact."""
+    pos = lax.broadcasted_iota(jnp.int32, (cum.shape[0], 1), 0)
+    return jnp.sum(jnp.where(pos == i, cum, 0.0), axis=0, keepdims=True)
+
+
+def _scores(qf, kf, cum, beta, lp):
+    """The chunk's score matrices (module docstring) ``P`` (j <= i) and
+    ``A`` (j < i) [C, C] float32 from q, k, ``cum`` = G [C, d_k] float32
+    and beta [C, 1]: for each row sub-block ONE product, on operands of
+    type ``lp``, of its own rows of q and of beta k, stacked, against the
+    keys up to its end."""
+    c = qf.shape[0]
     sub = min(c, _SUB)
-    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
     pos = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-    qf, kf = q.astype(f32), k.astype(f32)
-    cum = _chunk_cumsum(g)                               # G, [C, d_k]
-
-    def at(i):                           # G at position i, [1, d_k], exact
-        return jnp.sum(jnp.where(pos == i, cum, 0.0), axis=0, keepdims=True)
-
-    firsts = [at(a * sub) for a in range(c // sub)]
+    firsts = [_at(cum, a * sub) for a in range(c // sub)]
     first = firsts[0]
     for a in range(1, c // sub):         # each row's own sub-block's first
         first = jnp.where(pos >= a * sub, firsts[a], first)
     from_first = jnp.exp(cum - first)                    # <= 1
     q_rows = (qf * from_first).astype(lp)
     k_rows = (kf * from_first * beta).astype(lp)
-    p = jnp.zeros((c, c), f32)
-    a_mat = jnp.zeros((c, c), f32)
+    slabs = []
     for a in range(c // sub):
         # keys up to the end of sub-block a, against its first position;
         # later keys are an exact zero
         to_first = jnp.exp(jnp.where(pos < (a + 1) * sub, firsts[a] - cum,
                                      -jnp.inf))
         keys = (kf * to_first).astype(lp)
-        mine = pos // sub == a
-        p = p + jnp.where(mine, _dot(q_rows, keys, _NT), 0.0)
-        a_mat = a_mat + jnp.where(mine, _dot(k_rows, keys, _NT), 0.0)
-    p = jnp.where(row >= col, p, 0.0)
-    t_mat = _unit_lower_inverse(jnp.where(row > col, a_mat, 0.0))
+        mine = slice(a * sub, (a + 1) * sub)
+        slabs.append(_dot(jnp.concatenate([q_rows[mine], k_rows[mine]]),
+                          keys, _NT))                    # [2 sub, C]
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    p = jnp.concatenate([both[:sub] for both in slabs])
+    a_mat = jnp.concatenate([both[sub:] for both in slabs])
+    return jnp.where(row >= col, p, 0.0), jnp.where(row > col, a_mat, 0.0)
+
+
+def _chunk_forward(q, k, v, g, beta, state):
+    """One chunk of one head (module docstring): q, k [C, d_k]; v
+    [C, d_v]; g [C, d_k] float32; beta [C, 1] float32; ``state`` the
+    TRANSPOSED state [d_v, d_k] float32 the chunk starts from -> (o
+    [C, d_v] float32, the transposed state it hands on)."""
+    f32, lp = jnp.float32, q.dtype
+    qf, kf = q.astype(f32), k.astype(f32)
+    cum = _chunk_cumsum(g)                               # G, [C, d_k]
+    p, a_mat = _scores(qf, kf, cum, beta, lp)
+    t_mat = _unit_lower_inverse(a_mat)
     from_start = jnp.exp(cum)                            # Gamma
     s_lp = state.astype(lp)
     rhs = beta * (v.astype(f32)
@@ -276,9 +311,33 @@ def _chunk_forward(q, k, v, g, beta, state):
     r_lp = r.astype(lp)
     o = (_dot((qf * from_start).astype(lp), s_lp, _NT)
          + _dot(p.astype(lp), r_lp, _NN))
-    last = at(c - 1)
+    last = _at(cum, q.shape[0] - 1)
     to_end = (kf * jnp.exp(last - cum)).astype(lp)
     return o, state * jnp.exp(last) + _dot(r_lp, to_end, _TN)
+
+
+def _dot_operand_bytes(jaxpr) -> int:
+    """Bytes of both operands of every ``dot_general`` of a jaxpr and of
+    the jaxprs inside its equations (``custom_vjp``, ``pjit``)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            total += sum(x.aval.size * x.aval.dtype.itemsize
+                         for x in eqn.invars)
+        total += sum(_dot_operand_bytes(inner) for inner in
+                     jax.core.jaxprs_in_params(eqn.params))
+    return total
+
+
+@functools.lru_cache(maxsize=None)
+def _matmul_operand_bytes(chunk, dk, dv, dtype) -> int:
+    """What ONE head's :func:`_chunk_forward` hands to the matrix unit a
+    chunk, read off its own jaxpr at these shapes and types."""
+    f32 = jnp.float32
+    avals = [jax.ShapeDtypeStruct(shape, kind) for shape, kind in (
+        ((chunk, dk), dtype), ((chunk, dk), dtype), ((chunk, dv), dtype),
+        ((chunk, dk), f32), ((chunk, 1), f32), ((dv, dk), f32))]
+    return _dot_operand_bytes(jax.make_jaxpr(_chunk_forward)(*avals).jaxpr)
 
 
 # ------------------------------------------------------------ chunked form
@@ -460,9 +519,10 @@ def kda_scan(q, k, v, g, beta, *, chunk: int,
     its measured ``KDA_CHUNK``).
     Tracing a call sets the gauges ``kda.heads``, ``kda.chunk``,
     ``kda.chunks_per_seq``, ``kda.state_bytes`` (the carried state of one
-    sequence: H x d_k x d_v float32) and ``kda.saved_state_bytes`` (the
+    sequence: H x d_k x d_v float32), ``kda.saved_state_bytes`` (the
     chunk-start states one differentiated call keeps for its backward:
-    B x T / chunk of them)."""
+    B x T / chunk of them) and ``kda.matmul_operand_bytes_per_chunk`` (both
+    operands of every matrix product of one head's chunk)."""
     if interpret is None:
         from .pallas_kernels import on_tpu
         interpret = not on_tpu()
@@ -480,6 +540,8 @@ def kda_scan(q, k, v, g, beta, *, chunk: int,
     gauges.set("kda.chunks_per_seq", float(t // chunk))
     gauges.set("kda.state_bytes", float(state_bytes))
     gauges.set("kda.saved_state_bytes", float(b * (t // chunk) * state_bytes))
+    gauges.set("kda.matmul_operand_bytes_per_chunk",
+               float(_matmul_operand_bytes(chunk, dk, dv, q.dtype)))
     columns = beta.astype(jnp.float32).reshape(b, t, h // heads, heads)
     o = _scan_core(q.reshape(b, t, h * dk), k.reshape(b, t, h * dk),
                    v.reshape(b, t, h * dv),

@@ -122,6 +122,47 @@ def test_a_whole_chunk_at_the_gate_s_floor(form):
     against_the_recurrence(form, 32, (q, k, v, g.at[:, :32].set(-5.0), beta))
 
 
+def full_square_scores(q, k, cum, beta, sub=16):
+    """The witness of ``_scores``, the form the text had before PR 45: for
+    each row sub-block ALL the chunk's rows of q and of beta k against the
+    sub-block's keys — two [C, C] products — of which a mask keeps the
+    sub-block's own rows."""
+    c = q.shape[0]
+    pos = jnp.arange(c)[:, None]
+    first = cum[(jnp.arange(c) // sub) * sub]
+    q_rows = q * jnp.exp(cum - first)
+    k_rows = k * jnp.exp(cum - first) * beta
+    p = a_mat = jnp.zeros((c, c), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        for a in range(c // sub):
+            keys = k * jnp.exp(jnp.where(pos < (a + 1) * sub,
+                                         cum[a * sub] - cum, -jnp.inf))
+            mine = pos // sub == a
+            p = p + jnp.where(mine, q_rows @ keys.T, 0.0)
+            a_mat = a_mat + jnp.where(mine, k_rows @ keys.T, 0.0)
+    return jnp.tril(p), jnp.tril(a_mat, -1)
+
+
+@pytest.mark.parametrize("chunk", [32, 128])
+def test_the_score_products_by_row_sub_block_are_the_full_squares(chunk):
+    """``P`` and ``A`` of one seeded chunk both ways, its second sub-block
+    whole at the gate's floor: every kept element is the sum of the same
+    d_k channel products, so they agree to float32's rounding — and each
+    sub-block's rows come out of ONE stacked product."""
+    q, k, _, g, beta = [x[0, :, 0] for x in inputs(
+        13, b=1, t=chunk, h=1, g_range=(-0.3, 0.0))]
+    cum = jnp.cumsum(g.at[16:32].set(-5.0), axis=0)
+    got = kda._scores(q, k, cum, beta[:, None], q.dtype)
+    want = full_square_scores(q, k, cum, beta[:, None])
+    for name, x, y in zip("PA", got, want):
+        assert np.isfinite(np.asarray(x)).all() and x.shape == (chunk, chunk)
+        assert np.abs(np.asarray(y)).max() > 0.01, name
+        close(x, y, rtol=1e-6)
+    text = str(jax.make_jaxpr(functools.partial(kda._scores, lp=q.dtype))(
+        q, k, cum, beta[:, None]))
+    assert text.count("dot_general") == chunk // 16
+
+
 def test_the_result_does_not_depend_on_the_chunk():
     args = inputs(7, t=64)
     close(kernels(*args, chunk=16), kernels(*args, chunk=32), rtol=1e-5)
@@ -171,6 +212,35 @@ def test_a_differentiated_call_is_the_two_kernels_and_sets_the_gauges():
     assert gauges["kda.chunks_per_seq"] == 2
     assert gauges["kda.state_bytes"] == 4 * 2 * 16 * 8
     assert gauges["kda.saved_state_bytes"] == 2 * 4 * 2 * 16 * 8
+    # both operands of every product of one head's chunk, float32 here: the
+    # two stacked score products [16 + 16, 16] x [32, 16], 6 + 1 + 0 + 1 = 8
+    # three-pass [32, 32] products of the inverse (sub-blocks of 16: three
+    # squarings a diagonal block, none for the two-block rest), the three
+    # passes of G, and the five products the algorithm needs
+    square, rows = 4 * 32 * 32, 4 * 32 * 16
+    assert gauges["kda.matmul_operand_bytes_per_chunk"] == (
+        2 * (rows + rows) + 24 * 2 * square + 3 * (square + rows)
+        + (rows + 4 * 8 * 16) + (square + 4 * 32 * 8) + (rows + 4 * 8 * 16)
+        + (square + 4 * 32 * 8) + (4 * 32 * 8 + rows))
+
+
+def test_the_cell_s_chunk_hands_the_matrix_unit_5632_KiB():
+    """By tracing alone, at ``ling3_flash.fused_1c``'s shapes (C = 128,
+    heads of 128 x 128, bfloat16 q, k, v) and as Mosaic gets it
+    (``interpret=False``): the gauge reads 5 632 KiB where the text before
+    PR 45 read 6 336 (60 products of [128, 128] x [128, 128], 16 of them
+    score products over all 128 rows on bfloat16 operands: 8 stacked ones
+    of [32, 128] x [128, 128] now)."""
+    import byteps_tpu as bps
+    shape = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    decay = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, 256, 2), jnp.float32)
+    jax.eval_shape(functools.partial(kda.kda_scan, chunk=128,
+                                     interpret=False),
+                   shape, shape, shape, decay, beta)
+    assert bps.metrics_snapshot()["gauges"][
+        "kda.matmul_operand_bytes_per_chunk"] == (6336 - 16 * 64 + 8 * 40
+                                                  ) * 1024 == 5632 * 1024
 
 
 def test_the_inverse_in_three_passes_stays_float32_accurate(monkeypatch):
@@ -195,3 +265,37 @@ def test_the_inverse_in_three_passes_stays_float32_accurate(monkeypatch):
     monkeypatch.setattr(kda, "_dot3", rounded_pass)
     one_pass = np.asarray(kda._inverse(jnp.asarray(a, jnp.float32)))
     assert np.abs(one_pass - want).max() > 3e-4
+
+
+def test_the_operand_metric_s_entry_and_reader(monkeypatch):
+    """``kda_matmul_operand_KiB`` (``benchmarks/layer_metrics/``): the entry
+    is found by name, matches its reader file and names the Ling cell
+    alone; the reader divides the gauge a traced ``kda_scan`` set and gives
+    nothing, without raising, for a program without it (the parent's)."""
+    import json
+    import os
+    import types
+    import byteps_tpu as bps
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(os.path.join(root, "benchmarks"))
+    from harness import spec
+    name, cell = "kda_matmul_operand_KiB", "ling3_flash.fused_1c"
+    reader = spec.load_module("layer_metrics", name)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert [m for m in bench["per_layer"] if m["name"] == name] == [{
+        "name": name, "unit": reader.UNIT, "better": reader.BETTER,
+        "source": reader.SOURCE, "layer": reader.LAYER,
+        "moves": reader.MOVES, "workloads": [cell]}]
+    assert reader.LAYER == "ops kernels" and reader.BETTER == "lower"
+    for w in bench["workloads"]:
+        names = {m["name"] for m in spec.metrics_for(bench, "per_layer",
+                                                     w["name"])}
+        assert (name in names) == (w["name"] == cell)
+    jax.eval_shape(functools.partial(kernels, chunk=32), *inputs(4, b=1))
+    run = types.SimpleNamespace(snap1=bps.metrics_snapshot(), info={})
+    assert reader.read(run) == 241664 / 1024
+    without = {k: v for k, v in run.snap1["gauges"].items() if k != (
+        "kda.matmul_operand_bytes_per_chunk")}
+    for snap in ({"gauges": without}, {}):
+        assert reader.read(types.SimpleNamespace(snap1=snap, info={})) is None

@@ -545,6 +545,13 @@ def test_ling3_flash_cell_step_fits_a_v5e(topo, monkeypatch):
     assert sum(c.endswith("bps_kda_fwd/pallas_call") for c in kda) == 10
     assert sum(c.endswith("bps_kda_bwd/pallas_call") for c in kda) == 5
     assert len(kda) == 15
+    # ... with the chunk text of PR 45: tracing the step left the gauge
+    # ``kda_matmul_operand_KiB`` reads at what Mosaic is handed a chunk and
+    # head (a sub-block's own stacked rows in the score products: 5 632 KiB
+    # where the text before read 6 336)
+    import byteps_tpu as bps
+    assert bps.metrics_snapshot()["gauges"][
+        "kda.matmul_operand_bytes_per_chunk"] == 5632 * 1024
     # the row stages around the scan (ops/kda_rows.py), what
     # ``kda_rows_ms`` reads by this rule: a KDA layer's two kernels in
     # front of the scan and behind it, forward, recomputed and backward
