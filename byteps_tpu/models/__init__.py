@@ -39,6 +39,12 @@ from .olmoe import (  # noqa: F401
     olmoe_loss,
     olmoe_tiny,
 )
+from .qwen3_next import (  # noqa: F401
+    Qwen3Next,
+    Qwen3NextConfig,
+    qwen3_next_loss,
+    qwen3_next_tiny,
+)
 from .zaya import (  # noqa: F401
     Zaya,
     ZayaConfig,

@@ -377,19 +377,28 @@ def kda_pre(proj, conv_kernel, a_log, dt_bias, *, lower_bound: float,
 
 # ------------------------------------------------------------ behind the scan
 
-def _post_fwd_kernel(o_ref, gate_ref, w_ref, y_ref, *, heads, d, eps):
+def _gate_act(x, silu):
+    """(the output gate's activation of x, its derivative): a sigmoid, or
+    with ``silu`` ``x sigmoid(x)``."""
+    sg = jax.nn.sigmoid(x)
+    if silu:
+        return x * sg, sg * (1.0 + x * (1.0 - sg))
+    return sg, sg * (1.0 - sg)
+
+
+def _post_fwd_kernel(o_ref, gate_ref, w_ref, y_ref, *, heads, d, eps, silu):
     for h in range(heads):
         lanes = slice(h * d, (h + 1) * d)
         o = o_ref[0, :, lanes].astype(jnp.float32)
         r = lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
         y_ref[0, :, lanes] = (
             o * r * w_ref[...]
-            * jax.nn.sigmoid(gate_ref[0, :, lanes].astype(jnp.float32))
+            * _gate_act(gate_ref[0, :, lanes].astype(jnp.float32), silu)[0]
         ).astype(y_ref.dtype)
 
 
 def _post_bwd_kernel(o_ref, gate_ref, w_ref, dy_ref, do_ref, dgate_ref,
-                     dw_ref, *, heads, d, eps, rows, t_len):
+                     dw_ref, *, heads, d, eps, rows, t_len, silu):
     @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
     def _():
         dw_ref[...] = jnp.zeros_like(dw_ref)
@@ -406,11 +415,11 @@ def _post_bwd_kernel(o_ref, gate_ref, w_ref, dy_ref, do_ref, dgate_ref,
         o, dy = rows_of(o_ref), rows_of(dy_ref)
         r = lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
         unit = o * r
-        sg = jax.nn.sigmoid(rows_of(gate_ref))
+        act, slope = _gate_act(rows_of(gate_ref), silu)
         dgate_ref[0, :, lanes] = (
-            dy * (unit * w_ref[...]) * (sg * (1.0 - sg))
+            dy * (unit * w_ref[...]) * slope
         ).astype(dgate_ref.dtype)
-        dn = dy * sg
+        dn = dy * act
         dw_ref[:, lanes] += jnp.sum(dn * unit, axis=0, keepdims=True)
         dunit = dn * w_ref[...]
         do_ref[0, :, lanes] = (
@@ -426,13 +435,14 @@ def _post_specs(heads, d, rows):
     return block, weight, pl.BlockSpec((1, c), lambda j, b, i: (0, j))
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _post_forward(o, gate, weight, heads, d, eps, rows, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _post_forward(o, gate, weight, heads, d, eps, rows, interpret, silu):
     b, t, inner = o.shape
     rows, _, blocks = _layout(t, rows)
     block, one, _ = _post_specs(heads, d, rows)
     return pl.pallas_call(
-        functools.partial(_post_fwd_kernel, heads=heads, d=d, eps=eps),
+        functools.partial(_post_fwd_kernel, heads=heads, d=d, eps=eps,
+                          silu=silu),
         grid=(inner // (heads * d), b, blocks),
         in_specs=[block, block, one], out_specs=block,
         out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
@@ -440,14 +450,15 @@ def _post_forward(o, gate, weight, heads, d, eps, rows, interpret):
         name="bps_kda_post_fwd", interpret=interpret)(o, gate, weight)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
-def _post_backward(o, gate, weight, dy, heads, d, eps, rows, interpret):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+def _post_backward(o, gate, weight, dy, heads, d, eps, rows, interpret,
+                   silu):
     b, t, inner = o.shape
     rows, _, blocks = _layout(t, rows)
     block, one, lane_sums = _post_specs(heads, d, rows)
     do, dgate, dw = pl.pallas_call(
         functools.partial(_post_bwd_kernel, heads=heads, d=d, eps=eps,
-                          rows=rows, t_len=t),
+                          rows=rows, t_len=t, silu=silu),
         grid=(inner // (heads * d), b, blocks),
         in_specs=[block, block, one, block],
         out_specs=[block, block, lane_sums],
@@ -460,29 +471,34 @@ def _post_backward(o, gate, weight, dy, heads, d, eps, rows, interpret):
     return do, dgate, dw.reshape(inner // d, d).sum(0)[None]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _post_core(o, gate, weight, heads, d, eps, rows, interpret):
-    return _post_forward(o, gate, weight, heads, d, eps, rows, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _post_core(o, gate, weight, heads, d, eps, rows, interpret, silu):
+    return _post_forward(o, gate, weight, heads, d, eps, rows, interpret,
+                         silu)
 
 
-def _post_core_fwd(o, gate, weight, heads, d, eps, rows, interpret):
-    return (_post_forward(o, gate, weight, heads, d, eps, rows, interpret),
-            (o, gate, weight))
+def _post_core_fwd(o, gate, weight, heads, d, eps, rows, interpret, silu):
+    return (_post_forward(o, gate, weight, heads, d, eps, rows, interpret,
+                          silu), (o, gate, weight))
 
 
-def _post_core_bwd(heads, d, eps, rows, interpret, res, dy):
-    return _post_backward(*res, dy, heads, d, eps, rows, interpret)
+def _post_core_bwd(heads, d, eps, rows, interpret, silu, res, dy):
+    return _post_backward(*res, dy, heads, d, eps, rows, interpret, silu)
 
 
 _post_core.defvjp(_post_core_fwd, _post_core_bwd)
 
 
-def kda_post(o, gate, weight, *, eps: float, rows: int = _ROWS,
-             interpret: Optional[bool] = None):
+def kda_post(o, gate, weight, *, eps: float, gate_act: str = "sigmoid",
+             rows: int = _ROWS, interpret: Optional[bool] = None):
     """``o`` [B, T, H, d] (the scan's), ``gate`` [B, T, H d], ``weight``
     [d] float32 -> ``y`` [B, T, H d] in ``o.dtype``: each head's RMSNorm
-    under the one weight, times the gate's sigmoid, in float32 (module
-    docstring)."""
+    under the one weight, times the gate's activation — ``gate_act``
+    ``"sigmoid"`` (Kimi Delta Attention) or ``"silu"`` (Gated DeltaNet,
+    ``models/qwen3_next.py``) — in float32 (module docstring)."""
+    if gate_act not in ("sigmoid", "silu"):
+        raise ValueError(f"kda_post: gate_act={gate_act!r} is neither "
+                         f"'sigmoid' nor 'silu'")
     b, t, heads, d = o.shape
     if gate.shape != (b, t, heads * d) or weight.shape != (d,):
         raise ValueError(f"kda_post: o {o.shape} wants gate "
@@ -491,4 +507,4 @@ def kda_post(o, gate, weight, *, eps: float, rows: int = _ROWS,
     interpret = _on_chip(interpret, d, "kda_post")
     return _post_core(o.reshape(b, t, heads * d), gate,
                       weight.astype(jnp.float32)[None], math.gcd(heads, _HEADS),
-                      d, float(eps), rows, interpret)
+                      d, float(eps), rows, interpret, gate_act == "silu")

@@ -197,3 +197,40 @@ def test_sizes_that_do_not_fit_are_refused():
         kda_post(x["o"], x["gate"][..., :-1], x["weight"], eps=EPS)
     with pytest.raises(ValueError, match="whole lane tiles"):
         kda_post(x["o"], x["gate"], x["weight"], eps=EPS, interpret=False)
+
+
+def plain_post_silu(o, gate, weight):
+    """``plain_post`` with the gate's activation SiLU (Gated DeltaNet's
+    output stage, ``models/qwen3_next.py``)."""
+    b, t, heads, d = o.shape
+    of = o.astype(jnp.float32)
+    y = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + EPS) * weight
+    return (y.reshape(b, t, heads * d)
+            * jax.nn.silu(gate.astype(jnp.float32))).astype(o.dtype)
+
+
+@pytest.mark.parametrize("gate_act", ["sigmoid", "silu"])
+@pytest.mark.parametrize("case", ["two_sequences", "ragged_3heads"])
+def test_post_takes_the_gate_s_activation_as_an_argument(case, gate_act):
+    """``kda_post(gate_act=...)``: the sigmoid (the default, Kimi Delta
+    Attention) and SiLU (Gated DeltaNet), values and the gradients by
+    ``o``, the gate and the weight, against the plain text; the default is
+    the kernel the existing cases run, to the bit."""
+    rows = CASES[case][4]
+    x = inputs(case, jnp.float32, seed=3)
+    args = (x["o"], x["gate"], x["weight"])
+    plain = plain_post if gate_act == "sigmoid" else plain_post_silu
+    got = values_and_grads(
+        lambda *a: kda_post(*a, eps=EPS, rows=rows, gate_act=gate_act),
+        x["key"], args)
+    want = values_and_grads(plain, x["key"], args)
+    np.testing.assert_allclose(np.asarray(got[0][0]), np.asarray(want[0][0]),
+                               rtol=2e-5, atol=2e-6)
+    for name, a, b in zip(POST[1], got[1], want[1]):
+        assert rel(a, b) < 2e-5, (name, rel(a, b))
+    if gate_act == "sigmoid":
+        default = kda_post(*args, eps=EPS, rows=rows)
+        np.testing.assert_array_equal(np.asarray(default),
+                                      np.asarray(got[0][0]))
+    with pytest.raises(ValueError, match="gate_act"):
+        kda_post(*args, eps=EPS, gate_act="tanh")

@@ -1,6 +1,7 @@
 """``route_select_ms`` (PR 41, ``benchmarks/layer_metrics/``): the entry is
-found by name, matches its reader file and names the six MoE cells (the
-sixth, ``ling3_flash.fused_1c``, appended by PR 43); the
+found by name, matches its reader file and names the seven MoE cells (the
+sixth, ``ling3_flash.fused_1c``, appended by PR 43, the seventh,
+``qwen3_next_80b.fused_1c``, by PR 46); the
 reader sums the Mosaic kernels under the stage ``bps.moe.route`` of a
 made-up trace and counts their calls a step — and gives nothing, without
 raising, for a program with no kernel under the stage (the parent commit's)
@@ -16,7 +17,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MOE_CELLS = ["olmoe_1b_7b.fused_1c", "mellum2_12b.fused_1c",
              "zaya1_8b.fused_1c", "glm47_flash.fused_1c",
-             "nemotron3_super.fused_1c", "ling3_flash.fused_1c"]
+             "nemotron3_super.fused_1c", "ling3_flash.fused_1c",
+             "qwen3_next_80b.fused_1c"]
 
 
 def test_route_select_entry_and_reader(monkeypatch):

@@ -585,3 +585,99 @@ def test_ling3_flash_cell_step_fits_a_v5e(topo, monkeypatch):
                   "bps.moe.group_limit", "bps.moe.score", "bps.moe.shared",
                   "mixer_kda", "attn_mla"):
         assert scope in text, scope
+
+
+@pytest.mark.parametrize("seqs,heads", [(4, (16, 32)), (1, (4, 4))],
+                         ids=["qwen3_next_cell", "equal_heads"])
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_head_decay_scan_kernels_compile_for_a_v5e(one_chip, seqs, heads,
+                                                   chunk):
+    """``ops/gdn_scan.py`` at ``qwen3_next_80b.fused_1c``'s shape — 4 x
+    8 192 positions, 16 key heads under 32 value heads of 128 — and with a
+    value head a key head (two key heads a grid step), forward (storing the
+    chunk-start states) and backward (``jax.vjp`` of the chunk's text in
+    the kernel): Mosaic takes the [C, 1] - [1, C] difference, the masked
+    ``exp``, the [1, C] rows of ``G`` and their cotangent."""
+    import importlib
+    gdn = importlib.import_module("byteps_tpu.ops.gdn_scan")
+    hk, hv = heads
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, v = shaped((seqs, 8192, hk, 128)), shaped((seqs, 8192, hv, 128))
+    g = shaped((seqs, 8192, hv), jnp.float32)
+
+    def objective(q, k, v, g, beta):
+        return jnp.sum(gdn.gdn_scan(q, k, v, g, beta, chunk=chunk,
+                                    interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(objective, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, v, g, g).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("bps_gdn_fwd" in c for c in calls) == 1
+    assert sum("bps_gdn_bwd" in c for c in calls) == 1
+    # q and k go in at the KEY heads' width and the gate as one number a
+    # position and value head: no repeated q / k, no [T, H, 128] gate
+    assert f"bf16[{seqs},8192,{hv * 128}]" in text
+    assert f"f32[{seqs},8192,{hv},128]" not in text
+    assert f"f32[{seqs},8192,{hv * 128}]" not in text
+
+
+def test_qwen3_next_80b_cell_step_fits_a_v5e(topo, monkeypatch):
+    """``qwen3_next_80b.fused_1c``'s step at the published widths on the
+    rung of ISSUE 46's memory ladder the cell takes: Mosaic takes the
+    head-decay scan's kernels under ``bps.gdn.scan`` in all three DeltaNet
+    layers (``bps_gdn_fwd`` twice a layer — the forward and, under
+    ``remat``, the one that stores the chunk-start states — and
+    ``bps_gdn_bwd`` once), the output stage's row kernels with the SiLU
+    gate under ``bps.gdn.out``, the flash kernels at 16 heads of 256 under
+    ``attn`` and the selection kernel under ``bps.moe.route`` in all four
+    sparse MLPs; the head's ``[tokens, 18992]`` logits exist only a block
+    at a time; q and k of the scan are never repeated to the value heads
+    and the gate is never broadcast over a head's channels."""
+    compiled, config, traffic = _compiled_cell_step(
+        topo, monkeypatch, "qwen3_next_80b.fused_1c")
+    memory = compiled.memory_analysis()
+    # weights and two moments: 3 x 625,667,136 x 4 B = 6.99 GiB
+    assert 6.95 < memory.argument_size_in_bytes / 2 ** 30 < 7.05
+    # rung (a), 4 x 8 192 positions: arguments 6.99 + temp 7.52 + code
+    # 0.08 = 14.58 GiB at the scan's chunk of 128 (15.72 at 64: twice the
+    # chunk-start states); the peak is the attention layer's sparse MLP in
+    # the backward pass, whole [327 680, 2048] pair-row arrays
+    assert traffic["seqs_per_chip"] == 4
+    assert _used_gib(memory) < 15.0
+    seqs = traffic["seqs_per_chip"]
+    text = compiled.as_text()
+    tokens = traffic["seq_len"] * seqs
+    assert f"[{tokens},{config['vocab_size']}]" not in text
+    assert "bps.head" in text
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    scan = [c for c in calls
+            if re.search(r"bps\.gdn\.scan\)*/.*pallas_call$", c)]
+    assert sum(c.endswith("bps_gdn_fwd/pallas_call") for c in scan) == 6
+    assert sum(c.endswith("bps_gdn_bwd/pallas_call") for c in scan) == 3
+    assert len(scan) == 9 == sum("bps_gdn" in c for c in calls)
+    rows = [c for c in calls
+            if re.search(r"bps\.gdn\.(pre|out)\)*/.*pallas_call$", c)]
+    assert sum(c.endswith("bps_kda_post_fwd/pallas_call") for c in rows) == 6
+    assert sum(c.endswith("bps_kda_post_bwd/pallas_call") for c in rows) == 3
+    assert len(rows) == 9
+    # the one attention layer: flash forward, its recomputation, two
+    # backward kernels (T = 8192 at 256 lanes is past the resident form)
+    assert sum(c.endswith("/attn/pallas_call") for c in calls) == 4
+    # four sparse layers: the selection and its recomputation
+    assert len(_route_kernels(text)) == 4 * 2
+    experts = [c for c in calls
+               if re.search(r"bps\.moe\.experts/.*pallas_call$", c)]
+    assert len(experts) == 4 * 12
+    # the scan reads q, k at 16 heads x 128 lanes; nothing repeats them to
+    # 32 heads in float32 or lays the gate out a channel
+    assert f"f32[{seqs},8192,32,128]" not in text
+    for scope in ("bps.gdn.proj", "bps.gdn.pre", "bps.gdn.scan",
+                  "bps.gdn.out", "bps.attn.gate", "bps.moe.route",
+                  "bps.moe.shared", "mixer_gdn", "attn"):
+        assert scope in text, scope
