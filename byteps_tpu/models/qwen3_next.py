@@ -46,10 +46,14 @@ interleaves them by key-head group): a permutation of the same matrix.
 The recurrence runs as ``ops/gdn_scan.py`` ``gdn_scan`` (Mosaic kernels,
 forward and backward, interpreted off the TPU), the head norm times the
 output gate as ``ops/kda_rows.py`` ``kda_post`` with the gate's activation
-SiLU; the convolution, SiLU, the L2 norms, ``g`` and ``beta`` in front of
-the scan are plain ``jax.numpy`` under the scope ``bps.gdn.pre``
-(:func:`gdn_pre`).  k and v are repeated H_kv -> H heads in front of the
-flash call.  The multi-token-prediction module is left out (the config
+SiLU; the convolution, SiLU and the L2 norms in front of the scan as ONE
+pass over ``W_qkvz``'s result (``ops/kda_rows.py`` ``qkv_pre``: float32
+inside the kernel, q, k, v rounded to ``dtype`` where the scan reads them,
+z's columns handed on to ``kda_post``), ``g`` and ``beta`` [T, H_v] a few
+plain lines beside it, all under the scope ``bps.gdn.pre``;
+:func:`gdn_pre` is the stage's plain text, which the kernels are tested
+against.  k and v are repeated H_kv -> H heads in front of the flash
+call.  The multi-token-prediction module is left out (the config
 counts none), and there is no auxiliary loss.  Initialisation: ``A_log =
 log U[1, 16]``, ``dt_bias`` 1, normal(0.02) for every matrix, zero-centred
 norm weights 0, ``w_n`` 1.
@@ -154,6 +158,12 @@ class Qwen3NextConfig:
                 f"linear_num_value_heads={self.linear_num_value_heads} do "
                 f"not divide over linear_num_key_heads="
                 f"{self.linear_num_key_heads}")
+        if self.linear_key_head_dim != self.linear_value_head_dim:
+            raise ValueError(
+                f"linear_key_head_dim={self.linear_key_head_dim} and "
+                f"linear_value_head_dim={self.linear_value_head_dim} differ: "
+                f"the row kernels in front of the scan walk heads of one "
+                f"size")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must be divisible by "
                              "num_key_value_heads")
@@ -227,10 +237,11 @@ def gdn_log_decay(alpha, a_log, dt_bias):
 
 
 def gdn_pre(qkv, ba, conv_kernel, a_log, dt_bias, cfg: Qwen3NextConfig):
-    """The row stages in front of the scan, float32 up to the rounding of
-    q, k, v to ``cfg.dtype``: ``qkv`` [B, T, 2 H_k d + H_v d] and ``ba``
-    [B, T, 2 H_v] -> q, k [B, T, H_k, d], v [B, T, H_v, d], g and beta
-    [B, T, H_v] float32."""
+    """The row stages in front of the scan as plain text (the mixer runs
+    ``ops/kda_rows.py`` ``qkv_pre``, whose tests read this), float32 up to
+    the rounding of q, k, v to ``cfg.dtype``: ``qkv`` [B, T, 2 H_k d + H_v
+    d] and ``ba`` [B, T, 2 H_v] -> q, k [B, T, H_k, d], v [B, T, H_v, d],
+    g and beta [B, T, H_v] float32."""
     b, t, _ = qkv.shape
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -260,16 +271,17 @@ def join_shared(routed, shared, gate_logit, dtype):
 class Qwen3NextGdn(nn.Module):
     """The Gated DeltaNet mixer on the normed rows ``a`` [B, T, h] (module
     docstring); each stage under a ``bps.gdn.*`` scope: the projections
-    (``proj``), the row stages in front of the scan (``pre``), the scan's
-    kernels (``scan``), the head norm times ``silu(z)`` as one pass and
-    ``W_o`` (``out``)."""
+    (``proj``), the row stages in front of the scan as one pass over
+    ``in_proj_qkvz``'s result, with ``g`` and ``beta`` (``pre``), the
+    scan's kernels (``scan``), the head norm times ``silu(z)`` as one pass
+    and ``W_o`` (``out``)."""
 
     cfg: Qwen3NextConfig
 
     @nn.compact
     def __call__(self, a):
         from ..ops.gdn_scan import gdn_scan
-        from ..ops.kda_rows import kda_post
+        from ..ops.kda_rows import kda_post, qkv_pre
         cfg = self.cfg
         hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
         dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -285,13 +297,17 @@ class Qwen3NextGdn(nn.Module):
         dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,),
                              jnp.float32)
         with jax.named_scope("bps.gdn.pre"):
-            q, k, v, g, beta = gdn_pre(qkvz[..., :conv_dim], ba, kernel,
-                                       a_log, dt_bias, cfg)
+            # gdn_pre's rows as one pass over the projection, float32
+            # inside the kernel; z's columns handed on as they lie
+            q, k, v, z = qkv_pre(qkvz, kernel, key_heads=hk, value_heads=hv,
+                                 head_dim=dk)
+            beta = jax.nn.sigmoid(ba[..., :hv].astype(jnp.float32))
+            g = gdn_log_decay(ba[..., hv:], a_log, dt_bias)
         with jax.named_scope("bps.gdn.scan"):
             o = gdn_scan(q, k, v, g, beta, chunk=math.gcd(t, GDN_CHUNK))
         with jax.named_scope("bps.gdn.out"):
             # one weight [d] for every head's norm, from one
-            y = kda_post(o, qkvz[..., conv_dim:], _Scale(name="o_norm")(dv),
+            y = kda_post(o, z, _Scale(name="o_norm")(dv),
                          eps=cfg.rms_norm_eps, gate_act="silu")
             return _dense(cfg.hidden_size, "o_proj", cfg.dtype)(y)
 

@@ -2,7 +2,9 @@
 Pallas interpreter here) against the plain text they replace —
 ``causal_conv`` + ``silu`` + ``l2_normalize`` + ``log_decay`` in front of
 the scan, the head RMSNorm times the gate's sigmoid behind it — values and
-every gradient."""
+every gradient; and the same kernels in Gated DeltaNet's form (``qkv_pre``:
+key heads under value heads, no decay slice; the gate's activation SiLU)
+against ``models/qwen3_next.py`` ``gdn_pre``'s lines."""
 
 import functools
 import math
@@ -14,11 +16,13 @@ import pytest
 
 from byteps_tpu.models.ling import l2_normalize, log_decay
 from byteps_tpu.models.nemotron_h import causal_conv
-from byteps_tpu.ops.kda_rows import kda_post, kda_pre
+from byteps_tpu.models.qwen3_next import Qwen3NextConfig, gdn_pre
+from byteps_tpu.ops.kda_rows import kda_post, kda_pre, qkv_pre
 
 LOWER, EPS, TAPS = -5.0, 1e-6, 4
 
-# (B, T, H, d, rows a block): what each case is there for
+# (B, T, H, d, rows a block): what each case is there for; H = (key heads,
+# value heads) is the DeltaNet form
 CASES = {
     "blocks_1head": (1, 24, 1, 16, 8),     # the taps cross two block edges
     "two_sequences": (2, 32, 2, 16, 16),   # sequence 1 starts on zeros
@@ -26,7 +30,18 @@ CASES = {
     "ragged_halo16": (1, 40, 2, 16, 16),   # T = 2.5 blocks of 16 positions
     "one_block": (1, 16, 4, 8, 256),       # the block clipped to T, 2 steps
                                            # of 2 heads
+    # q | k | v of 16 | 16 | 32 lanes in ONE column step; sequence 1 starts
+    # on zeros; T = 2.5 blocks
+    "gdn_ragged_2seq": (2, 20, (2, 4), 8, 8),
+    # 32 | 32 | 64 in two column steps: k's, v's and the gate's first
+    # blocks are 2, 2 and 4 of their own widths
+    "gdn_2steps": (1, 24, (4, 8), 8, 8),
+    "gdn_halo16": (2, 40, (2, 4), 16, 16),  # T = 2.5 blocks of 16 positions
 }
+
+
+def deltanet(case):
+    return isinstance(CASES[case][2], tuple)
 
 
 def plain_pre(proj, conv_kernel, a_log, dt_bias):
@@ -45,22 +60,41 @@ def plain_pre(proj, conv_kernel, a_log, dt_bias):
     return q, k, v, g, beta, proj[..., 4 * inner:5 * inner]
 
 
-def plain_post(o, gate, weight):
+def plain_qkv_pre(heads, d, proj, conv_kernel):
+    """``models/qwen3_next.py`` ``gdn_pre``'s q, k, v (its ``g`` and
+    ``beta`` read the other projection and stay plain text in the model),
+    and the gate's columns."""
+    hk, hv = heads
+    b, t, _ = proj.shape
+    cfg = Qwen3NextConfig(
+        linear_num_key_heads=hk, linear_num_value_heads=hv,
+        linear_key_head_dim=d, linear_value_head_dim=d, dtype=proj.dtype)
+    conv_dim = conv_kernel.shape[1]
+    return (*gdn_pre(proj[..., :conv_dim], jnp.zeros((b, t, 2 * hv)),
+                     conv_kernel, jnp.zeros(hv), jnp.zeros(hv), cfg)[:3],
+            proj[..., conv_dim:])
+
+
+def plain_post(o, gate, weight, act=jax.nn.sigmoid):
     b, t, heads, d = o.shape
     of = o.astype(jnp.float32)
     y = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + EPS) * weight
     return (y.reshape(b, t, heads * d)
-            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
+            * act(gate.astype(jnp.float32))).astype(o.dtype)
 
 
 def inputs(case, dtype, seed=0):
     b, t, heads, d, _ = CASES[case]
+    if deltanet(case):       # [ q | k | v | gate ], no decay, no beta
+        keys, heads = heads[0], heads[1]
+        conv, width = (2 * keys + heads) * d, 2 * (keys + heads) * d
+    else:
+        conv, width = 3 * heads * d, 5 * heads * d + heads
     inner = heads * d
     keys = jax.random.split(jax.random.PRNGKey(seed), 8)
     return dict(
-        proj=jax.random.normal(keys[0], (b, t, 5 * inner + heads)
-                               ).astype(dtype),
-        conv_kernel=0.5 * jax.random.normal(keys[1], (TAPS, 3 * inner)),
+        proj=jax.random.normal(keys[0], (b, t, width)).astype(dtype),
+        conv_kernel=0.5 * jax.random.normal(keys[1], (TAPS, conv)),
         a_log=jnp.log(jax.random.uniform(keys[2], (heads,), minval=1.0,
                                          maxval=4.0)),
         dt_bias=jax.random.normal(keys[3], (heads, d)),
@@ -93,28 +127,46 @@ def rel(got, want):
 
 
 PRE = ("q k v g beta gate".split(), "proj conv_kernel A_log dt_bias".split())
+QKV_PRE = ("q k v gate".split(), "proj conv_kernel".split())
 POST = (["y"], "o gate weight".split())
+
+
+def stages(case):
+    """(names, kernels, plain text, keys of the arguments in ``inputs``) of
+    the stage in front of the scan and of the one behind it, in the
+    case's form."""
+    _, _, heads, d, rows = CASES[case]
+    if deltanet(case):
+        hk, hv = heads
+        return {
+            "pre": (QKV_PRE,
+                    functools.partial(qkv_pre, key_heads=hk, value_heads=hv,
+                                      head_dim=d, rows=rows),
+                    functools.partial(plain_qkv_pre, heads, d),
+                    ("proj", "conv_kernel")),
+            "post": (POST,
+                     functools.partial(kda_post, eps=EPS, rows=rows,
+                                       gate_act="silu"),
+                     functools.partial(plain_post, act=jax.nn.silu),
+                     ("o", "gate", "weight"))}
+    return {
+        "pre": (PRE, functools.partial(kda_pre, lower_bound=LOWER, rows=rows),
+                plain_pre, ("proj", "conv_kernel", "a_log", "dt_bias")),
+        "post": (POST, functools.partial(kda_post, eps=EPS, rows=rows),
+                 plain_post, ("o", "gate", "weight"))}
 
 
 @functools.lru_cache(maxsize=None)
 def both(case, dtype, seed=0):
     """Per stage: (names of outputs and arguments, the kernels' values and
     gradients, the plain text's); computed once a case."""
-    rows = CASES[case][4]
     x = inputs(case, dtype, seed)
-
-    def pre(*a):
-        return kda_pre(*a, lower_bound=LOWER, rows=rows)
-
-    def post(*a):
-        return kda_post(*a, eps=EPS, rows=rows)
-
-    pre_args = (x["proj"], x["conv_kernel"], x["a_log"], x["dt_bias"])
-    post_args = (x["o"], x["gate"], x["weight"])
-    return {"pre": (PRE, values_and_grads(pre, x["key"], pre_args),
-                    values_and_grads(plain_pre, x["key"], pre_args)),
-            "post": (POST, values_and_grads(post, x["key"], post_args),
-                     values_and_grads(plain_post, x["key"], post_args))}
+    return {stage: (names,
+                    values_and_grads(kernels, x["key"],
+                                     tuple(x[a] for a in args)),
+                    values_and_grads(plain, x["key"],
+                                     tuple(x[a] for a in args)))
+            for stage, (names, kernels, plain, args) in stages(case).items()}
 
 
 @pytest.mark.parametrize("stage", ["pre", "post"])
@@ -130,7 +182,7 @@ def test_kernels_match_the_plain_text_float32(case, stage):
 
 
 @pytest.mark.parametrize("stage", ["pre", "post"])
-@pytest.mark.parametrize("case", ["ragged_halo16"])
+@pytest.mark.parametrize("case", ["ragged_halo16", "gdn_halo16"])
 def test_bfloat16_rows_round_where_the_plain_text_rounds(case, stage):
     """bfloat16 ``proj`` / ``o``: float32 inside, so q, k, v and y are the
     plain text's to a rounding of bfloat16 (2^-8), ``g`` and ``beta`` to
@@ -149,27 +201,29 @@ def test_bfloat16_rows_round_where_the_plain_text_rounds(case, stage):
         assert rel(a, b) < 1e-2, (name, rel(a, b))
 
 
-def test_a_sequence_starts_on_zeros_not_on_its_neighbour():
+@pytest.mark.parametrize("case", ["two_sequences", "gdn_ragged_2seq"])
+def test_a_sequence_starts_on_zeros_not_on_its_neighbour(case):
     """Positions 0 .. 2 read zeros on their left — sequence 1's too, whose
     left neighbour in memory is sequence 0's last rows, and whose
     gradient must not reach them."""
-    rows = CASES["two_sequences"][4]
-    x = inputs("two_sequences", jnp.float32, seed=2)
+    x = inputs(case, jnp.float32, seed=2)
+    _, pre, _, args = stages(case)["pre"]
     proj = x["proj"]
     loud = proj.at[0, -3:].set(1e3)          # sequence 0's last three rows
-    rest = (x["conv_kernel"], x["a_log"], x["dt_bias"])
+    rest = tuple(x[a] for a in args[1:])
 
     @jax.jit
     def kernels(p):
-        return kda_pre(p, *rest, lower_bound=LOWER, rows=rows)
+        return pre(p, *rest)
 
     quiet = kernels(proj)
     for a, b in zip(quiet, kernels(loud)):
         np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
-    inner = x["gate"].shape[-1]
+    inner = x["gate"].shape[-1]                       # v's lanes: the last
+    conv = x["conv_kernel"].shape[1]                  # of the taps' columns
     first = quiet[2][1, :3].reshape(3, inner)                     # v
-    xv = proj[1, :3, 2 * inner:3 * inner]
-    w = x["conv_kernel"][:, 2 * inner:]
+    xv = proj[1, :3, conv - inner:conv]
+    w = x["conv_kernel"][:, conv - inner:]
     by_hand = jnp.stack([sum(w[TAPS - 1 - j] * xv[t - j]
                              for j in range(t + 1)) for t in range(3)])
     np.testing.assert_allclose(np.asarray(first),
@@ -197,16 +251,26 @@ def test_sizes_that_do_not_fit_are_refused():
         kda_post(x["o"], x["gate"][..., :-1], x["weight"], eps=EPS)
     with pytest.raises(ValueError, match="whole lane tiles"):
         kda_post(x["o"], x["gate"], x["weight"], eps=EPS, interpret=False)
+    # the DeltaNet form: the message names the widths it wants ...
+    x = inputs("gdn_ragged_2seq", jnp.float32)
+    heads = dict(key_heads=2, value_heads=4, head_dim=8)
+    with pytest.raises(ValueError, match=r"qkv_pre: 2 key heads under 4 "
+                       r"value heads of 8 want proj \[.., q 16 \| k 16 \| "
+                       r"v 32 \| gate 32\] and conv_kernel \[.., 64\]"):
+        qkv_pre(x["proj"][..., :-8], x["conv_kernel"], **heads)
+    with pytest.raises(ValueError, match="qkv_pre: 2 key heads"):
+        qkv_pre(x["proj"], x["conv_kernel"][:, :-8], **heads)
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        qkv_pre(x["proj"], x["conv_kernel"], **heads, interpret=False)
+    # ... and widths no one number of column steps cuts into whole blocks
+    # (v's first lane, 16, is no multiple of its 24)
+    with pytest.raises(ValueError, match=r"slices of 8 \| 8 \| 24 \| 24 "
+                       r"lanes at heads of 8"):
+        qkv_pre(jnp.zeros((1, 8, 64)), jnp.zeros((TAPS, 40)), key_heads=1,
+                value_heads=3, head_dim=8)
 
 
-def plain_post_silu(o, gate, weight):
-    """``plain_post`` with the gate's activation SiLU (Gated DeltaNet's
-    output stage, ``models/qwen3_next.py``)."""
-    b, t, heads, d = o.shape
-    of = o.astype(jnp.float32)
-    y = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + EPS) * weight
-    return (y.reshape(b, t, heads * d)
-            * jax.nn.silu(gate.astype(jnp.float32))).astype(o.dtype)
+plain_post_silu = functools.partial(plain_post, act=jax.nn.silu)
 
 
 @pytest.mark.parametrize("gate_act", ["sigmoid", "silu"])

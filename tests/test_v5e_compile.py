@@ -631,8 +631,12 @@ def test_qwen3_next_80b_cell_step_fits_a_v5e(topo, monkeypatch):
     head-decay scan's kernels under ``bps.gdn.scan`` in all three DeltaNet
     layers (``bps_gdn_fwd`` twice a layer — the forward and, under
     ``remat``, the one that stores the chunk-start states — and
-    ``bps_gdn_bwd`` once), the output stage's row kernels with the SiLU
-    gate under ``bps.gdn.out``, the flash kernels at 16 heads of 256 under
+    ``bps_gdn_bwd`` once), the row kernels around it — the input stage as
+    one pass over ``in_proj_qkvz``'s result under ``bps.gdn.pre`` (q | k |
+    v of 2048 | 2048 | 4096 lanes, no decay slice: no float32 ``[4, 8192,
+    8192]`` array and no slice copy of those columns outside a kernel), the
+    output stage with the SiLU gate under ``bps.gdn.out`` —, the flash
+    kernels at 16 heads of 256 under
     ``attn`` and the selection kernel under ``bps.moe.route`` in all four
     sparse MLPs; the head's ``[tokens, 18992]`` logits exist only a block
     at a time; q and k of the scan are never repeated to the value heads
@@ -663,9 +667,17 @@ def test_qwen3_next_80b_cell_step_fits_a_v5e(topo, monkeypatch):
     assert len(scan) == 9 == sum("bps_gdn" in c for c in calls)
     rows = [c for c in calls
             if re.search(r"bps\.gdn\.(pre|out)\)*/.*pallas_call$", c)]
-    assert sum(c.endswith("bps_kda_post_fwd/pallas_call") for c in rows) == 6
-    assert sum(c.endswith("bps_kda_post_bwd/pallas_call") for c in rows) == 3
-    assert len(rows) == 9
+    # what ``gdn_rows_ms`` reads by this rule: a layer's two kernels in
+    # front of the scan and behind it, forward, recomputed and backward
+    for name, count in (("bps_kda_pre_fwd", 6), ("bps_kda_pre_bwd", 3),
+                        ("bps_kda_post_fwd", 6), ("bps_kda_post_bwd", 3)):
+        assert sum(c.endswith(f"{name}/pallas_call") for c in rows) == count
+    assert len(rows) == 18
+    # no float32 copy of the q | k | v columns exists outside a kernel (the
+    # parent held ~8 of 1 GiB a layer), and nothing slices them out of the
+    # projection: q | k | v leave the kernel as the scan reads them
+    assert f"f32[{seqs},8192,8192]" not in text
+    assert f"bf16[{seqs},8192,8192]" not in text
     # the one attention layer: flash forward, its recomputation, two
     # backward kernels (T = 8192 at 256 lanes is past the resident form)
     assert sum(c.endswith("/attn/pallas_call") for c in calls) == 4
