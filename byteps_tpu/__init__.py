@@ -14,6 +14,13 @@ push_pull, declare, plus the framework adapters under byteps_tpu.jax and
 byteps_tpu.torch.
 """
 
+# The start-up record's first stamp (``metrics_snapshot()["startup"]``,
+# common/telemetry.py): a clock read before anything is imported, and one
+# more as the last statement — nothing else happens at import.
+import time as _time
+
+_IMPORT_BEGIN = _time.monotonic()
+
 __version__ = "0.1.0"
 
 from byteps_tpu.core.api import (  # noqa: F401
@@ -48,3 +55,5 @@ from byteps_tpu.server import (  # noqa: F401
     ServingTier,
     SnapshotStore,
 )
+
+_IMPORT_END = _time.monotonic()

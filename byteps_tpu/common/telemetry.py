@@ -34,10 +34,14 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import sys
 import threading
 import time
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
+import jax
+
+from .config import get_config
 from .metrics import (Counters, Gauges, Histograms,  # noqa: F401
                       counters, gauges, histograms, registry)
 from . import tracing as _tracing
@@ -596,7 +600,6 @@ class StepStatsTracker:
         # the flight event names the lagging tensor and this rank — a
         # crash black box says WHO the dying step was waiting on
         try:
-            from .config import get_config
             rank = get_config().host_id
         except Exception:  # noqa: BLE001 — publishing must never raise
             rank = 0
@@ -665,3 +668,151 @@ class StepStatsTracker:
                 med([s.overlap_fraction for s in hist]), 4),
             "retransmits_total": sum(s.retransmits for s in hist),
         }
+
+
+# -- start-up: where the time before the first step went (ISSUE 48) ----------
+#
+# One clock (``time.monotonic``, seconds) from the package's first
+# statement to the first ``bps.init()``, and JAX's own compile durations
+# as registry counters: ``metrics_snapshot()["startup"]`` plus the seven
+# ``compile.*`` counters answer "why did my job take 40 s (or 190 s) to
+# take its first step, and was the cache cold?".
+
+# JAX's duration events (``jax/_src/dispatch.py``, ``compiler.py``) and the
+# counter of milliseconds each feeds — one literal a name (bpslint).
+COMPILE_DURATION_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace_ms",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower_ms",
+    "/jax/core/compile/backend_compile_duration": "compile.backend_ms",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "compile.cache_retrieval_ms",
+}
+COMPILE_EVENT_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+
+
+class CompileSpans:
+    """What each thread has already recorded of its compile work, so that
+    the ``compile.*_ms`` counters are a UNION of intervals and not a sum.
+
+    JAX reports a duration at its END, with its length, and durations
+    nest: an inner ``jax.jit`` traced while an outer one is being traced
+    reports first and lies inside the outer's interval
+    (``pjit._create_pjit_jaxpr`` emits ``jaxpr_trace_duration`` for inner
+    and outer alike); a lowering rule may trace; and in JAX 0.9.0
+    ``backend_compile_duration`` wraps ``compile_or_get_cached``
+    (``jax/_src/interpreters/pxla.py``), so on a cache hit it CONTAINS
+    ``cache_retrieval_time_sec``.  :meth:`claim` therefore gives an event
+    ``[now - seconds, now]`` minus what the same thread has recorded
+    inside that interval: the inner event keeps its time under its own
+    name, the outer gets the rest, the four ``*_ms`` counters are
+    disjoint (``compile.backend_ms`` is the part of the backend call
+    OUTSIDE the cache's retrieval: near 0 on a warm run, the XLA compile
+    on a cold one) and ``trace + lower + backend + cache_retrieval``
+    never exceeds the wall a thread spent in them.
+
+    A thread's record is a list of disjoint ``[start, end, covered]``
+    spans in order of ``end``; events end in that order, so an event only
+    ever meets the list's tail, and one that encloses its children
+    replaces them.  Past ``_CAP`` spans (a process that compiles for
+    ever, never under one enclosing event) the older half becomes ONE
+    span that remembers how much of it was covered; an event that cuts a
+    span takes the share of its cover that lies inside."""
+
+    _CAP = 1024
+
+    def __init__(self):
+        self._local = threading.local()
+
+    def claim(self, seconds: float, now: float) -> float:
+        """Seconds of ``[now - seconds, now]`` new to this thread."""
+        spans = getattr(self._local, "spans", None)
+        if spans is None:
+            spans = self._local.spans = []
+        start = now - max(0.0, seconds)
+        fresh = now - start
+        first, kept = start, 0.0
+        while spans and spans[-1][1] > start:
+            a, b, cover = spans.pop()
+            inside = cover if a >= start else cover * (b - start) / (b - a)
+            fresh -= inside
+            kept += cover - inside
+            first = min(first, a)
+        spans.append([first, now, kept + now - start])
+        if len(spans) > self._CAP:
+            old = spans[:self._CAP // 2]
+            spans[:self._CAP // 2] = [[old[0][0], old[-1][1],
+                                       sum(s[2] for s in old)]]
+        return max(0.0, fresh)
+
+
+_compile_spans = CompileSpans()
+_listen_lock = threading.Lock()
+_listening = False
+
+
+def _on_compile_duration(event: str, seconds: float, **_kw) -> None:
+    name = COMPILE_DURATION_COUNTERS.get(event)
+    if name is None or not get_config().telemetry_on:
+        return
+    counters.inc(name, _compile_spans.claim(seconds, time.monotonic()) * 1e3)
+    if name == "compile.backend_ms":      # one event a program
+        counters.inc("compile.programs")
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    name = COMPILE_EVENT_COUNTERS.get(event)
+    if name is not None and get_config().telemetry_on:
+        counters.inc(name)
+
+
+def listen_to_compiles() -> None:
+    """Register the two ``jax.monitoring`` listeners that feed the
+    ``compile.*`` counters, once a process (``bps.init()`` and
+    ``enable_compile_cache()`` both call this; whichever runs first
+    registers).  The listeners run on whatever thread JAX compiles on —
+    the engine's dispatcher compiles lazily — and cost nothing where
+    nothing compiles.  Counters are cumulative over the process: their
+    value when set-up ends is set-up's, their delta over a window of
+    steps should be 0."""
+    global _listening
+    with _listen_lock:
+        if _listening:
+            return
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_compile_event)
+        _listening = True
+
+
+_init_record: Dict[str, object] = {}
+
+
+def record_init(begin: float, end: float, parts_ms: Dict[str, float]) -> None:
+    """``bps.init()``'s stamps; the FIRST init that built an engine keeps
+    them (``resume()`` runs ``init`` again and must not move the job's
+    start)."""
+    if not _init_record:
+        _init_record.update(init_begin=begin, init_end=end,
+                            init_parts_ms=dict(parts_ms))
+
+
+def startup_record() -> Dict[str, object]:
+    """``metrics_snapshot()["startup"]``: ``import_begin`` / ``import_end``
+    (first and last statement of ``byteps_tpu/__init__.py``),
+    ``init_begin`` / ``init_end`` and ``init_parts_ms`` (the first
+    ``bps.init()`` and its ``bps.init.mesh | engine | services`` phases),
+    and ``now`` — all ``time.monotonic`` seconds, so a reader places the
+    stamps against its own wall through ``now`` without sharing an epoch.
+    A stamp not taken yet is None."""
+    pkg = sys.modules.get("byteps_tpu")
+    record: Dict[str, object] = {
+        "import_begin": getattr(pkg, "_IMPORT_BEGIN", None),
+        "import_end": getattr(pkg, "_IMPORT_END", None),
+        "init_begin": None, "init_end": None, "init_parts_ms": {},
+    }
+    record.update(_init_record)
+    record["now"] = time.monotonic()
+    return record

@@ -18,6 +18,7 @@ from typing import Any, Dict, List, Optional
 import jax
 
 from ..comm import mesh as mesh_mod
+from ..common import telemetry, tracing
 from ..common.config import Config, get_config, set_config
 from ..common.handles import Handle
 from ..common.logging import get_logger
@@ -45,102 +46,133 @@ def init(config: Optional[Config] = None,
     Reference: byteps_init() (operations.cc:36-88) — spawns the background
     stage loops; here it builds the (dcn, ici) mesh and starts the
     dispatcher/syncer pair.
+
+    The call is one ``tracing.phase`` (``bps.init``) of three
+    (``bps.init.mesh`` — ``mesh_mod.bootstrap``, which starts the
+    backend when the caller has not; ``bps.init.engine`` — threads,
+    planner, scheduler; ``bps.init.services`` — heartbeat, flight
+    recorder, obs server, health, durable store): spans in any profiler
+    session open over start-up, and the first call's stamps and
+    milliseconds in ``metrics_snapshot()["startup"]``.
     """
-    global _engine, _heartbeat
+    global _engine
     with _lock:
         if _engine is not None:
             return
         if config is not None:
             set_config(config)
         cfg = get_config()
-        from ..fault import injector as fault_injector
-        if cfg.fault_spec:
-            # Eager validation: a chaos-spec typo must fail init() with
-            # the valid kind/site lists, not silently inject nothing.
-            # Armed before bootstrap so rendezvous-time sites are live.
-            fault_injector.arm(cfg.fault_spec, seed=cfg.fault_seed,
-                               rank=cfg.host_id)
-        else:
-            # engine-scoped only: a persist-armed injector (e.g. a
-            # partition blackhole) outlives the resume it provoked
-            fault_injector.disarm(engine_scoped_only=True)
-        comm = mesh_mod.bootstrap(cfg, devices=devices)
-        engine = PushPullEngine(comm, cfg)
-        if cfg.heartbeat_on and jax.process_count() > 1:
-            # auto-armed liveness: one beat per process; a dead host makes
-            # every survivor exit (restartable) instead of wedging in the
-            # next DCN collective (utils/failure_detector.py).  Armed
-            # BEFORE _engine is published: if the UDP bind fails (port in
-            # use), init() raises cleanly and a retry re-runs everything
-            # — never a running engine that silently believes liveness
-            # is on.
-            from ..common.retry import RetryPolicy
-            from ..utils.failure_detector import HeartbeatMonitor
+        telemetry.listen_to_compiles()
+        parts_ms: Dict[str, float] = {}
 
-            def _arm_heartbeat():
-                # fresh monitor per attempt: a failed bind leaves the old
-                # instance's socket state unusable
-                return HeartbeatMonitor(
-                    rank=jax.process_index(),
-                    num_ranks=jax.process_count(),
-                    interval=cfg.heartbeat_interval_s,
-                    timeout=cfg.heartbeat_timeout_s).start()
+        def part(name: str) -> tracing.phase:
+            def feed(wall_ms: float, _cpu_ms: Optional[float]) -> None:
+                parts_ms[name] = wall_ms
+            return tracing.phase("bps.init." + name, feed)
 
-            try:
-                # the UDP bind races the previous incarnation's socket
-                # teardown after an elastic restart (TIME_WAIT, port still
-                # held) — exactly the transient the backoff layer is for
-                _heartbeat = RetryPolicy.from_config(
-                    cfg, retry_on=(OSError,)).call(
-                        _arm_heartbeat, describe="heartbeat UDP bind")
-            except Exception:
-                engine.shutdown(wait=False)
-                mesh_mod.shutdown_comm()
-                raise
-        # Observability plane: flight-recorder knobs + crash/SIGTERM/
-        # atexit dump hooks, and (when BYTEPS_OBS_PORT is set) the
-        # per-process HTTP endpoint.  The endpoint outlives the engine —
-        # an elastic suspend/resume keeps it (ensure_started is a
-        # process-lifetime idempotent singleton), so /healthz can report
-        # the transition instead of going dark.
-        from ..common import flight_recorder as flight_recorder_mod
-        from ..common import obs_server as obs_server_mod
-        flight_recorder_mod.configure_from_config(cfg)
-        flight_recorder_mod.install_hooks()
+        with tracing.phase("bps.init") as whole:
+            from ..fault import injector as fault_injector
+            if cfg.fault_spec:
+                # Eager validation: a chaos-spec typo must fail init() with
+                # the valid kind/site lists, not silently inject nothing.
+                # Armed before bootstrap so rendezvous-time sites are live.
+                fault_injector.arm(cfg.fault_spec, seed=cfg.fault_seed,
+                                   rank=cfg.host_id)
+            else:
+                # engine-scoped only: a persist-armed injector (e.g. a
+                # partition blackhole) outlives the resume it provoked
+                fault_injector.disarm(engine_scoped_only=True)
+            with part("mesh"):
+                comm = mesh_mod.bootstrap(cfg, devices=devices)
+            with part("engine"):
+                engine = PushPullEngine(comm, cfg)
+            with part("services"):
+                _start_services(cfg, engine)
+            _engine = engine
+            for name in _declared_order:
+                _engine.registry.declare(name)
+        if cfg.telemetry_on:
+            telemetry.record_init(whole.t0, whole.t1, parts_ms)
+        get_logger().info("byteps_tpu initialized: %d ranks", comm.num_ranks)
+
+
+def _start_services(cfg: Config, engine: PushPullEngine) -> None:
+    """What ``init`` starts beside the engine: the heartbeat, the
+    observability plane, retention + judgment, the durable store.  A
+    heartbeat or an endpoint that cannot bind takes the engine and the
+    mesh down again before it raises."""
+    global _heartbeat
+    if cfg.heartbeat_on and jax.process_count() > 1:
+        # auto-armed liveness: one beat per process; a dead host makes
+        # every survivor exit (restartable) instead of wedging in the
+        # next DCN collective (utils/failure_detector.py).  Armed
+        # BEFORE _engine is published: if the UDP bind fails (port in
+        # use), init() raises cleanly and a retry re-runs everything
+        # — never a running engine that silently believes liveness
+        # is on.
+        from ..common.retry import RetryPolicy
+        from ..utils.failure_detector import HeartbeatMonitor
+
+        def _arm_heartbeat():
+            # fresh monitor per attempt: a failed bind leaves the old
+            # instance's socket state unusable
+            return HeartbeatMonitor(
+                rank=jax.process_index(),
+                num_ranks=jax.process_count(),
+                interval=cfg.heartbeat_interval_s,
+                timeout=cfg.heartbeat_timeout_s).start()
+
         try:
-            obs_server_mod.ensure_started(cfg)
+            # the UDP bind races the previous incarnation's socket
+            # teardown after an elastic restart (TIME_WAIT, port still
+            # held) — exactly the transient the backoff layer is for
+            _heartbeat = RetryPolicy.from_config(
+                cfg, retry_on=(OSError,)).call(
+                    _arm_heartbeat, describe="heartbeat UDP bind")
         except Exception:
-            # the operator explicitly asked for the endpoint: a bind
-            # failure fails init() loudly, never a silently-dark plane
-            if _heartbeat is not None:
-                _heartbeat.stop()
-                _heartbeat = None
             engine.shutdown(wait=False)
             mesh_mod.shutdown_comm()
             raise
-        # Retention + judgment (ISSUE 16): the time-series sampler and
-        # SLO engine, process-lifetime like the obs server — an elastic
-        # suspend/resume keeps the ring and the alert state, and the
-        # registry underneath stays monotonic, so a transition never
-        # reads as a phantom counter reset.
-        from ..common import health as health_mod
-        from ..common import timeseries as timeseries_mod
-        health_mod.configure(cfg)
-        timeseries_mod.ensure_started(cfg)
-        # Durable state plane (server/wal.py, ISSUE 19): with
-        # BYTEPS_DURABLE_DIR set, open the process-lifetime durable
-        # trainer-side KV store — on a cold start this replays the
-        # journal and restores the last snapshot cut BEFORE any push
-        # lands, so a full-world crash resumes from disk instead of
-        # from zero.  Process-lifetime like the obs server: an elastic
-        # suspend/resume must not close and re-replay the journal.
-        if cfg.durable_dir:
-            from ..server import wal as wal_mod
-            wal_mod.ensure_process_store(cfg)
-        _engine = engine
-        for name in _declared_order:
-            _engine.registry.declare(name)
-        get_logger().info("byteps_tpu initialized: %d ranks", comm.num_ranks)
+    # Observability plane: flight-recorder knobs + crash/SIGTERM/
+    # atexit dump hooks, and (when BYTEPS_OBS_PORT is set) the
+    # per-process HTTP endpoint.  The endpoint outlives the engine —
+    # an elastic suspend/resume keeps it (ensure_started is a
+    # process-lifetime idempotent singleton), so /healthz can report
+    # the transition instead of going dark.
+    from ..common import flight_recorder as flight_recorder_mod
+    from ..common import obs_server as obs_server_mod
+    flight_recorder_mod.configure_from_config(cfg)
+    flight_recorder_mod.install_hooks()
+    try:
+        obs_server_mod.ensure_started(cfg)
+    except Exception:
+        # the operator explicitly asked for the endpoint: a bind
+        # failure fails init() loudly, never a silently-dark plane
+        if _heartbeat is not None:
+            _heartbeat.stop()
+            _heartbeat = None
+        engine.shutdown(wait=False)
+        mesh_mod.shutdown_comm()
+        raise
+    # Retention + judgment (ISSUE 16): the time-series sampler and
+    # SLO engine, process-lifetime like the obs server — an elastic
+    # suspend/resume keeps the ring and the alert state, and the
+    # registry underneath stays monotonic, so a transition never
+    # reads as a phantom counter reset.
+    from ..common import health as health_mod
+    from ..common import timeseries as timeseries_mod
+    health_mod.configure(cfg)
+    timeseries_mod.ensure_started(cfg)
+    # Durable state plane (server/wal.py, ISSUE 19): with
+    # BYTEPS_DURABLE_DIR set, open the process-lifetime durable
+    # trainer-side KV store — on a cold start this replays the
+    # journal and restores the last snapshot cut BEFORE any push
+    # lands, so a full-world crash resumes from disk instead of
+    # from zero.  Process-lifetime like the obs server: an elastic
+    # suspend/resume must not close and re-replay the journal.
+    if cfg.durable_dir:
+        from ..server import wal as wal_mod
+        wal_mod.ensure_process_store(cfg)
 
 
 def initialized() -> bool:
@@ -348,9 +380,12 @@ def metrics_snapshot(light: bool = False) -> Dict[str, Any]:
     """This process's observability snapshot: counters + gauges (one
     consistent registry view), membership epoch, push_pull speed, and
     the last completed :class:`~byteps_tpu.common.telemetry.StepStats`.
-    ``light=True`` drops the histogram buckets — the compact form the
-    membership bus piggybacks on every ``step_sync`` so the coordinator
-    always holds a fresh per-rank view."""
+    The full form also holds ``"startup"``: the process's start-up
+    record (``common/telemetry.py`` ``startup_record``), which with the
+    ``compile.*`` counters says where the time before the first step
+    went.  ``light=True`` drops it and the histogram buckets — the
+    compact form the membership bus piggybacks on every ``step_sync`` so
+    the coordinator always holds a fresh per-rank view."""
     import os
     import time
 
@@ -367,6 +402,7 @@ def metrics_snapshot(light: bool = False) -> Dict[str, Any]:
     }
     if not light:
         snap["histograms"] = reg["histograms"]
+        snap["startup"] = telemetry.startup_record()
         from ..utils import slowness as _slowness
         snap["slowness"] = _slowness.tracker().snapshot()
     eng = _engine

@@ -19,6 +19,8 @@ import os
 
 import jax
 
+from ..common.telemetry import listen_to_compiles
+
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 DEFAULT_DIR = os.path.join(_CHECKOUT, ".jax_cache")
@@ -34,7 +36,11 @@ def enable_compile_cache() -> str:
     chunk programs are cached along with the big train step.  Both
     ``jax.jit`` and the engine's ``.lower().compile()`` warm go through
     the same ``compile_or_get_cached``, so one directory serves both.
+    What the cache then does for a job — hits, misses, the seconds spent
+    loading against compiling — is counted from here on
+    (``compile.*``, ``common/telemetry.py`` ``listen_to_compiles``).
     """
+    listen_to_compiles()
     if not jax.config.jax_compilation_cache_dir:
         jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

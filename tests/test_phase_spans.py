@@ -189,8 +189,17 @@ def test_dispatch_events_equal_dispatch_count(traced):
     assert all("compile" not in s.attrib
                for n, s in steps.items() if n > 1)
     if shape["buckets"]:
-        assert steps[1].attrib["compile"] > 10 * steps[3].attrib["enqueue"]
+        # the bucket's first push fed "compile" and nothing of it
+        # "enqueue" — held by the spans' own arguments and the SAME
+        # step's counters, not by two steps' wall times against each
+        # other (a thread descheduled in step 3 under -n 6 broke a
+        # ratio, ISSUE 48): what step 1 has under "compile" is its
+        # ``compiled=1`` spans, from one enter/exit pair (the span is
+        # never the shorter; (d) holds how much longer it may be)
         assert "enqueue" not in steps[1].attrib   # its one tensor compiled
+        span_ms = sum(s.end - s.start for s in compiled) / 1e6
+        assert 0 < steps[1].attrib["compile"] <= (
+            span_ms + 0.002 * (len(compiled) + 1))
     else:
         assert "compile" not in steps[1].attrib
 
@@ -433,9 +442,11 @@ def test_phase_feeds_wall_and_cpu():
 @pytest.mark.parametrize("how", ["sleeps", "spins"])
 def test_cpu_is_the_running_part_of_the_wall(how):
     """A phase that sleeps reads CPU ~ 0 and one that spins reads CPU ~
-    wall (best of five: another process may hold the core once)."""
+    wall (the first of up to twenty tries that does: beside five busy
+    xdist workers another process holds the core more than once)."""
+    good = (lambda x: x < 0.05) if how == "sleeps" else (lambda x: x > 0.9)
     shares = []
-    for _ in range(5):
+    for _ in range(20):
         got, feed = _fed()
         with tracing.phase("bps.test." + how, feed, cpu=True):
             if how == "sleeps":
@@ -447,10 +458,9 @@ def test_cpu_is_the_running_part_of_the_wall(how):
         (wall, cpu), = got
         assert wall >= 20.0 and cpu <= wall + 0.01
         shares.append(cpu / wall)
-    if how == "sleeps":
-        assert min(shares) < 0.05, shares
-    else:
-        assert max(shares) > 0.9, shares
+        if good(shares[-1]):
+            break
+    assert good(shares[-1]), shares
 
 
 @pytest.fixture
@@ -615,7 +625,10 @@ def test_update_ms_holds_push_pull_and_tx_update(cpu_steps):
     for s in cpu_steps:
         own = s.update_ms - s.push_pull_ms - s.attrib["tx_update"]
         assert own >= -0.005, s
-        assert s.update_ms <= s.wall_ms + 0.005, s
+        # the step's wall starts at its first push, a moment INSIDE
+        # update(): a caller descheduled before that push adds to
+        # update_ms alone
+        assert s.update_ms <= s.wall_ms + PREEMPTION_MS, s
         assert s.attrib["tx_update"] > 0
 
 
@@ -625,7 +638,10 @@ def test_the_first_enqueue_of_a_bucket_feeds_compile(cpu_steps):
     ``enqueue`` is back from the second step on."""
     first, second = cpu_steps[0], cpu_steps[1]
     assert "enqueue" not in first.attrib
-    assert first.attrib["compile"] > 10 * second.attrib["enqueue"] > 0
+    # each against its own step, not one step's wall against another's
+    # (a thread descheduled in the second step broke a ratio, ISSUE 48)
+    assert first.attrib["compile"] > 0 and second.attrib["enqueue"] > 0
+    assert "compile" not in second.attrib
     assert all("compile" not in s.attrib for s in cpu_steps[1:])
 
 
