@@ -3,11 +3,12 @@
 What runs on the chip and knows nothing of routing: the grouped matmul
 over ragged row groups (``_grouped_matmul``: JAX's Pallas megablox
 kernels), the row kernels of a layer that holds a share of the experts —
-the spread into sorted order (``_spread_rows``) and the activations
-(``_gate_call``) — and the route stage's selection of k of E in one pass
-(``_select_call``).  The row kernels take the rows to visit as an
-argument (``sched``: ``{"lo", "hi", "first", "end"}``, four int32 words);
-which rows are live is the layer's knowledge, not this file's.  Gradients
+the spread into sorted order (``_spread_rows``), its transpose over a
+window's rows (``_sum_rows``) and the activations (``_gate_call``) — and
+the route stage's selection of k of E in one pass (``_select_call``).  The
+row kernels take the rows to visit as an argument (``sched``: ``{"lo",
+"hi", "first", "end"}``, four int32 words); which rows are live is the
+layer's knowledge, not this file's.  Gradients
 (the ``custom_vjp``s that string these calls together) are the layer's
 too.  Every kernel takes ``interpret=`` so CPU tests run the same code.
 """
@@ -96,6 +97,14 @@ def _live_chunk(n_chunks):
     return index
 
 
+def _loop(trips, body):
+    """``body(i)`` for ``i`` in ``0 .. trips - 1`` as a loop, not unrolled."""
+    def trip(i, carry):
+        body(i)
+        return carry
+    lax.fori_loop(0, trips, trip, 0)
+
+
 def _spread_kernel(words, tok, src, *refs, chunk, part, sub, scaled):
     """One chunk of ``_spread_rows``, ``part`` rows at a time: the live
     rows' sources come by one DMA each from ``src`` [N, 1, h] float32 in
@@ -110,12 +119,6 @@ def _spread_kernel(words, tok, src, *refs, chunk, part, sub, scaled):
         out, buf, sem = refs
     group = math.gcd(part, _DMA_GROUP)
     start = pl.program_id(0) * chunk
-
-    def loop(trips, body):
-        def trip(i, carry):
-            body(i)
-            return carry
-        lax.fori_loop(0, trips, trip, 0)
 
     def one_part(p):
         base = pl.multiple_of(p * part, part)
@@ -147,8 +150,8 @@ def _spread_kernel(words, tok, src, *refs, chunk, part, sub, scaled):
                 pltpu.make_async_copy(src.at[pl.ds(0, group)],
                                       buf.at[pl.ds(0, group)], sem).wait()
 
-            loop(groups, fetch)
-            loop(groups, land)
+            _loop(groups, fetch)
+            _loop(groups, land)
 
             def piece(i):
                 s = pl.multiple_of(i * sub, sub)
@@ -163,9 +166,9 @@ def _spread_kernel(words, tok, src, *refs, chunk, part, sub, scaled):
                     rows = rows * weight[at, :]
                 out[at, :] = jnp.where(live, rows, 0.0).astype(out.dtype)
 
-            loop(part // sub, piece)
+            _loop(part // sub, piece)
 
-    loop(chunk // part, one_part)
+    _loop(chunk // part, one_part)
 
 
 # jitted: the layers' calls share ONE traced and lowered copy of each kernel
@@ -217,6 +220,85 @@ def _spread_rows(src, token, sched, chunk, interpret, scale=None, dot=None):
         name="bps_moe_spread_scaled" if scaled else "bps_moe_spread",
         interpret=interpret)(*args)
     return (got[0], got[1].reshape(m)) if scaled else got
+
+
+# Tokens a grid step of the token-order sum owns, and window rows it
+# fetches and adds at a time (a landing buffer of that many float32 rows).
+# Measured on a v5e at the three windows the models send (PERF.md section 6,
+# PR 49; ms a call, XLA's float32 scatter-add in brackets): [40 960, 2048]
+# -> [32 768, 2048] with 20 582 live rows 2.27 (4.50), [4096, 2560] ->
+# [16 384, 2560] 0.41 (6.72), [6144, 1024] -> [8192, 1024] 0.21 (0.38);
+# (128, 128) lies 4-6 % behind at the first and third, (512, 256) level at
+# the first two and 20 % behind at the third.
+_SUM_TOKENS = 256
+_SUM_PART = 256
+
+
+def _sum_kernel(starts, toks, rows, src, out, buf, sem, *, tile, part):
+    """One tile of ``_sum_rows``: the tile's tokens own the rows ``starts[c]
+    .. starts[c + 1] - 1`` of the window's rows SORTED BY TOKEN (``toks``
+    their tokens, ``rows`` their places in the window).  Each comes by one
+    DMA from ``src`` [W, 1, h] float32 in HBM, ``part`` of them at a time,
+    and is added to its token's row of the tile in the sorted order.  Loops,
+    as in ``_spread_kernel``."""
+    c = pl.program_id(0)
+    first, end = starts[c], starts[c + 1]
+    base = c * tile
+
+    out[...] = jnp.zeros(out.shape, out.dtype)
+
+    def one_part(p):
+        at = first + p * part
+        count = lax.min(end - at, part)
+
+        def fetch(i):
+            pltpu.make_async_copy(src.at[rows[at + i]], buf.at[i],
+                                  sem).start()
+
+        def land(i):
+            # a wait counts bytes: one row's worth a trip
+            pltpu.make_async_copy(src.at[0], buf.at[0], sem).wait()
+
+        def add(i):
+            t = pl.ds(toks[at + i] - base, 1)
+            out[t, :] = out[t, :] + buf[i]
+
+        _loop(count, fetch)
+        _loop(count, land)
+        _loop(count, add)
+
+    _loop(lax.div(end - first + part - 1, part), one_part)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _sum_rows(src, token, sched, n, interpret):
+    """Token order from a window's rows, the transpose of ``_spread_rows``:
+    ``out[t] = sum of src[r] over the rows lo <= r < hi with token[r] ==
+    t``, float32 [n, h], a token's rows added in their order in the window;
+    ``src`` [W, h] float32, ``token`` [W], ``sched`` the window's live
+    range.  The rows are sorted by token (dead ones last: never fetched),
+    so a tile of tokens owns ONE range of the sorted rows."""
+    w, h = src.shape
+    tile = math.gcd(n, _SUM_TOKENS)
+    row = lax.iota(jnp.int32, w)
+    live = (row >= sched["lo"]) & (row < sched["hi"])
+    toks, rows = lax.sort((jnp.where(live, token, n).astype(jnp.int32), row),
+                          num_keys=1, is_stable=True)
+    edges = jnp.arange(0, n + 1, tile, dtype=jnp.int32)
+    starts = jnp.sum(toks[None, :] < edges[:, None], axis=1, dtype=jnp.int32)
+    return pl.pallas_call(
+        functools.partial(_sum_kernel, tile=tile, part=_SUM_PART),
+        out_shape=jax.ShapeDtypeStruct((n, h), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(n // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, h), lambda c, *_: (c, 0)),
+            scratch_shapes=[pltpu.VMEM((_SUM_PART, 1, h), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ROW_VMEM_BYTES),
+        name="bps_moe_sum", interpret=interpret)(
+            starts, toks, rows, src.astype(jnp.float32)[:, None, :])
 
 
 def _gate_kernel(words, gate, up, *refs, sub, backward):

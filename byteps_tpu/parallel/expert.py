@@ -1,7 +1,12 @@
 """Dropless top-k expert MLPs over the experts a chip holds
 (:func:`dropless_moe_mlp`: ``models/olmoe.py``, ``mellum.py``, ``zaya.py``,
-``glm_lite.py``, ``nemotron_h.py``): one route stage (``_route``) and three
-implementations of the rest, chosen in ONE place (:func:`layer_plan`).
+``glm_lite.py``, ``nemotron_h.py``, ``ling.py``, ``qwen3_next.py``): one
+route stage (``_route``) and three implementations of the rest, chosen in
+ONE place (:func:`layer_plan`) from the layer's shapes alone — every expert
+local (OLMoE); a share on whole pair-row arrays (Mellum: a window would be
+half the rows, ZAYA: all of them, GLM: a quarter); a thin share in windows
+of its live range (Nemotron and Ling: a window a 32nd of the rows, Qwen3-Next:
+an eighth).
 
 With ``held=(first, count)`` the layer is one chip's share of an
 expert-parallel deployment: it routes over all E experts, holds the stacks
@@ -27,7 +32,7 @@ import numpy as np
 from jax import lax
 
 from ..ops.moe_kernels import (_gate_call, _grouped_matmul, _row_chunk,
-                               _select_call, _spread_rows)
+                               _select_call, _spread_rows, _sum_rows)
 
 
 def held_range(experts_held, experts: int) -> Tuple[int, int]:
@@ -44,12 +49,17 @@ def held_range(experts_held, experts: int) -> Tuple[int, int]:
 
 # A held layer works in windows where one window (twice the expected live
 # rows, in whole chunks) is at most this share of the pair rows.  Measured
-# at one shape below it: 8 of 512 experts held at top-22 over 8 192 tokens
-# (windows of 6 144 of 180 224 rows, a 29th) ran forward + backward in 11.8
-# ms where the whole arrays took 35.9 (v5e; PERF.md section 6, PR 40).  The
-# next shape up the models have, an eighth live (a window a quarter of the
-# rows), is not measured; the constant lies between.
-_WINDOW_SHARE = 1 / 16
+# at two shapes (v5e; the layer alone, forward + backward): 8 of 512 experts
+# held at top-22 over 8 192 tokens (windows of 6 144 of 180 224 rows, a
+# 29th) ran in 11.8 ms where the whole arrays took 35.9 (PERF.md section 6,
+# PR 40); 32 of 512 at top-10 over 32 768 tokens (windows of 40 960 of
+# 327 680 rows, exactly an eighth) in 35.6 where they took 82.7, and the
+# step of ``qwen3_next_80b.fused_1c`` went from 1 110 to 834 ms (PR 49).
+# The first shape above it the models have, 8 of 64 at top-4 over 16 384
+# tokens (GLM: a window a quarter of the rows), read +5.8 % tokens/s and
+# -0.42 GiB in ONE scratch pair with the constant at a quarter (PR 49, not
+# shipped there: a PR of its own, ROADMAP S4 (0)); Mellum's half is unmeasured.
+_WINDOW_SHARE = 1 / 8
 
 
 class LayerPlan(NamedTuple):
@@ -284,16 +294,6 @@ def _window_rows_of(order, scale, counts, held, window, i):
             lax.dynamic_slice(scale, (s,), (window,)), sizes)
 
 
-def _sum_rows_by_token(rows, token, n):
-    """Token order from a window's rows: ``out[t] = sum of rows[r] over the
-    r with token[r] == t``, float32 [n, h].  Dead rows are exact zeros and
-    add nothing.  XLA's scatter-add: 0.36 ms at [6144, 1024] -> [8192, 1024]
-    on a v5e, where a one-hot matmul in the three bfloat16 passes float32
-    rows need took 1.67 (PERF.md section 6, PR 40)."""
-    return jnp.zeros((n, rows.shape[1]), jnp.float32).at[token].add(
-        rows.astype(jnp.float32))
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _dispatch_window(x32, token, sched, dtype, chunk, interpret):
     """A window's ``xs`` (``_spread_rows`` of the rows rounded to ``dtype``);
@@ -303,12 +303,12 @@ def _dispatch_window(x32, token, sched, dtype, chunk, interpret):
 
 def _dispatch_window_fwd(x32, token, sched, dtype, chunk, interpret):
     return (_dispatch_window(x32, token, sched, dtype, chunk, interpret),
-            (token, x32))
+            (token, sched, x32))
 
 
 def _dispatch_window_bwd(dtype, chunk, interpret, res, g):
-    token, x32 = res                  # the rows' count: nothing of it read
-    return _sum_rows_by_token(g, token, x32.shape[0]), None, None
+    token, sched, x32 = res           # the rows' count: nothing of it read
+    return _sum_rows(g, token, sched, x32.shape[0], interpret), None, None
 
 
 _dispatch_window.defvjp(_dispatch_window_fwd, _dispatch_window_bwd)
@@ -319,8 +319,8 @@ def _combine_window(ys, scale, token, sched, n, chunk, interpret):
     """A window's part of ``y``: each token's float32 sum of its pairs'
     rows in the window, each times its weight.  Backward is
     ``_combine_rows``'s, at the window's rows: one scaled ``_spread_rows``."""
-    return _sum_rows_by_token(ys.astype(jnp.float32) * scale[:, None], token,
-                              n)
+    return _sum_rows(ys.astype(jnp.float32) * scale[:, None], token, sched, n,
+                     interpret)
 
 
 def _combine_window_fwd(ys, scale, token, sched, n, chunk, interpret):
@@ -545,7 +545,9 @@ def _experts_held_rows(x, params, pair_expert, weights, counts, held, top_k,
     follows the live rows (``row_schedule``): the grouped matmuls' grids
     and the row kernels (``_spread_rows``, the activation) cover them alone.
     The token-order half (``_gather_sum_rows``) fetches every row, and
-    every array is written whole, zeros and all."""
+    every array is written whole, zeros and all.  What ``layer_plan`` gives
+    the shares past an eighth: ``mellum2_12b`` (16 of 64 held), ``zaya1_8b``
+    (8 of 16) and ``glm47_flash`` (8 of 64 at top-4)."""
     dt, chunk = x.dtype, plan.chunk
     first = jnp.asarray(held[0], jnp.int32)
     sched = row_schedule(counts, held, chunk)
@@ -582,7 +584,12 @@ def _experts_held_windows(x, params, pair_expert, weights, counts, held,
     """A thin held share: no array of ``N k`` rows but the sort's three
     columns; ``_windowed_experts`` runs the pieces above on ``plan.window``
     rows at a time (nothing is dropped: a heavier batch runs more windows).
-    Same result, same precision (rows in ``x.dtype``, sums in float32)."""
+    Same result, same precision (rows in ``x.dtype``, sums in float32).
+    ``nemotron3_super`` (8 of 512 at top-22: windows of 6 144 of 180 224
+    rows), ``ling3_flash`` (8 of 512 at top-8: 4 096 of 131 072) and, since
+    PR 49, ``qwen3_next_80b`` (32 of 512 at top-10: 40 960 of 327 680, where
+    the whole arrays cost 311 ms a step of glue around 28 ms of matmuls and
+    the windows 76)."""
     with jax.named_scope("bps.moe.dispatch"):
         order, scale = _sorted_pairs(pair_expert, weights)
     stacks = {k: v for k, v in params.items() if k != "router"}

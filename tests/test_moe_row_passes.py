@@ -6,7 +6,9 @@ the token-order gather-sum (``jax.vjp``); the gate product's kernels; the
 schedule against a brute-force count; the gauge.  And the layer in windows
 of its live range (PR 40): against the whole-array held layer and the dense
 arithmetic, the rule that sizes a window, the trip count, and the scopes
-its kernels land under in a differentiated program.
+its kernels land under in a differentiated program; the windows' token-order
+sum as a kernel against the scatter-add, and the crossover at an eighth
+(PR 49).
 
 Float32 inputs, so a moved row is compared EXACTLY and a weighted one to
 float32 rounding of one product (rtol 1e-6): a wrong row, a wrong weight or
@@ -23,7 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from byteps_tpu.ops.moe_kernels import _ROW_CHUNK, _spread_rows
+from byteps_tpu.ops.moe_kernels import (_ROW_CHUNK, _SUM_PART, _spread_rows,
+                                        _sum_rows)
+from byteps_tpu.parallel import expert
 from byteps_tpu.parallel.expert import (_combine_rows, _dispatch_rows,
                                         _gather_sum_rows, _silu_gate_rows,
                                         dropless_moe_mlp, layer_plan,
@@ -197,6 +201,42 @@ def test_gate_product_over_the_live_chunks_is_the_plain_product(case):
     assert not np.asarray(got)[~live].any()
 
 
+# name -> (tokens n, window rows, live range, tokens of the rows)
+_SUMS = {
+    "a_quarter_live": (64, 96, (8, 70), None),
+    "none_live": (64, 96, (40, 40), None),
+    "all_live": (64, 96, (0, 96), None),
+    # every live row one token's: more rows than a landing buffer holds
+    "one_token_owns_them": (32, 2 * _SUM_PART + 24, (3, 2 * _SUM_PART + 20),
+                            5),
+    # the last token, the first token, tiles in between with no row
+    "first_and_last_token": (512, 64, (0, 64), (0, 511)),
+}
+
+
+@pytest.mark.parametrize("case", _SUMS)
+def test_sum_rows_is_the_scatter_add_of_the_live_rows(case):
+    """The token-order sum of a window's rows (PR 49) against XLA's
+    scatter-add of the live ones; rows outside the range are NOT zeros here
+    and must not be read."""
+    n, w, (lo, hi), tokens = _SUMS[case]
+    rng = np.random.default_rng(3)
+    if tokens is None:
+        token = rng.integers(0, n, w)
+    else:
+        token = np.resize(np.asarray(tokens), w)
+    rows = _rows((w, H), 12)
+    live = (np.arange(w) >= lo) & (np.arange(w) < hi)
+    want = np.zeros((n, H), np.float32)
+    np.add.at(want, token[live], np.asarray(rows)[live])
+    got = jax.jit(lambda r, t, lo, hi: _sum_rows(
+        r, t, {"lo": lo, "hi": hi}, n, True))(
+            rows, jnp.asarray(token, jnp.int32), jnp.int32(lo), jnp.int32(hi))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-5)
+    assert got.dtype == jnp.float32
+    assert not np.asarray(got)[np.setdiff1d(np.arange(n), token[live])].any()
+
+
 @pytest.mark.parametrize("counts,held,chunk", [
     ([12, 12, 12, 12, 12, 12, 12, 12], (0, 2), 16),
     ([12, 12, 12, 12, 12, 12, 12, 12], (6, 2), 16),
@@ -270,6 +310,8 @@ CELLS = {
     "zaya1_8b": ((16384 * 1, 8, 16), ("held_rows", 1024, None)),
     "glm47_flash": ((16384 * 4, 8, 64), ("held_rows", 1024, None)),
     "nemotron3_super": ((8192 * 22, 8, 512), ("held_windows", 1024, 6144)),
+    "ling3_flash": ((16384 * 8, 8, 512), ("held_windows", 1024, 4096)),
+    "qwen3_next_80b": ((32768 * 10, 32, 512), ("held_windows", 1024, 40960)),
 }
 # cell -> (visited_row_share, window_trips) on balanced counts, as the rule
 # plans it and with the other kind forced (windows of half the rows; none)
@@ -278,6 +320,7 @@ GAUGES = {
     "zaya1_8b": ((8192 / 16384, None), (8192 / 16384, 1.0)),
     "glm47_flash": ((8192 / 65536, None), (32768 / 65536, 1.0)),
     "nemotron3_super": ((6144 / 180224, 1.0), (3072 / 180224, None)),
+    "qwen3_next_80b": ((40960 / 327680, 1.0), (20480 / 327680, None)),
 }
 
 
@@ -341,7 +384,8 @@ def test_the_expert_layers_imports_point_one_way():
     assert not [m for m in switch if m.startswith(
         ("byteps_tpu.parallel.expert", "byteps_tpu.ops.moe_kernels",
          "byteps_tpu.models"))]
-    for model in ("olmoe", "mellum", "zaya", "glm_lite", "nemotron_h"):
+    for model in ("olmoe", "mellum", "zaya", "glm_lite", "nemotron_h", "ling",
+                  "qwen3_next"):
         assert "byteps_tpu.parallel.expert.dropless_moe_mlp" in _imported(
             pkg / "models" / f"{model}.py")
 
@@ -354,8 +398,9 @@ def test_the_expert_layers_imports_point_one_way():
     (16384, 1, 8, 16, None),          # zaya1_8b.fused_1c: half
     (16384, 4, 8, 64, None),          # glm47_flash.fused_1c: an eighth
     (4096, 8, 64, 64, None),          # every expert held
-    (8192, 22, 16, 512, 11264),       # exactly a 16th of 180 224
-    (8192, 22, 17, 512, None),        # 12 288: over it
+    (32768, 10, 32, 512, 40960),      # qwen3_next_80b.fused_1c: a 16th live
+    (8192, 22, 32, 512, 22528),       # exactly an eighth of 180 224
+    (8192, 22, 33, 512, None),        # 23 552: over it
     (8192, 8, 1, 64, 2048),
     (66, 4, 1, 64, 16),               # chunks of 8 rows
     (33, 1, 1, 64, None),             # no chunk of whole sublane tiles
@@ -363,15 +408,73 @@ def test_the_expert_layers_imports_point_one_way():
 def test_window_rows_against_a_brute_force_count(n, top_k, held_count,
                                                  experts, want):
     """The least whole number of row chunks that holds twice the expected
-    live rows, where that is at most a 16th of the pair rows."""
+    live rows, where that is at most an eighth of the pair rows."""
     assert window_rows(n, top_k, held_count, experts) == want
     rows = n * top_k
     chunk = math.gcd(rows, _ROW_CHUNK)
     window = chunk
     while window * experts < 2 * rows * held_count:
         window += chunk
-    brute = window if chunk % 8 == 0 and 16 * window <= rows else None
+    brute = window if chunk % 8 == 0 and 8 * window <= rows else None
     assert brute == want
+
+
+def test_the_crossover_is_a_window_of_an_eighth_of_the_pair_rows():
+    """The edge itself (PR 49): a window of exactly ``rows / 8`` takes
+    windows — 32 of 512 experts at top-10 over 32 768 tokens, 40 of 320
+    chunks — and one held expert more does not: 33 of 512 wants 42."""
+    rows = 32768 * 10
+    assert layer_plan(rows, 32, 512) == ("held_windows", 1024, rows // 8)
+    assert layer_plan(rows, 33, 512) == ("held_rows", 1024, None)
+    assert -(-2 * rows * 33 // (512 * 1024)) == 42 > rows // 8 // 1024 == 40
+    # the share alone decides: the same at a tenth of the tokens' pairs ...
+    assert layer_plan(rows // 10, 32, 512) == ("held_windows", 1024, 4096)
+    assert layer_plan(rows // 10, 33, 512).kind == "held_rows"
+    # ... and GLM's quarter and Mellum's half stay on whole arrays
+    assert layer_plan(16384 * 4, 8, 64).kind == "held_rows"
+    assert layer_plan(16384 * 8, 16, 64).kind == "held_rows"
+
+
+def test_a_sixteenth_held_in_windows_of_an_eighth_is_the_whole_array_layer(
+        monkeypatch):
+    """``qwen3_next_80b.fused_1c``'s share scaled down — 8 of 128 experts at
+    top-8 over 1 024 tokens: 8 192 pair rows of which a 16th are live, ONE
+    window of 1 024 by the rule itself — against the same layer forced on
+    whole arrays: ``y`` and the gradients of the rows, the three stacks and
+    the router, the model's own softmax router renormalised over its k."""
+    n, top_k, held, e = 1024, 8, (40, 8), 128
+    assert layer_plan(n * top_k, held[1], e) == ("held_windows", 1024, 1024)
+    k = jax.random.split(jax.random.PRNGKey(49), 3)
+    params = dict(_layer_params(held[1], True),
+                  router=jax.random.normal(k[0], (_LH, e)))
+    x = jax.random.normal(k[1], (n, _LH))
+    cot = jax.random.normal(k[2], (n, _LH))
+
+    def layer(x, params):
+        return dropless_moe_mlp(x, params, top_k, interpret=True, held=held,
+                                renormalize=True)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(
+                lambda x, p: jnp.sum(layer(x, p)[0] * cot), (0, 1)))(x, params)
+
+    asked = []
+    with monkeypatch.context() as patch:
+        patch.setattr(expert, "layer_plan", lambda *shape: (
+            asked.append(shape), layer_plan(*shape))[1])
+        windowed = run()
+    assert asked == [(n * top_k, held[1], e)]
+    counts = np.asarray(layer(x, params)[3])
+    live = int(counts[held[0]:held[0] + held[1]].sum())
+    assert 0.04 < live / (n * top_k) < 0.09
+    assert int(window_trips(counts, held, 1024)) == 1
+    with_the_window(monkeypatch, None)
+    whole = run()
+    np.testing.assert_allclose(windowed[0], whole[0], rtol=2e-5)
+    for g, w in zip(jax.tree.leaves(windowed[1]), jax.tree.leaves(whole[1])):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-5, atol=2e-5 * float(jnp.max(jnp.abs(w))))
 
 
 @pytest.mark.parametrize("counts,held,window,want", [
@@ -599,10 +702,17 @@ def test_windowed_kernels_land_under_the_scopes_the_readers_look_for(gated):
     assert all("jit(gmm)" in s or "jit(tgmm)" in s for s in experts)
     assert all("while/body" in s for s in stacks_)
     act = "bps_moe_gate" if gated else "bps_moe_act"
+    # the token-order sum: the combine's (forward loop, and the forward the
+    # backward loop's ``jax.vjp`` traces: dead there, XLA drops it) and the
+    # dispatch's backward
+    sums = [s for s in own if s.split("/")[-2] == "bps_moe_sum"]
+    own = [s for s in own if s not in sums]
+    assert sorted(re.search(r"bps\.moe\.(\w+)\)*/jit\(_sum_rows\)", s).group(1)
+                  for s in sums) == ["combine", "combine", "dispatch"], sums
     assert sorted(s.split("/")[-2] for s in own) == sorted(
         ["bps_moe_spread"] * 2 + [act] * 2 + [act + "_bwd",
                                               "bps_moe_spread_scaled"]), own
-    assert not any("gmm)" in s for s in own)
+    assert not any("gmm)" in s for s in own + sums)
     for s in own:
         scope = {"bps_moe_spread": "dispatch", "bps_moe_spread_scaled":
                  "combine"}.get(s.split("/")[-2], "gate" if gated else "act")

@@ -146,7 +146,8 @@ def test_thin_held_share_compiles_for_a_v5e_in_windows(one_chip):
     the kernels under ``bps.moe.experts`` — what ``latent_moe_ms`` sums —
     are the forward loop's two and the backward loop's two recomputed,
     two row gradients and two matrix gradients; the row kernels keep
-    their stages' scopes."""
+    their stages' scopes, the token-order sum (``bps_moe_sum``, PR 49)
+    under the combine forward and the dispatch backward."""
     n, h, f, e, g, k = 8192, 1024, 2688, 512, 8, 22
 
     def shaped(shape, dtype):
@@ -183,8 +184,10 @@ def test_thin_held_share_compiles_for_a_v5e_in_windows(one_chip):
         ("bps.moe.act", "bps_moe_act"), ("bps.moe.act", "bps_moe_act"),
         ("bps.moe.act", "bps_moe_act_bwd"),
         ("bps.moe.combine", "bps_moe_spread_scaled"),
+        ("bps.moe.combine", "bps_moe_sum"),
         ("bps.moe.dispatch", "bps_moe_spread"),
-        ("bps.moe.dispatch", "bps_moe_spread")], own
+        ("bps.moe.dispatch", "bps_moe_spread"),
+        ("bps.moe.dispatch", "bps_moe_sum")], own
     # no array of all 180 224 pair rows is left but the sort's columns
     assert not re.search(r"\[180224,\d+\]", text)
 
@@ -472,8 +475,9 @@ def test_nemotron3_super_cell_step_fits_a_v5e(topo, monkeypatch):
     # forward, two recomputed under ``remat``, and the layer's backward —
     # its own forward again (two) and four gradients; the activation three
     # times and its backward; the spread three times and the scaled spread;
-    # and OUTSIDE the loops the selection and its recomputation, which
-    # ``route_select_ms`` reads (twelve a step).
+    # the token-order sum three times (the combine's forward and recomputed,
+    # the dispatch's backward: PR 49); and OUTSIDE the loops the selection
+    # and its recomputation, which ``route_select_ms`` reads (twelve a step).
     # ``latent_moe_ms`` reads the ten by this rule, ``jvp(...)`` or not
     experts = [c for c in calls
                if re.search(r"bps\.moe\.experts/.*pallas_call$", c)]
@@ -485,14 +489,15 @@ def test_nemotron3_super_cell_step_fits_a_v5e(topo, monkeypatch):
     assert not any("bps_moe_gate" in c for c in calls)     # no gate
     select = _route_kernels(text)
     assert len(select) == 6 * 2
-    assert len(calls) == 15 + 8 + 6 * (10 + 4 + 4 + 2)
+    assert sum(c.endswith("bps_moe_sum/pallas_call") for c in calls) == 18
+    assert len(calls) == 15 + 8 + 6 * (10 + 4 + 4 + 3 + 2)
     moe = [c for c in calls if "bps.moe." in c and c not in select]
     assert all("/while/body/" in c for c in moe)
     assert not any("/while/body/" in c for c in select)
-    # the module's block: 4 flash calls, its experts' eighteen kernels, its
-    # selection twice
+    # the module's block: 4 flash calls, its experts' twenty-one kernels
+    # (three of them the token-order sum), its selection twice
     assert sum(bool(re.search(r"/mtp/.*pallas_call$", c))
-               for c in calls) == 4 + 18 + 2
+               for c in calls) == 4 + 21 + 2
     # no array of all 180 224 pair rows but the sort's columns
     assert not re.search(r"\[180224,\d+\]", text)
     for scope in ("bps.ssm.in_proj", "bps.ssm.conv", "bps.ssm.gate_norm",
@@ -638,18 +643,25 @@ def test_qwen3_next_80b_cell_step_fits_a_v5e(topo, monkeypatch):
     output stage with the SiLU gate under ``bps.gdn.out`` —, the flash
     kernels at 16 heads of 256 under
     ``attn`` and the selection kernel under ``bps.moe.route`` in all four
-    sparse MLPs; the head's ``[tokens, 18992]`` logits exist only a block
-    at a time; q and k of the scan are never repeated to the value heads
-    and the gate is never broadcast over a head's channels."""
+    sparse MLPs, whose held experts (32 of 512 at top-10: a 16th of 327 680
+    pair rows live) run in windows of 40 960 rows under ``bps.moe.window``
+    (PR 49: ``layer_plan``'s crossover at an eighth) — twelve grouped
+    matmuls a layer as before (three forward, three inside the backward
+    loop's ``jax.vjp``, six backward: the ``remat`` forward of a windowed
+    layer has no consumer and goes) and no ``[327 680, 2048]`` array; the
+    head's ``[tokens, 18992]`` logits exist only a block at a time; q and
+    k of the scan are never repeated to the value heads and the gate is
+    never broadcast over a head's channels."""
     compiled, config, traffic = _compiled_cell_step(
         topo, monkeypatch, "qwen3_next_80b.fused_1c")
     memory = compiled.memory_analysis()
     # weights and two moments: 3 x 625,667,136 x 4 B = 6.99 GiB
     assert 6.95 < memory.argument_size_in_bytes / 2 ** 30 < 7.05
-    # rung (a), 4 x 8 192 positions: arguments 6.99 + temp 7.52 + code
-    # 0.08 = 14.58 GiB at the scan's chunk of 128 (15.72 at 64: twice the
-    # chunk-start states); the peak is the attention layer's sparse MLP in
-    # the backward pass, whole [327 680, 2048] pair-row arrays
+    # rung (a), 4 x 8 192 positions: arguments 6.99 + temp 6.67 + code
+    # 0.21 = 13.87 GiB at the scan's chunk of 128 with the expert layers in
+    # windows (PR 49; on whole [327 680, 2048] pair-row arrays, where the
+    # peak was the attention layer's sparse MLP in the backward pass:
+    # temp 7.17 + code 0.07 = 14.24)
     assert traffic["seqs_per_chip"] == 4
     assert _used_gib(memory) < 15.0
     seqs = traffic["seqs_per_chip"]
@@ -686,6 +698,9 @@ def test_qwen3_next_80b_cell_step_fits_a_v5e(topo, monkeypatch):
     experts = [c for c in calls
                if re.search(r"bps\.moe\.experts/.*pallas_call$", c)]
     assert len(experts) == 4 * 12
+    # in windows: no array of all 327 680 pair rows is left
+    assert "bps.moe.window" in text
+    assert f"bf16[{tokens * config['num_experts_per_tok']},2048]" not in text
     # the scan reads q, k at 16 heads x 128 lanes; nothing repeats them to
     # 32 heads in float32 or lays the gate out a channel
     assert f"f32[{seqs},8192,32,128]" not in text
