@@ -2,6 +2,12 @@
 directory (PyTorch MNIST, synthetic ResNet-50, GluonNLP BERT-large —
 SURVEY.md §6 configs)."""
 
+from .evabyte import (  # noqa: F401
+    EvaByte,
+    EvaByteConfig,
+    evabyte_loss,
+    evabyte_tiny,
+)
 from .glm_lite import (  # noqa: F401
     GlmLite,
     GlmLiteConfig,

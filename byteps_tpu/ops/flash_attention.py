@@ -47,6 +47,15 @@ of zero trips (its DMA still runs).  ``window`` is static: the windowed
 kernels are a second specialisation, and with ``window=None`` every
 kernel's jaxpr is what it was before (tests/test_flash_attention.py
 pins the equation counts: a kernel's size is set-up time).
+A ``stair = (rows, cols)`` is the third static specialisation, for
+``ops/eva_attention.py``'s set of chunk summaries: row i sees key columns
+``c < cols * (i // rows)``, every column of every EARLIER step and none of
+its own — no diagonal (``causal``, a ``window`` and a row offset are
+refused beside it), steps of whole q sub-blocks, so a visited sub-block's
+mask is the tail's one compare against the step's edge
+and the key loops end there.  The kernels return ``(out, lse)`` and the
+backward takes a merged ``lse`` and ``delta``: several key sets under ONE
+softmax are several calls folded by ``_merge``.
 
 Layout notes (Mosaic): all kernel operands are [BH, T, D] with D padded
 to a lane multiple (128) and T padded to whole sub-blocks; the per-row
@@ -141,32 +150,71 @@ def _div(a, b):
     return a // b if _ints(a, b) else jax.lax.div(a, b)
 
 
-def _live_keys(row0, bq, col0, n, bk, kv_len, causal, window=None):
+def _stair_limit(row0, stair):
+    """Key columns the q sub-block that starts at ``row0`` sees under a
+    staircase ``(rows, cols)``: row i sees columns ``c < cols * (i //
+    rows)``.  A sub-block never straddles a step (``rows`` is a multiple
+    of ``block_q``: ``_check_stair``), so one number serves all its rows."""
+    rows, cols = stair
+    return cols * _div(row0, rows)
+
+
+def _check_stair(stair, bq, causal, window, q_off):
+    """A staircase is its own mask: ``_live_queries`` and ``_stair_limit``
+    count rows from 0 and know no diagonal, so what would make the
+    schedule disagree with ``_mask`` is refused."""
+    if stair is None:
+        return
+    if min(stair) < 1 or stair[0] % bq:
+        raise ValueError(
+            f"stair={stair}: a step of {stair[0]} rows must be whole q "
+            f"sub-blocks of {bq}, and both edges at least 1")
+    if causal or window is not None or not (
+            isinstance(q_off, int) and q_off == 0):
+        raise ValueError(
+            f"stair={stair} with causal={causal}, window={window}, "
+            f"q_off={q_off}: a staircase has no diagonal, no band and "
+            f"counts its rows from the static 0")
+
+
+def _live_keys(row0, bq, col0, n, bk, kv_len, causal, window=None,
+               stair=None):
     """``(lo, hi)``: of the ``n`` key sub-blocks ``[col0 + j*bk, +bk)``
     those with ``lo <= j < hi`` hold a live score for q rows ``[row0,
     row0 + bq)``.  From ``hi`` on they lie wholly above the diagonal or in
     the padded key tail; under a ``window`` the first ``lo`` lie wholly
     before the first row's window (the sub-block that holds key ``row0 -
-    window + 1`` is the first visited).  ``lo`` may pass ``hi``: nothing
-    is live."""
+    window + 1`` is the first visited); under a ``stair`` those from
+    ``hi`` on lie wholly past the rows' step.  ``lo`` may pass ``hi``:
+    nothing is live."""
     hi = _min(n, _div(_max(kv_len - col0 + bk - 1, 0), bk))
     if causal:
         hi = _min(hi, _div(_max(row0 + bq - 1 - col0 + bk, 0), bk))
+    if stair is not None:
+        hi = _min(hi, _div(_max(_stair_limit(row0, stair) - col0 + bk - 1,
+                                0), bk))
     if window is None:
         return 0, hi
     return _min(n, _div(_max(row0 - window + 1 - col0, 0), bk)), hi
 
 
-def _live_queries(col0, bk, row0, n, bq, tail, causal, window=None):
+def _live_queries(col0, bk, row0, n, bq, tail, causal, window=None,
+                  stair=None):
     """The mirror, for dK/dV: ``(lo, hi)``, of the ``n`` q sub-blocks
     ``[row0 + i*bq, +bq)`` those with ``lo <= i < hi`` hold a live score
     against key columns ``[col0, col0 + bk)``.  The first ``lo`` lie
     above the diagonal (all ``n`` where the columns lie wholly in the
     padded tail); under a ``window`` those from ``hi`` on have left the
     columns behind (their first row's window starts past the last
-    column).  The same set of sub-blocks as ``_live_keys`` leaves, cut by
-    columns: tests/test_flash_attention.py holds the two to each other."""
+    column); under a ``stair`` the first ``lo`` lie on steps that end
+    before the first column.  The same set of sub-blocks as ``_live_keys``
+    leaves, cut by columns: tests/test_flash_attention.py holds the two to
+    each other."""
     lo = _min(n, _div(_max(col0 - row0, 0), bq)) if causal else 0
+    if stair is not None:
+        # the first row that sees column col0: rows * (col0 // cols + 1)
+        rows, cols = stair
+        lo = _min(n, _div(_max(rows * (_div(col0, cols) + 1) - row0, 0), bq))
     if tail is not None:
         # 1 where col0 >= tail, else 0
         lo = _max(lo, n * _min(_div(_max(col0 - tail + bk, 0), bk), 1))
@@ -177,7 +225,8 @@ def _live_queries(col0, bk, row0, n, bq, tail, causal, window=None):
 
 def block_schedule(tq: int, tk: int, causal: bool, q_off: int = 0, *,
                    block_q: int = _SUB, block_k: int = _SUB,
-                   window: Optional[int] = None) -> dict:
+                   window: Optional[int] = None,
+                   stair: Optional[tuple] = None) -> dict:
     """Score sub-blocks the kernels run at this shape: ``{"visited",
     "total", "needed"}``.
 
@@ -188,15 +237,18 @@ def block_schedule(tq: int, tk: int, causal: bool, q_off: int = 0, *,
     loops); dK/dV cuts the same set by key columns (``_live_queries``).  ``q_off`` is the global row of
     the first q row against key column 0 (``Tk - Tq`` for the public
     entry's decode alignment); ``window`` as ``flash_attention`` takes
-    it.  Pure arithmetic on the shapes — the
+    it; ``stair = (rows, cols)`` the staircase of ``ops/eva_attention.py``
+    (row i sees columns ``c < cols * (i // rows)``; not causal, ``rows``
+    whole q sub-blocks).  Pure arithmetic on the shapes — the
     manner of ``parallel.collective_schedule``: it reads, and changes no
     program."""
     bq, bk, tq_p, tk_p = _blocks(tq, tk, block_q, block_k)
+    _check_stair(stair, bq, causal, window, q_off)
     nq, nk = tq_p // bq, tk_p // bk
     visited = needed = 0
     for i in range(nq):
         row0 = q_off + i * bq
-        lo, hi = _live_keys(row0, bq, 0, nk, bk, tk, causal, window)
+        lo, hi = _live_keys(row0, bq, 0, nk, bk, tk, causal, window, stair)
         visited += max(hi - lo, 0)
         last_row = q_off + min((i + 1) * bq, tq) - 1
         for j in range(nk):
@@ -206,7 +258,10 @@ def block_schedule(tq: int, tk: int, causal: bool, q_off: int = 0, *,
             first_r = max(row0, first_col) if causal else row0
             last_r = last_row if window is None else min(
                 last_row, last_col + window - 1)
-            needed += first_col < tk and first_r <= last_r
+            seen = first_col < tk and first_r <= last_r
+            if stair is not None:
+                seen = seen and first_col < _stair_limit(row0, stair)
+            needed += seen
     return {"visited": visited, "total": nq * nk, "needed": needed}
 
 
@@ -222,10 +277,12 @@ def _blocks(tq, tk, block_q, block_k):
     return bq, bk, _ceil_to(tq, bq), _ceil_to(tk, bk)
 
 
-def _mask(s, row0, col0, tail, causal, window=None):
-    """Causal, window and key-tail masks of one score sub-block whose
-    corner is ``(row0, col0)``.  ``tail`` is the key length where the
-    keys are padded beyond it, None where they are not."""
+def _mask(s, row0, col0, tail, causal, window=None, stair=None):
+    """Causal, window, staircase and key-tail masks of one score sub-block
+    whose corner is ``(row0, col0)``.  ``tail`` is the key length where the
+    keys are padded beyond it, None where they are not.  A sub-block lies
+    on ONE step of a ``stair``: its columns up to the step's edge are live
+    for every row, one compare a score as the tail's."""
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = None
     if causal:
@@ -236,6 +293,9 @@ def _mask(s, row0, col0, tail, causal, window=None):
     if tail is not None:
         inside = col < (tail - col0)
         valid = inside if valid is None else valid & inside
+    if stair is not None:
+        under = col < (_stair_limit(row0, stair) - col0)
+        valid = under if valid is None else valid & under
     return s if valid is None else jnp.where(valid, s, _NEG)
 
 
@@ -254,11 +314,12 @@ def _loop(lo, hi, body):
 
 
 def _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, window,
-                   body):
+                   body, stair=None):
     """``body(cols, col0)`` over the key sub-blocks of one resident span
     that hold a live score for q rows ``[row0, +bq)``."""
     n = sk // bk
-    lo, hi = _live_keys(row0, bq, col_base, n, bk, kv_len, causal, window)
+    lo, hi = _live_keys(row0, bq, col_base, n, bk, kv_len, causal, window,
+                        stair)
 
     def at(j):
         c = _at(j, bk, n)
@@ -291,7 +352,7 @@ def _scores(q, k, scale):
 
 def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, kv_len, tail, bq, bk,
-                window=None):
+                window=None, stair=None):
     sq, sk = q_ref.shape[1], k_ref.shape[1]
     ik, nk = pl.program_id(2), pl.num_programs(2)
     row_base = qoff_ref[0] + pl.program_id(1) * sq
@@ -312,7 +373,7 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         def attend(cols, col0):
             v = v_ref[0, cols, :]
             s = _mask(_scores(q_ref[0, rows, :], k_ref[0, cols, :], scale),
-                      row0, col0, tail, causal, window)
+                      row0, col0, tail, causal, window, stair)
             m_prev = m_scr[rows, :1]                       # (bq, 1)
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             if window is not None:
@@ -332,7 +393,7 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             m_scr[rows, :] = jnp.broadcast_to(m_new, (bq, _LANES))
 
         _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, window,
-                       attend)
+                       attend, stair)
 
         @pl.when(ik == nk - 1)
         def _finish():
@@ -358,10 +419,13 @@ def _call(kern, grid, in_specs, out_specs, out_shape, scratch, interpret):
 
 
 def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret,
-         window=None):
+         window=None, stair=None):
     """[BH, Tq, D] x [BH, Tk, D] (padded to whole ``bq`` / ``bk``
     sub-blocks) -> (out, lse[BH, Tq, 128]); ``v`` [BH, Tk, Dv] and the
-    output keep v's width."""
+    output keep v's width.  A row no key reaches (a ring block wholly in
+    the future, a ``stair``'s first step) comes back as out 0, lse ~
+    -1e30: weight 0 in ``_merge``."""
+    _check_stair(stair, bq, causal, window, q_off)
     from jax.experimental.pallas import tpu as pltpu
     bh, tq, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
@@ -370,7 +434,7 @@ def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret,
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              kv_len=kv_len, tail=_tail(kv_len, tk),
-                             bq=bq, bk=bk, window=window)
+                             bq=bq, bk=bk, window=window, stair=stair)
     return _call(
         kern, (bh, nq, nk),
         [
@@ -400,10 +464,11 @@ def _fwd(q, k, v, scale, causal, q_off, kv_len, bq, bk, interpret,
 # --------------------------------------------------------------------------
 
 def _p_ds(q, k, v, do, lse, delta, scale, row0, col0, tail, causal,
-          window=None):
+          window=None, stair=None):
     """Recompute the probabilities of the sub-block at (row0, col0) from
     (q, k, lse), and dS = P * (dO V^T - delta) * scale."""
-    s = _mask(_scores(q, k, scale), row0, col0, tail, causal, window)
+    s = _mask(_scores(q, k, scale), row0, col0, tail, causal, window,
+              stair)
     p = jnp.exp(s - lse)                                   # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -412,7 +477,7 @@ def _p_ds(q, k, v, do, lse, delta, scale, row0, col0, tail, causal,
 
 def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *refs, scale, causal, kv_len, tail, bq, bk,
-                    fused, window=None):
+                    fused, window=None, stair=None):
     """dK/dV of one k/v span against the resident q side, looped from the
     diagonal on.  ``fused`` (the whole q side is resident): dQ too, in a
     float32 scratch that lives across the head's k/v spans, so P and dS
@@ -449,7 +514,7 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             p, ds = _p_ds(
                 q, k, v_ref[0, cols, :], do,
                 lse_ref[0, rows, :1], delta_ref[0, rows, :1], scale,
-                row_base + r, col0, tail, causal, window)
+                row_base + r, col0, tail, causal, window, stair)
             ds = ds.astype(q.dtype)
             # dV += P^T dO; dK += dS^T Q; dQ += dS K
             dv_scr[cols, :] += jax.lax.dot_general(
@@ -464,7 +529,7 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     preferred_element_type=jnp.float32)
 
         _loop(*_live_queries(col0, bk, row_base, n, bq, tail, causal,
-                             window), accum)
+                             window, stair), accum)
 
         @pl.when(iq == nq - 1)
         def _finish():
@@ -481,7 +546,8 @@ def _bwd_dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_scr,
-                   *, scale, causal, kv_len, tail, bq, bk, window=None):
+                   *, scale, causal, kv_len, tail, bq, bk, window=None,
+                   stair=None):
     sq, sk = q_ref.shape[1], k_ref.shape[1]
     ik, nk = pl.program_id(2), pl.num_programs(2)
     row_base = qoff_ref[0] + pl.program_id(1) * sq
@@ -501,13 +567,13 @@ def _bwd_dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             _, ds = _p_ds(
                 q_ref[0, rows, :], k, v_ref[0, cols, :], do_ref[0, rows, :],
                 lse_ref[0, rows, :1], delta_ref[0, rows, :1], scale,
-                row0, col0, tail, causal, window)
+                row0, col0, tail, causal, window, stair)
             dq_scr[rows, :] += jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
         _for_live_keys(row0, bq, col_base, sk, bk, kv_len, causal, window,
-                       accum)
+                       accum, stair)
 
         @pl.when(ik == nk - 1)
         def _finish():
@@ -532,15 +598,35 @@ def _delta(do, out):
     return jnp.broadcast_to(delta, (bh, tq, _LANES))
 
 
+def _merge(o_acc, lse_acc, o_b, lse_b):
+    """Fold one key set's normalized output into the running accumulator.
+
+    Both inputs carry (normalized output, lse); the combine is the usual
+    two-term log-sum-exp: weights exp(lse - m) renormalize each side.
+    Fully-masked blocks come back with lse ~= -1e30 and weight exactly 0.
+    (``parallel/ring_flash.py`` folds a ring's blocks with it,
+    ``ops/eva_attention.py`` a window's keys and the summaries.)
+    """
+    m = jnp.maximum(lse_acc, lse_b)
+    wa = jnp.exp(lse_acc - m)[:, :, :1]
+    wb = jnp.exp(lse_b - m)[:, :, :1]
+    denom = jnp.maximum(wa + wb, 1e-30)
+    o_new = (o_acc * wa + o_b.astype(jnp.float32) * wb) / denom
+    lse_new = m + jnp.log(denom)
+    return o_new, lse_new
+
+
 def _bwd_impl(q, k, v, do, lse, delta, scale, causal, q_off, kv_len,
-              bq, bk, interpret, window=None):
+              bq, bk, interpret, window=None, stair=None):
     from jax.experimental.pallas import tpu as pltpu
+    _check_stair(stair, bq, causal, window, q_off)
     bh, tq, d = q.shape
     tk, d_v = k.shape[1], v.shape[2]    # v, dO and dV keep v's width
     (sq, rq), (sk, rk) = _spans(tq, tk, d, q.dtype.itemsize, bq, bk)
     qoff = jnp.asarray(q_off, jnp.int32).reshape(1)
     static = dict(scale=scale, causal=causal, kv_len=kv_len,
-                  tail=_tail(kv_len, tk), bq=bq, bk=bk, window=window)
+                  tail=_tail(kv_len, tk), bq=bq, bk=bk, window=window,
+                  stair=stair)
     # one kernel, five matmuls a sub-block, where the whole q side (q, dO,
     # lse, delta and a float32 dQ) fits in VMEM beside a k/v span; two
     # kernels, seven, where the context is too long for that

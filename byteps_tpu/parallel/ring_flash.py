@@ -47,27 +47,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.flash_attention import (_LANES, _NEG, _SUB, _bwd_impl, _ceil_to,
-                                   _delta, _fwd)
+                                   _delta, _fwd, _merge)
 from ..ops.pallas_kernels import on_tpu
 from .sequence import SP_AXIS
 
 __all__ = ["ring_flash_attention"]
-
-
-def _merge(o_acc, lse_acc, o_b, lse_b):
-    """Fold one block's normalized output into the running accumulator.
-
-    Both inputs carry (normalized output, lse); the combine is the usual
-    two-term log-sum-exp: weights exp(lse - m) renormalize each side.
-    Fully-masked blocks come back with lse ~= -1e30 and weight exactly 0.
-    """
-    m = jnp.maximum(lse_acc, lse_b)
-    wa = jnp.exp(lse_acc - m)[:, :, :1]
-    wb = jnp.exp(lse_b - m)[:, :, :1]
-    denom = jnp.maximum(wa + wb, 1e-30)
-    o_new = (o_acc * wa + o_b.astype(jnp.float32) * wb) / denom
-    lse_new = m + jnp.log(denom)
-    return o_new, lse_new
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))

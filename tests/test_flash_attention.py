@@ -616,3 +616,159 @@ def test_equal_widths_trace_to_the_programs_they_were(shape, dtype, causal,
         flash_attention(q, k, v, causal=causal, interpret=False)
         .astype(jnp.float32)), (0, 1, 2)))(q, q, q)
     assert equations(program.jaxpr) == want
+
+
+# ------------------------------------------------------------ staircase
+
+def _stair_live(tq, tk, bq, bk, stair):
+    """Sub-blocks of the padded rectangle with at least one score the
+    staircase leaves — column c < cols * (row // rows) — from the mask."""
+    bq, bk, tq_p, tk_p = _fa._blocks(tq, tk, bq, bk)
+    rows, cols = stair
+    row = np.arange(tq_p)[:, None]
+    col = np.arange(tk_p)[None, :]
+    live = (row < tq) & (col < tk) & (col < cols * (row // rows))
+    return live.reshape(tq_p // bq, bq, tk_p // bk, bk).any(axis=(1, 3))
+
+
+STAIRS = [
+    # tq, tk, block_q, block_k, (rows, cols)
+    (8192, 512, 512, 512, (2048, 128)),     # evabyte_6b5.fused_1c
+    (8192, 512, 512, 128, (2048, 128)),     # ... a step-wide key sub-block
+    (16384, 1024, 512, 512, (2048, 128)),   # the ladder's rung (a)
+    (12288, 768, 512, 512, (2048, 128)),    # rung (b): a padded key tail
+    (256, 32, 64, 8, (64, 8)),              # the CPU tests' size
+    (384, 48, 32, 16, (64, 8)),
+    (384, 48, 64, 48, (64, 8)),
+    (256, 40, 32, 16, (128, 20))]           # steps no multiple of a sub-block
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,stair", STAIRS)
+def test_stair_schedule_against_the_mask(tq, tk, bq, bk, stair):
+    """``block_schedule`` under a staircase against a brute-force count
+    from the mask, and the dK/dV mirror (``_live_queries``) against the
+    rows' rule (``_live_keys``): one set, which holds every sub-block the
+    mask leaves a score in; ``needed == visited`` where a step's edge is a
+    sub-block's."""
+    live = _stair_live(tq, tk, bq, bk, stair)
+    got = block_schedule(tq, tk, False, block_q=bq, block_k=bk, stair=stair)
+    assert (got["needed"], got["total"]) == (int(live.sum()), live.size)
+    bq, bk, tq_p, tk_p = _fa._blocks(tq, tk, bq, bk)
+    nq, nk = tq_p // bq, tk_p // bk
+    by_rows = {(i, j) for i in range(nq) for j in range(
+        *_fa._live_keys(i * bq, bq, 0, nk, bk, tk, False, None, stair))}
+    by_cols = {(i, j) for j in range(nk) for i in range(
+        *_fa._live_queries(j * bk, bk, 0, nq, bq, _fa._tail(tk, tk_p),
+                           False, None, stair))}
+    assert by_rows == by_cols and got["visited"] == len(by_rows)
+    assert set(zip(*np.nonzero(live))) == by_rows    # nothing dead visited
+    # rows on the first step see nothing: zero trips
+    assert all(_fa._live_keys(i * bq, bq, 0, nk, bk, tk, False, None,
+                              stair)[1] == 0
+               for i in range(stair[0] // bq))
+
+
+def test_stair_schedule_at_the_evabyte_cell():
+    """8 192 rows against 512 summaries, steps of 2 048 x 128: with the
+    default 512-wide key sub-block the 12 row blocks past window 0 each
+    visit the one key sub-block (12 of 16; up to 3/4 of it masked), with a
+    step-wide one (128) they visit 4 x (1 + 2 + 3) = 24 of 64."""
+    assert block_schedule(8192, 512, False, stair=(2048, 128)) == {
+        "visited": 12, "total": 16, "needed": 12}
+    assert block_schedule(8192, 512, False, block_k=128,
+                          stair=(2048, 128)) == {
+        "visited": 24, "total": 64, "needed": 24}
+
+
+def test_stair_is_whole_q_sub_blocks():
+    with pytest.raises(ValueError, match="whole q sub-blocks"):
+        block_schedule(256, 32, False, block_q=48, stair=(64, 8))
+    q = jnp.zeros((1, 256, 128), jnp.float32)
+    with pytest.raises(ValueError, match="whole q sub-blocks"):
+        _fa._fwd(q, q[:, :32], q[:, :32], 1.0, False, 0, 32, 48, 32, True,
+                 stair=(64, 8))
+
+
+@pytest.mark.parametrize("causal,window,q_off", [
+    (True, None, 0), (False, 64, 0), (False, None, 64),
+    (False, None, jnp.zeros((), jnp.int32))],
+    ids=["causal", "window", "q_off", "runtime_q_off"])
+def test_stair_refuses_a_diagonal_a_band_and_a_row_offset(causal, window,
+                                                          q_off):
+    """``_live_queries`` under a staircase replaces the causal bound and
+    ``_stair_limit`` counts rows from 0: a schedule that could disagree
+    with ``_mask`` is refused, in the arithmetic and in both kernels."""
+    q = jnp.zeros((1, 256, 128), jnp.float32)
+    k = q[:, :32]
+    with pytest.raises(ValueError, match="no diagonal, no band"):
+        _fa._fwd(q, k, k, 1.0, causal, q_off, 32, 64, 32, True,
+                 window=window, stair=(64, 8))
+    with pytest.raises(ValueError, match="no diagonal, no band"):
+        _fa._bwd_impl(q, k, k, q, q, q, 1.0, causal, q_off, 32, 64, 32,
+                      True, window=window, stair=(64, 8))
+    if isinstance(q_off, int):
+        with pytest.raises(ValueError, match="no diagonal, no band"):
+            block_schedule(256, 32, causal, q_off, block_q=64, block_k=32,
+                           window=window, stair=(64, 8))
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,stair", STAIRS[4:])
+def test_stair_kernels_match_the_masked_softmax(tq, tk, bq, bk, stair, form):
+    """``_fwd`` and ``_bwd_impl`` under a staircase against the plain
+    masked softmax: out, lse (rows of the first step: out 0, lse -1e30)
+    and dQ, dK, dV under the call's own lse."""
+    d = 16
+    q, do = (_rand((2, tq, d), jnp.float32, s) for s in (60, 63))
+    k, v = (_rand((2, tk, d), jnp.float32, s) for s in (61, 62))
+    bq, bk, tq_p, tk_p = _fa._blocks(tq, tk, bq, bk)
+    rows, cols = stair
+
+    def pad(x, t_p):
+        return jnp.pad(x, ((0, 0), (0, t_p - x.shape[1]), (0, 128 - d)))
+
+    args = (pad(q, tq_p), pad(k, tk_p), pad(v, tk_p))
+    out, lse = _fa._fwd(*args, 0.25, False, 0, tk, bq, bk, True, stair=stair)
+    seen = (np.arange(tk)[None, :]
+            < cols * (np.arange(tq)[:, None] // rows))
+
+    def plain(q, k, v):
+        s = jnp.where(seen, jnp.einsum("bqd,bkd->bqk", q, k) * 0.25,
+                      -jnp.inf)
+        p = jnp.where(seen, jax.nn.softmax(s, -1), 0.0)  # first step: 0 / 0
+        return jnp.einsum("bqk,bkd->bqd", p, v)
+
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(plain, q, k, v)
+        want_grads = vjp(do)
+    np.testing.assert_allclose(np.asarray(out[:, :tq, :d]), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    assert np.all(np.asarray(out[:, :rows]) == 0)
+    assert np.all(np.asarray(lse[:, :rows]) < -1e29)
+    got = _fa._bwd_impl(*args, pad(do, tq_p), lse,
+                        _fa._delta(pad(do, tq_p), out), 0.25, False, 0, tk,
+                        bq, bk, True, stair=stair)
+    for g, w, t in zip(got, want_grads, (tq, tk, tk)):
+        np.testing.assert_allclose(np.asarray(g[:, :t, :d]), np.asarray(w),
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_stair_none_leaves_the_kernels_jaxprs_as_they_were():
+    """``stair=None`` is every kernel as it was (the pinned counts above
+    are untouched); the staircase specialisation adds its few equations
+    (one compare a mask, one bound a loop)."""
+    q = jnp.zeros((1, 8192, 128), jnp.bfloat16)
+    k = jnp.zeros((1, 512, 128), jnp.bfloat16)
+    lse = jnp.zeros((1, 8192, 128), jnp.float32)
+
+    def counts(stair):
+        return (kernel_equations(
+            lambda q, k: _fa._fwd(q, k, k, 0.1, False, 0, 512, 512, 512,
+                                  False, stair=stair), q, k)
+            + kernel_equations(
+                lambda q, k, lse: _fa._bwd_impl(
+                    q, k, k, q, lse, lse, 0.1, False, 0, 512, 512, 512,
+                    False, stair=stair), q, k, lse))
+
+    plain, stairs = counts(None), counts((2048, 128))
+    assert len(plain) == len(stairs) == 3          # forward, dK/dV, dQ
+    assert all(0 < s - p <= 16 for s, p in zip(stairs, plain))

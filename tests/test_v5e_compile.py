@@ -708,3 +708,119 @@ def test_qwen3_next_80b_cell_step_fits_a_v5e(topo, monkeypatch):
                   "bps.gdn.out", "bps.attn.gate", "bps.moe.route",
                   "bps.moe.shared", "mixer_gdn", "attn"):
         assert scope in text, scope
+
+
+# ------------------------------------------------- EVA attention (PR 50)
+
+EVABYTE_CELL = "evabyte_6b5.fused_1c"
+
+
+@pytest.mark.parametrize("summary_block_k", [512, 128])
+def test_eva_attention_compiles_for_a_v5e(one_chip, summary_block_k):
+    """``ops/eva_attention.py`` forward and backward at the cell's ``(1, T,
+    32, 128)``, T = 16 384: Mosaic takes the flash kernels under the
+    staircase mask (a scalar division of the runtime row offset, one more
+    compare a score) beside the own-window causal calls at T = 2 048 in a
+    batch of 256 — five kernels: each set's forward, the windows'
+    one-kernel backward, the summaries' two-kernel one — and no ``[T,
+    T / 16]`` or ``[T, 2048]`` score array exists outside them.
+    ``summary_block_k`` is the tests' hook: 512 is what runs, 128 (a
+    step's width, the narrower sub-block ISSUE 50 asked to try) compiles
+    too."""
+    import importlib
+    eva = importlib.import_module("byteps_tpu.ops.eva_attention")
+    t = 16384
+    x = jax.ShapeDtypeStruct((1, t, 32, 128), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((32, 128), jnp.float32, sharding=one_chip)
+
+    def objective(q, k, v, mu, phi):
+        return jnp.sum(eva.eva_attention(
+            q, k, v, mu, phi, window=2048, chunk=16,
+            summary_block_k=summary_block_k, interpret=False
+        ).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(objective, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, w, w).compile().as_text()
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("bps.eva.local" in c for c in calls) == 2
+    assert sum("bps.eva.summary" in c for c in calls) == 3
+    assert len(calls) == 5
+    for scores in (f"[32,{t},{t // 16}]", f"[32,{t},2048]",
+                   f"[256,2048,2048]", f"[1,32,{t},{t // 16}]"):
+        assert scores not in text
+
+
+def test_evabyte_cell_step_fits_a_v5e(topo, monkeypatch):
+    """``evabyte_6b5.fused_1c``'s step at the published widths on the rung
+    of ISSUE 50's memory ladder the cell takes (16 384 positions): under
+    15.0 GiB; seven flash kernels a layer under ``attn`` — the own-window
+    set and the summary set forward, both again under ``remat``, the
+    windows' one-kernel backward and the summaries' two-kernel one; the
+    block inputs a ``remat`` keeps are float32; no score array of either
+    key set and no ``[tokens, 8 x 320]`` logits whole in float32 beside
+    their blocks."""
+    compiled, config, traffic = _compiled_cell_step(topo, monkeypatch,
+                                                    EVABYTE_CELL)
+    memory = compiled.memory_analysis()
+    # weights and two moments: 3 x 821,366,784 x 4 B = 9.18 GiB
+    assert 9.15 < memory.argument_size_in_bytes / 2 ** 30 < 9.21
+    # rung (a): arguments 9.18 + temp 4.70 + code 0.03 = 13.91 GiB
+    assert traffic["seq_len"] == 16384 and traffic["seqs_per_chip"] == 1
+    assert _used_gib(memory) < 15.0
+    text = compiled.as_text()
+    t = traffic["seq_len"]
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4 * 7
+    for layer in range(4):
+        here = [c for c in calls if f"/h{layer}/attn/" in c]
+        assert sum(c.endswith("bps.eva.local/pallas_call")
+                   for c in here) == 3
+        assert sum(c.endswith("bps.eva.summary/pallas_call")
+                   for c in here) == 4
+        assert sum("rematted_computation" in c for c in here) == 2
+    for scores in (f"[32,{t},{t // 16}]", f"[32,{t},2048]",
+                   f"[{t // 64},2048,2048]"):
+        assert scores not in text
+    for scope in ("bps.eva.pool", "bps.eva.merge", "bps.eva.rope",
+                  "bps.head"):
+        assert scope in text
+    assert f"f32[1,{t},4096]" in text                # the residual stream
+
+
+def test_evabyte_reference_fits_beside_the_harness_s_state(topo, monkeypatch):
+    """The float32 reference of ``evabyte_6b5.fused_1c`` is what memory
+    decides: ``value_and_grad(reference_loss)`` runs beside the harness's
+    parameters, their gradient and two moments (4 x 3.06 = 12.24 GiB), so
+    its temp + code stay under 15.0 - 12.24 GiB at the rung taken (one head
+    at a time from its projections to its ``W_o`` product: 2.06 GiB at
+    16 384 positions; all heads' q, k, v side by side read 3.77 at
+    8 192)."""
+    import os
+    monkeypatch.syspath_prepend(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks"))
+    from harness import spec
+    found = spec.resolve(spec.load_benchmark(), EVABYTE_CELL)
+    config, traffic = found["config"], found["traffic"]
+    family = spec.load_module("families", config["family"]).build(
+        config, traffic)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(family.init_params, key)
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1), key)
+    with jax.default_matmul_precision("highest"):
+        memory = jax.jit(jax.value_and_grad(family.reference_loss)).lower(
+            shaped(params), shaped(batch)).compile().memory_analysis()
+    state = 4 * memory.argument_size_in_bytes / 2 ** 30
+    assert 12.2 < state < 12.3
+    assert state + (memory.temp_size_in_bytes
+                    + memory.generated_code_size_in_bytes) / 2 ** 30 < 15.0
